@@ -1,0 +1,836 @@
+#!/usr/bin/env python3
+"""chip_smoke.py — the quickest proof that the system still starts on the chip.
+
+Drives the main path once through the entry points a user calls
+(``distributed_ba3c_tpu.cli.main``, i.e. ``train.py``) at the full width of
+the one model the repo has — ``BA3CNet`` on 84x84x4 uint8 frames, convs
+32/32/64/64, ``fc_units`` 512 — with seeded random weights, and checks what
+comes out by the repo's own means. It measures nothing: compile and step
+seconds are printed as information, and no rate is published from here.
+
+Phases (each fails the run; nothing is caught):
+
+``fused``     ``--trainer tpu_fused_ba3c --env jax:pong`` at 128 envs x 20 per
+              chip: two short epochs, the on-device greedy evaluator, an
+              orbax checkpoint per epoch, then a ``--load`` resume that must
+              continue the step counter.
+``plane``     ``--trainer tpu_vtrace_ba3c --env cpp:pong`` on the default wire
+              with staged ingest: C++ env-server children -> BatchedPredictor
+              on the chip -> TrajBlocks -> V-trace learner steps -> params
+              published back. No child may load libtpu; copies per ingested
+              block must be exactly 1; and the staged ingest must give the
+              learner bit-identical losses to plain ``device_put`` on the
+              same seeded blocks while its slots are being reused.
+``forwards``  the rollout forward at all three dtypes (``predict.server``,
+              ``_bf16``, ``_int8``) answering batches of 256 inside the
+              repo's parity bands of the f32 forward; the int8 arm ``auto``
+              resolved to; the Pallas conv blocks, Mosaic-compiled, against
+              the XLA block.
+``mesh``      only with more than one device: env state and batch sharded
+              over every device, and after K updates every param leaf's
+              replicas bit-identical — the on-chip form of audit rule T3.
+
+Every phase runs under ``BA3C_AUDIT=1``: a registered entry point that
+re-traces after its warm-up kills the run.
+
+One process for each chip: this parent never imports JAX. Each phase is a
+child process (``--phase NAME``) that holds the chip while it runs and has
+released it before the next one starts; JAX's compile cache is shared
+between them through ``utils/backend.py`` (``$JAX_COMPILATION_CACHE_DIR``,
+else ``<repo>/.jax_cache``). On anything but a TPU the first child exits
+nonzero and no result is printed.
+
+Last line of stdout on success::
+
+    {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}
+"""
+
+from __future__ import annotations
+
+import argparse
+import dataclasses
+import json
+import os
+import queue
+import shutil
+import signal
+import subprocess
+import sys
+import tempfile
+import threading
+import time
+from typing import Dict, List, Optional
+
+#: the contract's limit is 1200 s, compilation included
+TOTAL_BUDGET_S = 1150.0
+
+#: parity bands of the rollout-forward ladder vs the f32 forward — the same
+#: numbers tests/test_quantize.py and tests/test_staging.py hold the bf16 and
+#: int8 rungs to (log mu(a|s) and V(s), max abs over the batch)
+BAND_LOG_MU = 0.1
+BAND_VALUE = 0.05
+
+
+@dataclasses.dataclass(frozen=True)
+class Shape:
+    """One size of the smoke. ``FULL`` is the model's real width (the chip
+    run); ``SMALL`` is what tests/test_chip_smoke.py drives on the CPU mesh
+    through the same phase functions."""
+
+    fc_units: int
+    # fused trainer (jax:pong renders 84x84 at every size)
+    envs_per_chip: int
+    rollout_len: int
+    fused_steps_per_epoch: int
+    nr_eval: int
+    eval_max_steps: int       # >= one Pong episode at FULL, or no eval score
+    # actor plane
+    plane_env: str
+    plane_image_size: Optional[int]   # None = the env's own 84x84
+    plane_envs: int
+    plane_batch: int
+    plane_steps_per_epoch: int    # StatPrinter samples the loss every 20 steps
+    staging_blocks: int
+    # rollout forwards
+    serve_batch: int
+    check_pallas: bool        # Mosaic-compiled on a chip; interpreted, slow, on CPU
+
+
+FULL = Shape(
+    fc_units=512,
+    envs_per_chip=128, rollout_len=20, fused_steps_per_epoch=16,
+    nr_eval=8, eval_max_steps=3000,
+    plane_env="cpp:pong", plane_image_size=None, plane_envs=64,
+    plane_batch=128, plane_steps_per_epoch=20, staging_blocks=12,
+    serve_batch=256, check_pallas=True,
+)
+
+SMALL = Shape(
+    fc_units=16,
+    envs_per_chip=4, rollout_len=2, fused_steps_per_epoch=2,
+    nr_eval=1, eval_max_steps=8,
+    plane_env="fake", plane_image_size=16, plane_envs=4,
+    plane_batch=32, plane_steps_per_epoch=20, staging_blocks=5,
+    serve_batch=8, check_pallas=False,
+)
+
+
+class SmokeFailure(AssertionError):
+    """A phase's check did not hold."""
+
+
+def _check(cond: bool, what: str) -> None:
+    if not cond:
+        raise SmokeFailure(what)
+
+
+def _finite(record: dict, keys) -> None:
+    import math
+
+    for k in keys:
+        _check(
+            k in record and math.isfinite(record[k]),
+            f"stat {k!r} missing or not finite: {record.get(k)!r}",
+        )
+
+
+def _read_stats(logdir: str) -> List[dict]:
+    with open(os.path.join(logdir, "stat.json")) as f:
+        return json.load(f)
+
+
+def _require_device(platform: str) -> Dict[str, object]:
+    """The device as JAX reports it; anything but ``platform`` ends the
+    phase before it runs (a smoke that fell back to the CPU proves
+    nothing about the chip)."""
+    from distributed_ba3c_tpu.utils.backend import (
+        configure_compile_cache,
+        device_info,
+    )
+
+    configure_compile_cache()
+    device = device_info()
+    if device["platform"] != platform:
+        raise SystemExit(
+            f"chip_smoke: needs platform {platform!r}, jax found {device}"
+        )
+    return device
+
+
+def _check_run_device(record: dict, device: dict) -> None:
+    _check(
+        record.get("device") == device,
+        f"stat.json's first record names {record.get('device')!r}, "
+        f"the phase ran on {device!r}",
+    )
+
+
+# --------------------------------------------------------------------------
+# phase: fused trainer
+# --------------------------------------------------------------------------
+
+
+def phase_fused(shape: Shape, workdir: str, platform: str = "tpu") -> dict:
+    device = _require_device(platform)  # before the heavy imports: fail fast
+
+    from distributed_ba3c_tpu.cli import main as cli_main
+    from distributed_ba3c_tpu.train.checkpoint import CheckpointManager
+
+    n_chips = int(device["count"])
+    logdir = os.path.join(workdir, "fused")
+    steps = shape.fused_steps_per_epoch
+    argv = [
+        "--trainer", "tpu_fused_ba3c", "--env", "jax:pong",
+        "--batch_size", str(shape.envs_per_chip * shape.rollout_len),
+        "--rollout_len", str(shape.rollout_len),
+        "--fc_units", str(shape.fc_units),
+        "--steps_per_epoch", str(steps),
+        "--nr_eval", str(shape.nr_eval),
+        "--eval_max_steps", str(shape.eval_max_steps),
+        "--logdir", logdir,
+    ]
+    if n_chips > 1:
+        # one line per epoch: the per-leaf digest of the fetched replica
+        os.environ["BA3C_PARAM_DIGEST"] = "1"
+    _check(cli_main(argv + ["--max_epoch", "2"]) == 0, "fused run rc != 0")
+    stats = _read_stats(logdir)
+    _check(len(stats) == 2, f"expected 2 epoch records, got {len(stats)}")
+    _check(
+        [s["global_step"] for s in stats] == [steps, 2 * steps],
+        f"global_step {[s['global_step'] for s in stats]}",
+    )
+    _check_run_device(stats[0], device)
+    for s in stats:
+        _finite(s, ("loss", "policy_loss", "value_loss", "entropy",
+                    "grad_norm", "fps"))
+    if shape.eval_max_steps >= 1000:
+        # a whole Pong episode fits the horizon: the greedy evaluator must
+        # have scored one (random weights lose, but inside the game's range)
+        _check(
+            -21.0 <= stats[-1].get("eval_mean_score", float("nan")) <= 21.0,
+            f"greedy eval score {stats[-1].get('eval_mean_score')!r}",
+        )
+    ckpt_dir = os.path.join(logdir, "checkpoints")
+    _check(
+        CheckpointManager(ckpt_dir).latest_step == 2 * steps,
+        "no checkpoint at the last step",
+    )
+
+    # resume in the same process, as run_with_resume.sh does in a new one:
+    # --max_epoch is the TOTAL budget, so one more epoch continues at 3
+    _check(
+        cli_main(argv + ["--max_epoch", "3", "--load", ckpt_dir]) == 0,
+        "resume rc != 0",
+    )
+    stats = _read_stats(logdir)
+    _check(
+        [(s["epoch"], s["global_step"]) for s in stats]
+        == [(1, steps), (2, 2 * steps), (3, 3 * steps)],
+        f"resume did not continue the counters: "
+        f"{[(s['epoch'], s['global_step']) for s in stats]}",
+    )
+    _check_run_device(stats[2], device)
+    _finite(stats[2], ("loss", "entropy", "grad_norm"))
+    samples = shape.envs_per_chip * n_chips * shape.rollout_len
+    return {
+        "device": device,
+        "first_dispatch_s": round(stats[0]["first_dispatch_s"], 2),
+        "resume_first_dispatch_s": round(stats[2]["first_dispatch_s"], 2),
+        "steady_step_s": round(samples / stats[1]["fps"], 5),
+        "updates": 3 * steps,
+        "eval_mean_score": stats[-1].get("eval_mean_score"),
+    }
+
+
+# --------------------------------------------------------------------------
+# phase: actor plane with the device in the loop
+# --------------------------------------------------------------------------
+
+
+class _ChildWatch(threading.Thread):
+    """Samples this process's descendants while a phase runs and records any
+    that mapped libtpu: a chip belongs to ONE process, and the children of
+    the process that holds it (env servers, the resource tracker) must never
+    initialise an accelerator back-end."""
+
+    def __init__(self):
+        super().__init__(daemon=True, name="smoke-child-watch")
+        self.seen: Dict[int, str] = {}
+        self.offenders: Dict[int, str] = {}
+        self._stop_evt = threading.Event()
+
+    @staticmethod
+    def _descendants(root: int) -> List[int]:
+        parent: Dict[int, int] = {}
+        for name in os.listdir("/proc"):
+            if not name.isdigit():
+                continue
+            try:
+                with open(f"/proc/{name}/stat") as f:
+                    # "pid (comm) state ppid ...": comm may hold spaces
+                    parent[int(name)] = int(
+                        f.read().rsplit(")", 1)[1].split()[1]
+                    )
+            except (OSError, IndexError, ValueError):
+                continue  # the process exited while we were reading it
+        out, frontier = [], [root]
+        while frontier:
+            p = frontier.pop()
+            kids = [c for c, pp in parent.items() if pp == p]
+            out += kids
+            frontier += kids
+        return out
+
+    def run(self) -> None:
+        while not self._stop_evt.wait(0.25):
+            for pid in self._descendants(os.getpid()):
+                try:
+                    with open(f"/proc/{pid}/cmdline", "rb") as f:
+                        cmd = f.read().replace(b"\0", b" ").decode()[:120]
+                    with open(f"/proc/{pid}/maps") as f:
+                        maps = f.read()
+                except OSError:
+                    continue
+                self.seen[pid] = cmd
+                if "libtpu" in maps:
+                    self.offenders[pid] = cmd
+
+    def stop(self) -> None:
+        self._stop_evt.set()
+        self.join(timeout=5)
+
+
+def _staging_equivalence(shape: Shape) -> dict:
+    """The staged ingest (data/staging.py) against plain ``device_put`` on
+    the same seeded blocks, with TWO slots so every third block rewrites a
+    slot whose transfer may still be in flight. On a TPU ``device_put``
+    returns before it has read the host buffer (measured PR 21), so the
+    ring's ready fence is the only thing between a reused slot and a
+    corrupted batch: equal losses show it holds."""
+    import jax
+    import numpy as np
+
+    from distributed_ba3c_tpu import telemetry
+    from distributed_ba3c_tpu.config import BA3CConfig
+    from distributed_ba3c_tpu.data.staging import DeviceIngest, HostStagingRing
+    from distributed_ba3c_tpu.models.a3c import BA3CNet
+    from distributed_ba3c_tpu.ops.gradproc import make_optimizer
+    from distributed_ba3c_tpu.parallel.mesh import make_mesh
+    from distributed_ba3c_tpu.parallel.train_step import create_train_state
+    from distributed_ba3c_tpu.parallel.vtrace_step import make_vtrace_train_step
+
+    size = shape.plane_image_size or 84
+    cfg = BA3CConfig(fc_units=shape.fc_units, image_size=(size, size))
+    n_actions = cfg.num_actions
+    model = BA3CNet(num_actions=cfg.num_actions, fc_units=cfg.fc_units)
+    opt = make_optimizer(cfg.learning_rate, cfg.adam_epsilon, cfg.grad_clip_norm)
+    mesh = make_mesh()
+    step = make_vtrace_train_step(model, opt, cfg, mesh)
+    n_data = mesh.shape["data"]
+    T = cfg.local_time_max
+    B = max(n_data, shape.plane_batch // n_data * n_data)
+    rng = np.random.default_rng(0)
+    blocks = [
+        {
+            "state": rng.integers(0, 255, (T, B, *cfg.state_shape), np.uint8),
+            "action": rng.integers(0, n_actions, (T, B), np.int32),
+            "reward": rng.normal(size=(T, B)).astype(np.float32),
+            "done": (rng.random((T, B)) < 0.05).astype(np.float32),
+            "behavior_log_probs": -rng.random((T, B)).astype(np.float32),
+            "bootstrap_state": rng.integers(
+                0, 255, (B, *cfg.state_shape), np.uint8
+            ),
+        }
+        for _ in range(shape.staging_blocks)
+    ]
+    spec = {k: (v.shape, v.dtype) for k, v in blocks[0].items()}
+
+    def fresh_state():
+        return jax.device_put(
+            create_train_state(jax.random.PRNGKey(0), model, cfg, opt),
+            step.state_sharding,
+        )
+
+    def run_plain():
+        state, losses = fresh_state(), []
+        for blk in blocks:
+            batch = {
+                k: jax.device_put(v, step.batch_sharding[k])
+                for k, v in blk.items()
+            }
+            state, m = step(state, batch, cfg.entropy_beta)
+            losses.append(m["loss"])
+        return np.asarray(jax.device_get(losses))
+
+    def run_staged():
+        ring = HostStagingRing(slots=2)
+        pending = iter(blocks)
+
+        class SeededFeed:
+            def next_batch(self, timeout=None):
+                blk = next(pending, None)
+                if blk is None:
+                    raise queue.Empty
+                slot = ring.acquire(spec, timeout=60.0)
+                _check(slot is not None, "staging ring never freed a slot")
+                for k, v in blk.items():
+                    np.copyto(slot.buffers[k], v)
+                ring.count_staged_copy()
+                return ring.staged(slot)
+
+        ingest = DeviceIngest(SeededFeed(), step.batch_sharding)
+        state, losses = fresh_state(), []
+        for _ in blocks:
+            batch = ingest.next_batch(timeout=60.0)
+            state, m = step(state, batch, cfg.entropy_beta)
+            ingest.prefetch()  # the next block's H2D behind this step
+            losses.append(m["loss"])
+        return np.asarray(jax.device_get(losses))
+
+    tele = telemetry.registry("learner")
+    copies0 = tele.counter("ingest_copies_total").value()
+    blocks0 = tele.counter("ingest_blocks_total").value()
+    waits0 = tele.counter("staging_waits_total").value()
+    staged = run_staged()
+    copies = tele.counter("ingest_copies_total").value() - copies0
+    n_blocks = tele.counter("ingest_blocks_total").value() - blocks0
+    plain = run_plain()
+    _check(bool(np.all(np.isfinite(plain))), f"plain losses {plain}")
+    _check(
+        np.array_equal(staged, plain),
+        f"staged ingest changed the learner's losses:\n{staged}\nvs\n{plain}",
+    )
+    _check(
+        copies == n_blocks == len(blocks),
+        f"{copies} host copies for {n_blocks} staged blocks",
+    )
+    return {
+        "staging_blocks": len(blocks),
+        "staging_block_mb": round(
+            sum(v.nbytes for v in blocks[0].values()) / 2**20, 1
+        ),
+        "staging_fence_waits": int(
+            tele.counter("staging_waits_total").value() - waits0
+        ),
+    }
+
+
+def phase_plane(shape: Shape, workdir: str, platform: str = "tpu") -> dict:
+    device = _require_device(platform)
+
+    from distributed_ba3c_tpu.cli import main as cli_main
+
+    logdir = os.path.join(workdir, "plane")
+    steps = shape.plane_steps_per_epoch
+    argv = [
+        "--trainer", "tpu_vtrace_ba3c", "--env", shape.plane_env,
+        "--simulator_procs", str(shape.plane_envs),
+        "--batch_size", str(shape.plane_batch),
+        "--fc_units", str(shape.fc_units),
+        "--steps_per_epoch", str(steps), "--max_epoch", "2",
+        "--nr_eval", "0", "--ingest_staging", "on",
+        "--logdir", logdir,
+    ]
+    if shape.plane_image_size:
+        argv += ["--image_size", str(shape.plane_image_size)]
+    watch = _ChildWatch()
+    watch.start()
+    try:
+        _check(cli_main(argv) == 0, "plane run rc != 0")
+    finally:
+        watch.stop()
+    _check(bool(watch.seen), "the plane ran without one child process")
+    _check(
+        not watch.offenders,
+        f"children of the chip-holding process loaded libtpu: "
+        f"{watch.offenders}",
+    )
+    stats = _read_stats(logdir)
+    _check(len(stats) == 2, f"expected 2 epoch records, got {len(stats)}")
+    _check_run_device(stats[0], device)
+    last = stats[-1]
+    _check(last["global_step"] == 2 * steps, f"global_step {last['global_step']}")
+    for s in stats:
+        _finite(s, ("loss", "policy_loss", "value_loss", "entropy",
+                    "grad_norm", "mean_rho"))
+    copies = last["tele/learner/ingest_copies_total"]
+    n_blocks = last["tele/learner/ingest_blocks_total"]
+    _check(
+        n_blocks >= 2 * steps and copies == n_blocks,
+        f"ingest: {copies} host copies for {n_blocks} blocks (want 1.0 each)",
+    )
+    _check(
+        last["tele/predictor/param_publishes_total"] >= 2 * steps,
+        "the learner did not publish params back to the predictor",
+    )
+    _check(
+        last["tele/predictor/rows_total"] > 0
+        and last["tele/master/datapoints_total"] > 0,
+        "the predictor served no rows / the master assembled no datapoints",
+    )
+    if shape.plane_env.startswith("cpp:"):
+        # the default wire: `auto` resolves to block-shm where /dev/shm is
+        _check(
+            last.get("tele/master/block_shm_msgs_total", 0) > 0,
+            "no block-shm traffic: --wire auto did not resolve to block-shm",
+        )
+    return {
+        "device": device,
+        "children": len(watch.seen),
+        "predictor_warmup_s": round(last["tele/predictor/warmup_s"], 2),
+        "first_dispatch_s": round(stats[0]["first_dispatch_s"], 2),
+        "steady_step_s": round(shape.plane_batch / last["fps"], 5),
+        "ingest_copies_per_block": copies / n_blocks,
+        "param_publishes": int(last["tele/predictor/param_publishes_total"]),
+        **_staging_equivalence(shape),
+    }
+
+
+# --------------------------------------------------------------------------
+# phase: the rollout forward at three dtypes (+ the Pallas blocks)
+# --------------------------------------------------------------------------
+
+
+def _pong_frames(model, cfg, params, n_frames: int):
+    """Real Pong frame stacks through the actor's own scan body — parity
+    is measured on the pixels the rollout forward sees, not on noise."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from distributed_ba3c_tpu.envs.jaxenv import pong
+    from distributed_ba3c_tpu.fused.loop import make_rollout_body
+
+    T = 4
+    n_envs = max(1, n_frames // T)
+    key = jax.random.PRNGKey(7)
+    env_state = jax.vmap(pong.reset)(jax.random.split(key, n_envs))
+    obs = jax.vmap(pong.render)(env_state)
+    stack = jnp.zeros(
+        (n_envs, *obs.shape[1:], cfg.frame_history), jnp.uint8
+    ).at[..., -1].set(obs)
+    carry = (
+        env_state, stack, jax.random.fold_in(key, 1),
+        jnp.zeros(n_envs, jnp.float32), jnp.zeros(n_envs, jnp.int32),
+        jnp.zeros(n_envs, jnp.float32),
+    )
+    body = make_rollout_body(model, cfg, pong, params)
+    _, traj = jax.jit(lambda c: jax.lax.scan(body, c, None, length=T))(carry)
+    return np.asarray(traj[0]).reshape(-1, *cfg.state_shape)
+
+
+def _pallas_blocks(interpret: bool) -> dict:
+    """The repo's one Pallas kernel at every geometry it supports, against
+    the XLA block (same op order, so they agree to a bf16 rounding)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from distributed_ba3c_tpu.ops import pallas_conv as pc
+
+    out = {}
+    for i, s in enumerate(pc.ba3c_specs()):
+        if not pc.supported(s):
+            continue  # conv0: Ci=4 cannot fill a 128-lane row
+        rng = np.random.default_rng(i)
+        x = jnp.asarray(rng.normal(size=(64, s.H, s.W * s.Ci)), jnp.bfloat16)
+        w = jnp.asarray(
+            rng.normal(size=(s.kh, s.kw, s.Ci, s.Co)) * 0.05, jnp.float32
+        )
+        b = jnp.asarray(rng.normal(size=(s.Co,)) * 0.1, jnp.float32)
+        # one program per static geometry, run once: nothing to hoist
+        got = jax.jit(  # ba3clint: disable=J2
+            lambda x, w, b, s=s: pc.conv_block(x, w, b, s, interpret)
+        )(x, w, b)
+        ref = jax.jit(  # ba3clint: disable=J2
+            lambda x, w, b, s=s: pc.reference_block(x, w, b, s)
+        )(x, w, b)
+        err = float(jnp.max(jnp.abs(
+            got.astype(jnp.float32) - ref.astype(jnp.float32)
+        )))
+        absmax = float(jnp.max(jnp.abs(ref.astype(jnp.float32))))
+        # bf16 keeps 8 significant bits: one ulp at absmax is absmax / 128
+        _check(
+            err <= absmax / 64,
+            f"pallas block {i} ({s.Ci}->{s.Co}): max abs err {err} "
+            f"against absmax {absmax}",
+        )
+        out[f"pallas_conv{i}_max_abs_err"] = err
+    _check(len(out) == 3, f"expected 3 supported Pallas blocks, ran {len(out)}")
+    return out
+
+
+def phase_forwards(shape: Shape, workdir: str, platform: str = "tpu") -> dict:
+    del workdir
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from distributed_ba3c_tpu.config import BA3CConfig
+    from distributed_ba3c_tpu.envs.jaxenv import pong
+    from distributed_ba3c_tpu.models.a3c import BA3CNet
+    from distributed_ba3c_tpu.predict.server import BatchedPredictor
+    from distributed_ba3c_tpu.quantize import (
+        calibrate_offline,
+        int8_conv_supported,
+        make_quant_apply,
+        quantize_params,
+    )
+
+    device = _require_device(platform)
+    cfg = BA3CConfig(num_actions=pong.num_actions, fc_units=shape.fc_units)
+    model = BA3CNet(num_actions=cfg.num_actions, fc_units=cfg.fc_units)
+    params = model.init(
+        jax.random.PRNGKey(0), np.zeros((1, *cfg.state_shape), np.uint8)
+    )["params"]
+    frames = _pong_frames(model, cfg, params, shape.serve_batch)
+    _check(
+        frames.shape == (shape.serve_batch, *cfg.state_shape),
+        f"frames {frames.shape}",
+    )
+    spec = calibrate_offline(model, params, [frames])
+
+    int8_arm = "int8" if int8_conv_supported() else "folded"
+    _check(
+        platform != "tpu" or int8_arm == "int8",
+        "the chip resolved the `auto` quant arm to the bf16 `folded` "
+        "reference, not int8 compute",
+    )
+
+    # the reference: the f32-param forward, exactly as the learner runs it
+    apply = jax.jit(lambda p, x: model.apply({"params": p}, x))
+    ref = apply(params, frames)
+    ref_lp = np.asarray(jax.nn.log_softmax(ref.logits, axis=-1))
+    ref_v = np.asarray(ref.value)
+    _check(bool(np.all(np.isfinite(ref_lp)) and np.all(np.isfinite(ref_v))),
+           "the f32 reference forward is not finite")
+
+    # log mu(a|s), the record V-trace corrects against, at the two cheaper
+    # tables (predict_batch returns actions and values only)
+    bf16_params = jax.tree_util.tree_map(
+        lambda x: x.astype(jnp.bfloat16) if x.dtype == jnp.float32 else x,
+        params,
+    )
+    qapply = jax.jit(make_quant_apply(model))
+    ladder = {
+        "bfloat16": apply(bf16_params, frames),
+        "int8": qapply(quantize_params(params, spec), frames),
+    }
+    info: dict = {"device": device, "int8_arm": int8_arm}
+    for name, out in ladder.items():
+        d_lp = float(np.max(np.abs(
+            np.asarray(jax.nn.log_softmax(out.logits, axis=-1)) - ref_lp
+        )))
+        _check(d_lp < BAND_LOG_MU, f"{name}: log mu off by {d_lp}")
+        info[f"{name}_dlogmu"] = round(d_lp, 5)
+
+    for dtype in ("float32", "bfloat16", "int8"):
+        pred = BatchedPredictor(  # ba3clint: disable=A14 — sync predict_batch only, owned by this loop
+            model, params, batch_size=shape.serve_batch,
+            rollout_dtype=dtype,
+            quant_spec=spec if dtype == "int8" else None,
+            tele_role=f"predictor.{dtype}",
+        )
+        t0 = time.monotonic()
+        pred.warmup(cfg.state_shape)
+        warmup_s = time.monotonic() - t0
+        _check(pred.serving_dtype == dtype, f"serving {pred.serving_dtype}")
+        batch_s = []
+        for _ in range(3):
+            t0 = time.monotonic()
+            actions, values, greedy = pred.predict_batch(frames)
+            batch_s.append(time.monotonic() - t0)
+            _check(
+                actions.shape == values.shape == greedy.shape
+                == (shape.serve_batch,),
+                f"{dtype}: shapes {actions.shape} {values.shape}",
+            )
+            _check(
+                bool(np.all((actions >= 0) & (actions < cfg.num_actions))),
+                f"{dtype}: action out of range",
+            )
+            d_v = float(np.max(np.abs(values - ref_v)))
+            _check(d_v < BAND_VALUE, f"{dtype}: V off by {d_v}")
+        info[f"{dtype}_warmup_s"] = round(warmup_s, 2)
+        info[f"{dtype}_batch_s"] = round(min(batch_s), 5)
+        info[f"{dtype}_dvalue"] = round(d_v, 5)
+    if shape.check_pallas:
+        info.update(_pallas_blocks(interpret=platform != "tpu"))
+    return info
+
+
+# --------------------------------------------------------------------------
+# phase: more than one device
+# --------------------------------------------------------------------------
+
+
+def phase_mesh(shape: Shape, workdir: str, platform: str = "tpu") -> dict:
+    del workdir
+    import jax
+    import numpy as np
+
+    from distributed_ba3c_tpu.config import BA3CConfig
+    from distributed_ba3c_tpu.envs.jaxenv import pong
+    from distributed_ba3c_tpu.fused.loop import (
+        create_fused_state,
+        make_fused_step,
+    )
+    from distributed_ba3c_tpu.models.a3c import BA3CNet
+    from distributed_ba3c_tpu.ops.gradproc import make_optimizer
+    from distributed_ba3c_tpu.parallel.mesh import make_mesh
+
+    device = _require_device(platform)
+    n = int(device["count"])
+    if n < 2:
+        return {"device": device, "skipped": "one device: nothing to shard"}
+    cfg = BA3CConfig(num_actions=pong.num_actions, fc_units=shape.fc_units)
+    model = BA3CNet(num_actions=cfg.num_actions, fc_units=cfg.fc_units)
+    opt = make_optimizer(cfg.learning_rate, cfg.adam_epsilon, cfg.grad_clip_norm)
+    mesh = make_mesh()
+    step = make_fused_step(
+        model, opt, cfg, mesh, pong, rollout_len=shape.rollout_len
+    )
+    state = step.put(create_fused_state(
+        jax.random.PRNGKey(0), model, cfg, opt, pong,
+        shape.envs_per_chip * n, n_shards=n,
+    ))
+    everyone = {d.id for d in jax.devices()}
+    for name, arr in (
+        ("obs_stack", state.obs_stack),
+        ("env_state", jax.tree_util.tree_leaves(state.env_state)[0]),
+    ):
+        held = {s.device.id for s in arr.addressable_shards}
+        _check(held == everyone, f"{name} lives on {held}, not {everyone}")
+        _check(
+            all(s.data.shape[0] == shape.envs_per_chip
+                for s in arr.addressable_shards),
+            f"{name} shards are not {shape.envs_per_chip} envs each",
+        )
+    updates = shape.fused_steps_per_epoch
+    for _ in range(updates):
+        state, metrics = step(state, cfg.entropy_beta)
+    _check(np.isfinite(float(metrics["loss"])), "loss not finite")
+
+    # the on-chip form of T3: one all-reduce per leaf means every replica
+    # applied the same gradient, so the replicas are the same bits
+    digests = [[] for _ in range(n)]
+    leaves = jax.tree_util.tree_leaves((state.train.params, state.train.opt_state))
+    for leaf in leaves:
+        shards = sorted(leaf.addressable_shards, key=lambda s: s.device.id)
+        _check(len(shards) == n, f"a leaf has {len(shards)} replicas")
+        first = np.asarray(shards[0].data)
+        for k, s in enumerate(shards):
+            data = np.asarray(s.data)
+            _check(
+                np.array_equal(data, first, equal_nan=True),
+                f"replica {k} of a {first.shape} leaf differs from replica 0",
+            )
+            digests[k].append(float(np.float64(np.sum(data))))
+    _check(all(d == digests[0] for d in digests), "param digests differ")
+    return {
+        "device": device,
+        "updates": updates,
+        "replicated_leaves": len(leaves),
+        "sharded_over": sorted(everyone),
+        # the BA3C_PARAM_DIGEST formula, identical on every replica
+        "param_digest_head": [f"{v:.10e}" for v in digests[0][:3]],
+    }
+
+
+PHASES = {
+    "fused": phase_fused,
+    "plane": phase_plane,
+    "forwards": phase_forwards,
+    "mesh": phase_mesh,
+}
+
+
+# --------------------------------------------------------------------------
+# child and parent
+# --------------------------------------------------------------------------
+
+
+def run_phase(name: str, workdir: str) -> int:
+    """The child: holds the chip for one phase, prints its JSON last."""
+    os.environ["BA3C_AUDIT"] = "1"  # a post-warm-up re-trace kills the run
+    t0 = time.monotonic()
+    info = PHASES[name](FULL, workdir)
+    info = {"phase": name, "ok": True,
+            "wall_s": round(time.monotonic() - t0, 1), **info}
+    print(json.dumps(info), flush=True)
+    return 0
+
+
+def _run_child(name: str, workdir: str, timeout_s: float) -> dict:
+    """Run one phase as a child in its own session; everything it started
+    is killed with it. Its stdout is passed through; the last line is its
+    result."""
+    proc = subprocess.Popen(
+        [sys.executable, os.path.abspath(__file__),
+         "--phase", name, "--workdir", workdir],
+        stdout=subprocess.PIPE, text=True, start_new_session=True,
+    )
+    last = ""
+    timer = threading.Timer(timeout_s, os.killpg, (proc.pid, signal.SIGKILL))
+    timer.start()
+    try:
+        for line in proc.stdout:
+            sys.stdout.write(line)
+            sys.stdout.flush()
+            if line.strip():
+                last = line
+        rc = proc.wait()
+    finally:
+        timer.cancel()
+        try:
+            os.killpg(proc.pid, signal.SIGKILL)  # stragglers of the session
+        except ProcessLookupError:
+            pass
+    if rc != 0:
+        raise SystemExit(f"chip_smoke: phase {name!r} failed (rc {rc})")
+    result = json.loads(last)
+    if result.get("phase") != name or result.get("ok") is not True:
+        raise SystemExit(f"chip_smoke: phase {name!r} printed no result")
+    return result
+
+
+def main(argv: Optional[List[str]] = None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--phase", choices=sorted(PHASES), help=argparse.SUPPRESS)
+    ap.add_argument("--workdir", help=argparse.SUPPRESS)
+    args = ap.parse_args(argv)
+    if args.phase:
+        return run_phase(args.phase, args.workdir)
+
+    t0 = time.monotonic()
+    workdir = tempfile.mkdtemp(prefix="chip_smoke-")
+    results = []
+    try:
+        for name in PHASES:
+            left = TOTAL_BUDGET_S - (time.monotonic() - t0)
+            print(f"[chip_smoke] phase {name} ({left:.0f}s left)", flush=True)
+            results.append(_run_child(name, workdir, max(left, 1.0)))
+    finally:
+        shutil.rmtree(workdir, ignore_errors=True)
+    devices = [r["device"] for r in results]
+    if any(d != devices[0] for d in devices):
+        raise SystemExit(f"chip_smoke: phases disagree on the device: {devices}")
+    summary = {
+        "wall_s": round(time.monotonic() - t0, 1),
+        "phases": results,
+    }
+    for r in results:
+        print("[chip_smoke] " + json.dumps(
+            {k: v for k, v in r.items() if k not in ("device", "ok")}
+        ))
+    out_dir = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                           "chiprun_out")
+    os.makedirs(out_dir, exist_ok=True)
+    with open(os.path.join(out_dir, "chip_smoke.json"), "w") as f:
+        json.dump(summary, f, indent=1)
+    print(json.dumps({"ok": True, "device": devices[0]}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -412,7 +412,7 @@ class SimulatorMaster(threading.Thread):
             "would silently map every learning reward to a constant)"
         )
         self.reward_clip = reward_clip
-        self._last_prune = 0.0
+        self._last_prune = float("-inf")  # monotonic: 0.0 is not "long ago" just after boot
         self.context = zmq.Context()
         self.c2s_socket = self.context.socket(zmq.PULL)
         self.c2s_socket.bind(pipe_c2s)

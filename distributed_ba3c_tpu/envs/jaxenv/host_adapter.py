@@ -4,12 +4,13 @@ Lets the on-device envs (envs/jaxenv/) serve the HOST actor plane too — a
 SimulatorProcess child or the Evaluator can run `jax:pong` through the same
 player protocol as FakeEnv/ALE (envs/base.py).
 
-Backend policy (ADVICE r1): simulator CHILDREN force the CPU platform via the
-environment variable before jax is first imported — they must never grab the
-(single) TPU. In the TRAINER process (Evaluator / --task eval) the global
-platform is NEVER mutated; the env's tiny step is merely pinned to a CPU
-device with ``jax.default_device`` so eval cannot flip the trainer's backend
-mid-training.
+Backend policy: a chip belongs to ONE process, the trainer. Simulator
+CHILDREN pin themselves to the CPU platform before any back-end exists —
+a child that initialised the TPU back-end would fail on libtpu's lockfile
+(or, were the chip free, take it from the trainer). In the TRAINER process
+(Evaluator / --task eval) the global platform is NEVER mutated; the env's
+tiny step is pinned to a CPU device with ``jax.default_device`` so eval
+cannot flip the trainer's backend mid-training.
 """
 
 from __future__ import annotations
@@ -25,16 +26,16 @@ def _in_child_process() -> bool:
 
 
 def build_jax_player(idx: int, name: str = "pong", frame_history: int = 4):
-    if _in_child_process() and "jax" not in __import__("sys").modules:
-        # spawned simulator child: safe to force CPU before jax exists
-        os.environ["JAX_PLATFORMS"] = "cpu"
     import jax
 
-    if _in_child_process() and jax.default_backend() != "cpu":
-        try:
-            jax.config.update("jax_platforms", "cpu")
-        except Exception:
-            pass
+    if _in_child_process():
+        # The env var covers grandchildren; the config update covers THIS
+        # process, where unpickling the SimulatorProcess already imported
+        # jax (so jax read the parent's JAX_PLATFORMS). Neither touches a
+        # back-end — asking jax which back-end is the default would
+        # initialise it, which is the claim this must not make.
+        os.environ["JAX_PLATFORMS"] = "cpu"
+        jax.config.update("jax_platforms", "cpu")
 
     from distributed_ba3c_tpu.envs.base import RLEnvironment
     from distributed_ba3c_tpu.envs.jaxenv import get_env
@@ -44,11 +45,10 @@ def build_jax_player(idx: int, name: str = "pong", frame_history: int = 4):
     step = jax.jit(env.step)
     # pin the per-step computation to CPU WITHOUT touching global config:
     # one env step is host-scale work; dispatching it to the TPU would
-    # serialize against training for no gain.
-    try:
-        cpu = jax.devices("cpu")[0]
-    except RuntimeError:
-        cpu = None
+    # serialize against training for no gain. A process restricted to
+    # JAX_PLATFORMS=tpu has no CPU back-end and fails here, loudly — the
+    # sealed chip machine exports "tpu,cpu".
+    cpu = jax.devices("cpu")[0]
 
     class _JaxPlayer(RLEnvironment):
         def __init__(self):

@@ -36,29 +36,31 @@ _LIB_PATH = os.path.join(
 _lib = None
 
 
-def _try_build() -> bool:
-    """Attempt `make -C cpp` once (the .so is a build artifact, not committed)."""
+def _build() -> None:
+    """``make -C cpp``: the .so is a build artifact, never committed, and a
+    copy left in the tree may predate ``env_core.cc``. make itself decides
+    (a no-op when the .so is newer than the source and the Makefile), so a
+    stale library is rebuilt and a fresh checkout builds on first use."""
     import subprocess
 
+    cmd = ["make", "-C", os.path.dirname(_LIB_PATH)]
     try:
-        subprocess.run(
-            ["make", "-C", os.path.dirname(_LIB_PATH)],
-            check=True,
-            capture_output=True,
-            timeout=120,
+        proc = subprocess.run(cmd, capture_output=True, text=True, timeout=300)
+    except (OSError, subprocess.TimeoutExpired) as e:
+        raise ImportError(
+            f"native env core: `{' '.join(cmd)}` could not run: {e!r}"
+        ) from e
+    if proc.returncode != 0:
+        raise ImportError(
+            f"native env core: `{' '.join(cmd)}` failed "
+            f"(rc {proc.returncode}):\n{proc.stderr[-4000:]}"
         )
-    except (OSError, subprocess.SubprocessError):
-        return False
-    return os.path.isfile(_LIB_PATH)
 
 
 def _load():
     global _lib
     if _lib is None:
-        if not os.path.isfile(_LIB_PATH) and not _try_build():
-            raise ImportError(
-                f"native env core not built: {_LIB_PATH} missing (run `make -C cpp`)"
-            )
+        _build()
         lib = ctypes.CDLL(_LIB_PATH)
         lib.ba3c_env_create.restype = ctypes.c_void_p
         lib.ba3c_env_create.argtypes = [ctypes.c_char_p, ctypes.c_int, ctypes.c_uint64]
@@ -82,7 +84,16 @@ def _load():
 
 
 def available() -> bool:
-    return os.path.isfile(_LIB_PATH) or _try_build()
+    """Can the native core be built and loaded here? The reason it cannot
+    (the compiler's stderr) is logged, not swallowed."""
+    try:
+        _load()
+    except (ImportError, OSError) as e:
+        from distributed_ba3c_tpu.utils import logger
+
+        logger.warn("native env core unavailable: %s", e)
+        return False
+    return True
 
 
 class CppBatchedEnv:

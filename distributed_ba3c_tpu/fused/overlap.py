@@ -74,12 +74,7 @@ from distributed_ba3c_tpu.fused.loop import (
 from distributed_ba3c_tpu.models.a3c import BA3CNet
 from distributed_ba3c_tpu.ops.gradproc import grad_summaries, inject_learning_rate
 from distributed_ba3c_tpu.ops.vtrace import vtrace_returns
-from distributed_ba3c_tpu.parallel.mesh import (
-    DATA_AXIS,
-    axis_size,
-    grad_allreduce,
-    shard_map,
-)
+from distributed_ba3c_tpu.parallel.mesh import DATA_AXIS, shard_local
 from distributed_ba3c_tpu.parallel.train_step import (
     TrainState,
     macro_accumulate,
@@ -102,6 +97,8 @@ def make_block_grads(
     fixed lag-1 without a new gradient path to re-verify."""
 
     def block_grads(params, block: TrajBlock, entropy_beta):
+        # shard-local grads: make_finish_update owns the update's one psum
+        params = shard_local(params)
         T, B = block.actions.shape
 
         # chunk over ENV COLUMNS, not the flat [T*B] batch: V-trace's
@@ -231,8 +228,8 @@ def make_finish_update(optimizer: optax.GradientTransformation) -> Callable:
     finding, extended to pod/learner.py)."""
 
     def finish_update(train: TrainState, grads, aux, rewards, learning_rate):
-        grads = grad_allreduce(grads, DATA_AXIS)
-        n_data = axis_size(DATA_AXIS)
+        grads = jax.lax.psum(grads, DATA_AXIS)
+        n_data = jax.lax.axis_size(DATA_AXIS)
         grads = jax.tree_util.tree_map(lambda g: g / n_data, grads)
 
         opt_state = inject_learning_rate(train.opt_state, learning_rate)
@@ -412,7 +409,7 @@ def make_overlap_step(
         behavior_values=tb_spec,
         bootstrap_state=batch_spec,
     )
-    actor_sharded = shard_map(
+    actor_sharded = jax.shard_map(
         local_actor,
         mesh=mesh,
         in_specs=(P(), actor_specs),
@@ -460,7 +457,7 @@ def make_overlap_step(
         grads, aux = block_grads(train.params, block, entropy_beta)
         return finish_update(train, grads, aux, block.rewards, learning_rate)
 
-    learner_sharded = shard_map(
+    learner_sharded = jax.shard_map(
         local_learner,
         mesh=mesh,
         in_specs=(P(), block_specs, P(), P()),
@@ -508,7 +505,7 @@ def make_overlap_step(
                 train, grads, aux, stacked.rewards, learning_rate
             )
 
-        macro_learner_sharded = shard_map(
+        macro_learner_sharded = jax.shard_map(
             local_macro_learner,
             mesh=mesh,
             in_specs=(P(), (block_specs,) * K, P(), P()),
@@ -530,7 +527,7 @@ def make_overlap_step(
 
     ep_stats_jit = tripwire_jit(
         "fused.ep_stats",
-        shard_map(
+        jax.shard_map(
             local_ep_stats,
             mesh=mesh,
             in_specs=(batch_spec, batch_spec),

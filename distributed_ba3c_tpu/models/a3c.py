@@ -67,11 +67,14 @@ class _PallasConvBlock(nn.Module):
     """conv+bias+relu(+2x2 maxpool) as one fused Pallas kernel.
 
     Param names/shapes match ``nn.Conv`` ('kernel' [k,k,ci,co], 'bias'
-    [co]); interpret mode is selected automatically off-TPU so tests run
-    on the CPU backend.
+    [co]). ``interpret`` runs the kernel in the Pallas interpreter — the
+    CPU tests ask for it by name (``conv_backend="pallas-interpret"``); it
+    is never guessed from the back-end, so on a chip "pallas" always means
+    the Mosaic-compiled kernel.
     """
 
     spec: object  # ConvSpec (static)
+    interpret: bool = False
 
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
@@ -87,7 +90,7 @@ class _PallasConvBlock(nn.Module):
         y = conv_block(
             x.astype(jnp.bfloat16).reshape(B, s.H, s.W * s.Ci),
             kernel, bias, s,
-            jax.default_backend() != "tpu",
+            self.interpret,
         )
         return y.reshape(B, s.Ho, s.Wo, s.Co)
 
@@ -108,13 +111,15 @@ class BA3CNet(nn.Module):
     # for backends where the GEMM shape does bind. 0/1 = plain nn.Conv.
     # Numerically EXACT either way (value- and gradient-tested).
     conv_pack: Tuple[int, ...] = (0, 0, 0, 0)
-    # "xla" (default) or "pallas": fused Pallas conv+relu+pool blocks where
-    # the geometry allows (ops/pallas_conv.py — blocks whose P*Ci is a
-    # 128-multiple, i.e. the 32/64-channel layers; conv0's Ci=4 cannot).
-    # MEASURED SLOWER on the v5e (patch-assembly relayout outweighs the 4x
-    # MXU lane-occupancy win — PERF.md), so the default stays XLA; kept as
-    # value- and gradient-tested kernel infrastructure. Checkpoints are
-    # interchangeable (same param names/shapes).
+    # "xla" (default), "pallas" or "pallas-interpret": fused Pallas
+    # conv+relu+pool blocks where the geometry allows (ops/pallas_conv.py —
+    # blocks whose P*Ci is a 128-multiple, i.e. the 32/64-channel layers;
+    # conv0's Ci=4 cannot). The kernels compile under the installed Mosaic
+    # and match the XLA block on the v5e (chip_smoke.py checks both on every
+    # run); measured SLOWER than XLA in an earlier round on other code
+    # (patch-assembly relayout outweighs the 4x MXU lane-occupancy win), so
+    # the default stays XLA and ROADMAP D1 queues the removal. Checkpoints
+    # are interchangeable (same param names/shapes).
     conv_backend: str = "xla"
 
     @nn.compact
@@ -131,14 +136,24 @@ class BA3CNet(nn.Module):
             # explicit name "Conv_i" for ALL branches: PackedConv and
             # _PallasConvBlock own nn.Conv-shaped params, so checkpoints
             # stay interchangeable between configurations
-            # the Pallas block is bf16-only; any other compute dtype must
-            # use the XLA path to honor the requested precision
-            if self.conv_backend == "pallas" and self.compute_dtype == jnp.bfloat16:
+            if self.conv_backend in ("pallas", "pallas-interpret"):
                 from distributed_ba3c_tpu.ops.pallas_conv import supported
+
+                if self.compute_dtype != jnp.bfloat16:
+                    # the Pallas block is bf16-only: running XLA convs
+                    # under the kernel's name would hide which one ran
+                    raise ValueError(
+                        f"conv_backend={self.conv_backend!r} computes in "
+                        f"bfloat16, not {self.compute_dtype}"
+                    )
 
                 spec = _conv_spec(x, feats, k, pooled)
                 if supported(spec):
-                    x = _PallasConvBlock(spec=spec, name=f"Conv_{i}")(x)
+                    x = _PallasConvBlock(
+                        spec=spec,
+                        interpret=self.conv_backend == "pallas-interpret",
+                        name=f"Conv_{i}",
+                    )(x)
                     continue  # relu+pool fused inside the block
             if pack and pack > 1:
                 from distributed_ba3c_tpu.models.packed_conv import PackedConv

@@ -1,9 +1,11 @@
 """Pallas TPU kernels for the BA3C conv stack: fused conv+bias+relu+maxpool.
 
-STATUS — measured SLOWER than XLA on the v5e; default OFF; kept as working,
-tested, honestly-documented kernel infrastructure (the same policy as
-models/packed_conv.py). The round-2 A/B on the real chip (chained in-jit
-loops, B=4096 — full story in PERF.md):
+STATUS — default OFF, and no flag selects it (ROADMAP D1 queues the
+removal). Under the installed stack (jax 0.9.0, libtpu 0.0.34) all three
+supported geometries compile with Mosaic on the v5e and match the XLA block
+to one bf16 ulp (PR 21; chip_smoke.py repeats the check on every run). Its
+speed has NOT been re-measured there. An A/B in an earlier round, on other
+code (chained in-jit loops, B=4096), read:
 
     XLA conv1 block (conv+bias+relu+pool)      2.52 us/sample
     this kernel, VPU-assembled patches          4.17-4.75

@@ -57,9 +57,10 @@ class _HostProc:
     def start(self) -> None:
         env = dict(os.environ)
         # FORCED, not setdefault: actor hosts never claim a TPU — a
-        # learner launched with JAX_PLATFORMS=tpu exported must not hand
-        # N children a claim on the chip it holds (they would stall at
-        # jax init and burn the respawn budget into the circuit breaker)
+        # learner launched with JAX_PLATFORMS=tpu,cpu exported must not
+        # hand N children a claim on the chip it holds (each would fail on
+        # libtpu's lockfile at jax init and burn the respawn budget into
+        # the circuit breaker)
         env["JAX_PLATFORMS"] = "cpu"
         env["PYTHONPATH"] = _REPO_ROOT + (
             os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else ""
@@ -271,7 +272,17 @@ class PodLearnerPlane:
 
 
 def run_pod(args) -> int:
-    """The orchestrate pod mode: learner in-process, hosts supervised."""
+    """The orchestrate pod mode: learner in-process, hosts supervised.
+
+    This process holds the chip; the actor hosts it spawns are forced to
+    the CPU platform (:class:`_HostProc`), so none of them claims it."""
+    from distributed_ba3c_tpu.utils.backend import (
+        configure_compile_cache,
+        log_device_info,
+    )
+
+    configure_compile_cache()
+    log_device_info()
     cfg = BA3CConfig(
         image_size=(args.pod_image_size, args.pod_image_size),
         frame_history=args.pod_frame_history,

@@ -355,7 +355,9 @@ class PolicyResource(Reconcilable):
         self.name = name
         self.controller = controller
         self.interval_s = max(0.0, float(interval_s))
-        self._last_tick = 0.0
+        # -inf, not 0.0: time.monotonic() counts from boot, so on a freshly
+        # started machine "0.0 is long ago" is false for the first interval
+        self._last_tick = float("-inf")
 
     def observe(self) -> Dict[str, Any]:
         return {"kind": "policy", "due": (

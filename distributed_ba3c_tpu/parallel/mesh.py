@@ -18,72 +18,27 @@ from jax.sharding import Mesh
 DATA_AXIS = "data"    # batch / gradient data-parallel axis (the only one BA3C needs)
 MODEL_AXIS = "model"  # reserved for tensor-parallel shardings of larger models
 
-# shard_map moved from jax.experimental to the jax namespace (jax >= 0.6);
-# every step builder imports THIS symbol so the repo runs on both. The call
-# sites only use the (f, mesh=, in_specs=, out_specs=) surface, which is
-# identical across the move.
-try:
-    shard_map = jax.shard_map
-except AttributeError:  # jax <= 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map_experimental
 
-    def shard_map(f, **kwargs):
-        # The old check_rep machinery cannot infer the transpose-inserted
-        # psum for replicated params that the new check_vma semantics
-        # handle, and rejects the step's P() out_specs. check_rep=False
-        # ALSO disables that automatic psum, leaving grads shard-local —
-        # grad_allreduce (below) compensates with an explicit psum on this
-        # path, and test_sharded_step_matches_single_device pins the
-        # combined numerics.
-        kwargs.setdefault("check_rep", False)
-        return _shard_map_experimental(f, **kwargs)
+def shard_local(params, axis: str = DATA_AXIS):
+    """The device-varying view of replicated ``params`` that every learner
+    differentiates against (idempotent; inside ``jax.shard_map`` only; any
+    pytree — the greedy evaluator marks its constant-built scan carries
+    with it too).
 
+    ``jax.grad`` w.r.t. a REPLICATED shard_map input makes the transpose
+    insert one all-reduce per leaf per grad call — inside a
+    gradient-accumulation scan (macro sub-batches, HBM-bounding chunks) that
+    is one collective per sub-batch, not per update. Grads of the varying
+    view stay shard-local, so the step accumulates locally and pays exactly
+    ONE explicit ``jax.lax.psum`` per leaf per update, which is what
+    tools/ba3caudit T3 counts."""
 
-def axis_size(name: str):
-    """``jax.lax.axis_size`` with a fallback for jax <= 0.4.x, where the
-    mesh-axis size inside shard_map is obtained by summing 1 over the axis
-    (constant-folded by XLA — no runtime collective)."""
-    try:
-        return jax.lax.axis_size(name)
-    except AttributeError:
-        return jax.lax.psum(1, name)
+    def view(p):
+        if axis in jax.typeof(p).vma:
+            return p
+        return jax.lax.pcast(p, (axis,), to="varying")
 
-
-#: On jax >= 0.6 the check_vma transpose auto-inserts the psum for grads of
-#: replicated params, so the step bodies receive grads already SUMMED over
-#: the data axis. On jax <= 0.4.x we run shard_map with check_rep=False
-#: (see above), which disables that insertion — the sum must be explicit.
-_NEEDS_EXPLICIT_GRAD_PSUM = not hasattr(jax, "shard_map")
-
-
-def to_varying(x, axis: str = DATA_AXIS):
-    """Mark ``x`` device-varying over ``axis`` under the check_vma machinery
-    (jax >= 0.6: ``jax.typeof(...).vma`` + ``jax.lax.pcast``). Identity on
-    jax <= 0.4.x, where check_rep=False tracks no rep types — constants in
-    scan carries need no marking there."""
-    try:
-        typeof = jax.typeof
-        pcast = jax.lax.pcast
-    except AttributeError:
-        return x
-    if axis in getattr(typeof(x), "vma", frozenset()):
-        return x  # already varying (e.g. key-derived fields)
-    return pcast(x, (axis,), to="varying")
-
-
-def grad_allreduce(grads, axis: str = DATA_AXIS):
-    """Make ``grads`` the axis-SUMMED gradients on every jax version.
-
-    Identity where the shard_map transpose already summed them (new jax);
-    an explicit ``psum`` where check_rep=False left them shard-local (old
-    jax). Callers divide by :func:`axis_size` afterwards for the mean —
-    numerical parity is pinned by test_sharded_step_matches_single_device.
-    """
-    if _NEEDS_EXPLICIT_GRAD_PSUM:
-        return jax.tree_util.tree_map(
-            lambda g: jax.lax.psum(g, axis), grads
-        )
-    return grads
+    return jax.tree_util.tree_map(view, params)
 
 
 def make_mesh(

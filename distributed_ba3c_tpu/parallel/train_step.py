@@ -31,12 +31,7 @@ from distributed_ba3c_tpu.config import BA3CConfig
 from distributed_ba3c_tpu.models.a3c import BA3CNet
 from distributed_ba3c_tpu.ops.gradproc import grad_summaries, inject_learning_rate
 from distributed_ba3c_tpu.ops.loss import a3c_loss
-from distributed_ba3c_tpu.parallel.mesh import (
-    DATA_AXIS,
-    axis_size,
-    grad_allreduce,
-    shard_map,
-)
+from distributed_ba3c_tpu.parallel.mesh import DATA_AXIS, shard_local
 
 
 class TrainState(struct.PyTreeNode):
@@ -125,16 +120,16 @@ def _local_step(
     """Per-device shard-local step body; runs inside shard_map."""
 
     loss_fn = _make_loss_fn(model, cfg, batch, entropy_beta)
-    (_, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(state.params)
+    (_, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+        shard_local(state.params)
+    )
 
-    # The one collective that replaces the reference's whole PS gradient plane.
-    # Under shard_map's check_vma=True semantics the transpose auto-inserts the
-    # psum for the replicated params (grads arrive device-invariant, SUMMED over
-    # the data axis); dividing by the axis size yields the global batch mean.
-    # (An explicit lax.pmean here would double-count by the axis size;
-    # grad_allreduce is identity there and psums only on old-jax check_rep=False.)
-    grads = grad_allreduce(grads, DATA_AXIS)
-    n_data = axis_size(DATA_AXIS)
+    # The one collective that replaces the reference's whole PS gradient
+    # plane: shard-local grads (parallel/mesh.py shard_local) summed over the
+    # data axis, then divided by its size for the global batch mean.
+    # tools/ba3caudit T3 counts exactly one such reduction per param leaf.
+    grads = jax.lax.psum(grads, DATA_AXIS)
+    n_data = jax.lax.axis_size(DATA_AXIS)
     grads = jax.tree_util.tree_map(lambda g: g / n_data, grads)
 
     new_state = apply_grads(optimizer, state, grads, learning_rate)
@@ -162,13 +157,16 @@ def make_train_step(
 
     Returns fn(state, batch, entropy_beta) -> (state, metrics) with donated
     state buffers. ``batch`` leading dim must be divisible by the mesh's data
-    axis size.
+    axis size. Place the first ``state`` with ``step.state_sharding`` (the
+    Trainer does): jit keys its trace on input shardings, so an unplaced
+    state compiles the step once for itself and once more for the
+    mesh-replicated state the step returns.
     """
     replicated = P()
     batch_spec = P(DATA_AXIS)
 
     body = functools.partial(_local_step, model, optimizer, cfg)
-    sharded = shard_map(
+    sharded = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(replicated, batch_spec, replicated, replicated),
@@ -212,8 +210,11 @@ def macro_accumulate(loss_grad_one, params, batch, n_local: int):
     Shared by the BA3C and V-trace macro steps — the accumulation
     schedule (first sub-batch unrolled, rest scanned, symmetric mean) is
     one definition, same idiom as the fused learner's chunk accumulation
-    (fused/loop.py).
+    (fused/loop.py). The returned grads are SHARD-LOCAL (differentiated
+    against :func:`shard_local` params): the caller owns the update's one
+    psum.
     """
+    params = shard_local(params)
     first = jax.tree_util.tree_map(lambda x: x[0], batch)
     (_, aux0), g0 = loss_grad_one(params, first)
     if n_local == 1:
@@ -278,7 +279,7 @@ def make_macro_train_step(
         # ONE collective for the whole macro batch (T3 census unchanged):
         # the psum sums over the data axis, the divide completes the mean
         # over all K fleets
-        grads = grad_allreduce(grads, DATA_AXIS)
+        grads = jax.lax.psum(grads, DATA_AXIS)
         grads = jax.tree_util.tree_map(lambda g: g / n_data, grads)
         new_state = apply_grads(optimizer, state, grads, learning_rate)
         metrics = {
@@ -295,7 +296,7 @@ def make_macro_train_step(
 
     replicated = P()
     batch_spec = P(DATA_AXIS)  # leading = FLEET axis
-    sharded = shard_map(
+    sharded = jax.shard_map(
         local_macro_step,
         mesh=mesh,
         in_specs=(replicated, batch_spec, replicated, replicated),

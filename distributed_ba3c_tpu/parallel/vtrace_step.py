@@ -32,12 +32,7 @@ from distributed_ba3c_tpu.config import BA3CConfig
 from distributed_ba3c_tpu.models.a3c import BA3CNet
 from distributed_ba3c_tpu.ops.gradproc import grad_summaries
 from distributed_ba3c_tpu.ops.vtrace import vtrace_returns
-from distributed_ba3c_tpu.parallel.mesh import (
-    DATA_AXIS,
-    axis_size,
-    grad_allreduce,
-    shard_map,
-)
+from distributed_ba3c_tpu.parallel.mesh import DATA_AXIS, shard_local
 from distributed_ba3c_tpu.parallel.train_step import (
     TrainState,
     apply_grads,
@@ -114,9 +109,11 @@ def _local_step(
     learning_rate: jax.Array,
 ) -> Tuple[TrainState, Dict[str, jax.Array]]:
     loss_fn = _make_vtrace_loss_fn(model, cfg, batch, entropy_beta)
-    (_, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(state.params)
-    grads = grad_allreduce(grads, DATA_AXIS)
-    n_data = axis_size(DATA_AXIS)
+    (_, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+        shard_local(state.params)
+    )
+    grads = jax.lax.psum(grads, DATA_AXIS)
+    n_data = jax.lax.axis_size(DATA_AXIS)
     grads = jax.tree_util.tree_map(lambda g: g / n_data, grads)
 
     new_state = apply_grads(optimizer, state, grads, learning_rate)
@@ -142,7 +139,7 @@ def make_vtrace_train_step(
         "bootstrap_state": P(DATA_AXIS),
     }
     body = functools.partial(_local_step, model, optimizer, cfg)
-    sharded = shard_map(
+    sharded = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(replicated, specs, replicated, replicated),
@@ -212,7 +209,7 @@ def make_vtrace_macro_step(
             loss_grad_one, state.params, batch, n_local
         )
         # ONE collective for the whole macro batch (T3 census unchanged)
-        grads = grad_allreduce(grads, DATA_AXIS)
+        grads = jax.lax.psum(grads, DATA_AXIS)
         grads = jax.tree_util.tree_map(lambda g: g / n_data, grads)
         new_state = apply_grads(optimizer, state, grads, learning_rate)
         metrics = {**aux, **grad_summaries(grads)}
@@ -229,7 +226,7 @@ def make_vtrace_macro_step(
         "behavior_log_probs": fleet_spec,
         "bootstrap_state": fleet_spec,
     }
-    sharded = shard_map(
+    sharded = jax.shard_map(
         local_macro_step,
         mesh=mesh,
         in_specs=(replicated, specs, replicated, replicated),

@@ -50,7 +50,7 @@ from distributed_ba3c_tpu.fused.overlap import (
     make_finish_update,
 )
 from distributed_ba3c_tpu.models.a3c import BA3CNet
-from distributed_ba3c_tpu.parallel.mesh import DATA_AXIS, shard_map
+from distributed_ba3c_tpu.parallel.mesh import DATA_AXIS
 from distributed_ba3c_tpu.parallel.train_step import TrainState
 
 import optax
@@ -90,7 +90,7 @@ def make_pod_learner_step(
         behavior_values=tb_spec,
         bootstrap_state=batch_spec,
     )
-    sharded = shard_map(
+    sharded = jax.shard_map(
         local_learner,
         mesh=mesh,
         in_specs=(P(), block_specs, P(), P()),

@@ -64,9 +64,10 @@ def int8_conv_supported(backend: str = "") -> bool:
     """Can this backend compile an int8 x int8 -> int32 conv?
 
     Probed ONCE per backend with a 1-pixel conv; the result is cached.
-    CPU (jax 0.4.37) and TPU both support it; the probe exists so the
-    ``auto`` arm degrades to ``folded`` instead of crashing on a backend
-    that doesn't."""
+    The CPU client and the v5e (jax 0.9.0, libtpu 0.0.34: measured PR 21)
+    both support it; the probe exists so the ``auto`` arm degrades to
+    ``folded`` instead of crashing on a backend that doesn't — LOUDLY: on
+    a TPU that is a kernel giving way to a bf16 reference."""
     backend = backend or jax.default_backend()
     ok = _INT8_CONV_OK.get(backend)
     if ok is None:
@@ -81,7 +82,15 @@ def int8_conv_supported(backend: str = "") -> bool:
                 )
             )(x, w).block_until_ready()
             ok = True
-        except Exception:
+        except Exception as e:  # whatever the compiler raises means "no"
+            from distributed_ba3c_tpu.utils import logger
+
+            logger.warn(
+                "backend %s refused an int8 x int8 -> int32 conv (%r): the "
+                "`auto` quant arm resolves to the bf16 `folded` reference, "
+                "not int8 compute",
+                backend, e,
+            )
             ok = False
         _INT8_CONV_OK[backend] = ok
     return ok

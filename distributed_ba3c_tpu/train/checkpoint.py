@@ -127,5 +127,10 @@ class CheckpointManager:
         TrainState). Defaults to the latest step."""
         if step is None:
             step = self.latest_step
-        assert step is not None, "no checkpoint to restore"
+        if step is None:
+            # --load is outside input: a check that survives `python -O`
+            raise FileNotFoundError(
+                f"no checkpoint recorded under {self.root} "
+                "(checkpoint.json has no 'latest')"
+            )
         return self._ckpt.restore(self._dir(step), target)

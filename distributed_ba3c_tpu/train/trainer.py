@@ -79,12 +79,17 @@ class Trainer:
         self.global_step = 0
         self.epoch_num = 0
         self.batch_size = samples_per_step or cfg.batch_size
-        self.stat_holder = StatHolder(config.log_dir)
+        from distributed_ba3c_tpu.utils.backend import log_device_info
+
+        self.stat_holder = StatHolder(
+            config.log_dir, run_info={"device": log_device_info()}
+        )
         self.score_counter: Optional[StatCounter] = StatCounter()
         self.last_mean_score: Optional[float] = None
         self.ckpt_manager = None  # set by ModelSaver
         self.metrics = None
         self._pending_trace = None  # sampled trace between stage + step
+        self._first_dispatch = True
         self._callbacks = Callbacks(callbacks)
 
         # telemetry (docs/observability.md): the learner registry is the
@@ -174,6 +179,7 @@ class Trainer:
         # callbacks that fetch metrics (StatPrinter samples every N steps).
         t0 = time.monotonic()
         batch = self._next_device_batch()
+        t_claimed = time.monotonic()
         if self._pending_trace is not None:
             # sampled steps only: the jax.profiler step region carries the
             # trace/span ids, so a chip-session capture lines up with the
@@ -200,6 +206,16 @@ class Trainer:
                 self.hyperparams["learning_rate"],
             )
         self.global_step += 1
+        if self._first_dispatch:
+            # trace + compile (or cache read) + the first execution, from
+            # the moment the first batch was in hand: set-up time, recorded
+            # apart from the steady rate. One host sync, on this process's
+            # first step only.
+            self._first_dispatch = False
+            jax.block_until_ready(self.metrics)
+            self.stat_holder.add_stat(
+                "first_dispatch_s", time.monotonic() - t_claimed
+            )
         prefetch = getattr(self.feed, "prefetch", None)
         if prefetch is not None:
             # staged pipeline: dispatch the NEXT batch's H2D right behind
@@ -232,6 +248,10 @@ class Trainer:
         if self.config.log_dir:
             logger.set_logger_dir(self.config.log_dir)
         self._callbacks.before_train()
+        logger.info(
+            "learner state on devices %s",
+            sorted(d.id for d in self.state.step.sharding.device_set),
+        )
         self._publish_params()
         # multi-host rank-failure detection (SURVEY §5): a dead peer wedges
         # this rank in the next psum forever; the watchdog converts that into

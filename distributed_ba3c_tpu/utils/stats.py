@@ -56,8 +56,18 @@ class StatHolder:
     unchanged.
     """
 
-    def __init__(self, log_dir: Optional[str] = None, tensorboard: bool = True):
+    def __init__(
+        self,
+        log_dir: Optional[str] = None,
+        tensorboard: bool = True,
+        run_info: Optional[Dict[str, object]] = None,
+    ):
+        """``run_info`` (e.g. ``{"device": {...}}``) is written into the
+        FIRST record this process finalizes — stat.json then names the
+        device its numbers came from, per run and per resume. It is kept
+        out of the returned record and of TensorBoard, which are scalars."""
         self.log_dir = log_dir
+        self._run_info = dict(run_info or {})
         self.stat_now: Dict[str, float] = {}
         self.stat_history: List[Dict[str, float]] = []
         self._print_filter = None
@@ -91,7 +101,8 @@ class StatHolder:
     def finalize(self) -> Dict[str, float]:
         """Close the epoch: append the record, write stat.json + TB events."""
         record = dict(self.stat_now)
-        self.stat_history.append(record)
+        self.stat_history.append({**record, **self._run_info})
+        self._run_info = {}
         if self._path is not None:
             tmp = self._path + ".tmp"
             with open(tmp, "w") as f:

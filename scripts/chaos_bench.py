@@ -478,6 +478,10 @@ def main() -> int:
             "network": net,
         }
         # evidence prints BEFORE the verdict (the repo's bench contract)
+        import jax
+
+        # a CPU instrument by design: name the platform its rates came from
+        out["platform"] = jax.default_backend()
         print(json.dumps(out))
         ok = (
             net["gate_passed"]
@@ -615,6 +619,10 @@ def main() -> int:
     }
     # evidence prints BEFORE the verdict: per-rep rates and events are most
     # valuable exactly when a gate fails (plane_bench precedent)
+    import jax
+
+    # a CPU instrument by design: name the platform its rates came from
+    out["platform"] = jax.default_backend()
     print(json.dumps(out))
     if failures:
         for msg in failures:

@@ -35,6 +35,9 @@ def main():
     from distributed_ba3c_tpu.utils.devicelock import guard_tpu
 
     _lock = guard_tpu("eval_fused", mode=args.tpu_lock)  # noqa: F841
+    from distributed_ba3c_tpu.utils.backend import configure_compile_cache
+
+    configure_compile_cache()
 
     mgr, target, evaluate, _ = make_checkpoint_evaluator(
         args.env, args.load, args.nr_eval, args.max_steps, args.fc_units

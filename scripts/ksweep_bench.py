@@ -59,6 +59,12 @@ def main() -> None:
         mode=args.tpu_lock,
         timeout_s=float(os.environ.get("BA3C_TPU_LOCK_TIMEOUT", "1800")),
     )
+    from distributed_ba3c_tpu.utils.backend import (
+        configure_compile_cache,
+        device_info,
+    )
+
+    configure_compile_cache()
 
     from bench import bench_fused
 
@@ -88,6 +94,7 @@ def main() -> None:
         "shape": ",".join(rows),
         "total_updates_per_window": args.total,
         "rows": rows,
+        "device": device_info(),
     }
     if len(shapes) == 1:
         # legacy single-shape schema (runs/ksweep_r5.json, test_bench.py)

@@ -517,6 +517,10 @@ def main() -> int:
                 "max_staleness": args.max_staleness,
                 "net": net,
             }
+            import jax
+
+            # a CPU instrument by design: name the platform its rates came from
+            out["platform"] = jax.default_backend()
             print(json.dumps(out))
             if failures:
                 for msg in failures:
@@ -613,6 +617,10 @@ def main() -> int:
         "net": net,
     }
     # evidence prints BEFORE the verdict (plane_bench/chaos_bench precedent)
+    import jax
+
+    # a CPU instrument by design: name the platform its rates came from
+    out["platform"] = jax.default_backend()
     print(json.dumps(out))
     if failures:
         for msg in failures:

@@ -74,8 +74,8 @@ def profile_overlap(n_envs: int, rollout_len: int, fc_units: int,
         # probe carries the device-free proxy gate quantity
         # (learner_window_coverage: the learner window is long enough to
         # hide this fraction of the actor's wall time; realized hiding
-        # additionally needs concurrent execution queues — on-chip
-        # BENCH_r06 territory; overlap_efficiency is what THIS backend
+        # additionally needs concurrent execution queues, which only a
+        # chip run can show; overlap_efficiency is what THIS backend
         # realizes)
         **probe,
         "n_envs": n_envs * n_chips,
@@ -108,6 +108,9 @@ def main():
     from distributed_ba3c_tpu.utils.devicelock import guard_tpu
 
     _lock = guard_tpu("profile_split", mode=args.tpu_lock)  # noqa: F841
+    from distributed_ba3c_tpu.utils.backend import configure_compile_cache
+
+    configure_compile_cache()
 
     if args.overlap:
         row = profile_overlap(

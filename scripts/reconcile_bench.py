@@ -664,6 +664,10 @@ def main() -> int:
     }
     # evidence prints BEFORE the verdict (the repo's bench contract): the
     # per-phase detail and the decision trail matter most on a failure
+    import jax
+
+    # a CPU instrument by design: name the platform its rates came from
+    out["platform"] = jax.default_backend()
     print(json.dumps(out))
     if failures:
         for msg in failures:

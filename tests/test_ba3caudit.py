@@ -107,21 +107,29 @@ def test_t2_flags_dropped_donation():
 _GRAD_SHAPE = (4, 4)
 
 
-def _psum_step(n_psums):
-    from distributed_ba3c_tpu.parallel.mesh import DATA_AXIS, shard_map
+def _psum_step(extra_psums=0, check_vma=True):
+    """A toy sharded grad step. With ``check_vma`` (the step builders'
+    mode) the gradient of the replicated ``params`` is summed over the data
+    axis by the reduction the shard_map transpose inserts — ONE all-reduce
+    with no psum in the source; every ``extra_psums`` is the double-pmean
+    bug (the grad scaled by the axis size again). ``check_vma=False`` turns
+    the inserted reduction off: with no explicit psum each device applies
+    its shard-local gradient."""
+    from jax.sharding import PartitionSpec as P
+
+    from distributed_ba3c_tpu.parallel.mesh import DATA_AXIS
 
     mesh = audit_mod.canonical_mesh()
 
     def body(params, x):
         g = jax.grad(lambda p: jnp.sum((x @ p) ** 2))(params)
-        for _ in range(n_psums):
+        for _ in range(extra_psums):
             g = jax.lax.psum(g, DATA_AXIS)
         return params - g
 
-    from jax.sharding import PartitionSpec as P
-
-    return jax.jit(shard_map(
-        body, mesh=mesh, in_specs=(P(), P("data")), out_specs=P()
+    return jax.jit(jax.shard_map(
+        body, mesh=mesh, in_specs=(P(), P("data")), out_specs=P(),
+        check_vma=check_vma,
     ))
 
 
@@ -130,27 +138,34 @@ _T3_ARGS = (sds(_GRAD_SHAPE, jnp.float32), sds((8, 4), jnp.float32))
 
 def test_t3_clean_on_single_grad_psum():
     t = _toy_target(None, _T3_ARGS, grad_shapes=[_GRAD_SHAPE])
-    t.jit_fn = _psum_step(1)
+    t.jit_fn = _psum_step()
+    assert rules.check_t3(t, _measure(t)) == []
+
+
+def test_t3_counts_explicit_psum_without_check_vma():
+    # the pre-check_vma spelling: no inserted reduction, one explicit psum
+    t = _toy_target(None, _T3_ARGS, grad_shapes=[_GRAD_SHAPE])
+    t.jit_fn = _psum_step(extra_psums=1, check_vma=False)
     assert rules.check_t3(t, _measure(t)) == []
 
 
 def test_t3_flags_double_psum():
     t = _toy_target(None, _T3_ARGS, grad_shapes=[_GRAD_SHAPE])
-    t.jit_fn = _psum_step(2)
+    t.jit_fn = _psum_step(extra_psums=1)
     findings = rules.check_t3(t, _measure(t))
     assert findings and "extra" in findings[0].message
 
 
 def test_t3_flags_missing_psum():
     t = _toy_target(None, _T3_ARGS, grad_shapes=[_GRAD_SHAPE])
-    t.jit_fn = _psum_step(0)
+    t.jit_fn = _psum_step(check_vma=False)
     findings = rules.check_t3(t, _measure(t))
     assert findings and "NEVER all-reduced" in findings[0].message
 
 
 def test_t3_flags_collectives_in_collective_free_entry():
     t = _toy_target(None, _T3_ARGS, allow_collectives=False)
-    t.jit_fn = _psum_step(1)
+    t.jit_fn = _psum_step()
     findings = rules.check_t3(t, _measure(t))
     assert findings and "single-device" in findings[0].message
 
@@ -424,6 +439,32 @@ def test_predictor_chunks_oversized_eval_batch_after_arm(monkeypatch):
     assert actions.shape == values.shape == greedy.shape == (5,)
 
 
+def test_predictor_publish_from_mesh_does_not_retrace(monkeypatch):
+    """The learner publishes params REPLICATED over its mesh; the buckets
+    were warmed with a freshly initialised, unplaced table. jit keys its
+    trace on the sharding, so the predictor must commit every table to its
+    own device or the first publish recompiles every bucket mid-serving."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from distributed_ba3c_tpu.models.a3c import BA3CNet
+    from distributed_ba3c_tpu.parallel.mesh import make_mesh
+    from distributed_ba3c_tpu.predict.server import BatchedPredictor
+
+    monkeypatch.setenv("BA3C_AUDIT", "1")
+    state_shape = (8, 8, 2)
+    model = BA3CNet(num_actions=3, fc_units=8)
+    params = model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, *state_shape), jnp.uint8)
+    )["params"]
+    pred = BatchedPredictor(model, params, batch_size=2)
+    pred.warmup(state_shape)
+    pred.update_params(
+        jax.device_put(params, NamedSharding(make_mesh(), P()))
+    )
+    actions, _, _ = pred.predict_batch(np.zeros((2, *state_shape), np.uint8))
+    assert actions.shape == (2,)
+
+
 def test_tripwire_fires_on_real_train_step(monkeypatch):
     """Integration: the registered sync-step site detects a batch-shape
     change after warmup (the silent-recompile regression, as a machine
@@ -443,7 +484,12 @@ def test_tripwire_fires_on_real_train_step(monkeypatch):
     opt = make_optimizer(cfg.learning_rate, cfg.adam_epsilon, cfg.grad_clip_norm)
     mesh = make_mesh()
     step = make_train_step(model, opt, cfg, mesh)
-    state = create_train_state(jax.random.PRNGKey(0), model, cfg, opt)
+    # placed as the Trainer places it: an unplaced first state differs in
+    # sharding from the state the step returns, which is a second compile
+    state = jax.device_put(
+        create_train_state(jax.random.PRNGKey(0), model, cfg, opt),
+        step.state_sharding,
+    )
 
     def batch(n):
         return {

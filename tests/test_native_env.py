@@ -10,6 +10,23 @@ pytestmark = pytest.mark.skipif(
 )
 
 
+def test_stale_library_is_rebuilt_and_a_failed_build_says_why(monkeypatch):
+    """The .so is git-ignored, so whatever copy sits in the tree may predate
+    env_core.cc: the loader runs make, which rebuilds a library older than
+    its sources — and a build that fails raises with the compiler's words
+    instead of loading the stale copy."""
+    import os
+
+    os.utime(native._LIB_PATH, (0, 0))
+    monkeypatch.setenv("CXX", "false")  # the Makefile's `CXX ?= g++`
+    with pytest.raises(ImportError, match=r"make -C .*failed \(rc"):
+        native._build()
+    monkeypatch.delenv("CXX")
+    native._build()
+    assert os.path.getmtime(native._LIB_PATH) > 0
+    assert native.CppBatchedEnv("pong", 1).num_actions == 6
+
+
 def test_create_and_metadata():
     env = native.CppBatchedEnv("pong", 4, seed=1)
     assert env.num_actions == 6 and env.n == 4

@@ -1,9 +1,9 @@
 """Pallas fused conv blocks (ops/pallas_conv.py) vs the XLA reference.
 
-Runs in interpreter mode on the CPU backend (the kernel auto-selects
-interpret off-TPU), so CI needs no TPU. Perf status (measured slower on
-v5e, default off) is documented in the module and PERF.md; these tests pin
-CORRECTNESS so the infrastructure stays trustworthy.
+Runs in interpreter mode on the CPU backend — asked for by name
+(``interpret=True`` / ``conv_backend="pallas-interpret"``), never guessed —
+so CI needs no TPU; the Mosaic-compiled kernel is compared with the XLA
+block on the chip by chip_smoke.py. These tests pin CORRECTNESS.
 """
 
 from __future__ import annotations
@@ -87,7 +87,7 @@ def test_model_pallas_backend_value_and_grad(rng):
 
     x = jnp.asarray(rng.integers(0, 256, (2, 84, 84, 4), dtype=np.uint8))
     m_x = BA3CNet(num_actions=4)
-    m_p = BA3CNet(num_actions=4, conv_backend="pallas")
+    m_p = BA3CNet(num_actions=4, conv_backend="pallas-interpret")
     params = m_x.init(jax.random.PRNGKey(0), x)["params"]
     # identical param trees (names/shapes interchangeable)
     out_x = m_x.apply({"params": params}, x)

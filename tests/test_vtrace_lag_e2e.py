@@ -60,19 +60,28 @@ def test_vtrace_learns_under_lag_and_matches_or_beats_sync(tmp_path):
     assert vt["eval_mean_score"] >= sync["eval_mean_score"] - 0.1, (vt, sync)
 
 
+@pytest.mark.slow
 def test_overlap_lag1_matches_fused_learning_milestone():
-    """Overlap-vs-fused equivalence under REAL lag (ISSUE 8, tier-1/CPU):
-    same seeds, same budget, the lag-1 V-trace overlap run must reach the
-    fused run's learning milestone on jax Pong.
+    """Overlap-vs-fused equivalence under REAL lag (ISSUE 8): same seeds,
+    same budget, the lag-1 V-trace overlap run must reach the fused run's
+    learning milestone on jax Pong.
 
-    The milestone is the strong, reproducible optimization signature the
-    fused run exhibits in this CPU-sized budget (40 updates, 16 envs x 3
-    rollout, fc16): the policy COMMITS (mean entropy collapses from
-    log(6) = 1.79 to < 0.5) while the value function tracks the realized
-    returns (final-window value_loss in a fixed band of the fused run's).
-    The real Pong >= 18 milestone is an on-chip criterion (BENCH/RESULTS);
-    this is its device-free proxy, and the bit-exact lag-0 + one-update
-    math parity gates live in tests/test_overlap.py.
+    The milestone is the optimization signature the fused run exhibits in
+    this CPU-sized budget (40 updates, 16 envs x 3 rollout, fc16): the
+    policy COMMITS (mean entropy collapses from log(6) = 1.79 to < 0.5)
+    while the value function tracks the realized returns (final-window
+    value_loss in a band of the fused run's).
+
+    Both are compared as MEDIANS OVER FIVE SEEDS. At this budget a single
+    seed resolves nothing: the fused schedule's own final-window value loss
+    spans 1.3-5.3 across seeds 0-4 and the overlap schedule's 0.4-13
+    (measured PR 21, jax 0.9.0), against a +-50% band, so two single-seed
+    draws agree or not by luck — the one-seed form of this test passed on
+    jax 0.4.37, failed on 0.9.0, and moved again when a gradient psum was
+    reassociated. The across-seed medians agree to a few percent. Ten
+    40-update runs on the CPU mesh are why this is a slow test; the lag-0
+    bit-exact and one-update math parity gates stay in tier-1
+    (tests/test_overlap.py), and the on-chip comparison is ROADMAP S4.
     """
     import jax
     import numpy as np
@@ -97,38 +106,38 @@ def test_overlap_lag1_matches_fused_learning_milestone():
     n_data = mesh.shape["data"]
     n_envs = 2 * n_data
     N = 40
+    seeds = range(5)
 
-    def run(make_step):
-        step = make_step()
-        state = step.put(
-            create_fused_state(
-                jax.random.PRNGKey(0), model, cfg, opt, pong, n_envs,
-                n_shards=n_data,
+    def run(step):
+        """Per seed: (first entropy, last entropy, final-window value loss)."""
+        out = []
+        for seed in seeds:
+            state = step.put(
+                create_fused_state(
+                    jax.random.PRNGKey(seed), model, cfg, opt, pong, n_envs,
+                    n_shards=n_data,
+                )
             )
-        )
-        ent, vl = [], []
-        for _ in range(N):
-            state, m = step(state, cfg.entropy_beta)
-            ent.append(float(m["entropy"]))
-            vl.append(float(m["value_loss"]))
-        return ent, vl
+            ent, vl = [], []
+            for _ in range(N):
+                state, m = step(state, cfg.entropy_beta)
+                ent.append(float(m["entropy"]))
+                vl.append(float(m["value_loss"]))
+            out.append((ent[0], ent[-1], float(np.mean(vl[-10:]))))
+        return np.median(np.asarray(out), axis=0)
 
-    f_ent, f_vl = run(
-        lambda: make_fused_step(model, opt, cfg, mesh, pong, rollout_len=3)
+    f_ent0, f_ent, f_vl = run(
+        make_fused_step(model, opt, cfg, mesh, pong, rollout_len=3)
     )
-    o_ent, o_vl = run(
-        lambda: make_overlap_step(model, opt, cfg, mesh, pong, rollout_len=3)
+    _, o_ent, o_vl = run(
+        make_overlap_step(model, opt, cfg, mesh, pong, rollout_len=3)
     )
 
     # the fused run must itself reach the milestone (else the test budget
     # regressed and the comparison below means nothing)
-    assert f_ent[0] > 1.5 and f_ent[-1] < 0.5, (f_ent[0], f_ent[-1])
+    assert f_ent0 > 1.5 and f_ent < 0.5, (f_ent0, f_ent)
     # overlap, trained on one-update-stale V-trace-corrected experience,
     # reaches the same policy-commitment milestone
-    assert o_ent[-1] < max(0.5, 2.0 * f_ent[-1]), (o_ent[-1], f_ent[-1])
+    assert o_ent < max(0.5, 2.0 * f_ent), (o_ent, f_ent)
     # and its value function lands in the fused run's band
-    f_final = float(np.mean(f_vl[-10:]))
-    o_final = float(np.mean(o_vl[-10:]))
-    assert abs(o_final - f_final) <= max(0.5 * f_final, 0.1), (
-        o_final, f_final,
-    )
+    assert abs(o_vl - f_vl) <= max(0.5 * f_vl, 0.1), (o_vl, f_vl)

@@ -13,15 +13,22 @@ import re
 from collections import Counter
 from typing import Any, Dict, Iterator, List, Tuple
 
+# All-reduce-sum as a jaxpr spells it. Under shard_map's check_vma typing
+# (the only mode the step builders use) every cross-device sum — a user's
+# lax.psum/pmean AND the reduction the transpose inserts for the gradient of
+# a replicated input — is a ``psum_invariant`` eqn; plain ``psum`` appears
+# only under check_vma=False.
+PSUM_PRIMS = {"psum", "psum_invariant"}
+
 # communicating collectives (primitive names as they appear in jaxprs)
-COLLECTIVE_PRIMS = {
-    "psum",
+COLLECTIVE_PRIMS = PSUM_PRIMS | {
     "pmin",
     "pmax",
     "ppermute",
     "pbroadcast",
     "all_gather",
     "all_to_all",
+    "all_gather_invariant",
     "reduce_scatter",
     "psum_scatter",
 }
@@ -101,16 +108,17 @@ def dot_dtype_census(jaxpr) -> Counter:
 
 
 def nonscalar_psum_shapes(jaxpr) -> List[Tuple[int, ...]]:
-    """Operand shapes of every psum over a non-scalar array.
+    """Operand shapes of every all-reduce-sum over a non-scalar array.
 
-    The step's gradient all-reduce is one psum per param leaf; everything
-    else the steps psum (metrics, episode counters) is scalar, so the
-    non-scalar psum multiset IS the gradient-reduction census. (psum is
-    variadic — one eqn may carry several operands.)
+    The step's gradient all-reduce is one psum per param leaf (inserted by
+    the shard_map transpose, see :data:`PSUM_PRIMS`); everything else the
+    steps psum (metrics, episode counters) is scalar, so the non-scalar
+    psum multiset IS the gradient-reduction census. (psum is variadic —
+    one eqn may carry several operands.)
     """
     shapes: List[Tuple[int, ...]] = []
     for e in iter_eqns(jaxpr):
-        if e.primitive.name == "psum":
+        if e.primitive.name in PSUM_PRIMS:
             for a in _in_avals(e):
                 if getattr(a, "ndim", 0) >= 1:
                     shapes.append(tuple(a.shape))
