@@ -19,6 +19,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from distributed_ba3c_tpu.utils.profiling import ROLLOUT_RENDER, device_scope
+
 num_actions = 7
 obs_shape = (84, 84)
 
@@ -226,6 +228,7 @@ def step(state: State, action: jax.Array, key: jax.Array):
     return state, render(state), reward, done
 
 
+@device_scope(ROLLOUT_RENDER)
 def render(state: State) -> jax.Array:
     h, w = obs_shape
     ys = (jnp.arange(h, dtype=jnp.float32) + 0.5) / h

@@ -18,6 +18,8 @@ from typing import NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 
+from distributed_ba3c_tpu.utils.profiling import ROLLOUT_RENDER, device_scope
+
 num_actions = 4
 obs_shape = (84, 84)
 
@@ -177,6 +179,7 @@ def step(state: State, action: jax.Array, key: jax.Array):
     return state, render(state), reward, done
 
 
+@device_scope(ROLLOUT_RENDER)
 def render(state: State) -> jax.Array:
     h, w = obs_shape
     ys = (jnp.arange(h, dtype=jnp.float32) + 0.5) / h

@@ -30,6 +30,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from distributed_ba3c_tpu.utils.profiling import ROLLOUT_RENDER, device_scope
+
 num_actions = 5
 obs_shape = (84, 84)
 
@@ -150,6 +152,7 @@ def step(state: State, action: jax.Array, key: jax.Array):
     return new_state, render(new_state), reward, done
 
 
+@device_scope(ROLLOUT_RENDER)
 def render(state: State) -> jax.Array:
     """Scrolling viewport centered on the agent."""
     h, w = obs_shape

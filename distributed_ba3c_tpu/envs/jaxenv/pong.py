@@ -23,6 +23,8 @@ from typing import NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 
+from distributed_ba3c_tpu.utils.profiling import ROLLOUT_RENDER, device_scope
+
 num_actions = 6
 obs_shape = (84, 84)
 
@@ -169,6 +171,7 @@ def step(state: State, action: jax.Array, key: jax.Array) -> Tuple[State, jax.Ar
     return state, render(state), reward, done
 
 
+@device_scope(ROLLOUT_RENDER)
 def render(state: State) -> jax.Array:
     """Rasterize to uint8 [84, 84] (rows = y, cols = x). Pure masks, no loops."""
     h, w = obs_shape

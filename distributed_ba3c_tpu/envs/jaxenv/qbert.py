@@ -21,6 +21,8 @@ from typing import NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 
+from distributed_ba3c_tpu.utils.profiling import ROLLOUT_RENDER, device_scope
+
 num_actions = 5
 obs_shape = (84, 84)
 
@@ -132,6 +134,7 @@ def step(state: State, action: jax.Array, key: jax.Array):
     return new_state, render(new_state), reward, done
 
 
+@device_scope(ROLLOUT_RENDER)
 def render(state: State) -> jax.Array:
     """Isometric-ish pyramid: cube (r,c) centered at
     x = 0.5 + (c - r/2) * 0.13, y = 0.18 + r * 0.13."""

@@ -48,6 +48,8 @@ from distributed_ba3c_tpu.ops.loss import a3c_loss
 from distributed_ba3c_tpu.ops.returns import n_step_returns
 from distributed_ba3c_tpu.parallel.mesh import DATA_AXIS, shard_local
 from distributed_ba3c_tpu.parallel.train_step import TrainState
+from distributed_ba3c_tpu.utils import profiling
+from distributed_ba3c_tpu.utils.profiling import device_scope, host_span
 
 #: metrics that accumulate IN STATE across an epoch (reset by the outer
 #: loop): the K-step scan reduction takes their LAST value, every other
@@ -91,34 +93,38 @@ def make_rollout_body(model, cfg: BA3CConfig, env, params,
     def rollout_body(carry, _):
         env_state, stack, key, ep_ret, ep_cnt, ep_sum = carry
         B = stack.shape[0]
-        out = apply_fn(params, stack)
-        key, k_act, k_env = jax.random.split(key, 3)
-        actions = jax.random.categorical(k_act, out.logits, axis=-1).astype(
-            jnp.int32
-        )
-        env_keys = jax.random.split(k_env, B)
-        env_state, obs, reward, done = jax.vmap(env.step)(
-            env_state, actions, env_keys
-        )
-        # a done frame must not leak history into the new episode: zero
-        # the carried history via a mask multiply (single fused pass —
-        # cheaper than building a zeroed copy and where-selecting)
-        keep = (~done).astype(stack.dtype)[:, None, None, None]
-        new_stack = jnp.concatenate(
-            [stack[..., 1:] * keep, obs[..., None]], axis=-1
-        )
-        # episode bookkeeping (done ⇒ env auto-restarted inside step);
-        # scores accumulate RAW rewards, the learner sees clipped ones
-        ep_ret = ep_ret + reward
-        donef = done.astype(jnp.float32)
-        ep_sum = ep_sum + ep_ret * donef
-        ep_cnt = ep_cnt + done.astype(jnp.int32)
-        ep_ret = ep_ret * (1.0 - donef)
-        r_learn = (
-            jnp.clip(reward, -cfg.reward_clip, cfg.reward_clip)
-            if cfg.reward_clip
-            else reward
-        )
+        with device_scope(profiling.ROLLOUT_POLICY):
+            out = apply_fn(params, stack)
+        with device_scope(profiling.ROLLOUT_SAMPLE):
+            key, k_act, k_env = jax.random.split(key, 3)
+            actions = jax.random.categorical(
+                k_act, out.logits, axis=-1
+            ).astype(jnp.int32)
+        with device_scope(profiling.ROLLOUT_ENV_STEP):
+            env_keys = jax.random.split(k_env, B)
+            env_state, obs, reward, done = jax.vmap(env.step)(
+                env_state, actions, env_keys
+            )
+        with device_scope(profiling.ROLLOUT_STACK):
+            # a done frame must not leak history into the new episode: zero
+            # the carried history via a mask multiply (single fused pass —
+            # cheaper than building a zeroed copy and where-selecting)
+            keep = (~done).astype(stack.dtype)[:, None, None, None]
+            new_stack = jnp.concatenate(
+                [stack[..., 1:] * keep, obs[..., None]], axis=-1
+            )
+            # episode bookkeeping (done ⇒ env auto-restarted inside step);
+            # scores accumulate RAW rewards, the learner sees clipped ones
+            ep_ret = ep_ret + reward
+            donef = done.astype(jnp.float32)
+            ep_sum = ep_sum + ep_ret * donef
+            ep_cnt = ep_cnt + done.astype(jnp.int32)
+            ep_ret = ep_ret * (1.0 - donef)
+            r_learn = (
+                jnp.clip(reward, -cfg.reward_clip, cfg.reward_clip)
+                if cfg.reward_clip
+                else reward
+            )
         ys = (stack, actions, r_learn, donef)
         if record_log_probs:
             # behavior log-prob of the SAMPLED action at the ROLLOUT
@@ -239,16 +245,18 @@ def make_fused_step(
             state.ep_count,
             state.ep_return_sum,
         )
-        (env_state, stack, key, ep_ret, ep_cnt, ep_sum), traj = jax.lax.scan(
-            rollout_body, carry0, None, length=rollout_len
-        )
+        with device_scope(profiling.ROLLOUT):
+            (env_state, stack, key, ep_ret, ep_cnt, ep_sum), traj = (
+                jax.lax.scan(rollout_body, carry0, None, length=rollout_len)
+            )
         states_t, actions_t, rewards_t, dones_t = traj  # [T, B, ...]
 
         # bootstrap from the post-rollout stack (no gradient)
-        bootstrap = model.apply({"params": params}, stack).value
-        returns_t = n_step_returns(
-            rewards_t, dones_t, jax.lax.stop_gradient(bootstrap), cfg.gamma
-        )
+        with device_scope(profiling.RETURNS):
+            bootstrap = model.apply({"params": params}, stack).value
+            returns_t = n_step_returns(
+                rewards_t, dones_t, jax.lax.stop_gradient(bootstrap), cfg.gamma
+            )
 
         T, B = actions_t.shape
 
@@ -263,18 +271,20 @@ def make_fused_step(
 
             def loss_fn(pp):
                 out = model.apply({"params": pp}, states_c)
-                loss = a3c_loss(
-                    out.logits,
-                    out.value,
-                    actions_c,
-                    returns_c,
-                    entropy_beta=entropy_beta,
-                    value_loss_coef=cfg.value_loss_coef,
-                    huber_delta=cfg.value_huber_delta,
-                )
+                with device_scope(profiling.LEARNER_LOSS):
+                    loss = a3c_loss(
+                        out.logits,
+                        out.value,
+                        actions_c,
+                        returns_c,
+                        entropy_beta=entropy_beta,
+                        value_loss_coef=cfg.value_loss_coef,
+                        huber_delta=cfg.value_huber_delta,
+                    )
                 return loss.total, loss
 
-            return jax.value_and_grad(loss_fn, has_aux=True)(p)
+            with device_scope(profiling.LEARNER):
+                return jax.value_and_grad(loss_fn, has_aux=True)(p)
 
         flat = lambda x: x.reshape(T * B, *x.shape[2:])  # noqa: E731
         states_f, actions_f, returns_f = (
@@ -315,15 +325,20 @@ def make_fused_step(
                     chunked(returns_f)[1:],
                 ),
             )
-            grads = jax.tree_util.tree_map(lambda g: g / n_chunks, grads)
+            with device_scope(profiling.GRAD_REDUCE):
+                grads = jax.tree_util.tree_map(lambda g: g / n_chunks, grads)
             aux = jax.tree_util.tree_map(lambda a: a / n_chunks, aux_sum)
-        grads = jax.lax.psum(grads, DATA_AXIS)
-        n_data = jax.lax.axis_size(DATA_AXIS)
-        grads = jax.tree_util.tree_map(lambda g: g / n_data, grads)
+        with device_scope(profiling.GRAD_REDUCE):
+            grads = jax.lax.psum(grads, DATA_AXIS)
+            n_data = jax.lax.axis_size(DATA_AXIS)
+            grads = jax.tree_util.tree_map(lambda g: g / n_data, grads)
 
-        opt_state = inject_learning_rate(state.train.opt_state, learning_rate)
-        updates, new_opt_state = optimizer.update(grads, opt_state, params)
-        new_params = optax.apply_updates(params, updates)
+        with device_scope(profiling.OPTIMIZER):
+            opt_state = inject_learning_rate(
+                state.train.opt_state, learning_rate
+            )
+            updates, new_opt_state = optimizer.update(grads, opt_state, params)
+            new_params = optax.apply_updates(params, updates)
 
         new_state = FusedState(
             train=TrainState(
@@ -338,21 +353,26 @@ def make_fused_step(
             ep_count=ep_cnt,
             ep_return_sum=ep_sum,
         )
-        metrics = {
-            "loss": aux.total,
-            "policy_loss": aux.policy_loss,
-            "value_loss": aux.value_loss,
-            "entropy": aux.entropy,
-            "pred_value": aux.pred_value,
-            **grad_summaries(grads),
-            "reward_per_step": jnp.mean(rewards_t),
-        }
-        metrics = {k: jax.lax.pmean(v, DATA_AXIS) for k, v in metrics.items()}
-        # cumulative-in-state metrics MUST be listed in CUMULATIVE_METRICS:
-        # that's what tells the K>1 scan reduction to take the last value
-        # instead of the window mean (ADVICE r4 #3)
-        metrics["episodes"] = jax.lax.psum(jnp.sum(ep_cnt), DATA_AXIS)
-        metrics["episode_return_sum"] = jax.lax.psum(jnp.sum(ep_sum), DATA_AXIS)
+        with device_scope(profiling.METRICS):
+            metrics = {
+                "loss": aux.total,
+                "policy_loss": aux.policy_loss,
+                "value_loss": aux.value_loss,
+                "entropy": aux.entropy,
+                "pred_value": aux.pred_value,
+                **grad_summaries(grads),
+                "reward_per_step": jnp.mean(rewards_t),
+            }
+            metrics = {
+                k: jax.lax.pmean(v, DATA_AXIS) for k, v in metrics.items()
+            }
+            # cumulative-in-state metrics MUST be listed in
+            # CUMULATIVE_METRICS: that's what tells the K>1 scan reduction
+            # to take the last value instead of the window mean (ADVICE r4 #3)
+            metrics["episodes"] = jax.lax.psum(jnp.sum(ep_cnt), DATA_AXIS)
+            metrics["episode_return_sum"] = jax.lax.psum(
+                jnp.sum(ep_sum), DATA_AXIS
+            )
         assert set(CUMULATIVE_METRICS) <= set(metrics)
         return new_state, metrics
 
@@ -396,13 +416,14 @@ def make_fused_step(
     jitted = tripwire_jit("fused.step", sharded, donate_argnums=(0,))
 
     def step(state, entropy_beta, learning_rate=None):
-        if learning_rate is None:
-            learning_rate = cfg.learning_rate
-        return jitted(
-            state,
-            jnp.asarray(entropy_beta, jnp.float32),
-            jnp.asarray(learning_rate, jnp.float32),
-        )
+        with host_span(profiling.SPAN_STEP):
+            if learning_rate is None:
+                learning_rate = cfg.learning_rate
+            with host_span(profiling.SPAN_STEP_HYPER):
+                entropy_beta = jnp.asarray(entropy_beta, jnp.float32)
+                learning_rate = jnp.asarray(learning_rate, jnp.float32)
+            with host_span(profiling.SPAN_STEP_ENQUEUE):
+                return jitted(state, entropy_beta, learning_rate)
 
     replicated = NamedSharding(mesh, P())
     batched = NamedSharding(mesh, batch_spec)
@@ -822,7 +843,8 @@ def _fused_epoch_body(
                 jax.block_until_ready(metrics)  # ba3clint: disable=J1 — first dispatch only, guarded above
                 first_dispatch_s = time.monotonic() - t0
                 holder.add_stat("first_dispatch_s", first_dispatch_s)
-        metrics = {k: float(v) for k, v in metrics.items()}
+        with host_span(profiling.SPAN_EPOCH_FETCH):
+            metrics = {k: float(v) for k, v in metrics.items()}
         # the fetch above forced every dispatch's collectives to completion:
         # proven progress — don't charge the upcoming eval/save to the
         # compute window's stall budget
@@ -843,7 +865,8 @@ def _fused_epoch_body(
         # reset the per-env episode accumulators for the next window
         # (step-provided hook: the fused and overlap steps keep these
         # fields in different state layouts)
-        state = step.reset_episode_stats(state, n_envs)
+        with host_span(profiling.SPAN_EPOCH_RESET_STATS):
+            state = step.reset_episode_stats(state, n_envs)
         if os.environ.get("BA3C_PARAM_DIGEST"):
             # divergence detector for multi-host runs: ranks log this line
             # per epoch; any mismatch across ranks means the psum'd update
@@ -866,9 +889,10 @@ def _fused_epoch_body(
                 # compile or a tightly-sized timeout 75-loops right here
                 watchdog.grace()
                 first_eval_done = True
-            eval_mean, eval_max, eval_n = evaluate(
-                state.train.params, 1000 + epoch
-            )
+            with host_span(profiling.SPAN_EPOCH_EVAL):
+                eval_mean, eval_max, eval_n = evaluate(
+                    state.train.params, 1000 + epoch
+                )
             if eval_n > 0:
                 holder.add_stat("eval_mean_score", eval_mean)
                 holder.add_stat("eval_max_score", eval_max)
@@ -916,7 +940,8 @@ def _fused_epoch_body(
         )
         # epoch-boundary checkpoint: the fetch is the save's payload, once
         # per epoch — not a per-step sync
-        ckpt.save(jax.device_get(state.train), int(state.train.step))  # ba3clint: disable=J1
+        with host_span(profiling.SPAN_EPOCH_CHECKPOINT):
+            ckpt.save(jax.device_get(state.train), int(state.train.step))  # ba3clint: disable=J1
         telemetry.record("checkpoint", step=int(state.train.step))
         # keep-best on GREEDY EVAL (not training-policy returns): the
         # reference's MaxSaver tracked the Evaluator's number

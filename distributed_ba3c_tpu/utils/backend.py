@@ -47,14 +47,19 @@ def configure_compile_cache() -> Optional[str]:
     chip compiles, and this jaxlib's CPU client logs a machine-feature
     mismatch error on every cached executable it loads. Child processes
     that compile for a chip call this too and resolve the same directory.
+    Wherever a cache is in use its key takes in the program's metadata.
     """
     placed = os.environ.get(CACHE_DIR_ENV)
-    if placed:
-        return placed
-    if cpu_only():
+    if not placed and cpu_only():
         return None
     import jax
 
+    # the default key ignores op names and source lines, so a program read
+    # from the cache keeps those it was first compiled with: a capture
+    # would show an older build's scopes (utils/profiling.py), or none
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    if placed:
+        return placed
     jax.config.update("jax_compilation_cache_dir", REPO_CACHE_DIR)
     return REPO_CACHE_DIR
 

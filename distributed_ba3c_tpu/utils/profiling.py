@@ -1,41 +1,87 @@
-"""Tracing/profiling hooks.
+"""The package's one home for ``jax.profiler``: names, spans, and the reader.
 
-Reference equivalent (SURVEY.md §5): nothing built-in beyond
-``utils/timer.py`` ``timed_operation`` — op-level profiling was offline
-(VTune/TF timeline). The rebuild does better with the tools XLA ships:
+Reference equivalent (SURVEY.md §5): nothing built-in — op-level profiling
+was offline (VTune/TF timeline). The rebuild uses what XLA ships:
 
-- :func:`timed_operation` — the reference's host-side timer, kept API-alike.
-- :func:`start_server` — ``jax.profiler`` trace server; connect TensorBoard
-  or ``jax.profiler.trace`` to capture device timelines (HLO op breakdown,
-  ICI collective time) from a live run.
-- :func:`step_annotation` — wraps a train step in a named trace region so
-  captures show per-step boundaries.
+- the **scope names** of the fused step's phases (``ROLLOUT`` ... ``METRICS``)
+  and :func:`device_scope`, a ``jax.named_scope`` that puts one on the
+  ``op_name`` metadata of every HLO instruction traced inside it. Metadata
+  only: the compiled program is the same with or without them.
+- the **span names** of the fused trainer's host work (``SPAN_*``) and
+  :func:`host_span`, a ``jax.profiler.TraceAnnotation``: it lands in the host
+  planes of the same ``.xplane.pb`` as the device ops, so host spans and
+  device ops share one clock by construction. Inert while no capture is open.
+- :func:`start_server` — ``jax.profiler`` trace server (``--profiler_port``);
+  :func:`step_annotation` — per-step regions of the plane trainer.
+- the **reader** of a capture (``.xplane.pb``), with nothing but JAX:
+  :func:`op_time_by_scope`, :func:`host_spans`, and :func:`scope_of`, the
+  rule by which an op gets its scope.
+
+See docs/observability.md "Reading a device capture of the fused trainer".
 """
 
 from __future__ import annotations
 
 import contextlib
-import time
+import re
+from typing import Dict, Iterable, List, Optional, Tuple
+
+import jax
+from jax.profiler import ProfileData
 
 from distributed_ba3c_tpu.utils import logger
 
+# -- device scopes of fused.step (fused/loop.py, envs/jaxenv/pong.py) --------
+# A name is the path a reader reports; ``device_scope`` opens its last
+# component, so a scope nested in code nests in the name.
+ROLLOUT = "rollout"
+ROLLOUT_POLICY = "rollout/policy"
+ROLLOUT_SAMPLE = "rollout/sample"
+ROLLOUT_ENV_STEP = "rollout/env_step"
+ROLLOUT_RENDER = "rollout/env_step/render"
+ROLLOUT_STACK = "rollout/stack"
+RETURNS = "returns"
+LEARNER = "learner"
+LEARNER_LOSS = "learner/loss"
+GRAD_REDUCE = "grad_reduce"
+OPTIMIZER = "optimizer"
+METRICS = "metrics"
+#: the phases of one update, in program order: every scoped op is in one
+PHASES = (ROLLOUT, RETURNS, LEARNER, GRAD_REDUCE, OPTIMIZER, METRICS)
+SCOPES = (
+    ROLLOUT, ROLLOUT_POLICY, ROLLOUT_SAMPLE, ROLLOUT_ENV_STEP, ROLLOUT_RENDER,
+    ROLLOUT_STACK, RETURNS, LEARNER, LEARNER_LOSS, GRAD_REDUCE, OPTIMIZER,
+    METRICS,
+)
+#: reader-only splits of ``learner``: JAX marks the backward pass itself
+LEARNER_FWD = "learner:fwd"
+LEARNER_BWD = "learner:bwd"
+UNSCOPED = "unscoped"
 
-@contextlib.contextmanager
-def timed_operation(msg: str, log_start: bool = False):
-    """Log the wall-clock duration of a block (reference ``timed_operation``)."""
-    if log_start:
-        logger.info("start %s ...", msg)
-    t0 = time.monotonic()
-    try:
-        yield
-    finally:
-        logger.info("%s finished, time:%.4f sec.", msg, time.monotonic() - t0)
+# -- host spans of the fused trainer ----------------------------------------
+SPAN_PREFIX = "fused."
+SPAN_STEP = "fused.step"
+SPAN_STEP_HYPER = "fused.step.hyper"
+SPAN_STEP_ENQUEUE = "fused.step.enqueue"
+SPAN_EPOCH_FETCH = "fused.epoch.fetch"
+SPAN_EPOCH_RESET_STATS = "fused.epoch.reset_stats"
+SPAN_EPOCH_EVAL = "fused.epoch.eval"
+SPAN_EPOCH_CHECKPOINT = "fused.epoch.checkpoint"
+
+
+def device_scope(name: str):
+    """``jax.named_scope`` of one of the scope names above (context manager
+    or decorator)."""
+    return jax.named_scope(name.rsplit("/", 1)[-1])
+
+
+def host_span(name: str):
+    """A host span in the profiler's own trace, on the device trace's clock."""
+    return jax.profiler.TraceAnnotation(name)
 
 
 def start_server(port: int) -> None:
     """Start the jax.profiler gRPC server (TensorBoard-attachable)."""
-    import jax
-
     jax.profiler.start_server(port)
     logger.info("jax.profiler server listening on :%d", port)
 
@@ -56,8 +102,6 @@ def step_annotation(
     as metadata — line the Perfetto export of ``scripts/trace_dump.py``
     up against the XLA capture by matching the ids (ROADMAP item 1's
     on-chip captures land next to host spans instead of in a vacuum)."""
-    import jax
-
     kwargs = {"step_num": step}
     if trace_id is not None:
         kwargs["trace_id"] = int(trace_id)
@@ -67,12 +111,234 @@ def step_annotation(
         yield
 
 
-def capture_trace(log_dir: str, seconds: float, fn, *args, **kwargs):
-    """Run ``fn`` under a trace capture written to ``log_dir`` (offline use)."""
-    import jax
+# -- reading a capture --------------------------------------------------------
+# How an op gets its scope on the v5e (looked at by hand, PR 24). The device
+# plane's ``XLA Ops`` events are named by the instruction's whole HLO text,
+# which has no ``metadata={...}``; ``jax.profiler.ProfileData`` shows an
+# event's own stats (offset, duration) and not those of its *event metadata*;
+# the capture embeds no HLO module (``/host:metadata`` is empty). But every
+# instruction's event metadata does carry the stat ``tf_op``: the
+# instruction's ``op_name``, scopes and all. So the reader takes events and
+# their times from ``ProfileData`` and the ``tf_op`` of each event name from
+# the file itself: an ``.xplane.pb`` is a protobuf (``XSpace``), and the few
+# fields needed are read off its wire format below, with no schema and
+# nothing beyond the standard library.
 
-    with jax.profiler.trace(log_dir):
-        out = fn(*args, **kwargs)
-        jax.block_until_ready(out)
-    logger.info("trace written to %s", log_dir)
-    return out
+DEVICE_PLANE_PREFIX = "/device:TPU:"
+OPS_LINE = "XLA Ops"
+OP_NAME_STAT = "tf_op"
+_OPCODE = re.compile(r"(?<=\s)([a-z][a-z0-9\-]*)\(")
+#: an event of these opcodes spans its body's events: never summed as work
+_CONTAINERS = ("while", "conditional", "call")
+
+
+def _varint(buf, i: int) -> Tuple[int, int]:
+    value = shift = 0
+    while True:
+        byte = buf[i]
+        i += 1
+        value |= (byte & 0x7F) << shift
+        if byte < 0x80:
+            return value, i
+        shift += 7
+
+
+def _fields(buf) -> Iterable[Tuple[int, object]]:
+    """(field number, value) of one protobuf message: an int for a varint,
+    a memoryview for a length-delimited field; fixed-width ones skipped."""
+    i, end = 0, len(buf)
+    while i < end:
+        tag, i = _varint(buf, i)
+        number, wire = tag >> 3, tag & 7
+        if wire == 0:
+            value, i = _varint(buf, i)
+            yield number, value
+        elif wire == 2:
+            size, i = _varint(buf, i)
+            yield number, buf[i:i + size]
+            i += size
+        elif wire == 1:
+            i += 8
+        elif wire == 5:
+            i += 4
+        else:
+            raise ValueError(f"wire type {wire} in an xplane file")
+
+
+def _map_entry(buf) -> Tuple[int, object]:
+    key = value = None
+    for number, v in _fields(buf):
+        if number == 1:
+            key = v
+        elif number == 2:
+            value = v
+    return key, value
+
+
+def _plane_op_names(plane) -> Tuple[str, Dict[str, str]]:
+    """(plane name, {event name: op_name}) of one ``XPlane`` message.
+
+    XPlane: name=2, lines=3 (skipped unread), event_metadata=4 and
+    stat_metadata=5 (maps id -> message). XEventMetadata: name=2, stats=5.
+    XStatMetadata: name=2. XStat: metadata_id=1, str_value=5, ref_value=7
+    (the id of a stat metadata whose name is the string)."""
+    name, events, stat_names = "", [], {}
+    for number, v in _fields(plane):
+        if number == 2:
+            name = bytes(v).decode()
+        elif number == 4:
+            events.append(_map_entry(v)[1])
+        elif number == 5:
+            key, meta = _map_entry(v)
+            for n, s in _fields(meta):
+                if n == 2:
+                    stat_names[key] = bytes(s).decode()
+    out: Dict[str, str] = {}
+    if not name.startswith(DEVICE_PLANE_PREFIX):
+        return name, out
+    for meta in events:
+        event_name, op_name = None, None
+        for number, v in _fields(meta):
+            if number == 2:
+                event_name = bytes(v).decode()
+            elif number == 5:
+                stat = dict(_fields(v))
+                if stat_names.get(stat.get(1)) != OP_NAME_STAT:
+                    continue
+                if 5 in stat:
+                    op_name = bytes(stat[5]).decode()
+                elif 7 in stat:
+                    op_name = stat_names.get(stat[7])
+        if event_name and op_name:
+            out[event_name] = op_name
+    return name, out
+
+
+def event_op_names(xplane_path: str) -> Dict[str, Dict[str, str]]:
+    """{device plane: {event name: op_name}} of a capture."""
+    with open(xplane_path, "rb") as f:
+        space = memoryview(f.read())
+    return dict(
+        _plane_op_names(plane) for number, plane in _fields(space) if number == 1
+    )
+
+
+def _unwrap(component: str) -> str:
+    """``transpose(jvp(loss))`` -> ``loss``: JAX wraps the names inside a
+    transformed function in the transformation's own."""
+    while component.endswith(")") and "(" in component:
+        component = component[component.index("(") + 1:-1]
+    return component
+
+
+def scope_of(op_name: str) -> Optional[str]:
+    """The deepest scope of :data:`SCOPES` an ``op_name`` lies in, or None.
+
+    ``jit(multi_step)/rollout/while/body/closed_call/env_step/vmap(render)/..``
+    is ``rollout/env_step/render``: the first component that names a phase,
+    then each later component that names a scope inside the one so far."""
+    names = [_unwrap(c) for c in re.split(r"[/;]", op_name.rstrip(":"))]
+    for i, name in enumerate(names):
+        if name in PHASES:
+            break
+    else:
+        return None
+    scope = names[i]
+    for name in names[i + 1:]:
+        if f"{scope}/{name}" in SCOPES:
+            scope = f"{scope}/{name}"
+    return scope
+
+
+def is_backward(op_name: str) -> bool:
+    """JAX's own mark of the backward pass on an ``op_name``."""
+    return "transpose(" in op_name
+
+
+def _is_container(hlo_text: str) -> bool:
+    found = _OPCODE.search(" " + hlo_text.split(" = ", 1)[-1])
+    return bool(found) and found.group(1) in _CONTAINERS
+
+
+def op_time_by_scope(xplane_path: str) -> Optional[dict]:
+    """Device op time of a capture by scope; None if no op carries a scope.
+
+    -> ``seconds``: {scope: device seconds a chip} for every scope of
+    :data:`SCOPES` (a scope's time includes the scopes nested in it) plus
+    :data:`LEARNER_FWD` / :data:`LEARNER_BWD` (``learner`` without and with
+    JAX's ``transpose(``) and :data:`UNSCOPED`; ``total_s``: all op time a
+    chip; ``unscoped_share``; ``unscoped_ops``: the ten unscoped ops with
+    most time, [name, seconds]; ``events``: {chip: [events on its ``XLA
+    Ops`` line, first start in ns]}, by which a caller can tell whether
+    this is the capture it thinks it is.
+
+    A ``while``/``conditional``/``call`` event spans its body's events and
+    is never summed. A fusion is charged to the scope its own instruction
+    carries (XLA gives a fusion its root's metadata), so a fusion across a
+    scope boundary goes whole to one side; what carries no scope at all is
+    reported, not assumed zero. Times are means over the chips."""
+    op_names = event_op_names(xplane_path)
+    seconds = dict.fromkeys(SCOPES + (LEARNER_FWD, LEARNER_BWD, UNSCOPED), 0.0)
+    unscoped: Dict[str, float] = {}
+    events: Dict[str, list] = {}
+    total = 0.0
+    scoped_ops = 0
+    for plane in ProfileData.from_file(xplane_path).planes:
+        if not plane.name.startswith(DEVICE_PLANE_PREFIX):
+            continue
+        names = op_names.get(plane.name, {})
+        for line in plane.lines:
+            if line.name != OPS_LINE:
+                continue
+            count, first = 0, None
+            by_event: Dict[str, float] = {}  # an instruction's text -> seconds
+            for e in line.events:
+                count += 1
+                if first is None or e.start_ns < first:
+                    first = e.start_ns
+                by_event[e.name] = by_event.get(e.name, 0.0) + e.duration_ns / 1e9
+            events[plane.name] = [count, int(first) if count else None]
+            for text, s in by_event.items():
+                if _is_container(text):
+                    continue
+                total += s
+                op_name = names.get(text, "")
+                scope = scope_of(op_name)
+                if scope is None:
+                    seconds[UNSCOPED] += s
+                    short = text.split(" = ", 1)[0]
+                    unscoped[short] = unscoped.get(short, 0.0) + s
+                    continue
+                scoped_ops += 1
+                parts = scope.split("/")
+                for depth in range(1, len(parts) + 1):
+                    seconds["/".join(parts[:depth])] += s
+                if parts[0] == LEARNER:
+                    seconds[
+                        LEARNER_BWD if is_backward(op_name) else LEARNER_FWD
+                    ] += s
+    if not scoped_ops:
+        return None
+    chips = len(events)
+    top = sorted(unscoped.items(), key=lambda kv: -kv[1])[:10]
+    return {
+        "seconds": {k: v / chips for k, v in seconds.items()},
+        "total_s": total / chips,
+        "unscoped_share": seconds[UNSCOPED] / total,
+        "unscoped_ops": [[name, s / chips] for name, s in top],
+        "events": events,
+    }
+
+
+def host_spans(xplane_path: str, prefix: str = SPAN_PREFIX) -> List[list]:
+    """[name, start_ns, dur_ns] of the host spans whose name starts with
+    ``prefix``, in start order, on the clock of the capture's device events."""
+    out = []
+    for plane in ProfileData.from_file(xplane_path).planes:
+        if plane.name.startswith(DEVICE_PLANE_PREFIX):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith(prefix):
+                    out.append([e.name, int(e.start_ns), int(e.duration_ns)])
+    return sorted(out, key=lambda r: r[1])

@@ -312,7 +312,11 @@ def bench_attribution(n_envs: int, rollout_len: int, inner: int = 50):
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--trace", default=None)
+    ap.add_argument(
+        "--trace", default=None,
+        help="capture to DIR; the full step's ops carry the phases' scopes "
+        "(utils/profiling.py: op_time_by_scope reads them)",
+    )
     ap.add_argument("--shapes", default="1024x20")
     ap.add_argument(
         "--attribute", action="store_true",

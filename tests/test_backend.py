@@ -10,11 +10,16 @@ from distributed_ba3c_tpu.utils import backend
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
+METADATA_IN_KEY = "jax_compilation_cache_include_metadata_in_key"
+
+
 @pytest.fixture
 def cache_config():
     before = jax.config.jax_compilation_cache_dir
+    keyed = getattr(jax.config, METADATA_IN_KEY)
     yield lambda: jax.config.jax_compilation_cache_dir
     jax.config.update("jax_compilation_cache_dir", before)
+    jax.config.update(METADATA_IN_KEY, keyed)
 
 
 def test_cache_dir_from_outside_is_left_alone(monkeypatch, cache_config):
@@ -45,6 +50,22 @@ def test_cpu_only_process_gets_no_cache(monkeypatch, cache_config):
     monkeypatch.setenv("JAX_PLATFORMS", "cpu")
     assert backend.configure_compile_cache() is None
     assert cache_config() == before
+
+
+@pytest.mark.parametrize("placed", [None, "/somewhere/else"])
+def test_a_cache_in_use_is_keyed_on_the_programs_metadata(
+        monkeypatch, cache_config, placed):
+    """A program read from the cache keeps the op names it was compiled
+    with; JAX's default key ignores them, and a capture would then show
+    another build's scopes (utils/profiling.py), or none."""
+    if placed:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", placed)
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu,cpu")
+    jax.config.update(METADATA_IN_KEY, False)
+    backend.configure_compile_cache()
+    assert getattr(jax.config, METADATA_IN_KEY) is True
 
 
 @pytest.mark.parametrize("platforms,expected", [

@@ -1,4 +1,4 @@
-"""Multi-host helpers, profiling utils, actor failure detection."""
+"""Multi-host helpers, actor failure detection."""
 
 import time
 
@@ -12,7 +12,6 @@ from distributed_ba3c_tpu.parallel.distributed import (
     local_batch_slice,
     make_global_mesh,
 )
-from distributed_ba3c_tpu.utils.profiling import timed_operation
 
 
 def test_initialize_single_host_noop():
@@ -29,11 +28,6 @@ def test_global_mesh_covers_all_devices():
 def test_chief_and_batch_slice_single_process():
     assert is_chief()
     assert local_batch_slice(64) == slice(0, 64)
-
-
-def test_timed_operation_runs():
-    with timed_operation("noop"):
-        time.sleep(0.01)
 
 
 def _prune_master(tmp_path):
