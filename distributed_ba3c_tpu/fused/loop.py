@@ -4,7 +4,8 @@ Structure of one fused step (all inside one jit, shard_map'd over the mesh's
 ``data`` axis; B envs per device):
 
     lax.scan over T rollout steps:
-        forward policy on the frame stack  (bf16 convs on the MXU)
+        forward policy on the frame stack  (bf16 convs on the MXU; in
+                                            sub-batches when B is large)
         sample actions (on-device categorical)
         vmap(env.step): physics + uint8 render for B envs
         update frame stacks, episode-return accumulators
@@ -58,6 +59,59 @@ from distributed_ba3c_tpu.utils.profiling import device_scope, host_span
 #: desynchronize (ADVICE r4 #3).
 CUMULATIVE_METRICS = ("episodes", "episode_return_sum")
 
+#: stacks an inference forward takes at once when a shard carries many envs
+#: (the rollout's policy forward and the bootstrap under ``returns``; never
+#: the learner). PERF.md, PR 25: on the v5e the same forward costs more a
+#: sample the larger its batch, so a large env batch runs as sequential
+#: forwards of this many.
+FORWARD_SUB_BATCH = 256
+
+
+def forward_sub_batch(n_envs: int) -> int | None:
+    """Stacks a forward of a shard's ``n_envs`` env batch: None is all at
+    once (under two sub-batches' worth, or no divisor of ``n_envs`` in
+    [FORWARD_SUB_BATCH / 2, FORWARD_SUB_BATCH]), else the largest such
+    divisor. Read off the shape alone, so every program that traces the
+    rollout at one shape splits it the same way."""
+    top = FORWARD_SUB_BATCH
+    if n_envs < 2 * top:
+        return None
+    for size in range(top, (top - 1) // 2, -1):
+        if n_envs % size == 0:
+            return size
+    return None
+
+
+def sub_batched(apply_fn):
+    """``apply_fn(params, stack)`` in sequential sub-batches of
+    :func:`forward_sub_batch` stacks, or as it is where that says None. The
+    network has no op across samples: each sample's arithmetic is the same
+    either way."""
+
+    def apply(params, stack):
+        B = stack.shape[0]
+        size = forward_sub_batch(B)
+        if size is None:
+            return apply_fn(params, stack)
+        # one scope component, "sub_batch": under ``returns`` the same
+        # call reads as profiling.RETURNS_SUB_BATCH
+        with device_scope(profiling.ROLLOUT_POLICY_SUB_BATCH):
+            # slices of the batch where it lies, not rows of a reshape to
+            # [B // size, size, ...]: the v5e keeps the batch of these
+            # frames on the lanes, and there that reshape moves every frame
+            # (PERF.md, PR 25: 0.28 us a sample, against 0.07 this way)
+            out = jax.lax.map(
+                lambda start: apply_fn(
+                    params, jax.lax.dynamic_slice_in_dim(stack, start, size)
+                ),
+                jnp.arange(0, B, size),
+            )
+        return jax.tree_util.tree_map(
+            lambda x: x.reshape(B, *x.shape[2:]), out
+        )
+
+    return apply
+
 
 class FusedState(struct.PyTreeNode):
     train: TrainState
@@ -67,6 +121,12 @@ class FusedState(struct.PyTreeNode):
     ep_return: jax.Array      # [B_global] running episode return
     ep_count: jax.Array       # [B_global] int32 completed episodes per env
     ep_return_sum: jax.Array  # [B_global] float32 sum of completed returns per env
+
+
+def rollout_sub_batch_of(mesh: Mesh) -> Callable:
+    """fn(n_envs) -> :func:`forward_sub_batch` of one shard of ``n_envs``
+    global envs on ``mesh`` (a built step's ``rollout_sub_batch``)."""
+    return lambda n_envs: forward_sub_batch(n_envs // mesh.shape[DATA_AXIS])
 
 
 def make_rollout_body(model, cfg: BA3CConfig, env, params,
@@ -85,10 +145,13 @@ def make_rollout_body(model, cfg: BA3CConfig, env, params,
     ``apply_fn(params, stack) -> PolicyValue`` overrides the forward
     while keeping the key sequence/sampling math identical — the int8
     actor program (quantize/qforward.py) passes its quantized apply and
-    ``params`` becomes the int8 serving table.
+    ``params`` becomes the int8 serving table. Whichever forward it is
+    runs through :func:`sub_batched`: the split has to be made HERE, so
+    that every program built from this body draws the same actions.
     """
     if apply_fn is None:
         apply_fn = lambda p, stack: model.apply({"params": p}, stack)  # noqa: E731
+    apply_fn = sub_batched(apply_fn)
 
     def rollout_body(carry, _):
         env_state, stack, key, ep_ret, ep_cnt, ep_sum = carry
@@ -253,7 +316,9 @@ def make_fused_step(
 
         # bootstrap from the post-rollout stack (no gradient)
         with device_scope(profiling.RETURNS):
-            bootstrap = model.apply({"params": params}, stack).value
+            bootstrap = sub_batched(
+                lambda p, s: model.apply({"params": p}, s).value
+            )(params, stack)
             returns_t = n_step_returns(
                 rewards_t, dones_t, jax.lax.stop_gradient(bootstrap), cfg.gamma
             )
@@ -261,11 +326,12 @@ def make_fused_step(
         T, B = actions_t.shape
 
         # Learner: fwd+bwd over the FLAT [T*B] batch in as few chunks as HBM
-        # allows. Profile-driven (see PERF.md): at B=1024 per-timestep chunks
-        # ran the convs at ~30% MFU (180.7ms) while one flat 20480-sample
-        # fwd+bwd hit ~80% MFU (69.0ms) on a v5e — batch size per matmul is
-        # the whole game. Chunking (equal sizes) only bounds activation
-        # memory; mean-of-chunk-grads equals the full-batch gradient.
+        # allows. Chunking (equal sizes) only bounds activation memory;
+        # mean-of-chunk-grads equals the full-batch gradient. On the v5e the
+        # learner's cost a sample is flat in the chunk (PERF.md section 5:
+        # 12.61 us at chunks of 2,560, 12.36 at 4,096), so unlike the
+        # inference forward (``sub_batched``: cheaper a sample at 256 stacks
+        # than at 512 and over) it gains nothing from smaller pieces.
         def chunk_grad(p, chunk):
             states_c, actions_c, returns_c = chunk
 
@@ -458,6 +524,7 @@ def make_fused_step(
     step.batch_sharding = batched
     step.mesh = mesh
     step.rollout_len = rollout_len
+    step.rollout_sub_batch = rollout_sub_batch_of(mesh)
     step.steps_per_dispatch = steps_per_dispatch
     step.reset_episode_stats = reset_episode_stats
     step.audit_jit = jitted  # tools/ba3caudit traces THIS program
@@ -693,12 +760,13 @@ def run_fused_training(args, cfg: BA3CConfig, model, optimizer) -> int:
     samples_per_iter = n_envs * rollout_len * fleet_accum
     logger.info(
         "fused training: %d envs x %d rollout x %d accum windows = "
-        "%d samples/iter on %d devices",
+        "%d samples/iter on %d devices, inference forwards of %s stacks",
         n_envs,
         rollout_len,
         fleet_accum,
         samples_per_iter,
         n_data,
+        step.rollout_sub_batch(n_envs) or "all a device's",
     )
 
     # runtime-scheduled hyperparams (reference ScheduledHyperParamSetter

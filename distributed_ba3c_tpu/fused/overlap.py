@@ -70,6 +70,7 @@ from distributed_ba3c_tpu.fused.loop import (
     FusedState,
     make_put_batched,
     make_rollout_body,
+    rollout_sub_batch_of,
 )
 from distributed_ba3c_tpu.models.a3c import BA3CNet
 from distributed_ba3c_tpu.ops.gradproc import grad_summaries, inject_learning_rate
@@ -710,6 +711,7 @@ def make_overlap_step(
     step.batch_sharding = batched
     step.mesh = mesh
     step.rollout_len = rollout_len
+    step.rollout_sub_batch = rollout_sub_batch_of(mesh)
     step.steps_per_dispatch = steps_per_dispatch
     step.lag = lag
     step.rollout_dtype = rollout_dtype

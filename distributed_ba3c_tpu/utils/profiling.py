@@ -36,11 +36,15 @@ from distributed_ba3c_tpu.utils import logger
 # component, so a scope nested in code nests in the name.
 ROLLOUT = "rollout"
 ROLLOUT_POLICY = "rollout/policy"
+#: open only where the forward ran in sub-batches (fused/loop.py
+#: ``sub_batched``): time here says that it did
+ROLLOUT_POLICY_SUB_BATCH = "rollout/policy/sub_batch"
 ROLLOUT_SAMPLE = "rollout/sample"
 ROLLOUT_ENV_STEP = "rollout/env_step"
 ROLLOUT_RENDER = "rollout/env_step/render"
 ROLLOUT_STACK = "rollout/stack"
 RETURNS = "returns"
+RETURNS_SUB_BATCH = "returns/sub_batch"  # the bootstrap forward, likewise
 LEARNER = "learner"
 LEARNER_LOSS = "learner/loss"
 GRAD_REDUCE = "grad_reduce"
@@ -49,9 +53,9 @@ METRICS = "metrics"
 #: the phases of one update, in program order: every scoped op is in one
 PHASES = (ROLLOUT, RETURNS, LEARNER, GRAD_REDUCE, OPTIMIZER, METRICS)
 SCOPES = (
-    ROLLOUT, ROLLOUT_POLICY, ROLLOUT_SAMPLE, ROLLOUT_ENV_STEP, ROLLOUT_RENDER,
-    ROLLOUT_STACK, RETURNS, LEARNER, LEARNER_LOSS, GRAD_REDUCE, OPTIMIZER,
-    METRICS,
+    ROLLOUT, ROLLOUT_POLICY, ROLLOUT_POLICY_SUB_BATCH, ROLLOUT_SAMPLE,
+    ROLLOUT_ENV_STEP, ROLLOUT_RENDER, ROLLOUT_STACK, RETURNS,
+    RETURNS_SUB_BATCH, LEARNER, LEARNER_LOSS, GRAD_REDUCE, OPTIMIZER, METRICS,
 )
 #: reader-only splits of ``learner``: JAX marks the backward pass itself
 LEARNER_FWD = "learner:fwd"

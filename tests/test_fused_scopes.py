@@ -68,8 +68,15 @@ def chunked():
     return step, state, hlo
 
 
+#: open only where a shard's env batch is large enough for the forward to run
+#: in sub-batches, which these small steps' is not
+#: (tests/test_forward_sub_batch.py finds both in a step where it is)
+_BY_SHAPE = (profiling.ROLLOUT_POLICY_SUB_BATCH, profiling.RETURNS_SUB_BATCH)
+
+
 @pytest.mark.parametrize("which", ["canonical", "chunked"])
-@pytest.mark.parametrize("scope", profiling.SCOPES)
+@pytest.mark.parametrize(
+    "scope", [s for s in profiling.SCOPES if s not in _BY_SHAPE])
 def test_every_scope_is_in_the_compiled_steps_op_names(
         canonical_hlo, chunked, which, scope):
     hlo = canonical_hlo if which == "canonical" else chunked[2]
@@ -127,6 +134,11 @@ def test_every_convolution_is_in_exactly_one_forward_or_learner_scope(
      "rollout/env_step", False),
     ("jit(multi_step)/rollout/while", "rollout", False),
     ("jit(multi_step)/returns/BA3CNet/Dense_2/dot_general", "returns", False),
+    ("jit(multi_step)/rollout/while/body/closed_call/policy/sub_batch/while/body/closed_call/BA3CNet/Conv_0/conv_general_dilated",
+     "rollout/policy/sub_batch", False),
+    ("jit(multi_step)/returns/sub_batch/while/body/closed_call/BA3CNet/Conv_3/conv_general_dilated",
+     "returns/sub_batch", False),
+    ("jit(multi_step)/learner/sub_batch/mul", "learner", False),  # no such scope there
     ("jit(multi_step)/while/body/closed_call/learner/transpose(jvp(BA3CNet))/Conv_1/conv_general_dilated",
      "learner", True),
     ("jit(multi_step)/learner/transpose(jvp(loss))/mul;learner/transpose(jvp(loss))",
