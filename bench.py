@@ -112,13 +112,13 @@ def bench_fused(
     from distributed_ba3c_tpu.config import BA3CConfig
     from distributed_ba3c_tpu.envs.jaxenv import pong
     from distributed_ba3c_tpu.fused.loop import create_fused_state, make_fused_step
-    from distributed_ba3c_tpu.models.a3c import BA3CNet
+    from distributed_ba3c_tpu.models.policy import DEFAULT_MODEL, build_model
     from distributed_ba3c_tpu.ops.gradproc import make_optimizer
     from distributed_ba3c_tpu.parallel.mesh import make_mesh
 
     n_chips = len(jax.devices())
     cfg = BA3CConfig(num_actions=pong.num_actions)
-    model = BA3CNet(num_actions=cfg.num_actions, fc_units=cfg.fc_units)
+    model = build_model(DEFAULT_MODEL, cfg)
     opt = make_optimizer(cfg.learning_rate, cfg.adam_epsilon, cfg.grad_clip_norm)
     mesh = make_mesh()
     # default: ONE dispatch per window (iters updates in a single scanned
@@ -223,13 +223,13 @@ def bench_overlap(
     from distributed_ba3c_tpu.envs.jaxenv import pong
     from distributed_ba3c_tpu.fused.loop import create_fused_state
     from distributed_ba3c_tpu.fused.overlap import make_overlap_step
-    from distributed_ba3c_tpu.models.a3c import BA3CNet
+    from distributed_ba3c_tpu.models.policy import DEFAULT_MODEL, build_model
     from distributed_ba3c_tpu.ops.gradproc import make_optimizer
     from distributed_ba3c_tpu.parallel.mesh import make_mesh
 
     n_chips = len(jax.devices())
     cfg = BA3CConfig(num_actions=pong.num_actions)
-    model = BA3CNet(num_actions=cfg.num_actions, fc_units=cfg.fc_units)
+    model = build_model(DEFAULT_MODEL, cfg)
     opt = make_optimizer(cfg.learning_rate, cfg.adam_epsilon, cfg.grad_clip_norm)
     mesh = make_mesh()
     step = make_overlap_step(
@@ -461,7 +461,7 @@ def bench_zmq_plane(
     from distributed_ba3c_tpu.actors.master import BA3CSimulatorMaster
     from distributed_ba3c_tpu.config import BA3CConfig
     from distributed_ba3c_tpu.envs import native
-    from distributed_ba3c_tpu.models.a3c import BA3CNet
+    from distributed_ba3c_tpu.models.policy import DEFAULT_MODEL, build_model
     from distributed_ba3c_tpu.predict.server import BatchedPredictor
 
     # per-run telemetry accounting: fresh registries, and the A/B switch
@@ -479,7 +479,7 @@ def bench_zmq_plane(
 
     n_actions = native.CppBatchedEnv(game, 1).num_actions
     cfg = BA3CConfig(num_actions=n_actions, predict_batch_size=256)
-    model = BA3CNet(num_actions=cfg.num_actions, fc_units=cfg.fc_units)
+    model = build_model(DEFAULT_MODEL, cfg)
     params = model.init(
         jax.random.PRNGKey(0), np.zeros((1, *cfg.state_shape), np.uint8)
     )["params"]

@@ -313,7 +313,7 @@ def _staging_equivalence(shape: Shape) -> dict:
     from distributed_ba3c_tpu import telemetry
     from distributed_ba3c_tpu.config import BA3CConfig
     from distributed_ba3c_tpu.data.staging import DeviceIngest, HostStagingRing
-    from distributed_ba3c_tpu.models.a3c import BA3CNet
+    from distributed_ba3c_tpu.models.policy import DEFAULT_MODEL, build_model
     from distributed_ba3c_tpu.ops.gradproc import make_optimizer
     from distributed_ba3c_tpu.parallel.mesh import make_mesh
     from distributed_ba3c_tpu.parallel.train_step import create_train_state
@@ -322,7 +322,7 @@ def _staging_equivalence(shape: Shape) -> dict:
     size = shape.plane_image_size or 84
     cfg = BA3CConfig(fc_units=shape.fc_units, image_size=(size, size))
     n_actions = cfg.num_actions
-    model = BA3CNet(num_actions=cfg.num_actions, fc_units=cfg.fc_units)
+    model = build_model(DEFAULT_MODEL, cfg)
     opt = make_optimizer(cfg.learning_rate, cfg.adam_epsilon, cfg.grad_clip_norm)
     mesh = make_mesh()
     step = make_vtrace_train_step(model, opt, cfg, mesh)
@@ -568,7 +568,7 @@ def phase_forwards(shape: Shape, workdir: str, platform: str = "tpu") -> dict:
 
     from distributed_ba3c_tpu.config import BA3CConfig
     from distributed_ba3c_tpu.envs.jaxenv import pong
-    from distributed_ba3c_tpu.models.a3c import BA3CNet
+    from distributed_ba3c_tpu.models.policy import DEFAULT_MODEL, build_model
     from distributed_ba3c_tpu.predict.server import BatchedPredictor
     from distributed_ba3c_tpu.quantize import (
         calibrate_offline,
@@ -579,7 +579,7 @@ def phase_forwards(shape: Shape, workdir: str, platform: str = "tpu") -> dict:
 
     device = _require_device(platform)
     cfg = BA3CConfig(num_actions=pong.num_actions, fc_units=shape.fc_units)
-    model = BA3CNet(num_actions=cfg.num_actions, fc_units=cfg.fc_units)
+    model = build_model(DEFAULT_MODEL, cfg)
     params = model.init(
         jax.random.PRNGKey(0), np.zeros((1, *cfg.state_shape), np.uint8)
     )["params"]
@@ -675,7 +675,7 @@ def phase_mesh(shape: Shape, workdir: str, platform: str = "tpu") -> dict:
         create_fused_state,
         make_fused_step,
     )
-    from distributed_ba3c_tpu.models.a3c import BA3CNet
+    from distributed_ba3c_tpu.models.policy import DEFAULT_MODEL, build_model
     from distributed_ba3c_tpu.ops.gradproc import make_optimizer
     from distributed_ba3c_tpu.parallel.mesh import make_mesh
 
@@ -684,7 +684,7 @@ def phase_mesh(shape: Shape, workdir: str, platform: str = "tpu") -> dict:
     if n < 2:
         return {"device": device, "skipped": "one device: nothing to shard"}
     cfg = BA3CConfig(num_actions=pong.num_actions, fc_units=shape.fc_units)
-    model = BA3CNet(num_actions=cfg.num_actions, fc_units=cfg.fc_units)
+    model = build_model(DEFAULT_MODEL, cfg)
     opt = make_optimizer(cfg.learning_rate, cfg.adam_epsilon, cfg.grad_clip_norm)
     mesh = make_mesh()
     step = make_fused_step(

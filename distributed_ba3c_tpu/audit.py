@@ -253,11 +253,11 @@ def build_entry(name: str) -> TraceTarget:
 
 def _canonical_parts():
     from distributed_ba3c_tpu.config import BA3CConfig
-    from distributed_ba3c_tpu.models.a3c import BA3CNet
+    from distributed_ba3c_tpu.models.policy import DEFAULT_MODEL, build_model
     from distributed_ba3c_tpu.ops.gradproc import make_optimizer
 
     cfg = BA3CConfig(num_actions=6)
-    model = BA3CNet(num_actions=cfg.num_actions, fc_units=cfg.fc_units)
+    model = build_model(DEFAULT_MODEL, cfg)
     opt = make_optimizer(cfg.learning_rate, cfg.adam_epsilon, cfg.grad_clip_norm)
     return cfg, model, opt
 

@@ -98,6 +98,8 @@ def make_parser() -> argparse.ArgumentParser:
     p.add_argument("--num_actions", type=int, default=4)
     p.add_argument("--mesh_data", type=int, default=None, help="data-axis size (defaults to all devices)")
     p.add_argument("--publish_every", type=int, default=1)
+    p.add_argument("--model", default="ba3cnet", help="the policy, by its name in models/policy.py's registry: ba3cnet (the reference's conv stack) | lfm2-moe (LFM2-8B-A1B as a token-sequence policy that carries state; --trainer tpu_fused_ba3c with a token env such as jax:recall, --rollout_len = the episode length)")
+    p.add_argument("--model_cut", default=None, help="what one chip holds of --model, by name (models/lfm2_moe.py CUTS: chip-share-4 = one of 4 chips sharing each layer, the default | tiny = a CPU test's size); the widths are the model file's, as published")
     p.add_argument("--rollout_len", type=int, default=20, help="fused-trainer rollout length per update")
     p.add_argument("--grad_chunk_samples", type=int, default=4096, help="fused-trainer learner chunk size (HBM activation cap)")
     p.add_argument("--actor_timeout", type=float, default=120.0, help="seconds of actor silence before its state is dropped (0=off)")
@@ -405,7 +407,7 @@ def main(argv: Optional[list] = None) -> int:
     # trainers' HumanHyperParamSetter gets the same dir below)
     args.shared_hyper_dir = base_logdir
 
-    from distributed_ba3c_tpu.models.a3c import BA3CNet
+    from distributed_ba3c_tpu.models.policy import build_model, refuse_carry
     from distributed_ba3c_tpu.ops.gradproc import make_optimizer
     from distributed_ba3c_tpu.parallel.mesh import make_mesh
     from distributed_ba3c_tpu.parallel.train_step import (
@@ -415,7 +417,12 @@ def main(argv: Optional[list] = None) -> int:
     from distributed_ba3c_tpu.utils import logger
 
     cfg = build_config(args)
-    model = BA3CNet(num_actions=cfg.num_actions, fc_units=cfg.fc_units)
+    try:
+        model = build_model(args.model, cfg, args.model_cut)
+        if args.task != "train" or args.trainer != "tpu_fused_ba3c":
+            refuse_carry(model, f"--task {args.task} --trainer {args.trainer}")
+    except ValueError as e:
+        parser.error(str(e))
     optimizer = make_optimizer(
         cfg.learning_rate, cfg.adam_epsilon, cfg.grad_clip_norm
     )

@@ -10,6 +10,8 @@ Env functional protocol (unbatched; vmap at the call site):
     env.reset(key) -> state                       (pytree of arrays)
     env.step(state, action, key) -> (state, obs uint8 [H,W], reward, done)
     env.num_actions: int
+A token env (recall.py) gives an int32 token id for ``obs`` and has
+``env.episode_length``: every episode is that many steps.
 Episodes auto-restart on done (same contract as the host player protocol,
 envs/base.py) so rollout scans never branch.
 
@@ -29,12 +31,17 @@ from distributed_ba3c_tpu.envs.jaxenv import (
     coinrun,
     pong,
     qbert,
+    recall,
     seaquest,
     space_invaders,
 )
 
 
 def get_env(name: str):
+    if name.split(":")[0] == "recall":
+        # a token game, sized by its name (recall.py): an object with the
+        # same functions, not a module of constants
+        return recall.from_spec(name)
     envs = {
         "pong": pong,
         "breakout": breakout,
@@ -46,5 +53,6 @@ def get_env(name: str):
         "assault": assault,
     }
     if name not in envs:
-        raise ValueError(f"unknown jax env {name!r}; have {sorted(envs)}")
+        raise ValueError(
+            f"unknown jax env {name!r}; have {sorted(envs) + ['recall']}")
     return envs[name]

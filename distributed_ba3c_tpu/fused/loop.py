@@ -14,6 +14,14 @@ Structure of one fused step (all inside one jit, shard_map'd over the mesh's
     a3c loss over the [T*B] flat batch           ops/loss.py
     grads → mean over data axis → Adam update    (the one collective)
 
+A policy that carries state (models/policy.py; a token-sequence model with
+a K/V cache and short-conv state) runs through the same step: its carry
+rides in the rollout scan and in ``FusedState.policy_carry``, reset where an
+episode ends; the rollout is served from one bfloat16 snapshot of the
+weights an update; the learner takes whole episodes ``[B, T]`` in chunks of
+envs through the policy's causal unroll. Everything else (returns, the
+chunked-gradient scan, the one psum, clip + Adam, metrics) is shared.
+
 The rollout forward runs without gradient tracking; the loss recomputes the
 forward over the collected stacks — standard A2C, and on TPU the recompute is
 cheaper than storing activations (HBM-bandwidth-bound regime).
@@ -43,6 +51,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from distributed_ba3c_tpu.audit import tripwire_jit
 from distributed_ba3c_tpu.config import BA3CConfig
+from distributed_ba3c_tpu.models import a3c as a3c_model, policy
 from distributed_ba3c_tpu.models.a3c import BA3CNet
 from distributed_ba3c_tpu.ops.gradproc import grad_summaries, inject_learning_rate
 from distributed_ba3c_tpu.ops.loss import a3c_loss
@@ -58,28 +67,17 @@ from distributed_ba3c_tpu.utils.profiling import device_scope, host_span
 #: each of these is in its metrics dict so the two sites cannot
 #: desynchronize (ADVICE r4 #3).
 CUMULATIVE_METRICS = ("episodes", "episode_return_sum")
+#: a sequence policy's update also gives what it generated: the tokens each
+#: env showed and the actions drawn, ``[T, B_global]`` int32 (131 KB each at
+#: 128 x 256). The K-step scan keeps the last update's. What reads them: a
+#: run's log of its own generations, and the benchmark's comparison, which
+#: used to read the actions off a second compiled rollout (two compilations
+#: of one bfloat16 forward do not draw the same 32,768 tokens: PERF.md, PR 26)
+TRAJECTORY_METRICS = ("tokens", "actions")
 
-#: stacks an inference forward takes at once when a shard carries many envs
-#: (the rollout's policy forward and the bootstrap under ``returns``; never
-#: the learner). PERF.md, PR 25: on the v5e the same forward costs more a
-#: sample the larger its batch, so a large env batch runs as sequential
-#: forwards of this many.
-FORWARD_SUB_BATCH = 256
-
-
-def forward_sub_batch(n_envs: int) -> int | None:
-    """Stacks a forward of a shard's ``n_envs`` env batch: None is all at
-    once (under two sub-batches' worth, or no divisor of ``n_envs`` in
-    [FORWARD_SUB_BATCH / 2, FORWARD_SUB_BATCH]), else the largest such
-    divisor. Read off the shape alone, so every program that traces the
-    rollout at one shape splits it the same way."""
-    top = FORWARD_SUB_BATCH
-    if n_envs < 2 * top:
-        return None
-    for size in range(top, (top - 1) // 2, -1):
-        if n_envs % size == 0:
-            return size
-    return None
+#: ``BA3CNet``'s measured number and rule live beside the model
+#: (models/a3c.py): how many stacks an inference forward takes at once
+forward_sub_batch = a3c_model.forward_sub_batch
 
 
 def sub_batched(apply_fn):
@@ -113,14 +111,30 @@ def sub_batched(apply_fn):
     return apply
 
 
+def learner_chunks(n_rows: int, n_samples: int, grad_chunk_samples: int) -> int:
+    """Equal chunks the learner cuts ``n_rows`` rows of ``n_samples``
+    transitions into: the fewest of at most ``grad_chunk_samples`` each
+    that divide the rows (a row is one transition, or for a sequence
+    policy one env's whole episode)."""
+    n_chunks = max(1, -(-n_samples // grad_chunk_samples))
+    while n_rows % n_chunks:
+        n_chunks += 1
+    return n_chunks
+
+
 class FusedState(struct.PyTreeNode):
     train: TrainState
     env_state: Any            # batched env pytree, leaves [B_global, ...]
-    obs_stack: jax.Array      # [B_global, H, W, hist] uint8
+    obs_stack: jax.Array      # [B_global, H, W, hist] uint8; of a token env
+                              # the int32 token each env shows, [B_global]
     key: jax.Array            # [n_shards] typed PRNG keys, sharded on data axis
     ep_return: jax.Array      # [B_global] running episode return
     ep_count: jax.Array       # [B_global] int32 completed episodes per env
     ep_return_sum: jax.Array  # [B_global] float32 sum of completed returns per env
+    #: of a policy that carries state (models/policy.py): (the policy's
+    #: carry, leaves [B_global, ...]; bool [B_global], the next observation
+    #: opens an episode). Empty for a stateless policy.
+    policy_carry: Any = ()
 
 
 def rollout_sub_batch_of(mesh: Mesh) -> Callable:
@@ -148,26 +162,78 @@ def make_rollout_body(model, cfg: BA3CConfig, env, params,
     ``params`` becomes the int8 serving table. Whichever forward it is
     runs through :func:`sub_batched`: the split has to be made HERE, so
     that every program built from this body draws the same actions.
-    """
-    if apply_fn is None:
-        apply_fn = lambda p, stack: model.apply({"params": p}, stack)  # noqa: E731
-    apply_fn = sub_batched(apply_fn)
 
-    def rollout_body(carry, _):
-        env_state, stack, key, ep_ret, ep_cnt, ep_sum = carry
-        B = stack.shape[0]
-        with device_scope(profiling.ROLLOUT_POLICY):
-            out = apply_fn(params, stack)
+    For a policy that carries state the body's carry has a seventh
+    element, ``(policy carry, fresh)``, beside the stateless six; the
+    observation is the token the env shows, ``params`` is what
+    ``model.rollout_params`` gave, and the forward is ``model.step``, never
+    split (``FORWARD_SUB_BATCH`` is ``BA3CNet``'s number).
+    """
+    def draw_and_step(key, logits, env_state):
+        B = logits.shape[0]
         with device_scope(profiling.ROLLOUT_SAMPLE):
             key, k_act, k_env = jax.random.split(key, 3)
             actions = jax.random.categorical(
-                k_act, out.logits, axis=-1
+                k_act, logits, axis=-1
             ).astype(jnp.int32)
         with device_scope(profiling.ROLLOUT_ENV_STEP):
             env_keys = jax.random.split(k_env, B)
             env_state, obs, reward, done = jax.vmap(env.step)(
                 env_state, actions, env_keys
             )
+        return key, actions, env_state, obs, reward, done
+
+    def account(reward, done, ep_ret, ep_cnt, ep_sum):
+        # episode bookkeeping (done ⇒ env auto-restarted inside step);
+        # scores accumulate RAW rewards, the learner sees clipped ones
+        ep_ret = ep_ret + reward
+        donef = done.astype(jnp.float32)
+        ep_sum = ep_sum + ep_ret * donef
+        ep_cnt = ep_cnt + done.astype(jnp.int32)
+        ep_ret = ep_ret * (1.0 - donef)
+        r_learn = (
+            jnp.clip(reward, -cfg.reward_clip, cfg.reward_clip)
+            if cfg.reward_clip
+            else reward
+        )
+        return ep_ret, ep_cnt, ep_sum, donef, r_learn
+
+    if policy.carries_state(model):
+        if apply_fn is not None or record_log_probs:
+            raise ValueError(
+                "a policy that carries state is served by its own step: no "
+                "apply_fn override and no behaviour log-probs"
+            )
+
+        def sequence_body(carry, _):
+            # the stateless body's six, and beside them (the policy's carry,
+            # which envs' observation opens an episode)
+            env_state, obs, key, ep_ret, ep_cnt, ep_sum, (held, fresh) = carry
+            with device_scope(profiling.ROLLOUT_POLICY):
+                out, held = model.step(params, obs, held, fresh)
+            key, actions, env_state, new_obs, reward, done = draw_and_step(
+                key, out.logits, env_state
+            )
+            with device_scope(profiling.ROLLOUT_STACK):
+                ep_ret, ep_cnt, ep_sum, donef, r_learn = account(
+                    reward, done, ep_ret, ep_cnt, ep_sum
+                )
+            return (env_state, new_obs, key, ep_ret, ep_cnt, ep_sum,
+                    (held, done)), (obs, actions, r_learn, donef)
+
+        return sequence_body
+
+    if apply_fn is None:
+        apply_fn = lambda p, stack: model.apply({"params": p}, stack)  # noqa: E731
+    apply_fn = sub_batched(apply_fn)
+
+    def rollout_body(carry, _):
+        env_state, stack, key, ep_ret, ep_cnt, ep_sum = carry
+        with device_scope(profiling.ROLLOUT_POLICY):
+            out = apply_fn(params, stack)
+        key, actions, env_state, obs, reward, done = draw_and_step(
+            key, out.logits, env_state
+        )
         with device_scope(profiling.ROLLOUT_STACK):
             # a done frame must not leak history into the new episode: zero
             # the carried history via a mask multiply (single fused pass —
@@ -176,17 +242,8 @@ def make_rollout_body(model, cfg: BA3CConfig, env, params,
             new_stack = jnp.concatenate(
                 [stack[..., 1:] * keep, obs[..., None]], axis=-1
             )
-            # episode bookkeeping (done ⇒ env auto-restarted inside step);
-            # scores accumulate RAW rewards, the learner sees clipped ones
-            ep_ret = ep_ret + reward
-            donef = done.astype(jnp.float32)
-            ep_sum = ep_sum + ep_ret * donef
-            ep_cnt = ep_cnt + done.astype(jnp.int32)
-            ep_ret = ep_ret * (1.0 - donef)
-            r_learn = (
-                jnp.clip(reward, -cfg.reward_clip, cfg.reward_clip)
-                if cfg.reward_clip
-                else reward
+            ep_ret, ep_cnt, ep_sum, donef, r_learn = account(
+                reward, done, ep_ret, ep_cnt, ep_sum
             )
         ys = (stack, actions, r_learn, donef)
         if record_log_probs:
@@ -249,9 +306,15 @@ def create_fused_state(
     train = create_train_state(rng, model, cfg, optimizer)
     keys = jax.random.split(jax.random.fold_in(rng, 1), n_envs)
     env_state = jax.vmap(env.reset)(keys)
-    obs = jax.vmap(env.render)(env_state)  # [B, H, W]
-    stack = jnp.zeros((n_envs, *obs.shape[1:], cfg.frame_history), jnp.uint8)
-    stack = stack.at[..., -1].set(obs)
+    obs = jax.vmap(env.render)(env_state)  # [B, H, W], or [B] token ids
+    policy_carry = ()
+    if policy.carries_state(model):
+        stack = obs
+        policy_carry = (model.init_carry(n_envs), jnp.ones(n_envs, bool))
+    else:
+        stack = jnp.zeros(
+            (n_envs, *obs.shape[1:], cfg.frame_history), jnp.uint8)
+        stack = stack.at[..., -1].set(obs)
     shard_keys = jax.vmap(
         lambda i: jax.random.fold_in(jax.random.fold_in(rng, 2), i)
     )(jnp.arange(n_shards))
@@ -263,6 +326,7 @@ def create_fused_state(
         ep_return=jnp.zeros(n_envs, jnp.float32),
         ep_count=jnp.zeros(n_envs, jnp.int32),
         ep_return_sum=jnp.zeros(n_envs, jnp.float32),
+        policy_carry=policy_carry,
     )
 
 
@@ -292,13 +356,34 @@ def make_fused_step(
     pipelining entirely. β/lr are scan-carried scalars, so one
     dispatch spans only steps sharing a hyperparam setting (the epoch loop
     already changes them per epoch only).
+
+    A policy that carries state (``sequence`` below) is learned from whole
+    episodes: ``rollout_len`` has to be the env's episode length, so that
+    every segment starts at a reset and the learner's unroll starts from
+    the empty carry. A segment that starts mid-episode would need the carry
+    at its first step on the learner's side (ROADMAP A1) and is refused.
     """
+    sequence = policy.carries_state(model)
+    if sequence and getattr(env, "episode_length", None) != rollout_len:
+        raise ValueError(
+            f"{type(model).__name__} carries state and is learned from whole "
+            f"episodes: --rollout_len {rollout_len} has to be the env's "
+            f"episode length ({getattr(env, 'episode_length', None)}); a "
+            "segment that starts mid-episode is not supported"
+        )
 
     def local_step(state: FusedState, entropy_beta, learning_rate):
         params = state.train.params
         key = state.key[0]  # this shard's scalar key
 
-        rollout_body = make_rollout_body(model, cfg, env, params)
+        act_params = params
+        if sequence:
+            # every decode step reads every weight: read 2 bytes of each,
+            # from one snapshot an update, not 4 from the float32 table
+            with device_scope(profiling.ROLLOUT), device_scope(
+                    profiling.ROLLOUT_WEIGHTS_BF16):
+                act_params = model.rollout_params(params)
+        rollout_body = make_rollout_body(model, cfg, env, act_params)
 
         carry0 = (
             state.env_state,
@@ -308,17 +393,24 @@ def make_fused_step(
             state.ep_count,
             state.ep_return_sum,
         )
+        if sequence:
+            carry0 = carry0 + (state.policy_carry,)
         with device_scope(profiling.ROLLOUT):
-            (env_state, stack, key, ep_ret, ep_cnt, ep_sum), traj = (
-                jax.lax.scan(rollout_body, carry0, None, length=rollout_len)
+            carry, traj = jax.lax.scan(
+                rollout_body, carry0, None, length=rollout_len
             )
+        env_state, stack, key, ep_ret, ep_cnt, ep_sum = carry[:6]
+        policy_carry = carry[6] if sequence else ()
         states_t, actions_t, rewards_t, dones_t = traj  # [T, B, ...]
 
         # bootstrap from the post-rollout stack (no gradient)
         with device_scope(profiling.RETURNS):
-            bootstrap = sub_batched(
-                lambda p, s: model.apply({"params": p}, s).value
-            )(params, stack)
+            if sequence:
+                bootstrap = model.step(act_params, stack, *policy_carry)[0].value
+            else:
+                bootstrap = sub_batched(
+                    lambda p, s: model.apply({"params": p}, s).value
+                )(params, stack)
             returns_t = n_step_returns(
                 rewards_t, dones_t, jax.lax.stop_gradient(bootstrap), cfg.gamma
             )
@@ -332,49 +424,65 @@ def make_fused_step(
         # 12.61 us at chunks of 2,560, 12.36 at 4,096), so unlike the
         # inference forward (``sub_batched``: cheaper a sample at 256 stacks
         # than at 512 and over) it gains nothing from smaller pieces.
+        #
+        # A sequence policy's rows are whole episodes ``[B, T]`` and a chunk
+        # is so many envs (4,096 samples = 16 envs x 256): its forward is
+        # the policy's causal unroll, which also counts (``counters``: the
+        # tokens each held expert was routed, summed over the chunks).
         def chunk_grad(p, chunk):
             states_c, actions_c, returns_c = chunk
 
             def loss_fn(pp):
-                out = model.apply({"params": pp}, states_c)
+                counters = {}
+                if sequence:
+                    out, counters = model.unroll(pp, states_c)
+                else:
+                    out = model.apply({"params": pp}, states_c)
                 with device_scope(profiling.LEARNER_LOSS):
                     loss = a3c_loss(
-                        out.logits,
-                        out.value,
-                        actions_c,
-                        returns_c,
+                        *(samples(x) for x in (
+                            out.logits, out.value, actions_c, returns_c)),
                         entropy_beta=entropy_beta,
                         value_loss_coef=cfg.value_loss_coef,
                         huber_delta=cfg.value_huber_delta,
                     )
-                return loss.total, loss
+                return loss.total, (loss, counters)
 
             with device_scope(profiling.LEARNER):
                 return jax.value_and_grad(loss_fn, has_aux=True)(p)
 
-        flat = lambda x: x.reshape(T * B, *x.shape[2:])  # noqa: E731
+        if sequence:
+            n_rows = B
+            flat = lambda x: jnp.swapaxes(x, 0, 1)  # noqa: E731
+            # a chunk's [envs, T, ...] as the loss's independent samples
+            samples = lambda x: x.reshape(-1, *x.shape[2:])  # noqa: E731
+        else:
+            n_rows = T * B
+            flat = lambda x: x.reshape(T * B, *x.shape[2:])  # noqa: E731
+            samples = lambda x: x  # noqa: E731
         states_f, actions_f, returns_f = (
             flat(states_t),
             flat(actions_t),
             flat(returns_t),
         )
-        n_chunks = max(1, -(-(T * B) // grad_chunk_samples))
-        while (T * B) % n_chunks:
-            n_chunks += 1
+        n_chunks = learner_chunks(n_rows, T * B, grad_chunk_samples)
         # chunk grads stay shard-local; ONE psum after the accumulation
         p_local = shard_local(params)
         if n_chunks == 1:
-            (_, aux), grads = chunk_grad(
+            (_, (aux, counters)), grads = chunk_grad(
                 p_local, (states_f, actions_f, returns_f)
             )
         else:
-            C = (T * B) // n_chunks
+            C = n_rows // n_chunks
             chunked = lambda x: x.reshape(n_chunks, C, *x.shape[1:])  # noqa: E731
 
             def acc_body(carry, chunk):
                 g_acc, aux_acc = carry
                 (_, aux), g = chunk_grad(p_local, chunk)
-                g_acc = jax.tree_util.tree_map(jnp.add, g_acc, g)
+                # 13.5 MB of float32 a chunk for the conv policy; 2 GB for a
+                # 508 M-parameter one (6 GB moved): time worth a name
+                with device_scope(profiling.GRAD_REDUCE):
+                    g_acc = jax.tree_util.tree_map(jnp.add, g_acc, g)
                 aux_acc = jax.tree_util.tree_map(jnp.add, aux_acc, aux)
                 return (g_acc, aux_acc), None
 
@@ -393,6 +501,7 @@ def make_fused_step(
             )
             with device_scope(profiling.GRAD_REDUCE):
                 grads = jax.tree_util.tree_map(lambda g: g / n_chunks, grads)
+            aux_sum, counters = aux_sum
             aux = jax.tree_util.tree_map(lambda a: a / n_chunks, aux_sum)
         with device_scope(profiling.GRAD_REDUCE):
             grads = jax.lax.psum(grads, DATA_AXIS)
@@ -418,6 +527,7 @@ def make_fused_step(
             ep_return=ep_ret,
             ep_count=ep_cnt,
             ep_return_sum=ep_sum,
+            policy_carry=policy_carry,
         )
         with device_scope(profiling.METRICS):
             metrics = {
@@ -439,6 +549,22 @@ def make_fused_step(
             metrics["episode_return_sum"] = jax.lax.psum(
                 jnp.sum(ep_sum), DATA_AXIS
             )
+            # a sequence policy's counters (moe_tokens_per_expert, [expert
+            # layers, experts held]): counts of this update, over the mesh
+            for k, v in counters.items():
+                metrics[k] = jax.lax.psum(v, DATA_AXIS)
+            if sequence:
+                # every shard's block into its columns of [T, B_global], then
+                # a psum: the same on every shard (an all_gather's result is
+                # typed as varying, which a replicated output may not be)
+                shards = jax.lax.axis_size(DATA_AXIS)
+                at = jax.lax.axis_index(DATA_AXIS) * B
+                for k, v in zip(TRAJECTORY_METRICS, (states_t, actions_t),
+                                strict=True):
+                    whole = jnp.zeros((T, shards * B), v.dtype)
+                    metrics[k] = jax.lax.psum(
+                        jax.lax.dynamic_update_slice(whole, v, (0, at)),
+                        DATA_AXIS)
         assert set(CUMULATIVE_METRICS) <= set(metrics)
         return new_state, metrics
 
@@ -454,7 +580,8 @@ def make_fused_step(
         # loop): the LAST step's psum is "so far"; loss-like metrics
         # average over the dispatch window
         metrics = {
-            k: (v[-1] if k in CUMULATIVE_METRICS else jnp.mean(v, axis=0))
+            k: (v[-1] if k in CUMULATIVE_METRICS + TRAJECTORY_METRICS
+                else jnp.mean(v, axis=0))
             for k, v in ms.items()
         }
         return state, metrics
@@ -470,6 +597,12 @@ def make_fused_step(
         ep_return=batch_spec,
         ep_count=batch_spec,
         ep_return_sum=batch_spec,
+        policy_carry=jax.tree_util.tree_map(
+            lambda _: batch_spec,
+            jax.eval_shape(
+                lambda: (model.init_carry(1), jnp.ones(1, bool))
+            ) if sequence else (),
+        ),
     )
 
     sharded = jax.shard_map(
@@ -505,6 +638,8 @@ def make_fused_step(
             ep_return=_put_batched(state.ep_return),
             ep_count=_put_batched(state.ep_count),
             ep_return_sum=_put_batched(state.ep_return_sum),
+            policy_carry=jax.tree_util.tree_map(
+                _put_batched, state.policy_carry),
         )
 
     def reset_episode_stats(state: FusedState, n_envs: int) -> FusedState:
@@ -524,7 +659,8 @@ def make_fused_step(
     step.batch_sharding = batched
     step.mesh = mesh
     step.rollout_len = rollout_len
-    step.rollout_sub_batch = rollout_sub_batch_of(mesh)
+    step.rollout_sub_batch = (
+        (lambda n_envs: None) if sequence else rollout_sub_batch_of(mesh))
     step.steps_per_dispatch = steps_per_dispatch
     step.reset_episode_stats = reset_episode_stats
     step.audit_jit = jitted  # tools/ba3caudit traces THIS program
@@ -546,6 +682,7 @@ def make_greedy_eval(
     roll in lockstep under one jit; each env contributes its FIRST completed
     episode so long-running envs don't bias the mean toward short episodes.
     """
+    policy.refuse_carry(model, "the greedy on-device evaluator")
 
     def local_eval(params, seed):
         B = n_envs // mesh.shape[DATA_AXIS]
@@ -639,7 +776,15 @@ def run_fused_training(args, cfg: BA3CConfig, model, optimizer) -> int:
     device = log_device_info()
     env = jaxenv.get_env(args.env.split(":", 1)[1])
     cfg = cfg.replace(num_actions=env.num_actions)
-    model = dataclasses.replace(model, num_actions=env.num_actions)
+    sequence = policy.carries_state(model)
+    if sequence:
+        model = model.for_env(env)
+        if getattr(args, "rollout_dtype", "float32") != "float32":
+            # before the int8 arm calibrates; --overlap is refused where its
+            # step is built
+            policy.refuse_carry(model, "--rollout_dtype (a served table)")
+    else:
+        model = dataclasses.replace(model, num_actions=env.num_actions)
 
     if jax.process_count() > 1:
         # multi-host: global host-major mesh; every process runs this loop
@@ -787,9 +932,16 @@ def run_fused_training(args, cfg: BA3CConfig, model, optimizer) -> int:
     # greedy on-device Evaluator (reference Evaluator, SURVEY.md §3.5):
     # nr_eval envs rounded up to the mesh's data axis
     n_eval = max(n_data, (max(args.nr_eval, 1) + n_data - 1) // n_data * n_data)
-    evaluate = make_greedy_eval(
-        model, cfg, mesh, env, n_eval, max_steps=args.eval_max_steps
-    )
+    evaluate = None
+    if sequence:
+        logger.info(
+            "no greedy evaluator for a policy that carries state: "
+            "mean_score of the training rollouts is the run's score"
+        )
+    else:
+        evaluate = make_greedy_eval(
+            model, cfg, mesh, env, n_eval, max_steps=args.eval_max_steps
+        )
 
     # telemetry scrape endpoint (docs/observability.md): the fused loop has
     # no actor plane, but its learner counters + flight ring are still the
@@ -912,7 +1064,11 @@ def _fused_epoch_body(
                 first_dispatch_s = time.monotonic() - t0
                 holder.add_stat("first_dispatch_s", first_dispatch_s)
         with host_span(profiling.SPAN_EPOCH_FETCH):
-            metrics = {k: float(v) for k, v in metrics.items()}
+            # scalars; a sequence policy's counters are small arrays
+            metrics = {
+                k: float(v) if np.ndim(v) == 0 else np.asarray(v)
+                for k, v in metrics.items()
+            }
         # the fetch above forced every dispatch's collectives to completion:
         # proven progress — don't charge the upcoming eval/save to the
         # compute window's stall budget
@@ -950,7 +1106,7 @@ def _fused_epoch_body(
             )
         # greedy eval — the number the north-star (Pong >= 18) is defined on
         eval_mean = float("nan")
-        if epoch % max(args.eval_every, 1) == 0:
+        if evaluate is not None and epoch % max(args.eval_every, 1) == 0:
             if not first_eval_done:
                 # the first eval window includes the eval program's XLA
                 # compile — give it the same grace as the first train
@@ -987,6 +1143,12 @@ def _fused_epoch_body(
             )
         for k in ("loss", "policy_loss", "value_loss", "entropy", "grad_norm"):
             holder.add_stat(k, metrics[k])
+        if "moe_tokens_per_expert" in metrics:
+            # how evenly the router loads the experts held here: the fullest
+            # one's tokens over the mean, in the worst layer
+            held = metrics["moe_tokens_per_expert"]
+            holder.add_stat("moe_load_max_over_mean", float(np.max(
+                held.max(axis=-1) / np.maximum(held.mean(axis=-1), 1e-9))))
         for k in ("mean_rho", "value_lag_mae"):
             # overlap-mode series (fused/overlap.py): how hard V-trace is
             # clipping and how far the value fn moved across the lag

@@ -73,6 +73,7 @@ from distributed_ba3c_tpu.fused.loop import (
     rollout_sub_batch_of,
 )
 from distributed_ba3c_tpu.models.a3c import BA3CNet
+from distributed_ba3c_tpu.models.policy import refuse_carry
 from distributed_ba3c_tpu.ops.gradproc import grad_summaries, inject_learning_rate
 from distributed_ba3c_tpu.ops.vtrace import vtrace_returns
 from distributed_ba3c_tpu.parallel.mesh import DATA_AXIS, shard_local
@@ -324,6 +325,7 @@ def make_overlap_step(
     (quantize/qforward.py). The learner half is untouched — f32
     throughout, exactly like the bf16 rung.
     """
+    refuse_carry(model, "--overlap (the two-program fused step)")
     if lag not in (0, 1):
         raise ValueError(f"lag must be 0 or 1, got {lag}")
     if rollout_dtype not in ROLLOUT_DTYPES:

@@ -36,6 +36,30 @@ class PolicyValue(NamedTuple):
     value: jax.Array   # [B] float32
 
 
+#: stacks an inference forward of this network takes at once when a shard
+#: carries many envs (the fused rollout's policy forward and the bootstrap
+#: under ``returns``; never the learner). PERF.md, PR 25: on the v5e this
+#: conv stack's forward costs more a sample the larger its batch, so a large
+#: env batch runs as sequential forwards of this many. Measured for this
+#: network alone: a policy that carries state is not split this way.
+FORWARD_SUB_BATCH = 256
+
+
+def forward_sub_batch(n_envs: int) -> int | None:
+    """Stacks a forward of a shard's ``n_envs`` env batch: None is all at
+    once (under two sub-batches' worth, or no divisor of ``n_envs`` in
+    [FORWARD_SUB_BATCH / 2, FORWARD_SUB_BATCH]), else the largest such
+    divisor. Read off the shape alone, so every program that traces the
+    rollout at one shape splits it the same way."""
+    top = FORWARD_SUB_BATCH
+    if n_envs < 2 * top:
+        return None
+    for size in range(top, (top - 1) // 2, -1):
+        if n_envs % size == 0:
+            return size
+    return None
+
+
 def conv_layout(model: "BA3CNet") -> Tuple[Tuple[int, int, bool], ...]:
     """The conv stack's (features, kernel, pooled) triples — the ONE
     layout description shared by :meth:`BA3CNet.__call__` and the

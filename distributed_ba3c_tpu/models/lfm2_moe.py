@@ -1,0 +1,398 @@
+"""LFM2-8B-A1B (``model_type lfm2_moe``) as a token-sequence policy.
+
+Published (LiquidAI/LFM2-8B-A1B ``config.json``): hidden 2048, 24 layers of
+``conv`` and ``full_attention`` operators (3 : 1 after the two leading
+layers), the first ``num_dense_layers`` = 2 with a dense SwiGLU of 7168 and
+the rest with 32 routed experts of 1792, 4 a token, no shared expert.
+Every layer is
+
+    h = x + Op(RMSNorm(x))        y = h + FFN(RMSNorm(h))        eps 1e-5
+
+- Op ``conv`` (gated short convolution, ``conv_L_cache`` 3, no bias):
+  ``[b, c, u] = split3(W_in z)``; ``v = b * u``; ``s_t = k_0 v_t + k_1
+  v_{t-1} + k_2 v_{t-2}`` (depthwise, causal, zero before the episode);
+  ``out = W_out (c * s)``. Carried while decoding: ``v_{t-1}, v_{t-2}``.
+- Op ``full_attention``: 32 query heads over 8 key/value heads of 64;
+  RMSNorm with a learned gain over each head's 64 on ``q`` and on ``k``;
+  RoPE (theta 1e6, rotate-half) over the whole head; causal
+  ``softmax(q k^T / 8)``; ``W_o``. Carried: ``k, v`` of past positions.
+- FFN dense: ``W_2 (silu(W_1 z) * W_3 z)``. FFN experts: ``ops/moe.py``.
+- Final RMSNorm; logits over the vocabulary ids held here are the
+  embedding's rows times ``h`` (tied). The value head, a float32 ``Dense(1)``
+  on the same ``h``, is the trainer's own and no part of the published model.
+
+The widths are the defaults below and are never cut. What IS cut is how
+much of the model one chip holds (``benchmark/configs/lfm2-8b-a1b-recall-
+fused-a2c.json`` has the arithmetic): which published layers (``layer_ids``
+with their operator and FFN kinds), how many experts of each layer
+(``experts_held`` from ``expert_offset``) and how many vocabulary ids
+(``num_actions``). ``--model_cut`` names such a cut (:data:`CUTS`).
+
+Precision: float32 parameters, residual stream, norms, router, softmax and
+heads' outputs; bfloat16 matrix operands (``compute_dtype``) with float32
+accumulation. The policy protocol is models/policy.py's.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+from typing import NamedTuple, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from distributed_ba3c_tpu.models.a3c import PolicyValue
+from distributed_ba3c_tpu.ops import moe
+from distributed_ba3c_tpu.utils import profiling
+from distributed_ba3c_tpu.utils.profiling import device_scope
+
+CONV, ATTN = "conv", "full_attention"
+DENSE, EXPERTS = "dense", "experts"
+VALUE_INIT_SCALE = 0.01
+#: spread of the seeded ``use_expert_bias`` buffer: small beside the gaps
+#: between router scores, as a bias that exists to even the load out is
+EXPERT_BIAS_SCALE = 0.01
+
+#: ``--model_cut``: what one chip holds. ``chip-share-4``: one of 4 chips
+#: that share each layer (8 of 32 experts; the vocabulary slice is the
+#: env's action space), published layer 0 and the whole period 2-5.
+#: ``tiny``: every mechanism at a size a CPU test runs.
+CUTS = {
+    "chip-share-4": {},
+    "tiny": dict(
+        hidden_size=64, intermediate_size=96, moe_intermediate_size=32,
+        num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+        num_experts=8, num_experts_per_tok=2, experts_held=2,
+        layer_ids=(0, 2, 3), layer_kinds=((CONV, DENSE), (ATTN, EXPERTS),
+                                          (CONV, EXPERTS)),
+    ),
+}
+
+
+def cut_fields(cut: str | None) -> dict:
+    cut = cut or "chip-share-4"
+    if cut not in CUTS:
+        raise ValueError(f"unknown --model_cut {cut!r}; have {sorted(CUTS)}")
+    return dict(CUTS[cut])
+
+
+class Carry(NamedTuple):
+    """What decoding carries from one position to the next, an env a row."""
+
+    pos: jax.Array       # [B] int32 position in the episode
+    conv: Tuple          # per conv layer (v_{t-1}, v_{t-2}), each [B, d] f32
+    kv: Tuple            # per attention layer (k, v), each [B, P, KV, D]
+
+
+def rms_norm(x, gain, eps):
+    x = x.astype(jnp.float32)
+    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * gain
+
+
+def _rope(x, positions, theta):
+    """Rotate-half RoPE over the whole head. ``x`` [..., T, H, D] float32,
+    ``positions`` broadcastable to [..., T]."""
+    half = x.shape[-1] // 2
+    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    angle = positions[..., None].astype(jnp.float32) * inv_freq
+    cos = jnp.concatenate([jnp.cos(angle)] * 2, -1)[..., None, :]
+    sin = jnp.concatenate([jnp.sin(angle)] * 2, -1)[..., None, :]
+    rotated = jnp.concatenate([-x[..., half:], x[..., :half]], -1)
+    return x * cos + rotated * sin
+
+
+@dataclasses.dataclass(frozen=True)
+class LFM2MoE:
+    num_actions: int = 16384            # vocabulary ids held (of 65,536)
+    hidden_size: int = 2048
+    intermediate_size: int = 7168
+    moe_intermediate_size: int = 1792
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 8
+    head_dim: int = 64
+    conv_L_cache: int = 3
+    norm_eps: float = 1e-5
+    rope_theta: float = 1e6
+    num_experts: int = 32
+    num_experts_per_tok: int = 4
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    # -- the chip's share ---------------------------------------------------
+    layer_ids: Tuple[int, ...] = (0, 2, 3, 4, 5)
+    layer_kinds: Tuple[Tuple[str, str], ...] = (
+        (CONV, DENSE), (ATTN, EXPERTS), (CONV, EXPERTS), (CONV, EXPERTS),
+        (CONV, EXPERTS),
+    )
+    experts_held: int = 8
+    expert_offset: int = 0
+    # -- how it is run ------------------------------------------------------
+    max_positions: int = 256            # K/V cache rows: the episode length
+    compute_dtype: jnp.dtype = jnp.bfloat16
+    remat: bool = True                  # recompute a layer in the backward
+
+    carries_state = True
+
+    def __post_init__(self):
+        assert len(self.layer_ids) == len(self.layer_kinds)
+        assert self.conv_L_cache == 3, "the short conv is written for 3 taps"
+        assert self.num_attention_heads % self.num_key_value_heads == 0
+        assert 0 < self.experts_held <= self.num_experts
+
+    def for_env(self, env) -> "LFM2MoE":
+        """This policy over ``env``'s action space and episode length."""
+        return dataclasses.replace(
+            self, num_actions=env.num_actions, max_positions=env.episode_length
+        )
+
+    def layer_name(self, i: int) -> str:
+        return f"layer_{self.layer_ids[i]}"
+
+    # -- parameters -----------------------------------------------------------
+    def init_params(self, rng):
+        """Seeded float32 parameters, ``{layer: {leaf: array}}``: normal
+        kernels scaled by 1/sqrt(fan_in), unit gains. ``expert_bias`` is
+        the published buffer: it only chooses, so its gradient is
+        identically zero and Adam never moves it."""
+        d, f, fe = self.hidden_size, self.intermediate_size, self.moe_intermediate_size
+        hq = self.num_attention_heads * self.head_dim
+        hkv = self.num_key_value_heads * self.head_dim
+        keys = iter(jax.random.split(rng, 16 * len(self.layer_ids) + 4))
+
+        def normal(shape, fan_in):
+            return jax.random.normal(next(keys), shape, jnp.float32) / math.sqrt(fan_in)
+
+        params = {"embed": {"table": normal((self.num_actions, d), d)}}
+        for i, (op, ffn) in enumerate(self.layer_kinds):
+            layer = {"op_norm": jnp.ones((d,), jnp.float32),
+                     "ffn_norm": jnp.ones((d,), jnp.float32)}
+            if op == CONV:
+                layer.update(conv_in=normal((d, 3 * d), d),
+                             conv_taps=normal((self.conv_L_cache, d), self.conv_L_cache),
+                             conv_out=normal((d, d), d))
+            else:
+                layer.update(wq=normal((d, hq), d), wk=normal((d, hkv), d),
+                             wv=normal((d, hkv), d), wo=normal((hq, d), hq),
+                             q_norm=jnp.ones((self.head_dim,), jnp.float32),
+                             k_norm=jnp.ones((self.head_dim,), jnp.float32))
+            if ffn == DENSE:
+                layer.update(w1=normal((d, f), d), w3=normal((d, f), d),
+                             w2=normal((f, d), f))
+            else:
+                e = self.experts_held
+                layer.update(
+                    router=normal((d, self.num_experts), d),
+                    expert_bias=EXPERT_BIAS_SCALE * jax.random.normal(
+                        next(keys), (self.num_experts,), jnp.float32),
+                    w1=normal((e, d, fe), d), w3=normal((e, d, fe), d),
+                    w2=normal((e, fe, d), fe))
+            params[self.layer_name(i)] = layer
+        params["final"] = {"norm": jnp.ones((d,), jnp.float32)}
+        # a value head that starts near zero, as actor-critic code starts it:
+        # at unit scale V ~ N(0, 1) against returns of 0 swamps the advantage
+        params["value"] = {"kernel": VALUE_INIT_SCALE * normal((d, 1), d),
+                           "bias": jnp.zeros((1,), jnp.float32)}
+        return params
+
+    def rollout_params(self, params):
+        """The matrices in the compute type, once for a whole rollout: a
+        decode step then reads 2 bytes a weight and not 4. Gains, taps,
+        the router with its bias and the value head stay float32."""
+        keep = ("router", "expert_bias", "conv_taps")
+
+        def cast(layer, leaves):
+            if layer == "value":
+                return leaves
+            return {k: (v.astype(self.compute_dtype)
+                        if v.ndim >= 2 and k not in keep else v)
+                    for k, v in leaves.items()}
+
+        return {layer: cast(layer, leaves) for layer, leaves in params.items()}
+
+    # -- pieces shared by the decode step and the unroll -----------------------
+    def _mm(self, x, w, out_dtype=None):
+        cd = self.compute_dtype
+        return jnp.dot(x.astype(cd), w.astype(cd),
+                       preferred_element_type=out_dtype or cd)
+
+    def _ffn(self, p, ffn: str, h):
+        """h [N, d] float32 -> (h + FFN(RMSNorm(h)), None or (tokens routed
+        to each held expert, the chosen expert ids [N, k]))."""
+        if ffn == DENSE:
+            with device_scope(profiling.FFN_DENSE):
+                z = rms_norm(h, p["ffn_norm"], self.norm_eps)
+                gate = self._mm(z, p["w1"]).astype(jnp.float32)
+                up = self._mm(z, p["w3"]).astype(jnp.float32)
+                out = self._mm(jax.nn.silu(gate) * up, p["w2"], jnp.float32)
+                return h + out, None
+        with device_scope(profiling.MOE):
+            z = rms_norm(h, p["ffn_norm"], self.norm_eps)
+            routing = moe.route(
+                z, p["router"], p["expert_bias"], self.num_experts_per_tok,
+                self.norm_topk_prob, self.routed_scaling_factor,
+            )
+            cd = self.compute_dtype
+            out, counts = moe.expert_ffn(
+                z.astype(cd), routing, p["w1"].astype(cd), p["w3"].astype(cd),
+                p["w2"].astype(cd), self.expert_offset, self.num_experts,
+            )
+            return h + out, (counts, routing.experts)
+
+    def _qkv(self, p, z, positions):
+        """z [..., d] -> q [..., H, D], k, v [..., KV, D] in the compute
+        type: per-head RMSNorm on q and k, then RoPE at ``positions``."""
+        D = self.head_dim
+        q = self._mm(z, p["wq"]).reshape(*z.shape[:-1], -1, D)
+        k = self._mm(z, p["wk"]).reshape(*z.shape[:-1], -1, D)
+        v = self._mm(z, p["wv"]).reshape(*z.shape[:-1], -1, D)
+        q = _rope(rms_norm(q, p["q_norm"], self.norm_eps), positions, self.rope_theta)
+        k = _rope(rms_norm(k, p["k_norm"], self.norm_eps), positions, self.rope_theta)
+        cd = self.compute_dtype
+        return q.astype(cd), k.astype(cd), v
+
+    def _attend(self, q, k, v, mask):
+        """q [B, Tq, H, D], k/v [B, Tk, KV, D], mask [B or 1, Tq, Tk] ->
+        [B, Tq, H * D] float32; one KV head serves H / KV query heads."""
+        B, Tq, H, D = q.shape
+        KV = k.shape[2]
+        q = q.reshape(B, Tq, KV, H // KV, D)
+        scores = jnp.einsum("bqkgd,bskd->bkgqs", q, k,
+                            preferred_element_type=jnp.float32) / math.sqrt(D)
+        scores = jnp.where(mask[:, None, None, :, :], scores, -jnp.inf)
+        probs = jax.nn.softmax(scores, axis=-1).astype(self.compute_dtype)
+        out = jnp.einsum("bkgqs,bskd->bqkgd", probs, v,
+                         preferred_element_type=jnp.float32)
+        return out.reshape(B, Tq, H * D)
+
+    def _head(self, params, x):
+        """x [N, d] float32 -> PolicyValue over the held vocabulary."""
+        with device_scope(profiling.HEAD):
+            h = rms_norm(x, params["final"]["norm"], self.norm_eps)
+            logits = jnp.dot(
+                h.astype(self.compute_dtype),
+                params["embed"]["table"].astype(self.compute_dtype).T,
+                preferred_element_type=jnp.float32,
+            )
+            value = jnp.dot(
+                h, params["value"]["kernel"],
+                precision=jax.lax.Precision.HIGHEST,
+            )[:, 0] + params["value"]["bias"][0]
+            return PolicyValue(logits=logits, value=value)
+
+    def _embed(self, params, tokens):
+        with device_scope(profiling.EMBED):
+            # rounded to the compute type whichever table it is read from
+            # (float32 in the learner, the rollout's snapshot): one value
+            return params["embed"]["table"][tokens].astype(
+                self.compute_dtype).astype(jnp.float32)
+
+    # -- the rollout's decode step ---------------------------------------------
+    def init_carry(self, batch: int) -> Carry:
+        d = self.hidden_size
+        kv_shape = (batch, self.max_positions, self.num_key_value_heads,
+                    self.head_dim)
+
+        def zeros():  # a buffer each: the step donates its state
+            return jnp.zeros((batch, d), jnp.float32)
+
+        return Carry(
+            pos=jnp.zeros((batch,), jnp.int32),
+            conv=tuple((zeros(), zeros()) for op, _ in self.layer_kinds if op == CONV),
+            kv=tuple(
+                (jnp.zeros(kv_shape, self.compute_dtype),
+                 jnp.zeros(kv_shape, self.compute_dtype))
+                for op, _ in self.layer_kinds if op == ATTN
+            ),
+        )
+
+    def step(self, params, obs, carry: Carry, fresh):
+        """One token an env: ``obs`` [B] int32, ``fresh`` [B] bool (the
+        token opens an episode: forget the last one first)."""
+        B = obs.shape[0]
+        pos = jnp.where(fresh, 0, carry.pos)
+        keep = (~fresh).astype(jnp.float32)[:, None]
+        x = self._embed(params, obs)
+        conv_out, kv_out = [], []
+        conv_in, kv_in = iter(carry.conv), iter(carry.kv)
+        rows = jnp.arange(B)
+        for i, (op, ffn) in enumerate(self.layer_kinds):
+            p = params[self.layer_name(i)]
+            if op == CONV:
+                with device_scope(profiling.OP_CONV):
+                    v1, v2 = next(conv_in)
+                    v1, v2 = v1 * keep, v2 * keep
+                    z = rms_norm(x, p["op_norm"], self.norm_eps)
+                    b, c, u = jnp.split(
+                        self._mm(z, p["conv_in"]).astype(jnp.float32), 3, -1)
+                    v = b * u
+                    taps = p["conv_taps"]
+                    s = taps[0] * v + taps[1] * v1 + taps[2] * v2
+                    h = x + self._mm(c * s, p["conv_out"], jnp.float32)
+                    conv_out.append((v, v1))
+            else:
+                with device_scope(profiling.OP_ATTN):
+                    k_cache, v_cache = next(kv_in)
+                    z = rms_norm(x, p["op_norm"], self.norm_eps)
+                    q, k, v = self._qkv(p, z[:, None, :], pos[:, None])
+                    k_cache = k_cache.at[rows, pos].set(
+                        k[:, 0], indices_are_sorted=True, unique_indices=True)
+                    v_cache = v_cache.at[rows, pos].set(
+                        v[:, 0], indices_are_sorted=True, unique_indices=True)
+                    mask = jnp.arange(self.max_positions)[None, None, :] <= pos[:, None, None]
+                    a = self._attend(q, k_cache, v_cache, mask)[:, 0]
+                    h = x + self._mm(a, p["wo"], jnp.float32)
+                    kv_out.append((k_cache, v_cache))
+            x, _ = self._ffn(p, ffn, h)
+        return self._head(params, x), Carry(
+            pos=pos + 1, conv=tuple(conv_out), kv=tuple(kv_out))
+
+    # -- the learner's unroll ----------------------------------------------------
+    def _layer_unroll(self, i: int, p, x):
+        """One layer over whole episodes: x [B, T, d] float32."""
+        op, ffn = self.layer_kinds[i]
+        B, T, d = x.shape
+        if op == CONV:
+            with device_scope(profiling.OP_CONV):
+                z = rms_norm(x, p["op_norm"], self.norm_eps)
+                b, c, u = jnp.split(
+                    self._mm(z, p["conv_in"]).astype(jnp.float32), 3, -1)
+                v = b * u
+                padded = jnp.pad(v, ((0, 0), (2, 0), (0, 0)))
+                taps = p["conv_taps"]
+                s = (taps[0] * v + taps[1] * padded[:, 1:T + 1]
+                     + taps[2] * padded[:, :T])
+                h = x + self._mm(c * s, p["conv_out"], jnp.float32)
+        else:
+            with device_scope(profiling.OP_ATTN):
+                z = rms_norm(x, p["op_norm"], self.norm_eps)
+                positions = jnp.arange(T)[None, :]
+                q, k, v = self._qkv(p, z, positions)
+                mask = (jnp.arange(T)[None, :] <= jnp.arange(T)[:, None])[None]
+                h = x + self._mm(self._attend(q, k, v, mask), p["wo"], jnp.float32)
+        y, routed = self._ffn(p, ffn, h.reshape(B * T, d))
+        return y.reshape(B, T, d), routed
+
+    def unroll(self, params, tokens, with_routes: bool = False):
+        """Whole episodes from a reset: ``tokens`` [B, T] int32 ->
+        (PolicyValue with logits [B, T, A] and value [B, T], aux). ``aux``
+        counts the tokens routed to each held expert of each expert layer
+        (``moe_tokens_per_expert``) and, asked, names every token's chosen
+        experts (``routes`` [expert layers, B, T, k])."""
+        B, T = tokens.shape
+        x = self._embed(params, tokens)
+        counts, routes = [], []
+        for i in range(len(self.layer_kinds)):
+            layer = lambda p, x, i=i: self._layer_unroll(i, p, x)  # noqa: E731
+            if self.remat:
+                layer = jax.checkpoint(layer)
+            x, routed = layer(params[self.layer_name(i)], x)
+            if routed is not None:
+                counts.append(routed[0])
+                routes.append(routed[1].reshape(B, T, -1))
+        out = self._head(params, x.reshape(B * T, -1))
+        aux = {"moe_tokens_per_expert": jnp.stack(counts)} if counts else {}
+        if with_routes:
+            aux["routes"] = jnp.stack(routes)
+        return PolicyValue(
+            logits=out.logits.reshape(B, T, -1), value=out.value.reshape(B, T)
+        ), aux

@@ -1,0 +1,82 @@
+"""The policy protocol and the registry of policies by name (ROADMAP A0).
+
+A policy is what the trainers call to turn observations into
+:class:`PolicyValue`. Two kinds exist:
+
+- a **stateless** policy (``BA3CNet``): a flax module;
+  ``model.apply({"params": p}, obs) -> PolicyValue`` over a batch of
+  independent observations. Its carry is the empty pytree ``()``.
+- a policy that **carries state** (``carries_state = True``): besides its
+  parameters it gives
+
+      model.init_params(rng) -> params           {layer: {leaf: array}}
+      model.init_carry(batch) -> carry           pytree, leaves [batch, ...]
+      model.step(params, obs, carry, fresh) -> (PolicyValue, carry, aux)
+          one observation an env; where ``fresh`` (bool [batch]) the
+          observation opens an episode and the carry is reset before use
+      model.unroll(params, obs_seq) -> (PolicyValue, aux)
+          whole episodes ``[batch, T]`` from a reset, causal over ``T``;
+          the learner's forward
+      model.rollout_params(params) -> params     what ``step`` is served
+          from all through one rollout (a bfloat16 snapshot of the matrices)
+
+  ``aux`` is a dict of counters (``moe_tokens_per_expert``); ``step`` and
+  ``unroll`` agree position by position (tests/test_lfm2_moe.py).
+
+Only the fused trainer drives a policy that carries state; every other
+trainer refuses one through :func:`refuse_carry`. docs/policy_protocol.md.
+"""
+
+from __future__ import annotations
+
+from typing import Callable, Dict
+
+import jax.numpy as jnp
+
+DEFAULT_MODEL = "ba3cnet"
+
+
+def carries_state(model) -> bool:
+    return bool(getattr(model, "carries_state", False))
+
+
+def refuse_carry(model, what: str) -> None:
+    """The one error of every path that cannot drive a policy's carry."""
+    if carries_state(model):
+        raise ValueError(
+            f"{what} cannot drive a policy that carries state "
+            f"({type(model).__name__}): only --trainer tpu_fused_ba3c "
+            "(without --overlap) threads a policy carry through its rollout"
+        )
+
+
+def init_params(model, rng, cfg):
+    """Seeded parameters of either kind of policy."""
+    if carries_state(model):
+        return model.init_params(rng)
+    dummy = jnp.zeros((1, *cfg.state_shape), jnp.uint8)
+    return model.init(rng, dummy)["params"]
+
+
+def _ba3cnet(cfg, cut=None):
+    from distributed_ba3c_tpu.models.a3c import BA3CNet
+
+    if cut is not None:
+        raise ValueError("ba3cnet has no --model_cut")
+    return BA3CNet(num_actions=cfg.num_actions, fc_units=cfg.fc_units)
+
+
+def _lfm2_moe(cfg, cut=None):
+    from distributed_ba3c_tpu.models.lfm2_moe import LFM2MoE, cut_fields
+
+    return LFM2MoE(num_actions=cfg.num_actions, **cut_fields(cut))
+
+
+MODELS: Dict[str, Callable] = {DEFAULT_MODEL: _ba3cnet, "lfm2-moe": _lfm2_moe}
+
+
+def build_model(name: str, cfg, cut: str | None = None):
+    """The policy ``--model name`` names, its action space from ``cfg``."""
+    if name not in MODELS:
+        raise ValueError(f"unknown model {name!r}; have {sorted(MODELS)}")
+    return MODELS[name](cfg, cut)

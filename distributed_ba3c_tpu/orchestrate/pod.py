@@ -206,7 +206,7 @@ class PodLearnerPlane:
     ):
         import jax
 
-        from distributed_ba3c_tpu.models.a3c import BA3CNet
+        from distributed_ba3c_tpu.models.policy import DEFAULT_MODEL, build_model
         from distributed_ba3c_tpu.ops.gradproc import make_optimizer
         from distributed_ba3c_tpu.parallel.mesh import make_mesh
         from distributed_ba3c_tpu.parallel.train_step import create_train_state
@@ -220,7 +220,7 @@ class PodLearnerPlane:
 
         self.cfg = cfg
         self.endpoints = pod_endpoints(pipe_c2s, pipe_s2c)
-        model = BA3CNet(num_actions=cfg.num_actions, fc_units=cfg.fc_units)
+        model = build_model(DEFAULT_MODEL, cfg)
         optimizer = make_optimizer(
             cfg.learning_rate, cfg.adam_epsilon, cfg.grad_clip_norm
         )

@@ -28,6 +28,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from distributed_ba3c_tpu.audit import tripwire_jit
 from distributed_ba3c_tpu.config import BA3CConfig
+from distributed_ba3c_tpu.models import policy
 from distributed_ba3c_tpu.models.a3c import BA3CNet
 from distributed_ba3c_tpu.ops.gradproc import grad_summaries, inject_learning_rate
 from distributed_ba3c_tpu.ops.loss import a3c_loss
@@ -52,8 +53,7 @@ def create_train_state(
     cfg: BA3CConfig,
     optimizer: optax.GradientTransformation,
 ) -> TrainState:
-    dummy = jnp.zeros((1, *cfg.state_shape), jnp.uint8)
-    params = model.init(rng, dummy)["params"]
+    params = policy.init_params(model, rng, cfg)
     opt_state = optimizer.init(params)
     if inject_learning_rate(opt_state, 0.0) is opt_state:
         from distributed_ba3c_tpu.utils import logger
@@ -162,6 +162,7 @@ def make_train_step(
     state compiles the step once for itself and once more for the
     mesh-replicated state the step returns.
     """
+    policy.refuse_carry(model, "the sync data-parallel train step")
     replicated = P()
     batch_spec = P(DATA_AXIS)
 
@@ -257,6 +258,7 @@ def make_macro_train_step(
 
     Registered audit entry: ``parallel.train_macro_step``.
     """
+    policy.refuse_carry(model, "the multi-fleet macro train step")
     if n_fleets < 1:
         raise ValueError(f"n_fleets must be >= 1, got {n_fleets}")
     n_data = mesh.shape[DATA_AXIS]

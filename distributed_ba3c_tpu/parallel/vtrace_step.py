@@ -30,6 +30,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from distributed_ba3c_tpu.audit import tripwire_jit
 from distributed_ba3c_tpu.config import BA3CConfig
 from distributed_ba3c_tpu.models.a3c import BA3CNet
+from distributed_ba3c_tpu.models.policy import refuse_carry
 from distributed_ba3c_tpu.ops.gradproc import grad_summaries
 from distributed_ba3c_tpu.ops.vtrace import vtrace_returns
 from distributed_ba3c_tpu.parallel.mesh import DATA_AXIS, shard_local
@@ -129,6 +130,7 @@ def make_vtrace_train_step(
     mesh: Mesh,
 ) -> Callable:
     """Jitted mesh-sharded V-trace step: fn(state, batch, beta, lr)."""
+    refuse_carry(model, "the V-trace actor-plane learner")
     replicated = P()
     specs = {
         "state": P(None, DATA_AXIS),
@@ -189,6 +191,7 @@ def make_vtrace_macro_step(
 
     Registered audit entry: ``parallel.vtrace_macro_step``.
     """
+    refuse_carry(model, "the V-trace macro learner")
     if n_fleets < 1:
         raise ValueError(f"n_fleets must be >= 1, got {n_fleets}")
     n_data = mesh.shape[DATA_AXIS]

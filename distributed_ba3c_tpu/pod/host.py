@@ -319,7 +319,7 @@ def main(argv: Optional[list] = None) -> int:
 
     from distributed_ba3c_tpu.actors.simulator import SimulatorProcess, default_pipes
     from distributed_ba3c_tpu.config import BA3CConfig
-    from distributed_ba3c_tpu.models.a3c import BA3CNet
+    from distributed_ba3c_tpu.models.policy import DEFAULT_MODEL, build_model
     from distributed_ba3c_tpu.orchestrate import FleetSpec, FleetSupervisor
     from distributed_ba3c_tpu.predict.server import BatchedPredictor
 
@@ -332,7 +332,7 @@ def main(argv: Optional[list] = None) -> int:
         reward_clip=args.reward_clip,
         local_time_max=args.unroll_len,
     )
-    model = BA3CNet(num_actions=cfg.num_actions, fc_units=cfg.fc_units)
+    model = build_model(DEFAULT_MODEL, cfg)
     quant_spec = None
     if args.quant_spec:
         from distributed_ba3c_tpu.quantize import QuantSpec

@@ -50,6 +50,7 @@ from distributed_ba3c_tpu.fused.overlap import (
     make_finish_update,
 )
 from distributed_ba3c_tpu.models.a3c import BA3CNet
+from distributed_ba3c_tpu.models.policy import refuse_carry
 from distributed_ba3c_tpu.parallel.mesh import DATA_AXIS
 from distributed_ba3c_tpu.parallel.train_step import TrainState
 
@@ -71,6 +72,7 @@ def make_pod_learner_step(
     shape per run (the BA3C_AUDIT=1 tripwire raises on a mid-run reshape,
     exactly the predictor-bucket contract).
     """
+    refuse_carry(model, "the pod learner")
     block_grads = make_block_grads(model, cfg, grad_chunk_samples)
     finish_update = make_finish_update(optimizer)
 

@@ -47,6 +47,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from distributed_ba3c_tpu.models.policy import refuse_carry
 from distributed_ba3c_tpu import telemetry
 from distributed_ba3c_tpu.telemetry import tracing as _tracing
 from distributed_ba3c_tpu.audit import tripwire_jit
@@ -249,6 +250,7 @@ def make_fwd_sample(model, greedy: bool = False) -> Callable:
     ``predict.server_greedy``) traces the same function the live predictor
     jits — BOTH packed shapes are registered so T5 pins them.
     """
+    refuse_carry(model, "the batched action server")
 
     def fwd_sample(params, states, key):
         out = model.apply({"params": params}, states)

@@ -15,7 +15,7 @@ import jax
 from distributed_ba3c_tpu.config import BA3CConfig
 from distributed_ba3c_tpu.envs import jaxenv
 from distributed_ba3c_tpu.fused.loop import make_greedy_eval
-from distributed_ba3c_tpu.models.a3c import BA3CNet
+from distributed_ba3c_tpu.models.policy import DEFAULT_MODEL, build_model
 from distributed_ba3c_tpu.ops.gradproc import make_optimizer
 from distributed_ba3c_tpu.parallel.mesh import DATA_AXIS, make_mesh
 from distributed_ba3c_tpu.parallel.train_step import create_train_state
@@ -34,7 +34,7 @@ def make_checkpoint_evaluator(
     """
     env = jaxenv.get_env(env_spec.split(":", 1)[1])
     cfg = BA3CConfig(num_actions=env.num_actions, fc_units=fc_units)
-    model = BA3CNet(num_actions=cfg.num_actions, fc_units=cfg.fc_units)
+    model = build_model(DEFAULT_MODEL, cfg)
     opt = make_optimizer(cfg.learning_rate, cfg.adam_epsilon, cfg.grad_clip_norm)
     target = jax.device_get(
         create_train_state(jax.random.PRNGKey(0), model, cfg, opt)

@@ -57,6 +57,39 @@ SCOPES = (
     ROLLOUT_ENV_STEP, ROLLOUT_RENDER, ROLLOUT_STACK, RETURNS,
     RETURNS_SUB_BATCH, LEARNER, LEARNER_LOSS, GRAD_REDUCE, OPTIMIZER, METRICS,
 )
+# -- scopes inside a layered sequence policy (models/lfm2_moe.py, ops/moe.py),
+# opened under ``rollout/policy`` (the decode step) and under ``learner`` (the
+# unroll) alike; a conv policy's step has none of them, so they are kept
+# apart from SCOPES, which every fused step carries
+EMBED = "embed"
+OP_CONV = "op_conv"
+OP_ATTN = "op_attn"
+FFN_DENSE = "ffn_dense"
+MOE = "moe"
+MOE_ROUTER = "moe/router"
+MOE_DISPATCH = "moe/dispatch"
+MOE_EXPERTS = "moe/experts"
+MOE_COMBINE = "moe/combine"
+HEAD = "head"
+POLICY_LAYERS = (
+    EMBED, OP_CONV, OP_ATTN, FFN_DENSE, MOE, MOE_ROUTER, MOE_DISPATCH,
+    MOE_EXPERTS, MOE_COMBINE, HEAD,
+)
+#: the rollout's once-an-update bfloat16 snapshot of the matrix weights
+ROLLOUT_WEIGHTS_BF16 = "rollout/weights_bf16"
+
+
+def policy_scope(under: str, layer: str) -> str:
+    """``rollout/policy/moe/experts`` of (ROLLOUT_POLICY, MOE_EXPERTS)."""
+    return f"{under}/{layer}"
+
+
+SEQUENCE_SCOPES = (ROLLOUT_WEIGHTS_BF16,) + tuple(
+    policy_scope(under, layer)
+    for under in (ROLLOUT_POLICY, LEARNER) for layer in POLICY_LAYERS
+)
+#: every scope the reader sorts time into
+ALL_SCOPES = SCOPES + SEQUENCE_SCOPES
 #: reader-only splits of ``learner``: JAX marks the backward pass itself
 LEARNER_FWD = "learner:fwd"
 LEARNER_BWD = "learner:bwd"
@@ -236,7 +269,7 @@ def _unwrap(component: str) -> str:
 
 
 def scope_of(op_name: str) -> Optional[str]:
-    """The deepest scope of :data:`SCOPES` an ``op_name`` lies in, or None.
+    """The deepest scope of :data:`ALL_SCOPES` an ``op_name`` lies in, or None.
 
     ``jit(multi_step)/rollout/while/body/closed_call/env_step/vmap(render)/..``
     is ``rollout/env_step/render``: the first component that names a phase,
@@ -249,9 +282,28 @@ def scope_of(op_name: str) -> Optional[str]:
         return None
     scope = names[i]
     for name in names[i + 1:]:
-        if f"{scope}/{name}" in SCOPES:
+        if f"{scope}/{name}" in ALL_SCOPES:
             scope = f"{scope}/{name}"
     return scope
+
+
+#: op_names the TPU compiler gives the kernels it makes of ``ragged_dot``
+#: (``ragged-dot-none``, ``ragged-dot-metadata``): it drops the scopes the
+#: instruction was traced under (by hand on a capture, PR 26)
+RENAMED_KERNEL = "ragged-dot"
+
+
+def kernel_scope(op_name: str, neighbour_op_name: str) -> Optional[str]:
+    """The scope of a grouped-product kernel the compiler renamed: the
+    ``moe/experts`` of whichever ``moe`` scope the scoped op that ran just
+    before it lies in (its dispatch, or the products' own elementwise work)."""
+    if not op_name.startswith(RENAMED_KERNEL):
+        return None
+    scope = scope_of(neighbour_op_name) or ""
+    parts = scope.split("/")
+    if MOE not in parts:
+        return None
+    return "/".join(parts[:parts.index(MOE)] + MOE_EXPERTS.split("/"))
 
 
 def is_backward(op_name: str) -> bool:
@@ -268,7 +320,7 @@ def op_time_by_scope(xplane_path: str) -> Optional[dict]:
     """Device op time of a capture by scope; None if no op carries a scope.
 
     -> ``seconds``: {scope: device seconds a chip} for every scope of
-    :data:`SCOPES` (a scope's time includes the scopes nested in it) plus
+    :data:`ALL_SCOPES` (a scope's time includes the scopes nested in it) plus
     :data:`LEARNER_FWD` / :data:`LEARNER_BWD` (``learner`` without and with
     JAX's ``transpose(``) and :data:`UNSCOPED`; ``total_s``: all op time a
     chip; ``unscoped_share``; ``unscoped_ops``: the ten unscoped ops with
@@ -282,7 +334,7 @@ def op_time_by_scope(xplane_path: str) -> Optional[dict]:
     scope boundary goes whole to one side; what carries no scope at all is
     reported, not assumed zero. Times are means over the chips."""
     op_names = event_op_names(xplane_path)
-    seconds = dict.fromkeys(SCOPES + (LEARNER_FWD, LEARNER_BWD, UNSCOPED), 0.0)
+    seconds = dict.fromkeys(ALL_SCOPES + (LEARNER_FWD, LEARNER_BWD, UNSCOPED), 0.0)
     unscoped: Dict[str, float] = {}
     events: Dict[str, list] = {}
     total = 0.0
@@ -296,11 +348,18 @@ def op_time_by_scope(xplane_path: str) -> Optional[dict]:
                 continue
             count, first = 0, None
             by_event: Dict[str, float] = {}  # an instruction's text -> seconds
-            for e in line.events:
+            before: Dict[str, str] = {}  # a renamed kernel -> its neighbour
+            last_scoped = ""
+            for e in sorted(line.events, key=lambda e: e.start_ns):
                 count += 1
-                if first is None or e.start_ns < first:
+                if first is None:
                     first = e.start_ns
                 by_event[e.name] = by_event.get(e.name, 0.0) + e.duration_ns / 1e9
+                op_name = names.get(e.name, "")
+                if op_name.startswith(RENAMED_KERNEL):
+                    before.setdefault(e.name, last_scoped)
+                elif not _is_container(e.name) and scope_of(op_name):
+                    last_scoped = op_name
             events[plane.name] = [count, int(first) if count else None]
             for text, s in by_event.items():
                 if _is_container(text):
@@ -308,6 +367,11 @@ def op_time_by_scope(xplane_path: str) -> Optional[dict]:
                 total += s
                 op_name = names.get(text, "")
                 scope = scope_of(op_name)
+                if scope is None and text in before:
+                    # an instruction sits at one place of the program: the
+                    # neighbour of its first execution is its neighbour
+                    scope = kernel_scope(op_name, before[text])
+                    op_name = before[text]
                 if scope is None:
                     seconds[UNSCOPED] += s
                     short = text.split(" = ", 1)[0]

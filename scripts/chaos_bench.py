@@ -110,14 +110,14 @@ class _Plane:
         from distributed_ba3c_tpu.actors.master import BA3CSimulatorMaster
         from distributed_ba3c_tpu.config import BA3CConfig
         from distributed_ba3c_tpu.envs import native
-        from distributed_ba3c_tpu.models.a3c import BA3CNet
+        from distributed_ba3c_tpu.models.policy import DEFAULT_MODEL, build_model
         from distributed_ba3c_tpu.orchestrate import FleetSpec, FleetSupervisor
 
         n_actions = native.CppBatchedEnv(game, 1).num_actions
         cfg = BA3CConfig(
             num_actions=n_actions, predict_batch_size=max(256, per)
         )
-        model = BA3CNet(num_actions=cfg.num_actions, fc_units=cfg.fc_units)
+        model = build_model(DEFAULT_MODEL, cfg)
         params = model.init(
             jax.random.PRNGKey(0), np.zeros((1, *cfg.state_shape), np.uint8)
         )["params"]

@@ -76,7 +76,7 @@ def run_trace_capture(
     from distributed_ba3c_tpu.config import BA3CConfig
     from distributed_ba3c_tpu.data.dataflow import RolloutFeed
     from distributed_ba3c_tpu.envs import native
-    from distributed_ba3c_tpu.models.a3c import BA3CNet
+    from distributed_ba3c_tpu.models.policy import DEFAULT_MODEL, build_model
     from distributed_ba3c_tpu.ops.gradproc import make_optimizer
     from distributed_ba3c_tpu.parallel.mesh import make_mesh
     from distributed_ba3c_tpu.parallel.train_step import create_train_state
@@ -93,7 +93,7 @@ def run_trace_capture(
 
     n_actions = native.CppBatchedEnv(game, 1).num_actions
     cfg = BA3CConfig(num_actions=n_actions, predict_batch_size=max(64, n_envs))
-    model = BA3CNet(num_actions=cfg.num_actions, fc_units=cfg.fc_units)
+    model = build_model(DEFAULT_MODEL, cfg)
     params = model.init(
         jax.random.PRNGKey(0), np.zeros((1, *cfg.state_shape), np.uint8)
     )["params"]
@@ -255,7 +255,7 @@ def run_ingest_phase(
     from distributed_ba3c_tpu.data.dataflow import RolloutFeed
     from distributed_ba3c_tpu.data.staging import DeviceIngest, HostStagingRing
     from distributed_ba3c_tpu.envs import native
-    from distributed_ba3c_tpu.models.a3c import BA3CNet
+    from distributed_ba3c_tpu.models.policy import DEFAULT_MODEL, build_model
     from distributed_ba3c_tpu.ops.gradproc import make_optimizer
     from distributed_ba3c_tpu.parallel.mesh import make_mesh
     from distributed_ba3c_tpu.parallel.train_step import create_train_state
@@ -266,7 +266,7 @@ def run_ingest_phase(
 
     n_actions = native.CppBatchedEnv(game, 1).num_actions
     cfg = BA3CConfig(num_actions=n_actions, predict_batch_size=max(64, n_envs))
-    model = BA3CNet(num_actions=cfg.num_actions, fc_units=cfg.fc_units)
+    model = build_model(DEFAULT_MODEL, cfg)
     params = model.init(
         jax.random.PRNGKey(0), np.zeros((1, *cfg.state_shape), np.uint8)
     )["params"]

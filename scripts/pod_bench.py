@@ -152,7 +152,7 @@ def _phase_staleness_curve(args) -> dict:
     from distributed_ba3c_tpu.envs.jaxenv import pong
     from distributed_ba3c_tpu.fused.loop import create_fused_state
     from distributed_ba3c_tpu.fused.overlap import make_overlap_step
-    from distributed_ba3c_tpu.models.a3c import BA3CNet
+    from distributed_ba3c_tpu.models.policy import DEFAULT_MODEL, build_model
     from distributed_ba3c_tpu.ops.gradproc import make_optimizer
     from distributed_ba3c_tpu.parallel.mesh import make_mesh
     from distributed_ba3c_tpu.parallel.train_step import create_train_state
@@ -163,7 +163,7 @@ def _phase_staleness_curve(args) -> dict:
     )
 
     cfg = BA3CConfig(num_actions=pong.num_actions, fc_units=args.fc_units)
-    model = BA3CNet(num_actions=cfg.num_actions, fc_units=cfg.fc_units)
+    model = build_model(DEFAULT_MODEL, cfg)
     opt = make_optimizer(
         cfg.learning_rate, cfg.adam_epsilon, cfg.grad_clip_norm
     )

@@ -23,7 +23,7 @@ import numpy as np
 from distributed_ba3c_tpu.config import BA3CConfig
 from distributed_ba3c_tpu.envs.jaxenv import pong
 from distributed_ba3c_tpu.fused.loop import create_fused_state, make_fused_step
-from distributed_ba3c_tpu.models.a3c import BA3CNet
+from distributed_ba3c_tpu.models.policy import DEFAULT_MODEL, build_model
 from distributed_ba3c_tpu.ops.gradproc import make_optimizer
 from distributed_ba3c_tpu.parallel.mesh import make_mesh
 
@@ -40,7 +40,7 @@ def timeit(fn, *args, iters=10):
 
 def bench_full_only(n_envs: int, rollout_len: int, chunk: int):
     cfg = BA3CConfig(num_actions=pong.num_actions)
-    model = BA3CNet(num_actions=cfg.num_actions, fc_units=cfg.fc_units)
+    model = build_model(DEFAULT_MODEL, cfg)
     opt = make_optimizer(cfg.learning_rate, cfg.adam_epsilon, cfg.grad_clip_norm)
     mesh = make_mesh()
     step = make_fused_step(
@@ -77,7 +77,7 @@ def bench_full_only(n_envs: int, rollout_len: int, chunk: int):
 
 def bench_shape(n_envs: int, rollout_len: int):
     cfg = BA3CConfig(num_actions=pong.num_actions)
-    model = BA3CNet(num_actions=cfg.num_actions, fc_units=cfg.fc_units)
+    model = build_model(DEFAULT_MODEL, cfg)
     opt = make_optimizer(cfg.learning_rate, cfg.adam_epsilon, cfg.grad_clip_norm)
     mesh = make_mesh()
     step = make_fused_step(model, opt, cfg, mesh, pong, rollout_len=rollout_len)
@@ -209,7 +209,7 @@ def bench_attribution(n_envs: int, rollout_len: int, inner: int = 50):
     (which can exceed the components themselves) divides out, and the chain
     is unfoldable so XLA cannot elide it."""
     cfg = BA3CConfig(num_actions=pong.num_actions)
-    model = BA3CNet(num_actions=cfg.num_actions, fc_units=cfg.fc_units)
+    model = build_model(DEFAULT_MODEL, cfg)
     opt = make_optimizer(cfg.learning_rate, cfg.adam_epsilon, cfg.grad_clip_norm)
     state = create_fused_state(
         jax.random.PRNGKey(0), model, cfg, opt, pong, n_envs, n_shards=1

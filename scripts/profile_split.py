@@ -34,7 +34,7 @@ import numpy as np
 from distributed_ba3c_tpu.config import BA3CConfig
 from distributed_ba3c_tpu.envs.jaxenv import pong
 from distributed_ba3c_tpu.fused.loop import create_fused_state, make_fused_step
-from distributed_ba3c_tpu.models.a3c import BA3CNet
+from distributed_ba3c_tpu.models.policy import DEFAULT_MODEL, build_model
 from distributed_ba3c_tpu.ops.gradproc import inject_learning_rate
 from distributed_ba3c_tpu.ops.loss import a3c_loss
 from distributed_ba3c_tpu.ops.returns import n_step_returns
@@ -50,7 +50,7 @@ def profile_overlap(n_envs: int, rollout_len: int, fc_units: int,
     from distributed_ba3c_tpu.fused.overlap import make_overlap_step
 
     cfg = BA3CConfig(num_actions=pong.num_actions, fc_units=fc_units)
-    model = BA3CNet(num_actions=cfg.num_actions, fc_units=cfg.fc_units)
+    model = build_model(DEFAULT_MODEL, cfg)
     from distributed_ba3c_tpu.ops.gradproc import make_optimizer
 
     opt = make_optimizer(cfg.learning_rate, cfg.adam_epsilon,
@@ -123,7 +123,7 @@ def main():
         return
 
     cfg = BA3CConfig(num_actions=pong.num_actions)
-    model = BA3CNet(num_actions=cfg.num_actions, fc_units=cfg.fc_units)
+    model = build_model(DEFAULT_MODEL, cfg)
     from distributed_ba3c_tpu.ops.gradproc import make_optimizer
 
     opt = make_optimizer(cfg.learning_rate, cfg.adam_epsilon, cfg.grad_clip_norm)

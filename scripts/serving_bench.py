@@ -654,7 +654,7 @@ def _quant_artifacts(opts) -> dict:
     from distributed_ba3c_tpu.config import BA3CConfig
     from distributed_ba3c_tpu.envs.jaxenv import pong
     from distributed_ba3c_tpu.fused.loop import make_rollout_body
-    from distributed_ba3c_tpu.models.a3c import BA3CNet
+    from distributed_ba3c_tpu.models.policy import DEFAULT_MODEL, build_model
     from distributed_ba3c_tpu.quantize import (
         calibrate_from_env,
         make_quant_apply,
@@ -662,7 +662,7 @@ def _quant_artifacts(opts) -> dict:
     )
 
     cfg = BA3CConfig(num_actions=pong.num_actions)
-    model = BA3CNet(num_actions=cfg.num_actions, fc_units=cfg.fc_units)
+    model = build_model(DEFAULT_MODEL, cfg)
     key = jax.random.PRNGKey(opts.seed)
     dummy = jnp.zeros((1, *cfg.state_shape), jnp.uint8)
     params = model.init(key, dummy)["params"]

@@ -15,6 +15,7 @@ import pytest
 from distributed_ba3c_tpu.config import BA3CConfig
 from distributed_ba3c_tpu.envs.jaxenv import pong
 from distributed_ba3c_tpu.fused import loop
+from distributed_ba3c_tpu.models import a3c as a3c_model
 from distributed_ba3c_tpu.models.a3c import BA3CNet
 from distributed_ba3c_tpu.ops.gradproc import make_optimizer
 from distributed_ba3c_tpu.parallel.mesh import make_mesh
@@ -32,17 +33,17 @@ ROLLOUT_LEN = 3
     (1021, None),  # a prime
 ])
 def test_the_helpers_verdict_by_shape(n_envs, size):
-    assert loop.FORWARD_SUB_BATCH == 256
+    assert a3c_model.FORWARD_SUB_BATCH == 256
     assert loop.forward_sub_batch(n_envs) == size
     if size is not None:
         assert n_envs % size == 0 and 128 <= size <= 256
 
 
 def test_the_verdict_follows_the_module_constant(monkeypatch):
-    monkeypatch.setattr(loop, "FORWARD_SUB_BATCH", 2)
+    monkeypatch.setattr(a3c_model, "FORWARD_SUB_BATCH", 2)
     assert [loop.forward_sub_batch(n) for n in (2, 3, 4, 5, 6, 8)] == [
         None, None, 2, 1, 2, 2]  # [1, 2] holds a divisor of anything
-    monkeypatch.setattr(loop, "FORWARD_SUB_BATCH", 3)
+    monkeypatch.setattr(a3c_model, "FORWARD_SUB_BATCH", 3)
     assert [loop.forward_sub_batch(n) for n in (6, 8, 9)] == [3, 2, 3]
 
 
@@ -91,7 +92,7 @@ def test_a_sub_batched_rollout_is_the_whole_batch_rollout(
         f32_parts, monkeypatch, record_log_probs, passed):
     apply_fn = _scaled_apply(f32_parts[1]) if passed else None
     whole = _rollout(f32_parts, record_log_probs, apply_fn)
-    monkeypatch.setattr(loop, "FORWARD_SUB_BATCH", 2)
+    monkeypatch.setattr(a3c_model, "FORWARD_SUB_BATCH", 2)
     assert loop.forward_sub_batch(N_ENVS) == 2
     split = _rollout(f32_parts, record_log_probs, apply_fn)
     (w_carry, w_traj), (s_carry, s_traj) = whole, split
@@ -128,7 +129,7 @@ def both_updates(f32_parts):
     mesh = make_mesh(num_data=2, num_model=1, devices=jax.devices()[:2])
     whole = _one_update(f32_parts, mesh)
     with pytest.MonkeyPatch.context() as mp:
-        mp.setattr(loop, "FORWARD_SUB_BATCH", 2)
+        mp.setattr(a3c_model, "FORWARD_SUB_BATCH", 2)
         split = _one_update(f32_parts, mesh)
     return whole, split
 

@@ -1,0 +1,474 @@
+"""LFM2-8B-A1B as a token-sequence policy, at a size the CPU runs (hidden
+64, 8 experts top-2, a 2-expert share, vocabulary 256, T 16): the model
+against the benchmark's plain reference, decoding through the carry against
+the unroll, the expert layer's shares against the whole layer, the recall
+game against its reference, the fused step's gradient, the refusals.
+"""
+
+import contextlib
+import dataclasses
+import inspect
+import os
+import re
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from benchmark.reference import lfm2_moe as reference, recall as ref_recall  # noqa: E402
+from distributed_ba3c_tpu.config import BA3CConfig  # noqa: E402
+from distributed_ba3c_tpu.envs import jaxenv  # noqa: E402
+from distributed_ba3c_tpu.envs.jaxenv.recall import RecallEnv  # noqa: E402
+from distributed_ba3c_tpu.fused.loop import (  # noqa: E402
+    create_fused_state,
+    learner_chunks,
+    make_fused_step,
+    make_greedy_eval,
+)
+from distributed_ba3c_tpu.models import policy  # noqa: E402
+from distributed_ba3c_tpu.models.lfm2_moe import CUTS, LFM2MoE  # noqa: E402
+from distributed_ba3c_tpu.ops import moe  # noqa: E402
+from distributed_ba3c_tpu.ops.gradproc import make_optimizer  # noqa: E402
+from distributed_ba3c_tpu.parallel.mesh import make_mesh  # noqa: E402
+from distributed_ba3c_tpu.utils import profiling  # noqa: E402
+
+IDS, PROMPT, EPISODE = 256, 4, 16
+#: the configuration's keys at the small cut, as the reference reads them
+TINY_CONFIG = {
+    "hidden_size": 64, "intermediate_size": 96, "moe_intermediate_size": 32,
+    "num_attention_heads": 4, "num_key_value_heads": 2, "conv_L_cache": 3,
+    "norm_eps": 1e-5, "rope_theta": 1000000, "num_experts": 2,
+    "num_experts_per_tok": 2, "norm_topk_prob": True,
+    "routed_scaling_factor": 1, "vocab_size": IDS, "num_dense_layers": 2,
+    "layer_types": ["conv", "conv", "full_attention", "conv"],
+    "published": {"num_experts": 8},
+    "held": {"layers": [0, 2, 3], "expert_offset": 0},
+}
+SPEC = reference.spec_of(TINY_CONFIG)
+HYPER = {"gamma": 0.99, "entropy_beta": 0.01, "value_loss_coef": 0.5,
+         "grad_clip_norm": 0.5, "learning_rate": 1e-3, "adam_epsilon": 1e-3}
+
+
+def tiny(compute_dtype=jnp.float32, **kw) -> LFM2MoE:
+    return LFM2MoE(num_actions=IDS, max_positions=EPISODE,
+                   compute_dtype=compute_dtype, **dict(CUTS["tiny"], **kw))
+
+
+def params_of(seed):
+    return reference.init_params(jax.random.PRNGKey(seed), SPEC)
+
+
+def tokens_of(seed, batch=3, length=EPISODE):
+    return jax.random.randint(jax.random.PRNGKey(seed), (batch, length), 0, IDS)
+
+
+@pytest.fixture(params=["grouped", "every-token"])
+def moe_path(request, monkeypatch):
+    """Both forms of the expert layer (ops/moe.py): the sorted, grouped
+    products, and every held expert computing every token (few tokens)."""
+    monkeypatch.setattr(
+        moe, "DENSE_ROWS", 0 if request.param == "grouped" else 10**9)
+    return request.param
+
+
+def test_the_form_is_chosen_by_the_number_of_tokens():
+    assert moe.DENSE_ROWS == 256  # a decode step's 128 under, a chunk's 4,096 over
+
+
+def test_the_programs_parameters_are_the_references():
+    ours = jax.eval_shape(tiny().init_params, jax.random.PRNGKey(0))
+    theirs = jax.eval_shape(lambda k: reference.init_params(k, SPEC),
+                            jax.random.PRNGKey(0))
+    assert jax.tree_util.tree_map(lambda x: x.shape, ours) == \
+        jax.tree_util.tree_map(lambda x: x.shape, theirs)
+
+
+# -- (a) the unroll against the reference --------------------------------------
+@pytest.mark.parametrize("seed", [0, 1, 2])
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5), (jnp.bfloat16, 0.03)])
+def test_unroll_agrees_with_the_reference(seed, dtype, tol, moe_path):
+    params, tokens = params_of(seed), tokens_of(100 + seed)
+    out, aux = jax.jit(tiny(dtype).unroll, static_argnames="with_routes")(
+        params, tokens, with_routes=True)
+    with jax.default_matmul_precision("highest"):
+        # the reference computes with the routes the program chose, and says
+        # what it would have chosen at each of those points
+        logits, value, routes = reference.forward(
+            params, tokens, SPEC, forced_routes=aux["routes"])
+    scale = float(jnp.abs(logits).max())
+    same = np.asarray(jnp.sort(aux["routes"], -1) == jnp.sort(routes, -1)).all(-1)
+    assert same.mean() > (0.999 if dtype == jnp.float32 else 0.9)
+    gap = np.abs(np.asarray(out.logits - logits)).max()
+    assert gap < tol * scale, (gap, scale)
+    assert np.abs(np.asarray(out.value - value)).max() < 5 * tol
+    # what the counters count: the assignments that land on a held expert
+    held = np.asarray(aux["moe_tokens_per_expert"])
+    for layer in range(2):
+        want = [(np.asarray(aux["routes"][layer]) == e).sum() for e in range(2)]
+        assert held[layer].tolist() == want
+
+
+# -- (b) decoding through the carry equals the unroll ---------------------------
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5), (jnp.bfloat16, 0.03)])
+def test_decode_through_the_carry_equals_the_unroll_with_a_reset_inside(dtype, tol):
+    model, params = tiny(dtype), params_of(3)
+    first, second = tokens_of(7, length=7), tokens_of(8, length=9)
+    unroll = jax.jit(model.unroll)
+    want = jnp.concatenate(
+        [unroll(params, first)[0].logits, unroll(params, second)[0].logits], 1)
+    want_v = jnp.concatenate(
+        [unroll(params, first)[0].value, unroll(params, second)[0].value], 1)
+    step = jax.jit(model.step)
+    served = model.rollout_params(params)
+    carry, got, got_v = model.init_carry(3), [], []
+    tokens = jnp.concatenate([first, second], 1)
+    for t in range(EPISODE):
+        # a new episode opens at 0 and, inside the sequence, at 7
+        fresh = jnp.full((3,), t in (0, 7))
+        out, carry = step(served, tokens[:, t], carry, fresh)
+        got.append(out.logits)
+        got_v.append(out.value)
+    scale = float(jnp.abs(want).max())
+    gaps = np.abs(np.asarray(jnp.stack(got, 1) - want)).max(2) / scale
+    if dtype == jnp.float32:
+        assert gaps.max() < tol, gaps  # at every position of every env
+    else:
+        # under bfloat16 the two forwards round differently, and a token
+        # whose 2nd and 3rd router scores all but tie takes another expert
+        # in one of them (then it, and the next few, differ by an expert):
+        # rounding at the median token, and few tokens beyond it
+        assert np.median(gaps) < tol / 2 and (gaps > tol).mean() < 0.1, gaps
+    assert np.abs(np.asarray(jnp.stack(got_v, 1) - want_v)).max() < 5 * tol
+    assert np.asarray(carry.pos).tolist() == [9, 9, 9]
+
+
+# -- (c) the shares of one expert layer add up to the whole ---------------------
+def _layer_inputs(seed=5, n=48):
+    whole = reference.spec_of(dict(TINY_CONFIG, num_experts=8))
+    p = reference.init_params(jax.random.PRNGKey(seed), whole)["layer_2"]
+    z = jax.random.normal(jax.random.PRNGKey(seed + 1), (1, n, 64))
+    return whole, p, z
+
+
+def _share(p, z, offset, held=2, dtype=jnp.float32):
+    routing = moe.route(z[0], p["router"], p["expert_bias"], 2)
+    cut = lambda w: w[offset:offset + held].astype(dtype)  # noqa: E731
+    return moe.expert_ffn(z[0].astype(dtype), routing, cut(p["w1"]),
+                          cut(p["w3"]), cut(p["w2"]), offset, 8)
+
+
+@pytest.mark.parametrize("offset", [0, 2, 4, 6])
+def test_a_share_is_the_references_part_for_that_share(offset, moe_path):
+    whole, p, z = _layer_inputs()
+    out, counts = jax.jit(_share, static_argnums=2)(p, z, offset)
+    spec = dict(whole, experts=2, expert_offset=offset)
+    cut = {k: (v[offset:offset + 2] if k in ("w1", "w2", "w3") else v)
+           for k, v in p.items()}
+    with jax.default_matmul_precision("highest"):
+        want, chosen = reference._experts_ffn(cut, z, spec, lambda x: x)
+    np.testing.assert_allclose(out, want[0], atol=2e-5)
+    assert int(counts.sum()) == int(
+        ((chosen >= offset) & (chosen < offset + 2)).sum())
+
+
+def test_the_four_shares_add_up_to_the_uncut_layer(moe_path):
+    whole, p, z = _layer_inputs()
+    parts = [jax.jit(_share, static_argnums=2)(p, z, o) for o in (0, 2, 4, 6)]
+    with jax.default_matmul_precision("highest"):
+        want, _ = reference._experts_ffn(p, z, whole, lambda x: x)
+    np.testing.assert_allclose(sum(out for out, _ in parts), want[0], atol=5e-5)
+    # every assignment lands on exactly one share
+    assert sum(int(c.sum()) for _, c in parts) == 2 * z.shape[1]
+
+
+# -- (d) a router pushed onto one expert drops no token -------------------------
+@pytest.mark.parametrize("held", [2, 8])
+def test_a_router_pushed_onto_one_expert_drops_no_token(held, moe_path):
+    whole, p, z = _layer_inputs(n=64)
+    p = dict(p, expert_bias=p["expert_bias"].at[1].set(50.0))  # always chosen
+    out, counts = jax.jit(_share, static_argnums=(2, 3))(p, z, 0, held)
+    n = z.shape[1]
+    assert int(counts[1]) == n  # every token, no capacity
+    if held == 8:
+        assert int(counts.sum()) == 2 * n  # k x tokens, none lost
+    spec = dict(whole, experts=held)
+    cut = {k: (v[:held] if k in ("w1", "w2", "w3") else v) for k, v in p.items()}
+    with jax.default_matmul_precision("highest"):
+        want, _ = reference._experts_ffn(cut, z, spec, lambda x: x)
+    np.testing.assert_allclose(out, want[0], atol=5e-5)
+    assert np.isfinite(np.asarray(out)).all()
+
+
+def test_the_expert_layers_gradient_is_the_references(moe_path):
+    whole, p, z = _layer_inputs()
+
+    def ours(p, z):
+        return jnp.sum(_share(p, z, 2)[0] ** 2)
+
+    def theirs(p, z):
+        cut = {k: (v[2:4] if k in ("w1", "w2", "w3") else v) for k, v in p.items()}
+        spec = dict(whole, experts=2, expert_offset=2)
+        return jnp.sum(reference._experts_ffn(cut, z, spec, lambda x: x)[0] ** 2)
+
+    with jax.default_matmul_precision("highest"):
+        g_ours = jax.jit(jax.grad(ours, argnums=(0, 1)))(p, z)
+        g_theirs = jax.jit(jax.grad(theirs, argnums=(0, 1)))(p, z)
+    for leaf in ("router", "w1", "w2", "w3"):
+        # both differentiate the whole layer's tree: nothing outside [2:4]
+        a, b = g_ours[0][leaf], g_theirs[0][leaf]
+        np.testing.assert_allclose(a, b, atol=2e-4 * float(jnp.abs(b).max()),
+                                   err_msg=leaf)
+        if leaf != "router":
+            assert float(jnp.abs(a[:2]).max()) == 0.0 == float(jnp.abs(a[4:]).max())
+    np.testing.assert_allclose(
+        g_ours[1], g_theirs[1], atol=2e-4 * float(jnp.abs(g_theirs[1]).max()))
+    assert float(jnp.abs(g_ours[0]["expert_bias"]).max()) == 0.0  # a buffer
+
+
+# -- (f) the recall game -------------------------------------------------------
+@pytest.mark.parametrize("ids,prompt,episode", [(256, 4, 16), (16384, 64, 256), (7, 3, 5)])
+def test_recall_is_its_reference_bit_for_bit(ids, prompt, episode):
+    env = RecallEnv(ids, prompt, episode)
+    keys = jax.random.split(jax.random.PRNGKey(5), 6)
+    ours = jax.vmap(env.reset)(keys)
+    theirs = jax.vmap(lambda k: ref_recall.reset(k, ids, prompt))(keys)
+    step_ref = jax.jit(jax.vmap(
+        lambda s, a, k: ref_recall.step(s, a, k, ids, episode)))
+    step = jax.jit(jax.vmap(env.step))
+    for t in range(2 * episode + 3):
+        np.testing.assert_array_equal(
+            jax.vmap(env.render)(ours), jax.vmap(ref_recall.shown)(theirs))
+        actions = jax.random.randint(jax.random.PRNGKey(t), (6,), 0, ids)
+        # half the envs answer what the verifier wants
+        at = (t % episode - prompt) % prompt
+        actions = actions.at[:3].set(ours.prompt[:3, at])
+        step_keys = jax.random.split(jax.random.PRNGKey(1000 + t), 6)
+        ours, o1, r1, d1 = step(ours, actions, step_keys)
+        theirs, o2, r2, d2 = step_ref(theirs, actions, step_keys)
+        for a, b in ((o1, o2), (r1, r2), (d1, d2), (ours.prompt, theirs["prompt"]),
+                     (ours.t, theirs["t"]), (ours.last_action, theirs["last_action"])):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        if t % episode >= prompt:
+            assert np.asarray(r1[:3]).tolist() == [1.0, 1.0, 1.0]  # the verifier
+        else:
+            assert float(r1.sum()) == 0.0
+        assert bool(d1.all()) == (t % episode == episode - 1)
+
+
+def test_recall_by_name():
+    assert jaxenv.get_env("recall").num_actions == 16384
+    env = jaxenv.get_env("recall:256:4:16")
+    assert (env.num_actions, env.prompt_len, env.episode_length) == (256, 4, 16)
+    with pytest.raises(ValueError):
+        jaxenv.get_env("recall:256")
+
+
+# -- (e), (g) one fused update: its gradient is the reference's ----------------
+def _fused(n_shards, n_envs=8, dtype=jnp.float32, grad_chunk_samples=32, seed=11):
+    """The step's programs are traced at its first call: callers hold
+    ``as_on_the_chip`` round that (the rollout's few tokens the one form of
+    the expert layer, a learner chunk's many the other)."""
+    env = RecallEnv(IDS, PROMPT, EPISODE)
+    cfg = BA3CConfig(num_actions=IDS, batch_size=n_envs * EPISODE // n_shards)
+    model = tiny(dtype)
+    opt = make_optimizer(HYPER["learning_rate"], HYPER["adam_epsilon"],
+                         HYPER["grad_clip_norm"])
+    mesh = make_mesh(num_data=n_shards, num_model=1,
+                     devices=jax.devices()[:n_shards])
+    step = make_fused_step(model, opt, cfg, mesh, env, EPISODE,
+                           grad_chunk_samples=grad_chunk_samples)
+    state = create_fused_state(jax.random.PRNGKey(seed), model, cfg, opt, env,
+                               n_envs, n_shards=n_shards)
+    params = params_of(seed)
+    state = state.replace(train=state.train.replace(params=params))
+    # the step donates its state: what a test keeps, it keeps on the host
+    return env, cfg, model, step, state, jax.device_get(params)
+
+
+@contextlib.contextmanager
+def as_on_the_chip():
+    """8 envs a decode step at or under, 32 samples a chunk over."""
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(moe, "DENSE_ROWS", 8)
+        yield
+
+
+@pytest.fixture(scope="module", params=[1, 2], ids=["one-device", "two-shards"])
+def one_update(request):
+    """One fused update in float32 and what the reference makes of the same
+    start and the same actions."""
+    import optax
+
+    n_shards = request.param
+    env, cfg, model, step, state, params = _fused(n_shards)
+    per = 8 // n_shards
+    env_state0 = jax.device_get(state.env_state)
+    keys = [np.asarray(jax.random.key_data(k)) if jnp.issubdtype(
+        k.dtype, jax.dtypes.prng_key) else np.asarray(k) for k in state.key]
+    with as_on_the_chip():
+        new, metrics = step(
+            step.put(state), HYPER["entropy_beta"], HYPER["learning_rate"])
+    # the step says what it drew: [T, B_global] -> [shards, T, envs a shard]
+    actions = np.stack([np.asarray(metrics["actions"])[:, s * per:(s + 1) * per]
+                        for s in range(n_shards)])
+    mu = optax.tree_utils.tree_get(new.train.opt_state, "mu")
+    grad = jax.tree_util.tree_map(lambda m: np.asarray(m) / (1 - 0.9), mu)
+    return dict(n_shards=n_shards, params=params, actions=actions, keys=keys,
+                env_state0=env_state0, new=new, metrics=metrics, grad=grad)
+
+
+def _reference_update(u):
+    """The reference from the same env batch (handed over: this test is
+    about the gradient) and the same per-shard streams."""
+    numbers = {k: float(v) for k, v in HYPER.items()}
+    per = 8 // u["n_shards"]
+    loss, grads = 0.0, None
+    with jax.default_matmul_precision("highest"):
+        for s in range(u["n_shards"]):
+            env_state = {k: v[s * per:(s + 1) * per]
+                         for k, v in u["env_state0"]._asdict().items()}
+            l, g, *_ = reference._shard_pass(
+                u["params"], env_state, jax.vmap(ref_recall.shown)(env_state),
+                jnp.asarray(u["keys"][s]), jnp.asarray(u["actions"][s]), None,
+                numbers, reference._spec_key(SPEC), None, 4)
+            loss = loss + l
+            grads = g if grads is None else jax.tree_util.tree_map(jnp.add, grads, g)
+        n = 8.0 * EPISODE
+        clipped = reference.clip_by_global_norm(
+            jax.tree_util.tree_map(lambda g: g / n, grads), HYPER["grad_clip_norm"])
+    return float(loss) / n, clipped
+
+
+def test_the_fused_steps_gradient_is_the_references(one_update):
+    loss, want = _reference_update(one_update)
+    assert abs(float(one_update["metrics"]["loss"]) - loss) < 2e-4
+    for layer, leaves in want.items():
+        for leaf, g in leaves.items():
+            got = one_update["grad"][layer][leaf]
+            scale = max(float(jnp.abs(g).max()), 1e-4)
+            np.testing.assert_allclose(
+                got, g, atol=2e-3 * scale, err_msg=f"{layer}/{leaf}")
+
+
+def test_a_fused_update_moves_the_state_and_counts_its_tokens(one_update):
+    new, metrics, n_shards = (one_update[k] for k in ("new", "metrics", "n_shards"))
+    held = np.asarray(metrics["moe_tokens_per_expert"])
+    assert held.shape == (2, 2)
+    # about a quarter (2 of 8 held) of the 2 x tokens assignments a layer
+    assert 0.05 < held.sum() / (2 * 2 * 8 * EPISODE) < 0.6
+    assert int(metrics["episodes"]) == 8  # every env ended its episode
+    # what it generated: every env's tokens and actions, [T, B_global]; from
+    # the prompt's end on an env shows its own last action
+    tokens, actions = (np.asarray(metrics[k]) for k in ("tokens", "actions"))
+    assert tokens.shape == actions.shape == (EPISODE, 8)
+    np.testing.assert_array_equal(tokens[PROMPT + 1:], actions[PROMPT:-1])
+    assert np.asarray(new.policy_carry[1]).all()  # the next token opens one
+    assert len(new.policy_carry[0].pos.sharding.device_set) == n_shards
+    moved = jax.tree_util.tree_map(
+        lambda a, b: float(jnp.abs(a - b).max()), new.train.params,
+        one_update["params"])
+    assert moved["layer_2"]["w1"] > 0 and moved["embed"]["table"] > 0
+    assert moved["layer_2"]["expert_bias"] == 0.0  # the buffer stays
+    assert np.isfinite(float(metrics["loss"]))
+
+
+def test_learner_chunks_are_whole_envs():
+    assert learner_chunks(128, 128 * 256, 4096) == 8      # 16 envs a chunk
+    assert learner_chunks(8, 128, 32) == 4
+    assert learner_chunks(6, 6 * 16, 40) == 3             # 2.4 -> 3 divides 6
+    assert learner_chunks(81920, 81920, 4096) == 20       # the conv cells' rule
+
+
+# -- the scopes of the sequence policy are in the compiled step -----------------
+@pytest.fixture(scope="module")
+def compiled_op_names():
+    _, _, _, step, state, _ = _fused(1, dtype=jnp.bfloat16)
+    with as_on_the_chip():
+        hlo = step.audit_jit.lower(
+            step.put(state), jnp.float32(0.01), jnp.float32(1e-3)
+        ).compile().as_text()
+    return set(re.findall(r'op_name="([^"]*)"', hlo))
+
+
+@pytest.mark.parametrize("scope", profiling.SEQUENCE_SCOPES)
+def test_every_sequence_scope_is_in_the_compiled_step(compiled_op_names, scope):
+    assert scope in profiling.ALL_SCOPES and scope not in profiling.SCOPES
+    found = {profiling.scope_of(name) for name in compiled_op_names}
+    assert any(s is not None and (s == scope or s.startswith(scope + "/"))
+               for s in found), scope
+
+
+# -- (h) what is refused ---------------------------------------------------------
+def test_a_segment_that_starts_mid_episode_is_refused():
+    env = RecallEnv(IDS, PROMPT, EPISODE)
+    cfg = BA3CConfig(num_actions=IDS, batch_size=64)
+    mesh = make_mesh(num_data=1, num_model=1, devices=jax.devices()[:1])
+    with pytest.raises(ValueError, match="mid-episode"):
+        make_fused_step(tiny(), make_optimizer(1e-3), cfg, mesh, env, EPISODE // 2)
+    from distributed_ba3c_tpu.envs.jaxenv import pong
+    with pytest.raises(ValueError, match="episode length"):
+        make_fused_step(tiny(), make_optimizer(1e-3), cfg, mesh, pong, 20)
+
+
+def _builders():
+    from distributed_ba3c_tpu.fused.overlap import make_overlap_step
+    from distributed_ba3c_tpu.parallel.train_step import (
+        make_macro_train_step,
+        make_train_step,
+    )
+    from distributed_ba3c_tpu.parallel.vtrace_step import (
+        make_vtrace_macro_step,
+        make_vtrace_train_step,
+    )
+    from distributed_ba3c_tpu.pod.learner import make_pod_learner_step
+    from distributed_ba3c_tpu.predict.server import make_fwd_sample
+
+    return [make_overlap_step, make_train_step, make_macro_train_step,
+            make_vtrace_train_step, make_vtrace_macro_step,
+            make_pod_learner_step, make_fwd_sample, make_greedy_eval]
+
+
+@pytest.mark.parametrize("builder", _builders(), ids=lambda f: f.__name__)
+def test_every_other_trainer_refuses_a_policy_that_carries_state(builder):
+    needed = {
+        name: None for name, p in inspect.signature(builder).parameters.items()
+        if p.default is p.empty and name != "model"}
+    with pytest.raises(ValueError, match="carries state"):
+        builder(model=tiny(), **needed)
+    # and the stateless policy is not refused by the same check
+    policy.refuse_carry(policy.build_model("ba3cnet", BA3CConfig()), "anything")
+
+
+@pytest.mark.parametrize("argv", [
+    ["--trainer", "tpu_vtrace_ba3c"], ["--trainer", "tpu_sync_ba3c"],
+    ["--trainer", "tpu_fused_ba3c", "--task", "eval", "--load", "x"],
+])
+def test_the_cli_refuses_a_carry_policy_off_the_fused_trainer(argv, capsys):
+    from distributed_ba3c_tpu import cli
+
+    with pytest.raises(SystemExit) as e:
+        cli.main(["--model", "lfm2-moe", "--model_cut", "tiny", "--env", "fake",
+                  "--tpu_lock", "off"] + argv)
+    assert e.value.code == 2
+    assert "carries state" in capsys.readouterr().err
+
+
+def test_the_registry_builds_by_name():
+    cfg = BA3CConfig(num_actions=6)
+    assert type(policy.build_model("ba3cnet", cfg)).__name__ == "BA3CNet"
+    model = policy.build_model("lfm2-moe", cfg.replace(num_actions=16384))
+    assert policy.carries_state(model) and model.hidden_size == 2048
+    assert model.experts_held == 8 and model.num_experts == 32
+    assert dataclasses.replace(model, remat=False).remat is False
+    with pytest.raises(ValueError):
+        policy.build_model("no-such-model", cfg)
+    with pytest.raises(ValueError):
+        policy.build_model("lfm2-moe", cfg, "no-such-cut")
+    with pytest.raises(ValueError):
+        policy.build_model("ba3cnet", cfg, "tiny")
