@@ -1149,6 +1149,10 @@ def _fused_epoch_body(
             held = metrics["moe_tokens_per_expert"]
             holder.add_stat("moe_load_max_over_mean", float(np.max(
                 held.max(axis=-1) / np.maximum(held.mean(axis=-1), 1e-9))))
+            # blocks of sorted rows the expert layers ran beyond their first
+            # (ops/moe.py): 0 while the rows routed here fit the bound
+            holder.add_stat("moe_overflow_blocks", float(np.sum(
+                metrics["moe_overflow_blocks"])))
         for k in ("mean_rho", "value_lag_mae"):
             # overlap-mode series (fused/overlap.py): how hard V-trace is
             # clipping and how far the value fn moved across the lag

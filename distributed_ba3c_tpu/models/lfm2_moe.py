@@ -217,7 +217,8 @@ class LFM2MoE:
 
     def _ffn(self, p, ffn: str, h):
         """h [N, d] float32 -> (h + FFN(RMSNorm(h)), None or (tokens routed
-        to each held expert, the chosen expert ids [N, k]))."""
+        to each held expert, the chosen expert ids [N, k], the blocks of
+        sorted rows the layer ran beyond its first))."""
         if ffn == DENSE:
             with device_scope(profiling.FFN_DENSE):
                 z = rms_norm(h, p["ffn_norm"], self.norm_eps)
@@ -232,11 +233,11 @@ class LFM2MoE:
                 self.norm_topk_prob, self.routed_scaling_factor,
             )
             cd = self.compute_dtype
-            out, counts = moe.expert_ffn(
+            out, counts, overflow = moe.expert_ffn(
                 z.astype(cd), routing, p["w1"].astype(cd), p["w3"].astype(cd),
                 p["w2"].astype(cd), self.expert_offset, self.num_experts,
             )
-            return h + out, (counts, routing.experts)
+            return h + out, (counts, routing.experts, overflow)
 
     def _qkv(self, p, z, positions):
         """z [..., d] -> q [..., H, D], k, v [..., KV, D] in the compute
@@ -376,11 +377,14 @@ class LFM2MoE:
         """Whole episodes from a reset: ``tokens`` [B, T] int32 ->
         (PolicyValue with logits [B, T, A] and value [B, T], aux). ``aux``
         counts the tokens routed to each held expert of each expert layer
-        (``moe_tokens_per_expert``) and, asked, names every token's chosen
-        experts (``routes`` [expert layers, B, T, k])."""
+        (``moe_tokens_per_expert``) and the blocks of sorted rows each ran
+        beyond its first (``moe_overflow_blocks`` [expert layers]: 0 unless
+        more rows were routed here than ``ops/moe.py``'s bound) and, asked,
+        names every token's chosen experts (``routes`` [expert layers, B,
+        T, k])."""
         B, T = tokens.shape
         x = self._embed(params, tokens)
-        counts, routes = [], []
+        counts, routes, overflow = [], [], []
         for i in range(len(self.layer_kinds)):
             layer = lambda p, x, i=i: self._layer_unroll(i, p, x)  # noqa: E731
             if self.remat:
@@ -389,8 +393,10 @@ class LFM2MoE:
             if routed is not None:
                 counts.append(routed[0])
                 routes.append(routed[1].reshape(B, T, -1))
+                overflow.append(routed[2])
         out = self._head(params, x.reshape(B * T, -1))
-        aux = {"moe_tokens_per_expert": jnp.stack(counts)} if counts else {}
+        aux = {"moe_tokens_per_expert": jnp.stack(counts),
+               "moe_overflow_blocks": jnp.stack(overflow)} if counts else {}
         if with_routes:
             aux["routes"] = jnp.stack(routes)
         return PolicyValue(

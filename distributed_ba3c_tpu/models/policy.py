@@ -20,8 +20,8 @@ A policy is what the trainers call to turn observations into
       model.rollout_params(params) -> params     what ``step`` is served
           from all through one rollout (a bfloat16 snapshot of the matrices)
 
-  ``aux`` is a dict of counters (``moe_tokens_per_expert``); ``step`` and
-  ``unroll`` agree position by position (tests/test_lfm2_moe.py).
+  ``aux`` is a dict of counters (``moe_tokens_per_expert``, ``moe_overflow_blocks``);
+  ``step`` and ``unroll`` agree position by position (tests/test_lfm2_moe.py).
 
 Only the fused trainer drives a policy that carries state; every other
 trainer refuses one through :func:`refuse_carry`. docs/policy_protocol.md.

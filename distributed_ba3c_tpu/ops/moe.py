@@ -18,8 +18,33 @@ sorted by expert, the held ones first, and the grouped products
 kernel that visits only the tiles the groups cover) run over exactly the
 rows routed here, however uneven.
 
-Backward: ``ragged_dot`` has its own transpose; the two permutations are
-gathers both ways (``_permute``), so no scatter-add is traced.
+**Blocks.** The kernel skips the rows of absent experts, but everything
+round it (the gather in, ``silu * up``, the products' outputs, the masks,
+the gather back) would still touch all ``N * k`` sorted rows where a chip
+that holds 8 of 32 experts is routed a quarter of them. So the sorted rows
+are worked on ``R`` at a time, :func:`block_rows` from the shapes alone: the
+rows an even router sends here and a margin, in whole tiles. Block ``b`` is
+sorted rows ``[b R, (b + 1) R)``: its token rows gathered from ``z`` by
+``order[b R : (b + 1) R] // k`` (no ``N * k`` copy of ``z`` exists), the
+experts' groups clipped to that window, and each token adds the rows of its
+that lie in the block, weighted. Block 0 always runs, block ``b > 0`` while
+``b R`` is under the rows held here: a router that sends more than ``R``
+rows costs another block and nothing else, and the layer reports how many
+it ran beyond the first (``moe_overflow_blocks``; 0 whenever the bound
+held). **The overflow is a loop and not a branch**: one ``while`` whose body
+is traced once, forward and backward. A bounded copy beside a whole-batch
+copy under ``lax.cond`` computes the same and was refused for its set-up
+(PERF.md, PR 27 and PR 28: each copy of the layer is traced, lowered, and
+read back from the compile cache as part of the step, 8 layer-places a
+step). Where the chip holds every expert ``R`` is ``N * k``: one block, the
+layer as it was before there were blocks.
+
+Backward: the blocks are one ``custom_vjp``. Its forward keeps nothing of a
+block; its backward is the same loop, each needed block run again and pulled
+back (``ragged_dot`` has its own transposes), its ``[R, f]`` intermediates
+alive only while it runs: what a rematerialised layer would recompute
+anyway, without the residuals. Rows go in and out by gathers both ways
+(``_take_rows``, ``_combine``), so no scatter-add is traced.
 
 A decode step has few tokens (128 an update's rollout step): there every
 product is bound by reading the experts' matrices, whichever rows it
@@ -32,6 +57,8 @@ chosen, pick the result: the same sum, no sort and no gather.
 
 from __future__ import annotations
 
+import functools
+import math
 from typing import NamedTuple
 
 import jax
@@ -45,6 +72,16 @@ NORM_EPS = 1e-6  # the ``+ 1e-6`` of norm_topk_prob
 #: v5e's 240 FLOP a byte a bfloat16 product of so few rows is bound by its
 #: matrix's bytes, so the rows nobody routed here cost no time
 DENSE_ROWS = 256
+#: rows of the grouped kernel's tile: a block is whole tiles
+ROW_TILE = 512
+#: room in a block over the rows an even router sends to the experts held
+#: here. The share of a chunk's assignments that lands on 8 of 32 experts
+#: read 0.243-0.251 across seeds (PERF.md, PR 26) where even is 0.250, so
+#: 4,096 expected rows, exactly 8 tiles, were 8 or 9 by the seed; a quarter
+#: more (10 tiles: 5,120 of the chunk's 16,384 rows) holds every one of those
+#: and a router that drifts a fifth off even while it trains. Past it the
+#: layer is still exact: it runs another block.
+HELD_ROWS_MARGIN = 0.25
 
 
 class Routing(NamedTuple):
@@ -70,26 +107,6 @@ def route(z, router_w, expert_bias, top_k: int, norm_topk_prob: bool = True,
         return Routing(experts.astype(jnp.int32), weights * scale)
 
 
-@jax.custom_vjp
-def _permute(x, perm, inverse):
-    """``x[perm]`` for a permutation of the rows; the cotangent goes back by
-    the inverse permutation, a gather too."""
-    del inverse
-    return x[perm]
-
-
-def _permute_fwd(x, perm, inverse):
-    return x[perm], (perm, inverse)
-
-
-def _permute_bwd(res, g):
-    _, inverse = res
-    return g[inverse], None, None
-
-
-_permute.defvjp(_permute_fwd, _permute_bwd)
-
-
 def held_counts(experts, expert_offset: int, held: int, num_experts: int):
     """(local ids [N*k] with the held experts at 0..held-1 and the absent
     ones above, tokens routed to each held expert [held] int32)."""
@@ -101,44 +118,210 @@ def held_counts(experts, expert_offset: int, held: int, num_experts: int):
     return local, counts
 
 
+def block_rows(n: int, k: int, held: int, num_experts: int) -> int:
+    """``R``: the sorted rows one block of the grouped form works on, from
+    the shapes alone: the ``n * k * held / num_experts`` rows an even router
+    sends here, plus the margin, in whole tiles of the grouped kernel, and
+    never more than all ``n * k`` (a chip that holds every expert: one
+    block of everything, the layer as it was before there were blocks)."""
+    expected = n * k * held / num_experts
+    tiles = math.ceil(expected * (1 + HELD_ROWS_MARGIN) / ROW_TILE)
+    return min(n * k, ROW_TILE * tiles)
+
+
+class _Sorted(NamedTuple):
+    """A chunk's ``N * k`` assignments sorted by local expert id, the held
+    experts' rows first (a stable sort: a token's order within an expert)."""
+
+    order: jax.Array    # [blocks * R] sorted row -> assignment n * k + j
+    inverse: jax.Array  # [k, N] assignment (n, j) -> sorted row, at [j, n]
+    counts: jax.Array   # [held] rows of each held expert
+
+
+@jax.custom_vjp
+def _take_rows(z, tokens, where):
+    """``z[tokens]``: a block's R rows from the tokens' ``[N, d]``. ``where``
+    [k, N] says at which row of the block each assignment of a token lies
+    (R: at none), so the cotangent goes back as a gather too: no scatter-add
+    is traced."""
+    del where
+    return z[tokens]
+
+
+def _take_rows_fwd(z, tokens, where):
+    return z[tokens], where
+
+
+def _take_rows_bwd(where, g):
+    return _gather_sum(g, where, None).astype(g.dtype), None, None
+
+
+_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+
+def _gather_sum(rows, where, weights):
+    """out[n] = sum_j weights[j, n] * rows[where[j, n]] in float32, a row of
+    zeros standing at ``where == len(rows)``. ``j`` leads: the k picked
+    ``[N, d]`` lie one behind the other and add up where they lie (as ``[N,
+    k, d]`` all of it was relaid out before the sum: a ``reshape`` of 0.24
+    ms a layer, my chip run, PR 28)."""
+    padded = jnp.concatenate([rows, jnp.zeros_like(rows[:1])])
+    picked = padded[where].astype(jnp.float32)
+    if weights is not None:
+        picked = picked * weights[:, :, None]
+    return jnp.sum(picked, axis=0)
+
+
+@jax.custom_vjp
+def _combine(y, weights, assignments, tokens, where):
+    """The weighted sum over each token's rows in a block ``y`` [R, d]:
+    ``weights`` [k, N] -> [N, d] float32. Backward: the block's rows of the
+    cotangent, gathered by token (``assignments``, ``tokens`` [R]: which
+    (n, j) a row is)."""
+    del assignments, tokens
+    return _gather_sum(y, where, weights)
+
+
+def _combine_fwd(y, weights, assignments, tokens, where):
+    return _gather_sum(y, where, weights), (
+        y, weights, assignments, tokens, where)
+
+
+def _combine_bwd(res, g):
+    y, weights, assignments, tokens, where = res
+    g_rows = g[tokens]  # [R, d] float32
+    of_row = weights[assignments % weights.shape[0], tokens]
+    d_y = (g_rows * of_row[:, None]).astype(y.dtype)
+    d_w = jnp.sum(g_rows * y.astype(jnp.float32), axis=-1)  # by row, [R]
+    d_w = jnp.concatenate([d_w, jnp.zeros_like(d_w[:1])])[where]
+    return d_y, d_w, None, None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def _block(b, z, weights, w1, w3, w2, s: _Sorted, block: int):
+    """Sorted rows ``[b * block, (b + 1) * block)`` through the experts and
+    back to their tokens (``weights`` [k, N]): this block's part of the
+    layer, [N, d] float32."""
+    k = weights.shape[0]
+    lo = b * block
+    with device_scope(profiling.MOE_DISPATCH):
+        assignments = jax.lax.dynamic_slice(s.order, (lo,), (block,))
+        tokens = assignments // k
+        ends = jnp.cumsum(s.counts)
+        # the held experts' groups, clipped to this block's window of rows
+        sizes = (jnp.clip(ends, lo, lo + block)
+                 - jnp.clip(ends - s.counts, lo, lo + block))
+        here = lo + jnp.arange(block) < ends[-1]  # rows of a held expert
+        at = s.inverse - lo
+        where = jnp.where((at >= 0) & (at < block), at, block)
+        rows = _take_rows(z, tokens, where)
+        # the grouped products say nothing about rows outside every group:
+        # hold them at zero on the way in (so nothing comes back through
+        # them) and on the way out
+        rows = jnp.where(here[:, None], rows, 0)
+    with device_scope(profiling.MOE_EXPERTS):
+        gate = jax.lax.ragged_dot(rows, w1, sizes)
+        up = jax.lax.ragged_dot(rows, w3, sizes)
+        act = (jax.nn.silu(gate.astype(jnp.float32))
+               * up.astype(jnp.float32)).astype(z.dtype)
+        y = jax.lax.ragged_dot(act, w2, sizes)
+    with device_scope(profiling.MOE_COMBINE):
+        # the rows go back in the compute type (half the bytes of the
+        # gather); the weighted sum over a token's k experts is float32
+        y = jnp.where(here[:, None], y, 0)
+        return _combine(y, weights, assignments, tokens, where)
+
+
+def _zeros(like, dtype=None):
+    """Zeros of ``like``'s shape that vary over the mesh axes ``like`` varies
+    over: under ``shard_map`` a loop's carry keeps one type."""
+    out = jnp.zeros(like.shape, dtype or like.dtype)
+    varying = tuple(jax.typeof(like).vma)
+    return jax.lax.pcast(out, varying, to="varying") if varying else out
+
+
+def _blocks_needed(s: _Sorted, block: int):
+    """Block 0 always, block ``b > 0`` while ``b * block`` is under the rows
+    held here."""
+    return jnp.maximum(1, -(-jnp.sum(s.counts) // block))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _blocks(z, weights, w1, w3, w2, s: _Sorted, block: int):
+    """The sum of the needed blocks: one loop, its body traced once."""
+    def body(b, out):
+        return out + _block(b, z, weights, w1, w3, w2, s, block)
+
+    return jax.lax.fori_loop(
+        0, _blocks_needed(s, block), body, _zeros(z, jnp.float32))
+
+
+def _blocks_fwd(z, weights, w1, w3, w2, s, block):
+    # nothing of a block is kept: the backward runs the blocks again (as a
+    # rematerialised layer would have), so a block's [R, f] intermediates
+    # live only while it runs
+    return _blocks(z, weights, w1, w3, w2, s, block), (
+        z, weights, w1, w3, w2, s)
+
+
+def _blocks_bwd(block, res, g):
+    z, weights, w1, w3, w2, s = res
+    operands = (z, weights, w1, w3, w2)
+
+    def body(b, acc):
+        _, pull = jax.vjp(
+            lambda *ops: _block(b, *ops, s, block), *operands)
+        return jax.tree_util.tree_map(
+            lambda a, d: a + d.astype(a.dtype), acc, pull(g))
+
+    # a token's k rows may lie in different blocks: its cotangent adds up in
+    # float32; the experts' matrices add up as their products leave them
+    acc = (_zeros(z, jnp.float32),) + tuple(_zeros(x) for x in operands[1:])
+    d_z, *rest = jax.lax.fori_loop(0, _blocks_needed(s, block), body, acc)
+    return (d_z.astype(z.dtype), *rest, None)
+
+
+_blocks.defvjp(_blocks_fwd, _blocks_bwd)
+
+
 def expert_ffn(z, routing: Routing, w1, w3, w2, expert_offset: int,
                num_experts: int):
     """This chip's part of the routed feed-forward.
 
     ``z`` [N, d] in the compute type; ``w1``/``w3`` [held, d, f] and ``w2``
     [held, f, d] in the compute type. -> (out [N, d] float32, tokens routed
-    to each held expert [held] int32)."""
-    n, d = z.shape
-    k = routing.experts.shape[1]
-    held = w1.shape[0]
+    to each held expert [held] int32, blocks run beyond the first int32)."""
+    n, k = routing.experts.shape
     if n <= DENSE_ROWS:
         return _every_token(z, routing, w1, w3, w2, expert_offset, num_experts)
+    return _sorted_rows(z, routing, w1, w3, w2, expert_offset, num_experts,
+                        block_rows(n, k, w1.shape[0], num_experts))
+
+
+# a jit of its own, so that the places a step holds this layer (4 expert
+# layers x the 2 places the fused step differentiates its learner, each
+# traced forward, rematerialised and backward) share one trace and one
+# lowering by their shapes: the step's first call is set-up (PERF.md, PR 28)
+@functools.partial(
+    jax.jit, static_argnames=("expert_offset", "num_experts", "block"))
+def _sorted_rows(z, routing: Routing, w1, w3, w2, expert_offset: int,
+                 num_experts: int, block: int):
+    """``expert_ffn`` for many tokens: the assignments sorted by expert and
+    worked on ``block`` sorted rows at a time."""
+    n, k = routing.experts.shape
     with device_scope(profiling.MOE_DISPATCH):
         local, counts = held_counts(
-            routing.experts, expert_offset, held, num_experts
+            routing.experts, expert_offset, w1.shape[0], num_experts
         )
         order = jnp.argsort(local, stable=True)  # held experts' rows first
-        inverse = jnp.argsort(order)
-        here = jnp.arange(n * k) < jnp.sum(counts)  # rows of a held expert
-        rows = _permute(jnp.repeat(z, k, axis=0), order, inverse)
-        # the grouped products say nothing about rows outside every group:
-        # hold them at zero on the way in (so nothing comes back through
-        # them) and on the way out
-        rows = jnp.where(here[:, None], rows, 0)
-    with device_scope(profiling.MOE_EXPERTS):
-        gate = jax.lax.ragged_dot(rows, w1, counts)
-        up = jax.lax.ragged_dot(rows, w3, counts)
-        act = (jax.nn.silu(gate.astype(jnp.float32))
-               * up.astype(jnp.float32)).astype(z.dtype)
-        y = jax.lax.ragged_dot(act, w2, counts)
-    with device_scope(profiling.MOE_COMBINE):
-        # the rows go back in the compute type (half the bytes of the
-        # gather); the weighted sum over a token's k experts is float32
-        y = jnp.where(here[:, None], y, 0)
-        y = _permute(y, inverse, order).reshape(n, k, d)
-        out = jnp.sum(
-            y.astype(jnp.float32) * routing.weights[:, :, None], axis=1)
-    return out, counts
+        inverse = jnp.argsort(order).reshape(n, k).T
+        # whole blocks: the rows past n * k belong to no expert
+        order = jnp.pad(order, (0, -(n * k) % block))
+        s = _Sorted(order, inverse, counts)
+    out = _blocks(z, routing.weights.T, w1, w3, w2, s, block)
+    return out, counts, _blocks_needed(s, block) - 1
 
 
 def _every_token(z, routing: Routing, w1, w3, w2, expert_offset: int,
@@ -161,4 +344,4 @@ def _every_token(z, routing: Routing, w1, w3, w2, expert_offset: int,
         y = jnp.einsum("enf,efd->end", act, w2)
     with device_scope(profiling.MOE_COMBINE):
         out = jnp.einsum("end,ne->nd", y.astype(jnp.float32), share)
-    return out, counts
+    return out, counts, jnp.zeros((), jnp.int32)

@@ -289,17 +289,25 @@ def scope_of(op_name: str) -> Optional[str]:
 
 #: op_names the TPU compiler gives the kernels it makes of ``ragged_dot``
 #: (``ragged-dot-none``, ``ragged-dot-metadata``): it drops the scopes the
-#: instruction was traced under (by hand on a capture, PR 26)
+#: instruction was traced under (by hand on a capture, PR 26). Inside a
+#: ``jax.jit`` within the step (``ops/moe.py:_sorted_rows``) the name the
+#: compiler gave comes last, after the scopes of the call (PR 28)
 RENAMED_KERNEL = "ragged-dot"
+
+
+def is_renamed_kernel(op_name: str) -> bool:
+    return op_name.rsplit("/", 1)[-1].startswith(RENAMED_KERNEL)
 
 
 def kernel_scope(op_name: str, neighbour_op_name: str) -> Optional[str]:
     """The scope of a grouped-product kernel the compiler renamed: the
-    ``moe/experts`` of whichever ``moe`` scope the scoped op that ran just
-    before it lies in (its dispatch, or the products' own elementwise work)."""
-    if not op_name.startswith(RENAMED_KERNEL):
+    ``moe/experts`` of the ``moe`` scope its call lies in where the name
+    still says so, else of whichever ``moe`` scope the scoped op that ran
+    just before it lies in (its dispatch, or the products' own elementwise
+    work)."""
+    if not is_renamed_kernel(op_name):
         return None
-    scope = scope_of(neighbour_op_name) or ""
+    scope = scope_of(op_name) or scope_of(neighbour_op_name) or ""
     parts = scope.split("/")
     if MOE not in parts:
         return None
@@ -356,7 +364,7 @@ def op_time_by_scope(xplane_path: str) -> Optional[dict]:
                     first = e.start_ns
                 by_event[e.name] = by_event.get(e.name, 0.0) + e.duration_ns / 1e9
                 op_name = names.get(e.name, "")
-                if op_name.startswith(RENAMED_KERNEL):
+                if is_renamed_kernel(op_name):
                     before.setdefault(e.name, last_scoped)
                 elif not _is_container(e.name) and scope_of(op_name):
                     last_scoped = op_name
@@ -367,11 +375,13 @@ def op_time_by_scope(xplane_path: str) -> Optional[dict]:
                 total += s
                 op_name = names.get(text, "")
                 scope = scope_of(op_name)
-                if scope is None and text in before:
+                if text in before:
                     # an instruction sits at one place of the program: the
                     # neighbour of its first execution is its neighbour
+                    bare = scope is None  # no scopes of a call in front
                     scope = kernel_scope(op_name, before[text])
-                    op_name = before[text]
+                    if bare:  # forward or backward: the neighbour's mark
+                        op_name = before[text]
                 if scope is None:
                     seconds[UNSCOPED] += s
                     short = text.split(" = ", 1)[0]

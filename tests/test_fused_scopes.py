@@ -154,6 +154,29 @@ def test_scope_of_an_op_name(op_name, scope, backward):
     assert profiling.is_backward(op_name) is backward
 
 
+@pytest.mark.parametrize("op_name,neighbour,scope,backward", [
+    # the compiler's bare name: the scoped op that ran before it says where
+    ("ragged-dot-none",
+     "jit(multi_step)/learner/jvp(moe)/dispatch/jit(_where)/select_n",
+     "learner/moe/experts", False),
+    ("ragged-dot-metadata",
+     "jit(multi_step)/learner/transpose(jvp(learner))/jvp()/checkpoint/moe/experts/mul",
+     "learner/moe/experts", True),
+    # inside a jit within the step the call's scopes stand in front of it
+    ("jit(multi_step)/while/body/closed_call/learner/jvp(moe)/jit(_sorted_rows)/ragged-dot-none",
+     "jit(multi_step)/optimizer/sqrt", "learner/moe/experts", False),
+    ("jit(multi_step)/learner/transpose(jvp(learner))/jvp()/checkpoint/moe/jit(_sorted_rows)/ragged-dot-none",
+     "", "learner/moe/experts", True),
+    ("ragged-dot-none", "jit(multi_step)/optimizer/sqrt", None, False),
+    ("jit(multi_step)/learner/jvp(moe)/experts/mul", "", None, False),  # no kernel
+])
+def test_a_renamed_kernels_scope(op_name, neighbour, scope, backward):
+    assert profiling.kernel_scope(op_name, neighbour) == scope
+    if scope is not None:
+        marked = op_name if profiling.scope_of(op_name) else neighbour
+        assert profiling.is_backward(marked) is backward
+
+
 def test_scope_names_nest_as_their_paths_say():
     assert len(set(profiling.SCOPES)) == len(profiling.SCOPES)
     for scope in profiling.SCOPES:

@@ -8,6 +8,7 @@ game against its reference, the fused step's gradient, the refusals.
 import contextlib
 import dataclasses
 import inspect
+import math
 import os
 import re
 import sys
@@ -68,13 +69,25 @@ def tokens_of(seed, batch=3, length=EPISODE):
     return jax.random.randint(jax.random.PRNGKey(seed), (batch, length), 0, IDS)
 
 
-@pytest.fixture(params=["grouped", "every-token"])
+@pytest.fixture(params=["grouped", "blocked", "every-token"])
 def moe_path(request, monkeypatch):
-    """Both forms of the expert layer (ops/moe.py): the sorted, grouped
-    products, and every held expert computing every token (few tokens)."""
+    """The forms of the expert layer (ops/moe.py): the sorted, grouped
+    products in one block (at these sizes the bound is all the rows), the
+    same with a bound so small that the rows held here need two blocks or
+    more, and every held expert computing every token (few tokens)."""
     monkeypatch.setattr(
-        moe, "DENSE_ROWS", 0 if request.param == "grouped" else 10**9)
+        moe, "DENSE_ROWS", 10**9 if request.param == "every-token" else 0)
+    if request.param == "blocked":
+        # half the rows an even router sends here, in tiles of 8
+        monkeypatch.setattr(moe, "ROW_TILE", 8)
+        monkeypatch.setattr(moe, "HELD_ROWS_MARGIN", -0.5)
     return request.param
+
+
+def _overflow(held_rows, n, k=2, held=2, num_experts=8):
+    """Blocks beyond the first that ``held_rows`` sorted rows need."""
+    block = moe.block_rows(n, k, held, num_experts)
+    return max(0, math.ceil(held_rows / block) - 1)
 
 
 def test_the_form_is_chosen_by_the_number_of_tokens():
@@ -112,6 +125,11 @@ def test_unroll_agrees_with_the_reference(seed, dtype, tol, moe_path):
     for layer in range(2):
         want = [(np.asarray(aux["routes"][layer]) == e).sum() for e in range(2)]
         assert held[layer].tolist() == want
+    # and the blocks each layer ran beyond its first
+    ran = np.asarray(aux["moe_overflow_blocks"]).tolist()
+    assert ran == [0 if moe_path == "every-token" else _overflow(
+        rows, tokens.size) for rows in held.sum(-1)]
+    assert (min(ran) >= 1) == (moe_path == "blocked"), ran
 
 
 # -- (b) decoding through the carry equals the unroll ---------------------------
@@ -157,10 +175,11 @@ def _layer_inputs(seed=5, n=48):
 
 
 def _share(p, z, offset, held=2, dtype=jnp.float32):
+    """-> (this share's output, tokens routed to each of its experts)."""
     routing = moe.route(z[0], p["router"], p["expert_bias"], 2)
     cut = lambda w: w[offset:offset + held].astype(dtype)  # noqa: E731
     return moe.expert_ffn(z[0].astype(dtype), routing, cut(p["w1"]),
-                          cut(p["w3"]), cut(p["w2"]), offset, 8)
+                          cut(p["w3"]), cut(p["w2"]), offset, 8)[:2]
 
 
 @pytest.mark.parametrize("offset", [0, 2, 4, 6])
@@ -229,6 +248,120 @@ def test_the_expert_layers_gradient_is_the_references(moe_path):
     np.testing.assert_allclose(
         g_ours[1], g_theirs[1], atol=2e-4 * float(jnp.abs(g_theirs[1]).max()))
     assert float(jnp.abs(g_ours[0]["expert_bias"]).max()) == 0.0  # a buffer
+
+
+# -- (d') the bound of a block, and the rows that land on it exactly ------------
+@pytest.mark.parametrize("n,k,held,num_experts,rows", [
+    (4096, 4, 8, 32, 5120),    # the cell's chunk: 4,096 expected + a quarter
+    (4096, 4, 32, 32, 16384),  # a chip that holds every expert: all N * k
+    (8192, 4, 8, 32, 10240),
+    (300, 4, 8, 32, 512),      # 375 rows in whole tiles
+    (300, 4, 1, 32, 512),      # never under a tile
+    (64, 2, 2, 8, 128),        # this file's layers: a tile is over N * k
+    (64, 2, 8, 8, 128),
+])
+def test_a_blocks_rows_are_a_function_of_the_shapes(n, k, held, num_experts, rows):
+    assert (moe.ROW_TILE, moe.HELD_ROWS_MARGIN) == (512, 0.25)
+    assert moe.block_rows(n, k, held, num_experts) == rows
+    assert rows == min(n * k, 512 * math.ceil(
+        n * k * held / num_experts * 1.25 / 512))
+
+
+def _plain_share(z, experts, weights, w1, w3, w2):
+    """Experts 0..held-1 of the layer, every token through every one of
+    them, written out: out[n] = sum_j weights[n, j] FFN_experts[n, j](z[n])."""
+    out = jnp.zeros_like(z)
+    for e in range(w1.shape[0]):
+        share = jnp.sum(jnp.where(experts == e, weights, 0.0), axis=1)
+        y = (jax.nn.silu(z @ w1[e]) * (z @ w3[e])) @ w2[e]
+        out = out + share[:, None] * y
+    return out
+
+
+@pytest.mark.parametrize("over", [-1, 0, 1, 41], ids=lambda o: f"R{o:+d}")
+def test_rows_that_fill_a_block_exactly_and_one_more(over, monkeypatch):
+    """64 tokens, 2 of 8 experts held, tiles of 8: a block is 40 rows. The
+    first 20 tokens take both held experts (40 rows), ``over`` more or fewer
+    assignments land here: value, every gradient and the count of blocks."""
+    monkeypatch.setattr(moe, "DENSE_ROWS", 0)
+    monkeypatch.setattr(moe, "ROW_TILE", 8)
+    n, block = 64, 40
+    assert moe.block_rows(n, 2, 2, 8) == block
+    experts = np.stack([2 + np.arange(n) % 3, 5 + np.arange(n) % 3], 1)
+    experts[:20] = (0, 1)
+    if over < 0:
+        experts[19] = (0, 6)
+    for i in range(max(over, 0)):
+        experts[20 + i] = (i % 2, 7)
+    assert (experts < 2).sum() == block + over
+    assert (experts[:, 0] != experts[:, 1]).all()  # as a top-k's are
+    keys = jax.random.split(jax.random.PRNGKey(9), 6)
+    z = jax.random.normal(keys[0], (n, 64))
+    weights = jax.nn.softmax(jax.random.normal(keys[1], (n, 2)))
+    w1, w3 = (jax.random.normal(k, (2, 64, 32)) / 8 for k in keys[2:4])
+    w2 = jax.random.normal(keys[4], (2, 32, 64)) / 6
+    pull = jax.random.normal(keys[5], (n, 64))
+    experts = jnp.asarray(experts, jnp.int32)
+
+    def ours(z, weights, w1, w3, w2):
+        out, counts, ran = moe.expert_ffn(
+            z, moe.Routing(experts, weights), w1, w3, w2, 0, 8)
+        return jnp.sum(out * pull), (out, counts, ran)
+
+    def theirs(z, weights, w1, w3, w2):
+        out = _plain_share(z, experts, weights, w1, w3, w2)
+        return jnp.sum(out * pull), out
+
+    args = (z, weights, w1, w3, w2)
+    with jax.default_matmul_precision("highest"):
+        (_, (out, counts, ran)), got = jax.jit(jax.value_and_grad(
+            ours, argnums=range(5), has_aux=True))(*args)
+        (_, want_out), want = jax.jit(jax.value_and_grad(
+            theirs, argnums=range(5), has_aux=True))(*args)
+    assert int(counts.sum()) == block + over
+    assert int(ran) == {-1: 0, 0: 0, 1: 1, 41: 2}[over]
+    np.testing.assert_allclose(out, want_out, atol=2e-5)
+    for name, a, b in zip(("z", "weights", "w1", "w3", "w2"), got, want,
+                          strict=True):
+        np.testing.assert_allclose(
+            a, b, atol=1e-4 * float(jnp.abs(b).max()), err_msg=name)
+
+
+def _count(jaxpr, primitive):
+    return sum((e.primitive.name == primitive) + sum(
+        _count(sub, primitive) for sub in jax.core.jaxprs_in_params(e.params))
+        for e in jaxpr.eqns)
+
+
+def test_the_learners_program_holds_one_copy_of_the_block(monkeypatch):
+    """What PR 27 was refused for (a second, whole-batch copy of the expert
+    layer beside the bounded one grew the compiled step by a third, and its
+    set-up with it), caught before the chip: the gradient of a loss over
+    ``model.unroll`` holds the grouped product as often with a block smaller
+    than the rows (overflow possible) as with one block of all of them, the
+    blocks are a loop, and no branch holds a grouped product."""
+    monkeypatch.setattr(moe, "DENSE_ROWS", 0)
+    model, tokens = tiny(), tokens_of(1)
+    params = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
+
+    def loss(p):
+        out, _ = model.unroll(p, tokens)
+        return jnp.sum(out.logits) + jnp.sum(out.value)
+
+    def census():
+        jaxpr = jax.make_jaxpr(jax.grad(loss))(params).jaxpr
+        return {name: _count(jaxpr, name)
+                for name in ("ragged_dot_general", "while", "cond")}
+
+    assert moe.block_rows(tokens.size, 2, 2, 8) == 2 * tokens.size
+    whole = census()
+    monkeypatch.setattr(moe, "ROW_TILE", 8)
+    assert moe.block_rows(tokens.size, 2, 2, 8) < 2 * tokens.size
+    blocked = census()
+    # an expert layer: 3 products forward, and in the backward's loop the 3
+    # again (nothing of a block is kept) with the 2 transposes of each
+    assert whole == blocked == {
+        "ragged_dot_general": 2 * 12, "while": 2 * 2, "cond": 0}
 
 
 # -- (f) the recall game -------------------------------------------------------
@@ -362,6 +495,10 @@ def test_a_fused_update_moves_the_state_and_counts_its_tokens(one_update):
     assert held.shape == (2, 2)
     # about a quarter (2 of 8 held) of the 2 x tokens assignments a layer
     assert 0.05 < held.sum() / (2 * 2 * 8 * EPISODE) < 0.6
+    # the blocks run beyond the first, summed over chunks and shards: a
+    # chunk's 64 rows are under a tile, so one block holds them all
+    ran = np.asarray(metrics["moe_overflow_blocks"])
+    assert ran.dtype == np.int32 and ran.tolist() == [0, 0]
     assert int(metrics["episodes"]) == 8  # every env ended its episode
     # what it generated: every env's tokens and actions, [T, B_global]; from
     # the prompt's end on an env shows its own last action
