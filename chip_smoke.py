@@ -24,8 +24,7 @@ Phases (each fails the run; nothing is caught):
 ``forwards``  the rollout forward at all three dtypes (``predict.server``,
               ``_bf16``, ``_int8``) answering batches of 256 inside the
               repo's parity bands of the f32 forward; the int8 arm ``auto``
-              resolved to; the Pallas conv blocks, Mosaic-compiled, against
-              the XLA block.
+              resolved to.
 ``mesh``      only with more than one device: env state and batch sharded
               over every device, and after K updates every param leaf's
               replicas bit-identical — the on-chip form of audit rule T3.
@@ -93,7 +92,6 @@ class Shape:
     staging_blocks: int
     # rollout forwards
     serve_batch: int
-    check_pallas: bool        # Mosaic-compiled on a chip; interpreted, slow, on CPU
 
 
 FULL = Shape(
@@ -102,7 +100,7 @@ FULL = Shape(
     nr_eval=8, eval_max_steps=3000,
     plane_env="cpp:pong", plane_image_size=None, plane_envs=64,
     plane_batch=128, plane_steps_per_epoch=20, staging_blocks=12,
-    serve_batch=256, check_pallas=True,
+    serve_batch=256,
 )
 
 SMALL = Shape(
@@ -111,7 +109,7 @@ SMALL = Shape(
     nr_eval=1, eval_max_steps=8,
     plane_env="fake", plane_image_size=16, plane_envs=4,
     plane_batch=32, plane_steps_per_epoch=20, staging_blocks=5,
-    serve_batch=8, check_pallas=False,
+    serve_batch=8,
 )
 
 
@@ -487,7 +485,7 @@ def phase_plane(shape: Shape, workdir: str, platform: str = "tpu") -> dict:
 
 
 # --------------------------------------------------------------------------
-# phase: the rollout forward at three dtypes (+ the Pallas blocks)
+# phase: the rollout forward at three dtypes
 # --------------------------------------------------------------------------
 
 
@@ -517,47 +515,6 @@ def _pong_frames(model, cfg, params, n_frames: int):
     body = make_rollout_body(model, cfg, pong, params)
     _, traj = jax.jit(lambda c: jax.lax.scan(body, c, None, length=T))(carry)
     return np.asarray(traj[0]).reshape(-1, *cfg.state_shape)
-
-
-def _pallas_blocks(interpret: bool) -> dict:
-    """The repo's one Pallas kernel at every geometry it supports, against
-    the XLA block (same op order, so they agree to a bf16 rounding)."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from distributed_ba3c_tpu.ops import pallas_conv as pc
-
-    out = {}
-    for i, s in enumerate(pc.ba3c_specs()):
-        if not pc.supported(s):
-            continue  # conv0: Ci=4 cannot fill a 128-lane row
-        rng = np.random.default_rng(i)
-        x = jnp.asarray(rng.normal(size=(64, s.H, s.W * s.Ci)), jnp.bfloat16)
-        w = jnp.asarray(
-            rng.normal(size=(s.kh, s.kw, s.Ci, s.Co)) * 0.05, jnp.float32
-        )
-        b = jnp.asarray(rng.normal(size=(s.Co,)) * 0.1, jnp.float32)
-        # one program per static geometry, run once: nothing to hoist
-        got = jax.jit(  # ba3clint: disable=J2
-            lambda x, w, b, s=s: pc.conv_block(x, w, b, s, interpret)
-        )(x, w, b)
-        ref = jax.jit(  # ba3clint: disable=J2
-            lambda x, w, b, s=s: pc.reference_block(x, w, b, s)
-        )(x, w, b)
-        err = float(jnp.max(jnp.abs(
-            got.astype(jnp.float32) - ref.astype(jnp.float32)
-        )))
-        absmax = float(jnp.max(jnp.abs(ref.astype(jnp.float32))))
-        # bf16 keeps 8 significant bits: one ulp at absmax is absmax / 128
-        _check(
-            err <= absmax / 64,
-            f"pallas block {i} ({s.Ci}->{s.Co}): max abs err {err} "
-            f"against absmax {absmax}",
-        )
-        out[f"pallas_conv{i}_max_abs_err"] = err
-    _check(len(out) == 3, f"expected 3 supported Pallas blocks, ran {len(out)}")
-    return out
 
 
 def phase_forwards(shape: Shape, workdir: str, platform: str = "tpu") -> dict:
@@ -654,8 +611,6 @@ def phase_forwards(shape: Shape, workdir: str, platform: str = "tpu") -> dict:
         info[f"{dtype}_warmup_s"] = round(warmup_s, 2)
         info[f"{dtype}_batch_s"] = round(min(batch_s), 5)
         info[f"{dtype}_dvalue"] = round(d_v, 5)
-    if shape.check_pallas:
-        info.update(_pallas_blocks(interpret=platform != "tpu"))
     return info
 
 

@@ -213,7 +213,7 @@ def build_fleet_planes(
 
     This function is the sanctioned multi-fleet spawn point: ba3clint A8
     flags direct calls outside ``orchestrate/`` the same way it flags
-    direct env-server construction — cli.py and bench.py carry the
+    direct env-server construction — cli.py and scripts/plane_bench.py carry the
     sanctioned suppressions (factories handed to supervisors, and the raw
     measurand plane).
     """

@@ -636,8 +636,10 @@ def make_overlap_step(
         This is the ONE sanctioned host-sync site between the two
         dispatches: it exists to measure the very serialization J6
         forbids, runs a handful of iterations OUTSIDE the training hot
-        loop (bench warmup / scripts/profile_split.py --overlap), and
-        advances the state it was given so no experience is replayed.
+        loop, and advances the state it was given so no experience is
+        replayed. (A capture of a live run, read with
+        ``utils/profiling.op_time_by_scope`` / ``host_spans``, shows the
+        same split without a sync.)
         ``overlap_efficiency`` is the learner-hidden fraction of the actor
         program: (t_actor + t_learner - t_pair) / t_actor.
         """
@@ -688,7 +690,7 @@ def make_overlap_step(
         hidden = (a_ms + l_ms - p_ms) / a_ms if a_ms > 0 else 0.0
         # the device-free proxy gate quantity (ISSUE 8): how much of the
         # actor's wall time the learner window is LONG enough to hide —
-        # computed HERE so bench.py and profile_split report one number
+        # computed HERE so every reader of the probe reports one number
         coverage = round(min(1.0, l_ms / a_ms), 4) if a_ms > 0 else None
         from distributed_ba3c_tpu import telemetry
 

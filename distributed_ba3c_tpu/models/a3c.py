@@ -76,49 +76,6 @@ def conv_layout(model: "BA3CNet") -> Tuple[Tuple[int, int, bool], ...]:
     )
 
 
-def _conv_spec(x: jax.Array, features: int, k: int, pooled: bool):
-    """The ONE ConvSpec construction shared by the gate and the executed
-    block, so they can never diverge (ops/pallas_conv.py)."""
-    from distributed_ba3c_tpu.ops.pallas_conv import ConvSpec
-
-    return ConvSpec(
-        H=x.shape[1], W=x.shape[2], Ci=x.shape[3], Co=features,
-        kh=k, kw=k, pool=pooled, scale_uint8=False,
-    )
-
-
-class _PallasConvBlock(nn.Module):
-    """conv+bias+relu(+2x2 maxpool) as one fused Pallas kernel.
-
-    Param names/shapes match ``nn.Conv`` ('kernel' [k,k,ci,co], 'bias'
-    [co]). ``interpret`` runs the kernel in the Pallas interpreter — the
-    CPU tests ask for it by name (``conv_backend="pallas-interpret"``); it
-    is never guessed from the back-end, so on a chip "pallas" always means
-    the Mosaic-compiled kernel.
-    """
-
-    spec: object  # ConvSpec (static)
-    interpret: bool = False
-
-    @nn.compact
-    def __call__(self, x: jax.Array) -> jax.Array:
-        from distributed_ba3c_tpu.ops.pallas_conv import conv_block
-
-        s = self.spec
-        B = x.shape[0]
-        kernel = self.param(
-            "kernel", nn.initializers.lecun_normal(),
-            (s.kh, s.kw, s.Ci, s.Co), jnp.float32,
-        )
-        bias = self.param("bias", nn.initializers.zeros, (s.Co,), jnp.float32)
-        y = conv_block(
-            x.astype(jnp.bfloat16).reshape(B, s.H, s.W * s.Ci),
-            kernel, bias, s,
-            self.interpret,
-        )
-        return y.reshape(B, s.Ho, s.Wo, s.Co)
-
-
 class BA3CNet(nn.Module):
     """Policy/value network with the reference's conv stack."""
 
@@ -129,22 +86,6 @@ class BA3CNet(nn.Module):
     # maxpool after first 3 conv layers, as in the reference stack
     pooled_layers: Tuple[bool, ...] = (True, True, True, False)
     compute_dtype: jnp.dtype = jnp.bfloat16
-    # lane-packing factor per conv layer (models/packed_conv.py). MEASURED
-    # NEUTRAL on v5e (PERF.md: the net is HBM-roofline-bound, and XLA's conv
-    # emitter already packs output lanes) — kept as tested infrastructure
-    # for backends where the GEMM shape does bind. 0/1 = plain nn.Conv.
-    # Numerically EXACT either way (value- and gradient-tested).
-    conv_pack: Tuple[int, ...] = (0, 0, 0, 0)
-    # "xla" (default), "pallas" or "pallas-interpret": fused Pallas
-    # conv+relu+pool blocks where the geometry allows (ops/pallas_conv.py —
-    # blocks whose P*Ci is a 128-multiple, i.e. the 32/64-channel layers;
-    # conv0's Ci=4 cannot). The kernels compile under the installed Mosaic
-    # and match the XLA block on the v5e (chip_smoke.py checks both on every
-    # run); measured SLOWER than XLA in an earlier round on other code
-    # (patch-assembly relayout outweighs the 4x MXU lane-occupancy win), so
-    # the default stays XLA and ROADMAP D1 queues the removal. Checkpoints
-    # are interchangeable (same param names/shapes).
-    conv_backend: str = "xla"
 
     @nn.compact
     def __call__(self, state: jax.Array) -> PolicyValue:
@@ -154,51 +95,15 @@ class BA3CNet(nn.Module):
         else:
             x = state.astype(self.compute_dtype)
 
-        for i, ((feats, k, pooled), pack) in enumerate(
-            zip(conv_layout(self), self.conv_pack, strict=True)
-        ):
-            # explicit name "Conv_i" for ALL branches: PackedConv and
-            # _PallasConvBlock own nn.Conv-shaped params, so checkpoints
-            # stay interchangeable between configurations
-            if self.conv_backend in ("pallas", "pallas-interpret"):
-                from distributed_ba3c_tpu.ops.pallas_conv import supported
-
-                if self.compute_dtype != jnp.bfloat16:
-                    # the Pallas block is bf16-only: running XLA convs
-                    # under the kernel's name would hide which one ran
-                    raise ValueError(
-                        f"conv_backend={self.conv_backend!r} computes in "
-                        f"bfloat16, not {self.compute_dtype}"
-                    )
-
-                spec = _conv_spec(x, feats, k, pooled)
-                if supported(spec):
-                    x = _PallasConvBlock(
-                        spec=spec,
-                        interpret=self.conv_backend == "pallas-interpret",
-                        name=f"Conv_{i}",
-                    )(x)
-                    continue  # relu+pool fused inside the block
-            if pack and pack > 1:
-                from distributed_ba3c_tpu.models.packed_conv import PackedConv
-
-                x = PackedConv(
-                    features=feats,
-                    kernel_size=k,
-                    pack=pack,
-                    dtype=self.compute_dtype,
-                    param_dtype=jnp.float32,
-                    name=f"Conv_{i}",
-                )(x)
-            else:
-                x = nn.Conv(
-                    features=feats,
-                    kernel_size=(k, k),
-                    padding="SAME",
-                    dtype=self.compute_dtype,
-                    param_dtype=jnp.float32,
-                    name=f"Conv_{i}",
-                )(x)
+        for i, (feats, k, pooled) in enumerate(conv_layout(self)):
+            x = nn.Conv(
+                features=feats,
+                kernel_size=(k, k),
+                padding="SAME",
+                dtype=self.compute_dtype,
+                param_dtype=jnp.float32,
+                name=f"Conv_{i}",
+            )(x)
             x = nn.relu(x)
             if pooled:
                 x = nn.max_pool(x, window_shape=(2, 2), strides=(2, 2))

@@ -46,6 +46,7 @@ from distributed_ba3c_tpu.netchaos.schedule import (
     Partition,
 )
 from distributed_ba3c_tpu.pod.wire import pod_role
+from distributed_ba3c_tpu.telemetry.attribution import stall_attribution
 from distributed_ba3c_tpu.utils.serialize import set_wire_crc
 
 #: the pod's three DCN-shaped links, as wrap_pod names them
@@ -207,15 +208,9 @@ class PodNetRig:
                 # relative to this rebase, never to the jax-import warmup
                 self.nc.rebase_clock()
                 return
-        try:
-            from bench import stall_attribution
-
-            why = stall_attribution()
-        except ImportError:
-            why = "(bench.py not importable for attribution)"
         raise RuntimeError(
             f"pod produced no warmup blocks from {self.shape.hosts} "
-            f"host(s) through netchaos — {why}"
+            f"host(s) through netchaos — {stall_attribution()}"
         )
 
     def drain(self, seconds: float) -> Tuple[float, int]:

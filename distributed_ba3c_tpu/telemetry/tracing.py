@@ -99,7 +99,7 @@ def sample_n() -> int:
 def set_sampling(n: int) -> None:
     """Arm (or disarm, n=0) sampling process-wide. Child processes
     inherit the ``BA3C_TRACE`` env var instead — set both when spawning
-    (the cli.py / bench.py idiom for BA3C_TELEMETRY)."""
+    (the cli.py / scripts/plane_bench.py idiom for BA3C_TELEMETRY)."""
     global _sample_n
     _sample_n = max(0, int(n))
 

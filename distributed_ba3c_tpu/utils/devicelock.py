@@ -43,7 +43,7 @@ LOCK_PATH_ENV = "BA3C_TPU_LOCK"
 DEFAULT_LOCK_PATH = "/tmp/ba3c_tpu.lock"
 MODES = ("wait", "fail", "off")
 
-# diagnostics go to STDERR: bench.py and the eval scripts print exactly one
+# diagnostics go to STDERR: the bench and eval scripts print exactly one
 # JSON line on stdout for machine consumption — a "[tpu-lock] waiting" line
 # there would corrupt the contract. sys.stderr is resolved at CALL time —
 # a functools.partial bound the import-time stream and silently wrote to a

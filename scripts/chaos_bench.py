@@ -57,7 +57,7 @@ _ORCH_KINDS = (
 
 
 def _drain_warmup(master, n: int, first_timeout: float = 300.0) -> None:
-    from bench import stall_attribution
+    from distributed_ba3c_tpu.telemetry.attribution import stall_attribution
 
     try:
         master.queue.get(timeout=first_timeout)
@@ -106,12 +106,12 @@ class _Plane:
         import jax
         import numpy as np
 
-        from bench import make_null_predictor
         from distributed_ba3c_tpu.actors.master import BA3CSimulatorMaster
         from distributed_ba3c_tpu.config import BA3CConfig
         from distributed_ba3c_tpu.envs import native
         from distributed_ba3c_tpu.models.policy import DEFAULT_MODEL, build_model
         from distributed_ba3c_tpu.orchestrate import FleetSpec, FleetSupervisor
+        from distributed_ba3c_tpu.predict.null import make_null_predictor
 
         n_actions = native.CppBatchedEnv(game, 1).num_actions
         cfg = BA3CConfig(

@@ -30,7 +30,7 @@ set -euo pipefail
 cd "$(dirname "$0")/.."
 
 echo "== ba3clint =="
-python -m tools.ba3clint distributed_ba3c_tpu tools scripts train.py bench.py chip_smoke.py
+python -m tools.ba3clint distributed_ba3c_tpu tools scripts train.py chip_smoke.py
 
 echo "== ba3cflow =="
 python -m tools.ba3cflow
@@ -39,12 +39,12 @@ echo "== ba3cwire =="
 python -m tools.ba3cwire
 
 echo "== suppression hygiene =="
-python -m tools.ba3clint --check-suppressions distributed_ba3c_tpu tools scripts train.py bench.py chip_smoke.py
+python -m tools.ba3clint --check-suppressions distributed_ba3c_tpu tools scripts train.py chip_smoke.py
 python -m tools.ba3cflow --check-suppressions
 python -m tools.ba3cwire --check-suppressions
 
 echo "== compileall =="
-python -m compileall -q distributed_ba3c_tpu tools scripts tests train.py bench.py chip_smoke.py
+python -m compileall -q distributed_ba3c_tpu tools scripts tests train.py chip_smoke.py
 
 if [[ "${BA3C_CHECK_NO_AUDIT:-0}" != 1 ]]; then
   echo "== ba3caudit =="

@@ -82,7 +82,7 @@ def run_trace_capture(
     from distributed_ba3c_tpu.parallel.train_step import create_train_state
     from distributed_ba3c_tpu.parallel.vtrace_step import make_vtrace_train_step
 
-    from bench import make_null_predictor
+    from distributed_ba3c_tpu.predict.null import make_null_predictor
     from distributed_ba3c_tpu.utils.devicelock import stderr_print
 
     telemetry.reset_all()
@@ -261,7 +261,7 @@ def run_ingest_phase(
     from distributed_ba3c_tpu.parallel.train_step import create_train_state
     from distributed_ba3c_tpu.parallel.vtrace_step import make_vtrace_train_step
 
-    from bench import make_null_predictor
+    from distributed_ba3c_tpu.predict.null import make_null_predictor
     from distributed_ba3c_tpu.utils.devicelock import stderr_print
 
     n_actions = native.CppBatchedEnv(game, 1).num_actions
@@ -450,6 +450,239 @@ def run_ingest_phase(
     return row, failures
 
 
+def bench_zmq_plane(
+    game: str = "pong", n_envs: int = 256, seconds: float = 20.0,
+    null_device: bool = False, wire: str = "per-env",
+    envs_per_proc: int = 32, warmup_datapoints: int = 512,
+    windows: int = 1, telemetry_on: bool = True, fleets: int = 1,
+    trace_sample: int = 0,
+) -> dict:
+    """Actor-plane throughput (BASELINE configs #1/#2): C++ batched env
+    servers -> ZMQ -> master -> batched TPU predictor, counting n-step
+    datapoints entering the train queue.
+
+    ``null_device=True`` (every mode but ``--device``) swaps the device
+    forward for host-side random actions while keeping EVERY other stage —
+    C++ envs, serialization, ZMQ transport, master routing,
+    batching/coalesce, n-step assembly. That measures the plane's own ceiling with no device
+    in the loop: the number that separates "the plane is slow" from "the
+    device round trip is slow".
+
+    ``wire`` selects the env-server protocol: ``per-env`` (the reference's
+    B-messages-per-step shape, the historical 2,128/s ceiling) or ``block``
+    (one zero-copy multipart message per server per step,
+    docs/actor_plane.md).
+
+    ``fleets`` > 1 stands up K INDEPENDENT planes at the SAME per-fleet
+    shape — per-fleet pipes/masters/predictors/telemetry roles, fleet-
+    tagged idents (actors/fleet.py addressing) — and counts the AGGREGATE
+    datapoint rate across their train queues: the device-free proof of the
+    multi-fleet macro-batching scaling claim (``plane_bench --fleets``;
+    ``n_envs``/``envs_per_proc`` stay per-fleet quantities)."""
+    import queue
+    import tempfile
+    import time
+
+    import jax
+    import numpy as np
+
+    from distributed_ba3c_tpu import telemetry
+    from distributed_ba3c_tpu.actors.fleet import fleet_pipes
+    from distributed_ba3c_tpu.actors.master import BA3CSimulatorMaster
+    from distributed_ba3c_tpu.config import BA3CConfig
+    from distributed_ba3c_tpu.envs import native
+    from distributed_ba3c_tpu.models.policy import DEFAULT_MODEL, build_model
+    from distributed_ba3c_tpu.predict.null import make_null_predictor
+    from distributed_ba3c_tpu.predict.server import BatchedPredictor
+    from distributed_ba3c_tpu.telemetry.attribution import (
+        master_progress,
+        stall_attribution,
+        tele_snapshot,
+    )
+
+    # per-run telemetry accounting: fresh registries, and the A/B switch
+    # for the overhead gate (--telemetry both). Children inherit the env
+    # var through spawn.
+    telemetry.reset_all()
+    telemetry.set_enabled(telemetry_on)
+    os.environ["BA3C_TELEMETRY"] = "1" if telemetry_on else "0"
+    # the trace plane's A/B lever rides the same pattern (plane_bench
+    # --trace both): sampling armed here for the master/predictor side,
+    # via the env var for the spawned env servers
+    trace_n = trace_sample if telemetry_on else 0
+    telemetry.tracing.set_sampling(trace_n)
+    os.environ["BA3C_TRACE"] = str(trace_n)
+
+    n_actions = native.CppBatchedEnv(game, 1).num_actions
+    cfg = BA3CConfig(num_actions=n_actions, predict_batch_size=256)
+    model = build_model(DEFAULT_MODEL, cfg)
+    params = model.init(
+        jax.random.PRNGKey(0), np.zeros((1, *cfg.state_shape), np.uint8)
+    )["params"]
+    # Coalescing exists to multiply TINY per-env tasks per device call; a
+    # block already IS a full batch, so block wires serve greedily (waiting
+    # would only add latency to the lockstep round trip).
+    coalesce_ms = 5.0 if wire == "per-env" else 0.0
+    predict_bs = max(cfg.predict_batch_size, envs_per_proc)
+    tmp = tempfile.mkdtemp(prefix="ba3c-bench-")
+    base_c2s, base_s2c = f"ipc://{tmp}/c2s", f"ipc://{tmp}/s2c"
+    per = envs_per_proc
+    predictors, masters, procs = [], [], []
+    for k in range(max(1, fleets)):
+        tag = k if fleets > 1 else None
+        c2s, s2c = fleet_pipes(base_c2s, base_s2c, k)
+        if null_device:
+            predictor = make_null_predictor(
+                model, params, n_actions,
+                batch_size=predict_bs, num_threads=2,
+                coalesce_ms=coalesce_ms,
+                tele_role=telemetry.fleet_role("predictor", tag),
+            )
+        else:
+            predictor = BatchedPredictor(  # ba3clint: disable=A14 — the RAW single plane is the measurand here (the routed plane has its own instrument, serving_bench --replicas)
+                model, params, batch_size=predict_bs, num_threads=2,
+                coalesce_ms=coalesce_ms,
+                tele_role=telemetry.fleet_role("predictor", tag),
+            )
+            predictor.warmup(cfg.state_shape)
+        master = BA3CSimulatorMaster(
+            c2s, s2c, predictor,
+            gamma=cfg.gamma, local_time_max=cfg.local_time_max,
+            score_queue=queue.Queue(maxsize=100_000),
+            tele_role=telemetry.fleet_role("master", tag),
+        )
+        predictors.append(predictor)
+        masters.append(master)
+        procs += [
+            # the RAW unsupervised plane is the measurand here (no respawn
+            # machinery in the loop); the supervised path has its own
+            # instrument, scripts/chaos_bench.py
+            native.CppEnvServerProcess(  # ba3clint: disable=A8
+                i, c2s, s2c, game=game, n_envs=min(per, n_envs - i * per),
+                wire=wire,
+                ident_prefix=(
+                    f"f{k}-cppsim-{i}" if fleets > 1 else None
+                ),
+            )
+            for i in range((n_envs + per - 1) // per)
+        ]
+    for predictor in predictors:
+        predictor.start()
+    for master in masters:
+        master.start()
+    for p in procs:
+        p.start()
+    try:
+        # warmup until the pipeline flows, then count datapoints over
+        # best-of-N windows (the sandbox scheduler intermittently starves
+        # a window — a slow window is scheduler noise, not plane rate).
+        # First-datapoint
+        # timeout is generous: spawning the server fleet re-imports
+        # numpy/zmq per process and takes minutes under load
+        # (tests/test_native_env.py saw the same)
+        try:
+            # EVERY fleet must produce before the clock starts (an
+            # aggregate-only warmup would let a dead fleet hide behind a
+            # healthy one and publish a fake per-fleet scaling number)
+            for master in masters:
+                master.queue.get(timeout=300)
+            for _ in range(warmup_datapoints - len(masters)):
+                masters[_ % len(masters)].queue.get(timeout=60)
+        except queue.Empty:
+            # a bare Empty says "timeout"; the counters say WHICH stage
+            # never moved (fleet spawn, predictor serve, flush) — the
+            # difference between a mystery and a diagnosis when a fleet
+            # shape fails to come up (docs/observability.md)
+            raise RuntimeError(
+                f"plane produced no warmup data — {stall_attribution()}"
+            ) from None
+        window_rates = []
+        qs = [m.queue for m in masters]
+        for _ in range(max(1, windows)):
+            t0 = time.perf_counter()
+            deadline = t0 + seconds
+            n = 0
+            empty_since = None
+            # drain in BURSTS (get_nowait + short sleeps) rather than
+            # blocking get() per item: a consumer parked in the queue's
+            # condition variable makes every producer put() pay a futex
+            # wake — tens of us of syscall on sandboxed kernels, which at
+            # 40k datapoints/s would dominate the measurement. A real
+            # learner feed drains in batch-sized gulps for the same reason.
+            while True:
+                now = time.perf_counter()
+                if now >= deadline:
+                    break
+                drained = 0
+                for q in qs:
+                    # round-robin burst drain across fleets, same fairness
+                    # shape as the FleetMergeFeed collator
+                    try:
+                        while True:
+                            q.get_nowait()
+                            drained += 1
+                    except queue.Empty:
+                        pass
+                if drained:
+                    n += drained
+                    empty_since = None
+                else:
+                    if empty_since is None:
+                        empty_since = now
+                        stall_mark = master_progress()[1]
+                    elif now - empty_since > min(5.0, seconds / 2):
+                        # the quiet threshold only OPENS the investigation
+                        # (it must be reachable inside one window, else the
+                        # deadline expires first and a wedged wire silently
+                        # publishes a near-zero rate); the VERDICT comes
+                        # from the real counters — a master that provably
+                        # emitted DATAPOINTS during the quiet spell is
+                        # draining elsewhere, not stalled. Datapoints ONLY:
+                        # wire messages still ticking while the flush path
+                        # is dead is the "flush path stalled" wedge itself
+                        # and must keep counting toward the raise
+                        if master_progress()[1] != stall_mark:
+                            empty_since = None
+                            continue
+                        raise RuntimeError(
+                            "plane stalled: "
+                            f"{min(5.0, seconds / 2):.1f}s without data "
+                            f"post-warmup — {stall_attribution()}"
+                        )
+                    time.sleep(0.002)
+            window_rates.append(n / (time.perf_counter() - t0))
+    finally:
+        for p in procs:
+            p.terminate()
+        for master in masters:
+            master.close()
+        for predictor in predictors:
+            predictor.stop()
+        for predictor in predictors:
+            predictor.join(timeout=5)
+        for p in procs:
+            p.join(timeout=5)
+    rate = max(window_rates)
+    kind = "nodevice" if null_device else "tpu"
+    return {
+        "telemetry_enabled": telemetry_on,
+        "telemetry": tele_snapshot(),
+        # the null-predictor ceiling must be UNMISTAKABLE from a real plane
+        # measurement: distinct metric name + an explicit predictor field
+        "metric": f"zmq_plane_{kind}_{game}_env_steps_per_sec_per_host",
+        "value": round(rate, 1),
+        "unit": "env-steps/sec/host",
+        "predictor": "null-host-random" if null_device else "batched-tpu",
+        "wire": wire,
+        "fleets": max(1, fleets),
+        # per-fleet shape (the unit the --fleets scaling gate compares at)
+        "n_envs": n_envs,
+        "envs_per_proc": per,
+        "seconds": seconds,
+        "window_rates": [round(r, 1) for r in window_rates],
+    }
+
+
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--game", default="pong")
@@ -583,8 +816,6 @@ def main() -> int:
         )
 
     from distributed_ba3c_tpu.utils.devicelock import stderr_print
-
-    from bench import bench_zmq_plane
 
     runs = {}
     overhead = {}

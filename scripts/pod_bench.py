@@ -66,13 +66,13 @@ def _cfg(args):
 
 def _phase_aggregate(args, n_hosts: int) -> dict:
     """One pod at ``n_hosts`` actor hosts; env-steps/s at the ingest."""
-    from bench import stall_attribution
     from distributed_ba3c_tpu import telemetry
     from distributed_ba3c_tpu.orchestrate.pod import (
         PodLearnerPlane,
         PodSupervisor,
         host_argv,
     )
+    from distributed_ba3c_tpu.telemetry.attribution import stall_attribution
 
     telemetry.reset_all()
     c2s, s2c = _free_port_base()

@@ -119,7 +119,6 @@ def _drain(master, n: int, first_timeout: float = 240.0) -> int:
 def _phase_fleet(args, rng: random.Random) -> dict:
     """SIGKILL one supervised fake-env simulator slot; the reconciler's
     FleetResource must respawn it and the plane must stream again."""
-    from bench import make_null_predictor
     from distributed_ba3c_tpu import telemetry
     from distributed_ba3c_tpu.actors.master import BA3CSimulatorMaster
     from distributed_ba3c_tpu.actors.simulator import SimulatorProcess
@@ -129,6 +128,7 @@ def _phase_fleet(args, rng: random.Random) -> dict:
         FleetResource,
         Reconciler,
     )
+    from distributed_ba3c_tpu.predict.null import make_null_predictor
 
     t0 = time.monotonic()
     model = SimpleNamespace(num_actions=4, apply=None)
@@ -425,13 +425,13 @@ def _phase_serving(args, rng: random.Random) -> dict:
     corpse and heal the set back to target with a fresh incarnation."""
     import numpy as np
 
-    from bench import make_null_predictor
     from distributed_ba3c_tpu import telemetry
     from distributed_ba3c_tpu.orchestrate.reconcile import (
         Reconciler,
         ServingResource,
     )
     from distributed_ba3c_tpu.orchestrate.serving import ReplicaSet
+    from distributed_ba3c_tpu.predict.null import make_null_predictor
     from distributed_ba3c_tpu.predict.router import ServingRouter, replica_role
 
     t0 = time.monotonic()

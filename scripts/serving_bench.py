@@ -82,9 +82,9 @@ def _percentiles_ms(lats):
 
 def _make_replica(opts, tele_role: str):
     """One null-device replica (a complete BatchedPredictor serving plane
-    with simulated service time — bench.make_null_predictor) under its
+    with simulated service time — predict.null.make_null_predictor) under its
     own telemetry role, started."""
-    from bench import make_null_predictor
+    from distributed_ba3c_tpu.predict.null import make_null_predictor
 
     # a stub model is enough: the null predictor never traces the forward,
     # and the scheduler only reads num_actions for the fallback contract

@@ -176,3 +176,35 @@ def test_scanned_dispatch_matches_sequential_steps(fused_setup):
     assert not np.array_equal(p0, p1), "scanned dispatch did not update params"
     for k, v in m_live.items():
         assert np.isfinite(float(v)), k
+
+
+@pytest.mark.parametrize(
+    "steps_per_epoch, k", [(4, 3), (10, 4), (4, 8)],
+    ids=["4_by_3", "10_by_4", "k_over_epoch"],
+)
+def test_run_fused_training_rejects_k_not_dividing_epoch(
+    tmp_path, monkeypatch, steps_per_epoch, k
+):
+    """--steps_per_dispatch K must divide --steps_per_epoch: the driver
+    refuses before it builds (or compiles) a step. cli.main catches the same
+    rule first through TopologySpec; this is the loop's own check, which a
+    caller of run_fused_training meets."""
+    from distributed_ba3c_tpu import cli
+    from distributed_ba3c_tpu.fused import loop
+
+    def no_step(*a, **kw):
+        raise AssertionError("a step was built before K was checked")
+
+    monkeypatch.setattr(loop, "make_fused_step", no_step)
+    args = cli.make_parser().parse_args([
+        "--trainer", "tpu_fused_ba3c", "--env", "jax:pong",
+        "--batch_size", "8", "--rollout_len", "2", "--fc_units", "16",
+        "--steps_per_epoch", str(steps_per_epoch),
+        "--steps_per_dispatch", str(k),
+        "--logdir", str(tmp_path), "--tpu_lock", "off",
+    ])
+    cfg = cli.build_config(args)
+    model = BA3CNet(num_actions=cfg.num_actions, fc_units=cfg.fc_units)
+    opt = make_optimizer(cfg.learning_rate, cfg.adam_epsilon, cfg.grad_clip_norm)
+    with pytest.raises(SystemExit, match="must divide --steps_per_epoch"):
+        loop.run_fused_training(args, cfg, model, opt)

@@ -450,13 +450,13 @@ def test_export_scalars_carries_pod_host_roles():
     assert out["tele/pod.host1/env_steps_total"] == 7.0
 
 
-def test_bench_role_scalars_sums_pod_hosts():
-    from bench import _role_scalars
+def test_role_scalars_sums_pod_hosts():
+    from distributed_ba3c_tpu.telemetry.attribution import role_scalars
 
     telemetry.reset_all()
     telemetry.registry(pod_role(0)).counter("env_steps_total").inc(3)
     telemetry.registry(pod_role(1)).counter("env_steps_total").inc(4)
-    assert _role_scalars("pod")["env_steps_total"] == 7.0
+    assert role_scalars("pod")["env_steps_total"] == 7.0
 
 
 # ---------------------------------------------------------------------------

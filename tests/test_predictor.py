@@ -87,3 +87,62 @@ def test_update_params_changes_output():
     pred.update_params(new_params)
     _, values_after, _ = pred.predict_batch(states)
     assert not np.allclose(values_before, values_after)
+
+
+# -- the null-device fake (predict/null.py) -----------------------------------
+
+
+def _null(service_s=0.0, **kw):
+    from types import SimpleNamespace
+
+    from distributed_ba3c_tpu.predict.null import make_null_predictor
+
+    # a stub model is enough: the null device never traces the forward
+    model = SimpleNamespace(num_actions=4, apply=None)
+    return make_null_predictor(
+        model, {}, 4, service_s=service_s, coalesce_ms=0.0, **kw
+    )
+
+
+def test_null_predictor_rows_in_actions_out():
+    """Sync and queued paths both answer one in-range action per row."""
+    pred = _null(batch_size=8)
+    actions, values, greedy = pred.predict_batch(np.zeros((5, 16, 16, 4), np.uint8))
+    assert actions.shape == values.shape == greedy.shape == (5,)
+    assert ((actions >= 0) & (actions < 4)).all()
+    pred.start()
+    try:
+        got, done = [], threading.Event()
+
+        def cb(action, value, logp):
+            got.append(int(action))
+            if len(got) == 20:
+                done.set()
+
+        for _ in range(20):
+            pred.put_task(np.zeros((16, 16, 4), np.uint8), cb)
+        assert done.wait(20.0), f"only {len(got)}/20 callbacks fired"
+        assert all(0 <= a < 4 for a in got)
+    finally:
+        pred.stop()
+        pred.join(timeout=5)
+
+
+def test_null_predictor_honours_service_time():
+    """``service_s`` is paid once a device call, at fetch."""
+    import time
+
+    pred = _null(service_s=0.05, batch_size=8)
+    t0 = time.monotonic()
+    pred.predict_batch(np.zeros((8, 16, 16, 4), np.uint8))
+    assert time.monotonic() - t0 >= 0.05
+
+
+def test_null_predictor_stops():
+    pred = _null(batch_size=8)
+    pred.start()
+    pred.stop()
+    pred.join(timeout=5)
+    assert not any(t.is_alive() for t in pred.threads)
+    # a stopped predictor admits nothing: the task comes back unserved
+    assert pred.put_task(np.zeros((16, 16, 4), np.uint8), lambda *a: None) is False

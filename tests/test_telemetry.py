@@ -99,6 +99,38 @@ def test_set_enabled_gates_writes():
     assert c.value() == 2
 
 
+# -- attribution: the dead stage, named from the counters -------------------
+
+
+@pytest.mark.parametrize("counters, disabled, says", [
+    ({}, True, "telemetry disabled, no attribution"),
+    ({("predictor", "batches_total"): 3}, False, "no wire traffic"),
+    # fleet roles: the sums over master.f<k> are what is read
+    ({("master.f0", "block_msgs_total"): 2,
+      ("master.f1", "block_shm_msgs_total"): 1},
+     False, "wire traffic but predictor never served"),
+    ({("master", "per_env_msgs_total"): 9, ("predictor", "batches_total"): 4},
+     False, "predictor serving but no datapoints"),
+    ({("master", "block_msgs_total"): 9, ("predictor", "batches_total"): 4,
+      ("master", "datapoints_total"): 17},
+     False, "plane went quiet after progress"),
+], ids=["disabled", "no_wire", "never_served", "no_datapoints", "quiet"])
+def test_stall_attribution_names_the_dead_stage(counters, disabled, says):
+    from distributed_ba3c_tpu.telemetry.attribution import stall_attribution
+
+    for (role, name), v in counters.items():
+        telemetry.registry(role).counter(name).inc(v)
+    try:
+        telemetry.set_enabled(not disabled)
+        why = stall_attribution()
+    finally:
+        telemetry.set_enabled(True)
+    assert why.startswith(says), why
+    msgs = sum(v for (_, name), v in counters.items() if "msgs_total" in name)
+    assert f"wire_msgs={msgs} " in why
+    assert f"datapoints={counters.get(('master', 'datapoints_total'), 0)} " in why
+
+
 # -- flight recorder --------------------------------------------------------
 
 

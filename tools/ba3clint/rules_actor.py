@@ -296,8 +296,8 @@ class PrivateImportRule(Rule):
 
     A leading underscore is the module's statement that the name may be
     renamed, re-scoped, or deleted without notice; an external import turns
-    that private detail into silent API surface (``scripts/ksweep_bench.py``
-    depended on ``devicelock._stderr_print`` exactly this way — ADVICE r5).
+    that private detail into silent API surface (a script once depended on
+    ``devicelock._stderr_print`` exactly this way — ADVICE r5).
     Promote the name to a public one (keep a private alias in the owning
     module if its history matters), or suppress with the justification for
     why the coupling is intended.
