@@ -25,6 +25,11 @@ Phases (each fails the run; nothing is caught):
               ``_bf16``, ``_int8``) answering batches of 256 inside the
               repo's parity bands of the f32 forward; the int8 arm ``auto``
               resolved to.
+``grouped``   the learner's grouped expert product (``ops/grouped_matmul.py``)
+              at the language-model cell's shapes, seeded group sizes with
+              an empty group: the Pallas kernel's forward and both gradients
+              against ``jax.lax.ragged_dot`` on the same chip (tier-1 cannot
+              run Mosaic), the largest gaps in the result.
 ``mesh``      only with more than one device: env state and batch sharded
               over every device, and after K updates every param leaf's
               replicas bit-identical — the on-chip form of audit rule T3.
@@ -92,6 +97,8 @@ class Shape:
     staging_blocks: int
     # rollout forwards
     serve_batch: int
+    # grouped expert product: sorted rows, hidden, expert width, experts held
+    grouped_dims: tuple
 
 
 FULL = Shape(
@@ -101,6 +108,7 @@ FULL = Shape(
     plane_env="cpp:pong", plane_image_size=None, plane_envs=64,
     plane_batch=128, plane_steps_per_epoch=20, staging_blocks=12,
     serve_batch=256,
+    grouped_dims=(5120, 2048, 1792, 8),
 )
 
 SMALL = Shape(
@@ -110,6 +118,7 @@ SMALL = Shape(
     plane_env="fake", plane_image_size=16, plane_envs=4,
     plane_batch=32, plane_steps_per_epoch=20, staging_blocks=5,
     serve_batch=8,
+    grouped_dims=(256, 128, 128, 4),
 )
 
 
@@ -615,6 +624,60 @@ def phase_forwards(shape: Shape, workdir: str, platform: str = "tpu") -> dict:
 
 
 # --------------------------------------------------------------------------
+# phase: the grouped expert product against ragged_dot
+# --------------------------------------------------------------------------
+
+
+def phase_grouped(shape: Shape, workdir: str, platform: str = "tpu") -> dict:
+    del workdir
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from distributed_ba3c_tpu.ops.grouped_matmul import grouped_dot
+
+    device = _require_device(platform)
+    m, d, f, groups = shape.grouped_dims
+    # a seeded router's rows for the experts held here, one of them empty
+    share = np.random.default_rng(0).dirichlet(np.full(groups, 50.0))
+    sizes = np.floor(share * 0.8 * m).astype(np.int32)
+    sizes[groups // 2] = 0
+    held = int(sizes.sum())
+    keys = jax.random.split(jax.random.PRNGKey(0), 3)
+    lhs = jax.random.normal(keys[0], (m, d), jnp.bfloat16)
+    rhs = (jax.random.normal(keys[1], (groups, d, f)) / d ** 0.5).astype(
+        jnp.bfloat16)
+    pull = jax.random.normal(keys[2], (m, f), jnp.bfloat16)
+    here = (jnp.arange(m) < held)[:, None]
+
+    def all_three(dot):
+        def run(lhs, rhs, sizes):
+            out, pull_back = jax.vjp(lambda l, r: dot(l, r, sizes), lhs, rhs)
+            d_lhs, d_rhs = pull_back(jnp.where(here, pull, 0))
+            # rows outside every group: unspecified from the kernel
+            return jnp.where(here, out, 0), jnp.where(here, d_lhs, 0), d_rhs
+        return jax.jit(run)
+
+    ours = all_three(grouped_dot)
+    text = ours.lower(lhs, rhs, jnp.asarray(sizes)).as_text()
+    kernels = text.count("tpu_custom_call")
+    _check(kernels == (3 if platform == "tpu" else 0),
+           f"{kernels} Pallas kernels lowered on {platform}")
+    got = ours(lhs, rhs, jnp.asarray(sizes))
+    want = all_three(jax.lax.ragged_dot)(lhs, rhs, jnp.asarray(sizes))
+    info = {"device": device, "rows_held": held, "pallas_kernels": kernels}
+    for name, a, b in zip(("forward", "dx", "dw"), got, want):
+        a, b = (np.asarray(x.astype(jnp.float32)) for x in (a, b))
+        _check(bool(np.isfinite(a).all()), f"{name}: not finite")
+        gap, scale = float(np.abs(a - b).max()), float(np.abs(b).max())
+        # bf16 out of float32 sums on both sides: an ulp of the largest
+        _check(gap <= scale / 64, f"{name}: off by {gap} of {scale}")
+        info[f"{name}_max_abs_err"] = gap
+        info[f"{name}_max_abs"] = scale
+    return info
+
+
+# --------------------------------------------------------------------------
 # phase: more than one device
 # --------------------------------------------------------------------------
 
@@ -696,6 +759,7 @@ PHASES = {
     "fused": phase_fused,
     "plane": phase_plane,
     "forwards": phase_forwards,
+    "grouped": phase_grouped,
     "mesh": phase_mesh,
 }
 

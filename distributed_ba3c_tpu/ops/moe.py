@@ -14,9 +14,10 @@ them; an assignment to an absent expert adds nothing here (on its own chip
 it would; the chips' parts sum to the whole layer, tests/test_lfm2_moe.py).
 No token is dropped and there is no capacity: the ``N * k`` assignments are
 sorted by expert, the held ones first, and the grouped products
-(``jax.lax.ragged_dot``, which the TPU compiler lowers to one grouped-matmul
-kernel that visits only the tiles the groups cover) run over exactly the
-rows routed here, however uneven.
+(``ops/grouped_matmul.py:grouped_dot``: a Pallas kernel on the TPU,
+``jax.lax.ragged_dot`` elsewhere and at widths off whole lanes; either
+visits only the tiles the groups cover) run over exactly the rows routed
+here, however uneven.
 
 **Blocks.** The kernel skips the rows of absent experts, but everything
 round it (the gather in, ``silu * up``, the products' outputs, the masks,
@@ -41,7 +42,7 @@ layer as it was before there were blocks.
 
 Backward: the blocks are one ``custom_vjp``. Its forward keeps nothing of a
 block; its backward is the same loop, each needed block run again and pulled
-back (``ragged_dot`` has its own transposes), its ``[R, f]`` intermediates
+back (``grouped_dot`` has its own transposes), its ``[R, f]`` intermediates
 alive only while it runs: what a rematerialised layer would recompute
 anyway, without the residuals. Rows go in and out by gathers both ways
 (``_take_rows``, ``_combine``), so no scatter-add is traced.
@@ -64,6 +65,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from distributed_ba3c_tpu.ops.grouped_matmul import grouped_dot
 from distributed_ba3c_tpu.utils import profiling
 from distributed_ba3c_tpu.utils.profiling import device_scope
 
@@ -72,7 +74,8 @@ NORM_EPS = 1e-6  # the ``+ 1e-6`` of norm_topk_prob
 #: v5e's 240 FLOP a byte a bfloat16 product of so few rows is bound by its
 #: matrix's bytes, so the rows nobody routed here cost no time
 DENSE_ROWS = 256
-#: rows of the grouped kernel's tile: a block is whole tiles
+#: a block is whole multiples of this many rows (and so whole row tiles of
+#: the grouped kernel, ``ops/grouped_matmul.py:ROW_TILE``)
 ROW_TILE = 512
 #: room in a block over the rows an even router sends to the experts held
 #: here. The share of a chunk's assignments that lands on 8 of 32 experts
@@ -217,16 +220,17 @@ def _block(b, z, weights, w1, w3, w2, s: _Sorted, block: int):
         at = s.inverse - lo
         where = jnp.where((at >= 0) & (at < block), at, block)
         rows = _take_rows(z, tokens, where)
-        # the grouped products say nothing about rows outside every group:
-        # hold them at zero on the way in (so nothing comes back through
-        # them) and on the way out
+        # the grouped products say nothing about rows outside every group
+        # (the kernel leaves them unwritten, NaN as likely as not): hold
+        # them at zero on the way in (so nothing comes back through them)
+        # and on the way out
         rows = jnp.where(here[:, None], rows, 0)
     with device_scope(profiling.MOE_EXPERTS):
-        gate = jax.lax.ragged_dot(rows, w1, sizes)
-        up = jax.lax.ragged_dot(rows, w3, sizes)
+        gate = grouped_dot(rows, w1, sizes)
+        up = grouped_dot(rows, w3, sizes)
         act = (jax.nn.silu(gate.astype(jnp.float32))
                * up.astype(jnp.float32)).astype(z.dtype)
-        y = jax.lax.ragged_dot(act, w2, sizes)
+        y = grouped_dot(act, w2, sizes)
     with device_scope(profiling.MOE_COMBINE):
         # the rows go back in the compute type (half the bytes of the
         # gather); the weighted sum over a token's k experts is float32

@@ -69,11 +69,15 @@ MOE = "moe"
 MOE_ROUTER = "moe/router"
 MOE_DISPATCH = "moe/dispatch"
 MOE_EXPERTS = "moe/experts"
+#: open only round the Pallas grouped-product kernels
+#: (ops/grouped_matmul.py): time here says that they ran, none that the
+#: products went to ``jax.lax.ragged_dot`` (another backend, a small cut)
+MOE_EXPERTS_GMM = "moe/experts/gmm"
 MOE_COMBINE = "moe/combine"
 HEAD = "head"
 POLICY_LAYERS = (
     EMBED, OP_CONV, OP_ATTN, FFN_DENSE, MOE, MOE_ROUTER, MOE_DISPATCH,
-    MOE_EXPERTS, MOE_COMBINE, HEAD,
+    MOE_EXPERTS, MOE_EXPERTS_GMM, MOE_COMBINE, HEAD,
 )
 #: the rollout's once-an-update bfloat16 snapshot of the matrix weights
 ROLLOUT_WEIGHTS_BF16 = "rollout/weights_bf16"

@@ -47,6 +47,10 @@ def test_phase_at_small_shape_on_cpu_mesh(phase, tmp_path, child_env):
     elif phase == "forwards":
         assert info["int8_arm"] == "int8"
         assert info["float32_dvalue"] < info["int8_dvalue"] < chip_smoke.BAND_VALUE
+    elif phase == "grouped":
+        # off the chip the product is ragged_dot itself: no kernel, no gap
+        assert info["pallas_kernels"] == 0 and info["rows_held"] > 0
+        assert info["forward_max_abs_err"] == info["dw_max_abs_err"] == 0.0
     else:
         assert info["sharded_over"] == list(range(8))
         assert info["replicated_leaves"] > 0

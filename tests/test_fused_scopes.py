@@ -145,6 +145,23 @@ def test_every_convolution_is_in_exactly_one_forward_or_learner_scope(
      "learner/loss", True),
     ("jit(multi_step)/learner/jvp(loss)/jit(log_softmax)/sub", "learner/loss", False),
     ("jit(multi_step)/optimizer/sqrt", "optimizer", False),
+    # the Pallas grouped products, exactly as a v5e capture names them (my
+    # chip run, PR 30): the call keeps its scopes, and JAX's own mark of the
+    # backward pass; the forward the backward runs again counts as backward
+    ("jit(multi_step)/while/body/closed_call/learner/jvp(moe)/jit(_sorted_rows)/while/body/experts/gmm/jit(gmm)/gmm/pallas_call:",
+     "learner/moe/experts/gmm", False),
+    ("jit(multi_step)/learner/jvp(moe)/jit(_sorted_rows)/while/body/experts/gmm/jit(gmm)/gmm/pallas_call:",
+     "learner/moe/experts/gmm", False),
+    ("jit(multi_step)/while/body/closed_call/learner/transpose(jvp(learner))/jvp()/checkpoint/moe/jit(_sorted_rows)/while/body/jvp(experts)/gmm/jit(gmm)/gmm/pallas_call:",
+     "learner/moe/experts/gmm", True),
+    ("jit(multi_step)/while/body/closed_call/learner/transpose(jvp(learner))/jvp()/checkpoint/moe/jit(_sorted_rows)/while/body/transpose(jvp(experts))/gmm/jit(gmm)/gmm_dx/pallas_call:",
+     "learner/moe/experts/gmm", True),
+    ("jit(multi_step)/learner/transpose(jvp(learner))/jvp()/checkpoint/moe/jit(_sorted_rows)/while/body/transpose(jvp(experts))/gmm/jit(tgmm)/gmm_dw/pallas_call:",
+     "learner/moe/experts/gmm", True),
+    # the tile schedule computed for them lies in the same scope
+    ("jit(multi_step)/learner/jvp(moe)/jit(_sorted_rows)/while/body/experts/gmm/jit(_roll_dynamic)/select_n",
+     "learner/moe/experts/gmm", False),
+    ("jit(multi_step)/optimizer/gmm/pallas_call:", "optimizer", False),  # no such scope there
     ("jit(multi_step)/BA3CNet/Conv_0/add", None, False),
     ("jit(create)/vmap(render)/mul", None, False),  # a render outside a rollout
     ("", None, False),
@@ -169,6 +186,9 @@ def test_scope_of_an_op_name(op_name, scope, backward):
      "", "learner/moe/experts", True),
     ("ragged-dot-none", "jit(multi_step)/optimizer/sqrt", None, False),
     ("jit(multi_step)/learner/jvp(moe)/experts/mul", "", None, False),  # no kernel
+    # a Pallas call is no renamed kernel: its own name says where it lies
+    ("jit(multi_step)/learner/jvp(moe)/jit(_sorted_rows)/while/body/experts/gmm/jit(gmm)/gmm/pallas_call:",
+     "jit(multi_step)/optimizer/sqrt", None, False),
 ])
 def test_a_renamed_kernels_scope(op_name, neighbour, scope, backward):
     assert profiling.kernel_scope(op_name, neighbour) == scope

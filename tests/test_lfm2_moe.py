@@ -7,6 +7,7 @@ game against its reference, the fused step's gradient, the refusals.
 
 import contextlib
 import dataclasses
+import functools
 import inspect
 import math
 import os
@@ -34,7 +35,7 @@ from distributed_ba3c_tpu.fused.loop import (  # noqa: E402
 )
 from distributed_ba3c_tpu.models import policy  # noqa: E402
 from distributed_ba3c_tpu.models.lfm2_moe import CUTS, LFM2MoE  # noqa: E402
-from distributed_ba3c_tpu.ops import moe  # noqa: E402
+from distributed_ba3c_tpu.ops import grouped_matmul, moe  # noqa: E402
 from distributed_ba3c_tpu.ops.gradproc import make_optimizer  # noqa: E402
 from distributed_ba3c_tpu.parallel.mesh import make_mesh  # noqa: E402
 from distributed_ba3c_tpu.utils import profiling  # noqa: E402
@@ -65,23 +66,51 @@ def params_of(seed):
     return reference.init_params(jax.random.PRNGKey(seed), SPEC)
 
 
-def tokens_of(seed, batch=3, length=EPISODE):
-    return jax.random.randint(jax.random.PRNGKey(seed), (batch, length), 0, IDS)
+BATCH = 3  # envs of a test's token batch
 
 
-@pytest.fixture(params=["grouped", "blocked", "every-token"])
+def tokens_of(seed, batch=None, length=EPISODE):
+    return jax.random.randint(
+        jax.random.PRNGKey(seed), (batch or BATCH, length), 0, IDS)
+
+
+@contextlib.contextmanager
+def through_the_kernel():
+    """The grouped products in the Pallas kernel (ops/grouped_matmul.py)
+    under its interpreter, which needs whole lanes and whole tiles of rows:
+    the small cut with hidden and expert widths of 128, blocks in tiles of
+    128 sorted rows, and 4 envs x 16 tokens x top-2 = 128 rows a batch."""
+    wide = dict(hidden_size=128, moe_intermediate_size=128)
+    config = dict(TINY_CONFIG, **wide)
+    here = sys.modules[__name__]
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(grouped_matmul, "INTERPRET", True)
+        patch.setattr(moe, "ROW_TILE", 128)
+        patch.setitem(CUTS, "tiny", dict(CUTS["tiny"], head_dim=32, **wide))
+        patch.setattr(here, "TINY_CONFIG", config)
+        patch.setattr(here, "SPEC", reference.spec_of(config))
+        patch.setattr(here, "BATCH", 4)
+        moe._sorted_rows.clear_cache()  # traced by shape, whatever the path
+        yield
+    moe._sorted_rows.clear_cache()
+
+
+@pytest.fixture(params=["grouped", "blocked", "every-token", "kernel"])
 def moe_path(request, monkeypatch):
     """The forms of the expert layer (ops/moe.py): the sorted, grouped
     products in one block (at these sizes the bound is all the rows), the
     same with a bound so small that the rows held here need two blocks or
-    more, and every held expert computing every token (few tokens)."""
+    more, every held expert computing every token (few tokens), and the one
+    block with its products in the Pallas kernel."""
     monkeypatch.setattr(
         moe, "DENSE_ROWS", 10**9 if request.param == "every-token" else 0)
     if request.param == "blocked":
         # half the rows an even router sends here, in tiles of 8
         monkeypatch.setattr(moe, "ROW_TILE", 8)
         monkeypatch.setattr(moe, "HELD_ROWS_MARGIN", -0.5)
-    return request.param
+    with through_the_kernel() if request.param == "kernel" else \
+            contextlib.nullcontext():
+        yield request.param
 
 
 def _overflow(held_rows, n, k=2, held=2, num_experts=8):
@@ -167,11 +196,19 @@ def test_decode_through_the_carry_equals_the_unroll_with_a_reset_inside(dtype, t
 
 
 # -- (c) the shares of one expert layer add up to the whole ---------------------
-def _layer_inputs(seed=5, n=48):
+def _layer_inputs(seed=5, n=None):
     whole = reference.spec_of(dict(TINY_CONFIG, num_experts=8))
     p = reference.init_params(jax.random.PRNGKey(seed), whole)["layer_2"]
-    z = jax.random.normal(jax.random.PRNGKey(seed + 1), (1, n, 64))
+    z = jax.random.normal(jax.random.PRNGKey(seed + 1), (
+        1, n or BATCH * EPISODE, TINY_CONFIG["hidden_size"]))
     return whole, p, z
+
+
+def _jit_share(*static):
+    """``_share`` traced anew: JAX keeps a function's traces by its shapes,
+    and a test's paths are patched in underneath it (under one shared
+    ``jax.jit(_share)`` a test's second path ran its first one's trace)."""
+    return jax.jit(lambda p, z: _share(p, z, *static))
 
 
 def _share(p, z, offset, held=2, dtype=jnp.float32):
@@ -185,7 +222,7 @@ def _share(p, z, offset, held=2, dtype=jnp.float32):
 @pytest.mark.parametrize("offset", [0, 2, 4, 6])
 def test_a_share_is_the_references_part_for_that_share(offset, moe_path):
     whole, p, z = _layer_inputs()
-    out, counts = jax.jit(_share, static_argnums=2)(p, z, offset)
+    out, counts = _jit_share(offset)(p, z)
     spec = dict(whole, experts=2, expert_offset=offset)
     cut = {k: (v[offset:offset + 2] if k in ("w1", "w2", "w3") else v)
            for k, v in p.items()}
@@ -198,7 +235,7 @@ def test_a_share_is_the_references_part_for_that_share(offset, moe_path):
 
 def test_the_four_shares_add_up_to_the_uncut_layer(moe_path):
     whole, p, z = _layer_inputs()
-    parts = [jax.jit(_share, static_argnums=2)(p, z, o) for o in (0, 2, 4, 6)]
+    parts = [_jit_share(o)(p, z) for o in (0, 2, 4, 6)]
     with jax.default_matmul_precision("highest"):
         want, _ = reference._experts_ffn(p, z, whole, lambda x: x)
     np.testing.assert_allclose(sum(out for out, _ in parts), want[0], atol=5e-5)
@@ -206,12 +243,19 @@ def test_the_four_shares_add_up_to_the_uncut_layer(moe_path):
     assert sum(int(c.sum()) for _, c in parts) == 2 * z.shape[1]
 
 
+def test_a_path_runs_the_products_it_names(moe_path):
+    whole, p, z = _layer_inputs()
+    text = str(jax.make_jaxpr(lambda p, z: _share(p, z, 0))(p, z))
+    assert ("pallas_call" in text) == (moe_path == "kernel")
+    assert ("ragged_dot" in text) == (moe_path in ("grouped", "blocked"))
+
+
 # -- (d) a router pushed onto one expert drops no token -------------------------
 @pytest.mark.parametrize("held", [2, 8])
 def test_a_router_pushed_onto_one_expert_drops_no_token(held, moe_path):
     whole, p, z = _layer_inputs(n=64)
     p = dict(p, expert_bias=p["expert_bias"].at[1].set(50.0))  # always chosen
-    out, counts = jax.jit(_share, static_argnums=(2, 3))(p, z, 0, held)
+    out, counts = _jit_share(0, held)(p, z)
     n = z.shape[1]
     assert int(counts[1]) == n  # every token, no capacity
     if held == 8:
@@ -279,28 +323,34 @@ def _plain_share(z, experts, weights, w1, w3, w2):
 
 
 @pytest.mark.parametrize("over", [-1, 0, 1, 41], ids=lambda o: f"R{o:+d}")
-def test_rows_that_fill_a_block_exactly_and_one_more(over, monkeypatch):
-    """64 tokens, 2 of 8 experts held, tiles of 8: a block is 40 rows. The
-    first 20 tokens take both held experts (40 rows), ``over`` more or fewer
-    assignments land here: value, every gradient and the count of blocks."""
+@pytest.mark.parametrize("n,d,f,tile,block,kernel", [
+    (64, 64, 32, 8, 40, False), (256, 128, 128, 128, 256, True),
+], ids=["ragged-dot", "kernel"])
+def test_rows_that_fill_a_block_exactly_and_one_more(
+        over, n, d, f, tile, block, kernel, monkeypatch):
+    """64 tokens, 2 of 8 experts held, tiles of 8: a block is 40 rows (the
+    Pallas kernel under its interpreter: 256 tokens, tiles of 128, 256
+    rows). The first ``block / 2`` tokens take both held experts (``block``
+    rows), ``over`` more or fewer assignments land here: value, every
+    gradient and the count of blocks."""
     monkeypatch.setattr(moe, "DENSE_ROWS", 0)
-    monkeypatch.setattr(moe, "ROW_TILE", 8)
-    n, block = 64, 40
+    monkeypatch.setattr(moe, "ROW_TILE", tile)
+    monkeypatch.setattr(grouped_matmul, "INTERPRET", kernel)
     assert moe.block_rows(n, 2, 2, 8) == block
     experts = np.stack([2 + np.arange(n) % 3, 5 + np.arange(n) % 3], 1)
-    experts[:20] = (0, 1)
+    experts[:block // 2] = (0, 1)
     if over < 0:
-        experts[19] = (0, 6)
+        experts[block // 2 - 1] = (0, 6)
     for i in range(max(over, 0)):
-        experts[20 + i] = (i % 2, 7)
+        experts[block // 2 + i] = (i % 2, 7)
     assert (experts < 2).sum() == block + over
     assert (experts[:, 0] != experts[:, 1]).all()  # as a top-k's are
     keys = jax.random.split(jax.random.PRNGKey(9), 6)
-    z = jax.random.normal(keys[0], (n, 64))
+    z = jax.random.normal(keys[0], (n, d))
     weights = jax.nn.softmax(jax.random.normal(keys[1], (n, 2)))
-    w1, w3 = (jax.random.normal(k, (2, 64, 32)) / 8 for k in keys[2:4])
-    w2 = jax.random.normal(keys[4], (2, 32, 64)) / 6
-    pull = jax.random.normal(keys[5], (n, 64))
+    w1, w3 = (jax.random.normal(k, (2, d, f)) / 8 for k in keys[2:4])
+    w2 = jax.random.normal(keys[4], (2, f, d)) / 6
+    pull = jax.random.normal(keys[5], (n, d))
     experts = jnp.asarray(experts, jnp.int32)
 
     def ours(z, weights, w1, w3, w2):
@@ -313,14 +363,16 @@ def test_rows_that_fill_a_block_exactly_and_one_more(over, monkeypatch):
         return jnp.sum(out * pull), out
 
     args = (z, weights, w1, w3, w2)
+    moe._sorted_rows.clear_cache()  # traced by shape, whatever the path
     with jax.default_matmul_precision("highest"):
         (_, (out, counts, ran)), got = jax.jit(jax.value_and_grad(
             ours, argnums=range(5), has_aux=True))(*args)
         (_, want_out), want = jax.jit(jax.value_and_grad(
             theirs, argnums=range(5), has_aux=True))(*args)
+    moe._sorted_rows.clear_cache()
     assert int(counts.sum()) == block + over
-    assert int(ran) == {-1: 0, 0: 0, 1: 1, 41: 2}[over]
-    np.testing.assert_allclose(out, want_out, atol=2e-5)
+    assert int(ran) == {-1: 0, 0: 0, 1: 1, 41: 1 + (41 > block)}[over]
+    np.testing.assert_allclose(out, want_out, atol=2e-5 * d / 64)
     for name, a, b in zip(("z", "weights", "w1", "w3", "w2"), got, want,
                           strict=True):
         np.testing.assert_allclose(
@@ -328,40 +380,55 @@ def test_rows_that_fill_a_block_exactly_and_one_more(over, monkeypatch):
 
 
 def _count(jaxpr, primitive):
+    """Equations of ``primitive`` in the program (a kernel's own body, whose
+    ``pl.when`` is a ``cond`` on the chip's scalar core, is not the
+    program's)."""
     return sum((e.primitive.name == primitive) + sum(
-        _count(sub, primitive) for sub in jax.core.jaxprs_in_params(e.params))
-        for e in jaxpr.eqns)
+        _count(sub, primitive) for sub in jax.core.jaxprs_in_params(e.params)
+        if e.primitive.name != "pallas_call") for e in jaxpr.eqns)
 
 
-def test_the_learners_program_holds_one_copy_of_the_block(monkeypatch):
+@pytest.mark.parametrize("product", ["ragged_dot_general", "pallas_call"])
+def test_the_learners_program_holds_one_copy_of_the_block(product, monkeypatch):
     """What PR 27 was refused for (a second, whole-batch copy of the expert
     layer beside the bounded one grew the compiled step by a third, and its
     set-up with it), caught before the chip: the gradient of a loss over
-    ``model.unroll`` holds the grouped product as often with a block smaller
-    than the rows (overflow possible) as with one block of all of them, the
-    blocks are a loop, and no branch holds a grouped product."""
+    ``model.unroll`` holds the grouped product (``jax.lax.ragged_dot``, or
+    the Pallas kernel where it runs) as often with a block smaller than the
+    rows (overflow possible) as with one block of all of them, the blocks
+    are a loop, and no branch holds a grouped product."""
     monkeypatch.setattr(moe, "DENSE_ROWS", 0)
-    model, tokens = tiny(), tokens_of(1)
-    params = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
+    tile, batch = 8, 3
+    if product == "pallas_call":
+        # 8 envs: 256 rows, so a block of one 128-row tile leaves overflow
+        ctx, tile, batch = through_the_kernel(), 128, 8
+    else:
+        ctx = contextlib.nullcontext()
+    with ctx:
+        model, tokens = tiny(), tokens_of(1, batch)
+        params = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
 
-    def loss(p):
-        out, _ = model.unroll(p, tokens)
-        return jnp.sum(out.logits) + jnp.sum(out.value)
+        def loss(p):
+            out, _ = model.unroll(p, tokens)
+            return jnp.sum(out.logits) + jnp.sum(out.value)
 
-    def census():
-        jaxpr = jax.make_jaxpr(jax.grad(loss))(params).jaxpr
-        return {name: _count(jaxpr, name)
-                for name in ("ragged_dot_general", "while", "cond")}
+        def census():
+            moe._sorted_rows.clear_cache()
+            jaxpr = jax.make_jaxpr(jax.grad(loss))(params).jaxpr
+            return {name: _count(jaxpr, name) for name in (
+                "ragged_dot_general", "pallas_call", "while", "cond")}
 
-    assert moe.block_rows(tokens.size, 2, 2, 8) == 2 * tokens.size
-    whole = census()
-    monkeypatch.setattr(moe, "ROW_TILE", 8)
-    assert moe.block_rows(tokens.size, 2, 2, 8) < 2 * tokens.size
-    blocked = census()
+        monkeypatch.setattr(moe, "ROW_TILE", 512)
+        assert moe.block_rows(tokens.size, 2, 2, 8) == 2 * tokens.size
+        whole = census()
+        monkeypatch.setattr(moe, "ROW_TILE", tile)
+        assert moe.block_rows(tokens.size, 2, 2, 8) < 2 * tokens.size
+        blocked = census()
+    other = ({"ragged_dot_general", "pallas_call"} - {product}).pop()
     # an expert layer: 3 products forward, and in the backward's loop the 3
     # again (nothing of a block is kept) with the 2 transposes of each
     assert whole == blocked == {
-        "ragged_dot_general": 2 * 12, "while": 2 * 2, "cond": 0}
+        product: 2 * 12, other: 0, "while": 2 * 2, "cond": 0}
 
 
 # -- (f) the recall game -------------------------------------------------------
@@ -432,28 +499,53 @@ def as_on_the_chip():
         yield
 
 
-@pytest.fixture(scope="module", params=[1, 2], ids=["one-device", "two-shards"])
+@contextlib.contextmanager
+def _kernel_in_the_step():
+    """``through_the_kernel`` for a whole fused step. Pallas's interpreters
+    bind a kernel's primitives on operands some of which vary over the mesh
+    and some of which do not, which ``shard_map``'s typing of varying axes
+    refuses (on the chip the kernel is one custom call, and
+    tests/test_grouped_matmul.py compiles it for one): the step is built
+    with that typing off, which changes no number on one device."""
+    with through_the_kernel(), pytest.MonkeyPatch.context() as patch:
+        patch.setattr(jax, "shard_map", functools.partial(
+            jax.shard_map, check_vma=False))
+        yield
+
+
+@pytest.fixture(scope="module", params=[(1, False), (2, False), (1, True)],
+                ids=["one-device", "two-shards", "one-device-kernel"])
 def one_update(request):
     """One fused update in float32 and what the reference makes of the same
-    start and the same actions."""
+    start and the same actions; once more with the learner's grouped
+    products in the Pallas kernel (64 tokens a chunk: 128 sorted rows)."""
     import optax
 
-    n_shards = request.param
-    env, cfg, model, step, state, params = _fused(n_shards)
-    per = 8 // n_shards
-    env_state0 = jax.device_get(state.env_state)
-    keys = [np.asarray(jax.random.key_data(k)) if jnp.issubdtype(
-        k.dtype, jax.dtypes.prng_key) else np.asarray(k) for k in state.key]
-    with as_on_the_chip():
-        new, metrics = step(
-            step.put(state), HYPER["entropy_beta"], HYPER["learning_rate"])
-    # the step says what it drew: [T, B_global] -> [shards, T, envs a shard]
-    actions = np.stack([np.asarray(metrics["actions"])[:, s * per:(s + 1) * per]
-                        for s in range(n_shards)])
-    mu = optax.tree_utils.tree_get(new.train.opt_state, "mu")
-    grad = jax.tree_util.tree_map(lambda m: np.asarray(m) / (1 - 0.9), mu)
-    return dict(n_shards=n_shards, params=params, actions=actions, keys=keys,
-                env_state0=env_state0, new=new, metrics=metrics, grad=grad)
+    n_shards, kernel = request.param
+    with _kernel_in_the_step() if kernel else contextlib.nullcontext():
+        env, cfg, model, step, state, params = _fused(
+            n_shards, grad_chunk_samples=64 if kernel else 32)
+        per = 8 // n_shards
+        env_state0 = jax.device_get(state.env_state)
+        keys = [np.asarray(jax.random.key_data(k)) if jnp.issubdtype(
+            k.dtype, jax.dtypes.prng_key) else np.asarray(k) for k in state.key]
+        with as_on_the_chip():
+            put = step.put(state)
+            products = str(jax.make_jaxpr(step.audit_jit)(
+                put, jnp.float32(0), jnp.float32(0)))
+            assert ("pallas_call" in products) == kernel
+            assert ("ragged_dot" in products) != kernel
+            new, metrics = step(
+                put, HYPER["entropy_beta"], HYPER["learning_rate"])
+        # the step says what it drew: [T, B_global] -> [shards, T, envs a shard]
+        actions = np.stack([np.asarray(metrics["actions"])[:, s * per:(s + 1) * per]
+                            for s in range(n_shards)])
+        mu = optax.tree_utils.tree_get(new.train.opt_state, "mu")
+        grad = jax.tree_util.tree_map(lambda m: np.asarray(m) / (1 - 0.9), mu)
+        u = dict(n_shards=n_shards, params=params, actions=actions, keys=keys,
+                 env_state0=env_state0, new=new, metrics=metrics, grad=grad)
+        u["reference"] = _reference_update(u)  # at this case's widths
+    return u
 
 
 def _reference_update(u):
@@ -479,7 +571,7 @@ def _reference_update(u):
 
 
 def test_the_fused_steps_gradient_is_the_references(one_update):
-    loss, want = _reference_update(one_update)
+    loss, want = one_update["reference"]
     assert abs(float(one_update["metrics"]["loss"]) - loss) < 2e-4
     for layer, leaves in want.items():
         for leaf, g in leaves.items():
@@ -533,12 +625,37 @@ def compiled_op_names():
     return set(re.findall(r'op_name="([^"]*)"', hlo))
 
 
+#: open only round the Pallas grouped products, which this small step's
+#: chunks (64 rows on the CPU) do not reach
+_BY_KERNEL = tuple(profiling.policy_scope(under, profiling.MOE_EXPERTS_GMM)
+                   for under in (profiling.ROLLOUT_POLICY, profiling.LEARNER))
+
+
 @pytest.mark.parametrize("scope", profiling.SEQUENCE_SCOPES)
 def test_every_sequence_scope_is_in_the_compiled_step(compiled_op_names, scope):
     assert scope in profiling.ALL_SCOPES and scope not in profiling.SCOPES
     found = {profiling.scope_of(name) for name in compiled_op_names}
-    assert any(s is not None and (s == scope or s.startswith(scope + "/"))
-               for s in found), scope
+    there = any(s is not None and (s == scope or s.startswith(scope + "/"))
+                for s in found)
+    assert there == (scope not in _BY_KERNEL), scope
+
+
+def test_the_kernels_scope_is_in_the_learner_where_the_kernel_is_lowered():
+    """``learner/moe/experts/gmm`` holds the Pallas calls, forward and
+    backward, of a learner whose products run in the kernel (time there
+    says the mechanism ran); the rollout's few tokens never reach it."""
+    with _kernel_in_the_step(), as_on_the_chip():
+        _, _, _, step, state, _ = _fused(1, grad_chunk_samples=64)
+        hlo = step.audit_jit.lower(
+            step.put(state), jnp.float32(0.01), jnp.float32(1e-3)
+        ).compile().as_text()
+    names = set(re.findall(r'op_name="([^"]*)"', hlo))
+    gmm = {n for n in names if profiling.scope_of(n) == profiling.policy_scope(
+        profiling.LEARNER, profiling.MOE_EXPERTS_GMM)}
+    assert any(profiling.is_backward(n) for n in gmm)
+    assert any(not profiling.is_backward(n) for n in gmm)
+    assert not any((profiling.scope_of(n) or "").startswith(_BY_KERNEL[0])
+                   for n in names)
 
 
 # -- (h) what is refused ---------------------------------------------------------
