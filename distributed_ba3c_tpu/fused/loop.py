@@ -372,6 +372,11 @@ def make_fused_step(
             "segment that starts mid-episode is not supported"
         )
 
+    def carry_gauges(policy_carry):
+        if not sequence or not hasattr(model, "carry_gauges"):
+            return {}
+        return model.carry_gauges(policy_carry[0])
+
     def local_step(state: FusedState, entropy_beta, learning_rate):
         params = state.train.params
         key = state.key[0]  # this shard's scalar key
@@ -426,9 +431,10 @@ def make_fused_step(
         # than at 512 and over) it gains nothing from smaller pieces.
         #
         # A sequence policy's rows are whole episodes ``[B, T]`` and a chunk
-        # is so many envs (4,096 samples = 16 envs x 256): its forward is
-        # the policy's causal unroll, which also counts (``counters``: the
-        # tokens each held expert was routed, summed over the chunks).
+        # is so many envs (4,096 samples = 16 envs x 256, or 4 x 1,024): its
+        # forward is the policy's causal unroll, which also counts
+        # (``counters``: whatever the unroll's ``aux`` holds, summed over the
+        # chunks).
         def chunk_grad(p, chunk):
             states_c, actions_c, returns_c = chunk
 
@@ -549,10 +555,14 @@ def make_fused_step(
             metrics["episode_return_sum"] = jax.lax.psum(
                 jnp.sum(ep_sum), DATA_AXIS
             )
-            # a sequence policy's counters (moe_tokens_per_expert, [expert
-            # layers, experts held]): counts of this update, over the mesh
+            # a sequence policy's counters, whatever its unroll counts: this
+            # update's, summed over the mesh
             for k, v in counters.items():
                 metrics[k] = jax.lax.psum(v, DATA_AXIS)
+            # and its gauges of the carry as the rollout left it (the
+            # largest over the mesh): docs/policy_protocol.md
+            for k, v in carry_gauges(policy_carry).items():
+                metrics[k] = jax.lax.pmax(v, DATA_AXIS)
             if sequence:
                 # every shard's block into its columns of [T, B_global], then
                 # a psum: the same on every shard (an all_gather's result is
@@ -663,6 +673,9 @@ def make_fused_step(
         (lambda n_envs: None) if sequence else rollout_sub_batch_of(mesh))
     step.steps_per_dispatch = steps_per_dispatch
     step.reset_episode_stats = reset_episode_stats
+    #: fn(fetched metrics) -> an epoch's scalars of the policy's own counters
+    #: and gauges (a sequence policy's ``epoch_stats``)
+    step.policy_stats = getattr(model, "epoch_stats", lambda metrics: {})
     step.audit_jit = jitted  # tools/ba3caudit traces THIS program
     return step
 
@@ -1143,16 +1156,11 @@ def _fused_epoch_body(
             )
         for k in ("loss", "policy_loss", "value_loss", "entropy", "grad_norm"):
             holder.add_stat(k, metrics[k])
-        if "moe_tokens_per_expert" in metrics:
-            # how evenly the router loads the experts held here: the fullest
-            # one's tokens over the mean, in the worst layer
-            held = metrics["moe_tokens_per_expert"]
-            holder.add_stat("moe_load_max_over_mean", float(np.max(
-                held.max(axis=-1) / np.maximum(held.mean(axis=-1), 1e-9))))
-            # blocks of sorted rows the expert layers ran beyond their first
-            # (ops/moe.py): 0 while the rows routed here fit the bound
-            holder.add_stat("moe_overflow_blocks", float(np.sum(
-                metrics["moe_overflow_blocks"])))
+        # what a sequence policy makes of its own counters and gauges (the
+        # overlap step drives none and has no such hook)
+        policy_stats = getattr(step, "policy_stats", None)
+        if policy_stats is not None:
+            holder.add_stats(policy_stats(metrics))
         for k in ("mean_rho", "value_lag_mae"):
             # overlap-mode series (fused/overlap.py): how hard V-trace is
             # clipping and how far the value fn moved across the lag

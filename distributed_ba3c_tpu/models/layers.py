@@ -3,12 +3,23 @@
 Reference equivalent: ``tensorpack/models/nonlin.py`` (PReLU) and friends
 (SURVEY.md §2.6 #17). Conv/Dense/Pooling come from flax.linen directly — we do
 not re-wrap what the library already expresses idiomatically.
+
+Below ``PReLU``: what the token-sequence policies (models/lfm2_moe.py,
+models/phi4_flash.py) share, as plain functions of arrays. Their parameters
+are float32 trees ``{layer: {leaf: array}}``; matrices multiply in the
+compute type (bfloat16) with float32 accumulation.
 """
 
 from __future__ import annotations
 
+import math
+
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
+
+from distributed_ba3c_tpu.utils import profiling
+from distributed_ba3c_tpu.utils.profiling import device_scope
 
 
 class PReLU(nn.Module):
@@ -28,3 +39,85 @@ class PReLU(nn.Module):
         )
         alpha = alpha.astype(x.dtype)
         return jnp.where(x >= 0, x, alpha * x)
+
+
+# -- shared by the token-sequence policies ------------------------------------
+def mm(x, w, compute_dtype, out_dtype=None):
+    """``x @ w`` with both operands in the compute type, accumulated in
+    float32, given out in ``out_dtype`` (the compute type if None)."""
+    return jnp.dot(x.astype(compute_dtype), w.astype(compute_dtype),
+                   preferred_element_type=out_dtype or compute_dtype)
+
+
+def rms_norm(x, gain, eps):
+    x = x.astype(jnp.float32)
+    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * gain
+
+
+def layer_norm(x, gain, bias, eps):
+    x = x.astype(jnp.float32)
+    centred = x - jnp.mean(x, -1, keepdims=True)
+    var = jnp.mean(centred * centred, -1, keepdims=True)
+    return centred * jax.lax.rsqrt(var + eps) * gain + bias
+
+
+def swiglu(z, w_gate, w_up, w_down, compute_dtype):
+    """``W_down (silu(W_gate z) * W_up z)``: z [N, d] float32 -> [N, d]
+    float32. ``gate`` and ``up`` leave their products in the compute type."""
+    gate = mm(z, w_gate, compute_dtype).astype(jnp.float32)
+    up = mm(z, w_up, compute_dtype).astype(jnp.float32)
+    return mm(jax.nn.silu(gate) * up, w_down, compute_dtype, jnp.float32)
+
+
+def attend(q, k, v, mask, compute_dtype, scale=None):
+    """Masked grouped-query attention. q [B, Tq, H, D], k [B, Tk, KV, D],
+    v [B, Tk, KV, Dv], mask [B or 1, Tq, Tk] -> [B, Tq, H * Dv] float32; one
+    KV head serves H / KV query heads; ``scale`` is 1/sqrt(D) if None."""
+    B, Tq, H, D = q.shape
+    KV = k.shape[2]
+    q = q.reshape(B, Tq, KV, H // KV, D)
+    scores = jnp.einsum("bqkgd,bskd->bkgqs", q, k,
+                        preferred_element_type=jnp.float32)
+    scores = scores / math.sqrt(D) if scale is None else scores * scale
+    scores = jnp.where(mask[:, None, None, :, :], scores, -jnp.inf)
+    probs = jax.nn.softmax(scores, axis=-1).astype(compute_dtype)
+    out = jnp.einsum("bkgqs,bskd->bqkgd", probs, v,
+                     preferred_element_type=jnp.float32)
+    return out.reshape(B, Tq, -1)
+
+
+def embed_rows(table, tokens, compute_dtype):
+    """The held embedding rows of ``tokens``, rounded to the compute type
+    whichever table they are read from (float32 in the learner, the
+    rollout's snapshot): one value."""
+    with device_scope(profiling.EMBED):
+        return table[tokens].astype(compute_dtype).astype(jnp.float32)
+
+
+def tied_head(h, table, value_params, compute_dtype):
+    """h [N, d] float32, already through the final norm -> (logits over the
+    vocabulary ids held here: the embedding's rows times ``h``, tied; the
+    trainer's float32 value head on the same ``h``)."""
+    logits = jnp.dot(
+        h.astype(compute_dtype), table.astype(compute_dtype).T,
+        preferred_element_type=jnp.float32,
+    )
+    value = jnp.dot(
+        h, value_params["kernel"], precision=jax.lax.Precision.HIGHEST,
+    )[:, 0] + value_params["bias"][0]
+    return logits, value
+
+
+def matrices_in(params, compute_dtype, keep=()):
+    """The rollout's snapshot: every leaf of two or more dimensions in the
+    compute type, so that a decode step reads 2 bytes a weight and not 4.
+    Vectors, the leaves named in ``keep`` and the value head stay float32."""
+
+    def cast(layer, leaves):
+        if layer == "value":
+            return leaves
+        return {k: (v.astype(compute_dtype)
+                    if v.ndim >= 2 and k not in keep else v)
+                for k, v in leaves.items()}
+
+    return {layer: cast(layer, leaves) for layer, leaves in params.items()}

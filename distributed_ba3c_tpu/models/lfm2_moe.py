@@ -41,8 +41,11 @@ from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
+from distributed_ba3c_tpu.models import layers
 from distributed_ba3c_tpu.models.a3c import PolicyValue
+from distributed_ba3c_tpu.models.layers import rms_norm
 from distributed_ba3c_tpu.ops import moe
 from distributed_ba3c_tpu.utils import profiling
 from distributed_ba3c_tpu.utils.profiling import device_scope
@@ -83,11 +86,6 @@ class Carry(NamedTuple):
     pos: jax.Array       # [B] int32 position in the episode
     conv: Tuple          # per conv layer (v_{t-1}, v_{t-2}), each [B, d] f32
     kv: Tuple            # per attention layer (k, v), each [B, P, KV, D]
-
-
-def rms_norm(x, gain, eps):
-    x = x.astype(jnp.float32)
-    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * gain
 
 
 def _rope(x, positions, theta):
@@ -198,22 +196,13 @@ class LFM2MoE:
         """The matrices in the compute type, once for a whole rollout: a
         decode step then reads 2 bytes a weight and not 4. Gains, taps,
         the router with its bias and the value head stay float32."""
-        keep = ("router", "expert_bias", "conv_taps")
-
-        def cast(layer, leaves):
-            if layer == "value":
-                return leaves
-            return {k: (v.astype(self.compute_dtype)
-                        if v.ndim >= 2 and k not in keep else v)
-                    for k, v in leaves.items()}
-
-        return {layer: cast(layer, leaves) for layer, leaves in params.items()}
+        return layers.matrices_in(
+            params, self.compute_dtype,
+            keep=("router", "expert_bias", "conv_taps"))
 
     # -- pieces shared by the decode step and the unroll -----------------------
     def _mm(self, x, w, out_dtype=None):
-        cd = self.compute_dtype
-        return jnp.dot(x.astype(cd), w.astype(cd),
-                       preferred_element_type=out_dtype or cd)
+        return layers.mm(x, w, self.compute_dtype, out_dtype)
 
     def _ffn(self, p, ffn: str, h):
         """h [N, d] float32 -> (h + FFN(RMSNorm(h)), None or (tokens routed
@@ -222,10 +211,8 @@ class LFM2MoE:
         if ffn == DENSE:
             with device_scope(profiling.FFN_DENSE):
                 z = rms_norm(h, p["ffn_norm"], self.norm_eps)
-                gate = self._mm(z, p["w1"]).astype(jnp.float32)
-                up = self._mm(z, p["w3"]).astype(jnp.float32)
-                out = self._mm(jax.nn.silu(gate) * up, p["w2"], jnp.float32)
-                return h + out, None
+                return h + layers.swiglu(
+                    z, p["w1"], p["w3"], p["w2"], self.compute_dtype), None
         with device_scope(profiling.MOE):
             z = rms_norm(h, p["ffn_norm"], self.norm_eps)
             routing = moe.route(
@@ -252,40 +239,33 @@ class LFM2MoE:
         return q.astype(cd), k.astype(cd), v
 
     def _attend(self, q, k, v, mask):
-        """q [B, Tq, H, D], k/v [B, Tk, KV, D], mask [B or 1, Tq, Tk] ->
-        [B, Tq, H * D] float32; one KV head serves H / KV query heads."""
-        B, Tq, H, D = q.shape
-        KV = k.shape[2]
-        q = q.reshape(B, Tq, KV, H // KV, D)
-        scores = jnp.einsum("bqkgd,bskd->bkgqs", q, k,
-                            preferred_element_type=jnp.float32) / math.sqrt(D)
-        scores = jnp.where(mask[:, None, None, :, :], scores, -jnp.inf)
-        probs = jax.nn.softmax(scores, axis=-1).astype(self.compute_dtype)
-        out = jnp.einsum("bkgqs,bskd->bqkgd", probs, v,
-                         preferred_element_type=jnp.float32)
-        return out.reshape(B, Tq, H * D)
+        return layers.attend(q, k, v, mask, self.compute_dtype)
 
     def _head(self, params, x):
         """x [N, d] float32 -> PolicyValue over the held vocabulary."""
         with device_scope(profiling.HEAD):
             h = rms_norm(x, params["final"]["norm"], self.norm_eps)
-            logits = jnp.dot(
-                h.astype(self.compute_dtype),
-                params["embed"]["table"].astype(self.compute_dtype).T,
-                preferred_element_type=jnp.float32,
-            )
-            value = jnp.dot(
-                h, params["value"]["kernel"],
-                precision=jax.lax.Precision.HIGHEST,
-            )[:, 0] + params["value"]["bias"][0]
+            logits, value = layers.tied_head(
+                h, params["embed"]["table"], params["value"],
+                self.compute_dtype)
             return PolicyValue(logits=logits, value=value)
 
     def _embed(self, params, tokens):
-        with device_scope(profiling.EMBED):
-            # rounded to the compute type whichever table it is read from
-            # (float32 in the learner, the rollout's snapshot): one value
-            return params["embed"]["table"][tokens].astype(
-                self.compute_dtype).astype(jnp.float32)
+        return layers.embed_rows(
+            params["embed"]["table"], tokens, self.compute_dtype)
+
+    def epoch_stats(self, metrics: dict) -> dict:
+        """An epoch's scalars from the step's metrics of this policy."""
+        held = np.asarray(metrics["moe_tokens_per_expert"])
+        return {
+            # how evenly the router loads the experts held here: the fullest
+            # one's tokens over the mean, in the worst layer
+            "moe_load_max_over_mean": float(np.max(
+                held.max(axis=-1) / np.maximum(held.mean(axis=-1), 1e-9))),
+            # blocks of sorted rows the expert layers ran beyond their first
+            # (ops/moe.py): 0 while the rows routed here fit the bound
+            "moe_overflow_blocks": float(np.sum(metrics["moe_overflow_blocks"])),
+        }
 
     # -- the rollout's decode step ---------------------------------------------
     def init_carry(self, batch: int) -> Carry:

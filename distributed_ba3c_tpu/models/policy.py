@@ -20,8 +20,15 @@ A policy is what the trainers call to turn observations into
       model.rollout_params(params) -> params     what ``step`` is served
           from all through one rollout (a bfloat16 snapshot of the matrices)
 
-  ``aux`` is a dict of counters (``moe_tokens_per_expert``, ``moe_overflow_blocks``);
-  ``step`` and ``unroll`` agree position by position (tests/test_lfm2_moe.py).
+  ``aux`` is a dict of whatever the policy counts in its learner (summed
+  over chunks and shards into the step's metrics; may be empty); ``step``
+  and ``unroll`` agree position by position (tests/test_lfm2_moe.py,
+  tests/test_phi4_flash.py). Optional, for the trainer's reports:
+
+      model.carry_gauges(carry) -> dict          of the carry as a rollout left
+          it (the largest over the shards goes into the step's metrics)
+      model.epoch_stats(metrics) -> dict         an epoch's scalars of the
+          policy's own counters and gauges, for stat.json
 
 Only the fused trainer drives a policy that carries state; every other
 trainer refuses one through :func:`refuse_carry`. docs/policy_protocol.md.
@@ -72,7 +79,15 @@ def _lfm2_moe(cfg, cut=None):
     return LFM2MoE(num_actions=cfg.num_actions, **cut_fields(cut))
 
 
-MODELS: Dict[str, Callable] = {DEFAULT_MODEL: _ba3cnet, "lfm2-moe": _lfm2_moe}
+def _phi4_flash(cfg, cut=None):
+    from distributed_ba3c_tpu.models.phi4_flash import Phi4Flash, cut_fields
+
+    return Phi4Flash(num_actions=cfg.num_actions, **cut_fields(cut))
+
+
+MODELS: Dict[str, Callable] = {
+    DEFAULT_MODEL: _ba3cnet, "lfm2-moe": _lfm2_moe, "phi4-flash": _phi4_flash,
+}
 
 
 def build_model(name: str, cfg, cut: str | None = None):

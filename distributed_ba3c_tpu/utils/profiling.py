@@ -57,10 +57,11 @@ SCOPES = (
     ROLLOUT_ENV_STEP, ROLLOUT_RENDER, ROLLOUT_STACK, RETURNS,
     RETURNS_SUB_BATCH, LEARNER, LEARNER_LOSS, GRAD_REDUCE, OPTIMIZER, METRICS,
 )
-# -- scopes inside a layered sequence policy (models/lfm2_moe.py, ops/moe.py),
-# opened under ``rollout/policy`` (the decode step) and under ``learner`` (the
-# unroll) alike; a conv policy's step has none of them, so they are kept
-# apart from SCOPES, which every fused step carries
+# -- scopes inside a layered sequence policy (models/lfm2_moe.py, ops/moe.py,
+# models/phi4_flash.py, ops/ssm.py), opened under ``rollout/policy`` (the
+# decode step) and under ``learner`` (the unroll) alike; a conv policy's step
+# has none of them, so they are kept apart from SCOPES, which every fused
+# step carries
 EMBED = "embed"
 OP_CONV = "op_conv"
 OP_ATTN = "op_attn"
@@ -75,10 +76,35 @@ MOE_EXPERTS = "moe/experts"
 MOE_EXPERTS_GMM = "moe/experts/gmm"
 MOE_COMBINE = "moe/combine"
 HEAD = "head"
-POLICY_LAYERS = (
+#: a state-space (Mamba) mixer and its parts: norm + input projection, the
+#: causal depthwise conv + silu, the selective scan alone (ops/ssm.py; the
+#: ``dt``/``B``/``C`` projections lie in ``op_ssm`` outside the four), gate +
+#: output projection
+OP_SSM = "op_ssm"
+OP_SSM_IN_PROJ = "op_ssm/in_proj"
+OP_SSM_CONV = "op_ssm/conv"
+OP_SSM_SCAN = "op_ssm/scan"
+OP_SSM_OUT_PROJ = "op_ssm/out_proj"
+#: a gated memory unit: reads what a state-space layer before it produced
+OP_GMU = "op_gmu"
+#: attention over a ring of the last ``window`` positions; over every past
+#: position, writing the K/V that later layers share; queries only, over
+#: that shared K/V
+OP_ATTN_WINDOW = "op_attn_window"
+OP_ATTN_FULL = "op_attn_full"
+OP_ATTN_CROSS = "op_attn_cross"
+#: the layers each sequence policy opens (models/lfm2_moe.py with ops/moe.py;
+#: models/phi4_flash.py with ops/ssm.py): a step holds its own policy's
+LFM2_LAYERS = (
     EMBED, OP_CONV, OP_ATTN, FFN_DENSE, MOE, MOE_ROUTER, MOE_DISPATCH,
     MOE_EXPERTS, MOE_EXPERTS_GMM, MOE_COMBINE, HEAD,
 )
+PHI4_FLASH_LAYERS = (
+    EMBED, OP_SSM, OP_SSM_IN_PROJ, OP_SSM_CONV, OP_SSM_SCAN, OP_SSM_OUT_PROJ,
+    OP_GMU, OP_ATTN_WINDOW, OP_ATTN_FULL, OP_ATTN_CROSS, FFN_DENSE, HEAD,
+)
+POLICY_LAYERS = LFM2_LAYERS + tuple(
+    layer for layer in PHI4_FLASH_LAYERS if layer not in LFM2_LAYERS)
 #: the rollout's once-an-update bfloat16 snapshot of the matrix weights
 ROLLOUT_WEIGHTS_BF16 = "rollout/weights_bf16"
 

@@ -637,7 +637,12 @@ def test_every_sequence_scope_is_in_the_compiled_step(compiled_op_names, scope):
     found = {profiling.scope_of(name) for name in compiled_op_names}
     there = any(s is not None and (s == scope or s.startswith(scope + "/"))
                 for s in found)
-    assert there == (scope not in _BY_KERNEL), scope
+    # this policy's layers, and of those all but the kernel's
+    mine = scope == profiling.ROLLOUT_WEIGHTS_BF16 or any(
+        scope == profiling.policy_scope(under, layer)
+        for under in (profiling.ROLLOUT_POLICY, profiling.LEARNER)
+        for layer in profiling.LFM2_LAYERS)
+    assert there == (mine and scope not in _BY_KERNEL), scope
 
 
 def test_the_kernels_scope_is_in_the_learner_where_the_kernel_is_lowered():
