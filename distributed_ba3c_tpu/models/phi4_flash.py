@@ -50,7 +50,10 @@ state-space state and its scan, heads' outputs; bfloat16 matrix operands
 with float32 accumulation (``models/layers.py:mm``). A K/V pair is stored
 128 wide, ``[k_1 ; k_2]`` and ``[v_1 ; v_2]``: a query of 64 is laid beside
 64 zeros on its own half, so one masked grouped-query attention
-(``layers.attend``) over lane-wide rows gives both softmaxes of a pair. The
+(``layers.attend``) over lane-wide rows gives both softmaxes of a pair. A
+decode step attends over the rows of its carry's buffers up to the position
+and no further (``ops/decode_attention.py``: a Pallas kernel on a TPU at
+whole-lane widths, ``layers.attend`` under the mask anywhere else). The
 policy protocol is models/policy.py's.
 """
 
@@ -66,7 +69,7 @@ import jax.numpy as jnp
 from distributed_ba3c_tpu.models import layers
 from distributed_ba3c_tpu.models.a3c import PolicyValue
 from distributed_ba3c_tpu.models.layers import layer_norm, rms_norm
-from distributed_ba3c_tpu.ops import ssm
+from distributed_ba3c_tpu.ops import decode_attention, ssm
 from distributed_ba3c_tpu.utils import profiling
 from distributed_ba3c_tpu.utils.profiling import device_scope
 
@@ -127,9 +130,10 @@ class Carry(NamedTuple):
     pos: jax.Array     # [B] int32 position in the episode
     ssm: Tuple         # per Mamba layer (state [B, n, c] f32, the last
                        # d_conv - 1 inputs of the conv [B, 3, c] f32)
-    ring: Tuple        # per window layer (k, v), each [B, KV/2, window, 2D]:
+    ring: Tuple        # per window layer (k, v), each [B, window, KV/2 * 2D]
+                       # (a position's pairs side by side in one row):
                        # position p lies in slot p % window
-    shared_kv: Tuple   # () or the full layer's (k, v), each [B, KV/2, P, 2D]:
+    shared_kv: Tuple   # () or the full layer's (k, v), each [B, P, KV/2 * 2D]:
                        # written by that layer, read by it and every cross layer
 
 
@@ -301,23 +305,44 @@ class Phi4Flash:
         q = self._mm(a, p["wq"]) + p["bq"]
         return q.reshape(B, T, -1, self.head_dim).astype(self.compute_dtype)
 
-    def _diff_attend(self, p, i: int, q, k, v, mask):
-        """Differential attention of held layer ``i``. q [B, Tq, H, D]; k, v
-        [B, Tk, KV/2, 2D]; mask [B or 1, Tq, Tk] -> [B, Tq, d] float32."""
+    def _on_halves(self, q):
+        """q [B, Tq, H, D] -> [B, Tq, H, 2D]: head 2p of a pair reads the
+        first halves of a K/V pair, head 2p+1 the second, so each query is
+        laid on its own half beside zeros."""
         B, Tq, H, D = q.shape
-        # head 2p of a pair reads the first halves of a K/V pair, head 2p+1
-        # the second: each query laid on its own half beside zeros
         halves = jnp.eye(2, dtype=q.dtype)[:, :, None]
-        q = (q.reshape(B, Tq, H // 2, 2, 1, D) * halves).reshape(B, Tq, H, 2 * D)
-        out = layers.attend(q, k, v, mask, self.compute_dtype,
-                            scale=1.0 / math.sqrt(D))
-        out = out.reshape(B, Tq, H // 2, 2, 2 * D)
+        return (q.reshape(B, Tq, H // 2, 2, 1, D) * halves).reshape(B, Tq, H, 2 * D)
+
+    def _diff_out(self, p, i: int, out):
+        """What follows the two softmaxes of held layer ``i``'s pairs: out
+        [B, Tq, H * 2D] float32 (a head's softmax over ``[v_1 ; v_2]``) ->
+        their difference under ``lam``, the sub-norm, ``W_o``: [B, Tq, d]."""
+        B, Tq, _ = out.shape
+        D = self.head_dim
+        out = out.reshape(B, Tq, -1, 2, 2 * D)
         start = lambda_init(self.layer_ids[i])
         lam = (jnp.exp(jnp.sum(p["lam_q1"] * p["lam_k1"]))
                - jnp.exp(jnp.sum(p["lam_q2"] * p["lam_k2"])) + start)
         o = out[..., 0, :] - lam * out[..., 1, :]
         o = rms_norm(o, p["sub_norm"], self.layer_norm_eps) * (1.0 - start)
-        return self._mm(o.reshape(B, Tq, H * D), p["wo"]) + p["bo"]
+        return self._mm(o.reshape(B, Tq, -1), p["wo"]) + p["bo"]
+
+    def _diff_attend(self, p, i: int, q, k, v, mask):
+        """Differential attention of held layer ``i``. q [B, Tq, H, D]; k, v
+        [B, Tk, KV/2, 2D]; mask [B or 1, Tq, Tk] -> [B, Tq, d] float32."""
+        out = layers.attend(self._on_halves(q), k, v, mask, self.compute_dtype,
+                            scale=1.0 / math.sqrt(self.head_dim))
+        return self._diff_out(p, i, out)
+
+    def _diff_decode(self, p, i: int, q, k, v, length):
+        """The same for a decode step's one query an env, over rows ``[0,
+        length)`` of an env's buffers. q [B, 1, H, D]; k, v [B, rows, KV/2 *
+        2D]; length [B] -> [B, d] float32."""
+        B = q.shape[0]
+        out = decode_attention.decode_attend(
+            self._on_halves(q)[:, 0], k, v, length,
+            scale=1.0 / math.sqrt(self.head_dim))
+        return self._diff_out(p, i, out.reshape(B, 1, -1))[:, 0]
 
     def _head(self, params, x):
         """x [N, d] float32 -> PolicyValue over the held vocabulary."""
@@ -335,11 +360,15 @@ class Phi4Flash:
 
     # -- the rollout's decode step ---------------------------------------------
     def _kv_shape(self, batch: int, rows: int):
-        # pairs before positions: with positions first the compiled decode
-        # step copied the whole buffer into another order for its product,
-        # every step (read off the program compiled for a v5e, PR 31); in
-        # this shape the scatter and the products share one layout
-        return (batch, self.num_key_value_heads // 2, rows, 2 * self.head_dim)
+        # a position's pairs side by side in ONE row of whole lanes: the
+        # scatter that writes a position and the kernel that reads blocks
+        # of positions (ops/decode_attention.py) then share the default
+        # layout, and a row is all data. Read off the program compiled for
+        # a v5e: with pairs an axis of their own the compiler kept the
+        # buffers positions-major for the scatter, ten pairs in tiles of
+        # sixteen, and copied them whole into the kernel's order every step
+        # (PR 33; PR 31 met the same copy the other way round)
+        return (batch, rows, self.num_key_value_heads * self.head_dim)
 
     def init_carry(self, batch: int) -> Carry:
         kinds = self.layer_kinds
@@ -399,11 +428,9 @@ class Phi4Flash:
         shared_kv = carry.shared_kv
         memory = None
 
-        def write(cache, at, new):  # in place: one row a pair an env
-            return cache.at[rows, :, at].set(
-                new[:, 0], indices_are_sorted=True, unique_indices=True)
-
-        by_position = lambda cache: jnp.swapaxes(cache, 1, 2)  # noqa: E731
+        def write(cache, at, new):  # in place: one row an env
+            return cache.at[rows, at].set(
+                new.reshape(B, -1), indices_are_sorted=True, unique_indices=True)
 
         for i, kind in enumerate(self.layer_kinds):
             p = params[self.layer_name(i)]
@@ -438,11 +465,9 @@ class Phi4Flash:
                     # slots up to the position are this episode's; from
                     # position window - 1 on every slot is one of the last
                     # ``window`` positions
-                    mask = (jnp.arange(self.sliding_window)[None, None, :]
-                            <= pos[:, None, None])
-                    h = x + self._diff_attend(
-                        p, i, q, by_position(k_ring), by_position(v_ring),
-                        mask)[:, 0]
+                    h = x + self._diff_decode(
+                        p, i, q, k_ring, v_ring,
+                        jnp.minimum(pos + 1, self.sliding_window))
                     ring_out.append((k_ring, v_ring))
             else:
                 scope = (profiling.OP_ATTN_FULL if kind == FULL
@@ -455,10 +480,7 @@ class Phi4Flash:
                                      write(shared_kv[1], pos, v))
                     else:
                         q = self._q(p, a)
-                    mask = (jnp.arange(self.max_positions)[None, None, :]
-                            <= pos[:, None, None])
-                    h = x + self._diff_attend(
-                        p, i, q, *map(by_position, shared_kv), mask)[:, 0]
+                    h = x + self._diff_decode(p, i, q, *shared_kv, pos + 1)
             x = self._ffn(p, h)
         return self._head(params, x), Carry(
             pos=pos + 1, ssm=tuple(ssm_out), ring=tuple(ring_out),

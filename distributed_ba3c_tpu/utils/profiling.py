@@ -93,6 +93,14 @@ OP_GMU = "op_gmu"
 OP_ATTN_WINDOW = "op_attn_window"
 OP_ATTN_FULL = "op_attn_full"
 OP_ATTN_CROSS = "op_attn_cross"
+#: open only round the Pallas kernel of a decode step's attention
+#: (ops/decode_attention.py), inside each of the three above: time here says
+#: that the rows up to the position were read, none that whole buffers went
+#: to ``layers.attend`` under a mask (another backend, a small cut)
+DECODE_ATTEND = "decode_attend"
+OP_ATTN_DECODE = tuple(
+    f"{layer}/{DECODE_ATTEND}"
+    for layer in (OP_ATTN_WINDOW, OP_ATTN_FULL, OP_ATTN_CROSS))
 #: the layers each sequence policy opens (models/lfm2_moe.py with ops/moe.py;
 #: models/phi4_flash.py with ops/ssm.py): a step holds its own policy's
 LFM2_LAYERS = (
@@ -101,7 +109,8 @@ LFM2_LAYERS = (
 )
 PHI4_FLASH_LAYERS = (
     EMBED, OP_SSM, OP_SSM_IN_PROJ, OP_SSM_CONV, OP_SSM_SCAN, OP_SSM_OUT_PROJ,
-    OP_GMU, OP_ATTN_WINDOW, OP_ATTN_FULL, OP_ATTN_CROSS, FFN_DENSE, HEAD,
+    OP_GMU, OP_ATTN_WINDOW, OP_ATTN_FULL, OP_ATTN_CROSS, *OP_ATTN_DECODE,
+    FFN_DENSE, HEAD,
 )
 POLICY_LAYERS = LFM2_LAYERS + tuple(
     layer for layer in PHI4_FLASH_LAYERS if layer not in LFM2_LAYERS)

@@ -1,0 +1,297 @@
+"""A decode step's attention over the rows up to a length
+(ops/decode_attention.py): the Pallas kernel under Pallas's interpreter
+against ``layers.attend`` under the mask ``row < length``, lengths on and
+beside a block's edge and another for every env, rows past the length
+poisoned, the path chosen from backend and shapes, the block from the
+shapes, and the hybrid's decode compiled for a described v5e: the kernel
+under the three attention scopes and no whole K/V buffer moved in the loop.
+"""
+
+import os
+import re
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from distributed_ba3c_tpu.models import layers  # noqa: E402
+from distributed_ba3c_tpu.models.phi4_flash import Phi4Flash  # noqa: E402
+from distributed_ba3c_tpu.ops import decode_attention as da  # noqa: E402
+from distributed_ba3c_tpu.utils import profiling  # noqa: E402
+from distributed_ba3c_tpu.utils.profiling import device_scope  # noqa: E402
+
+# 4 queries a K/V pair, as the hybrid's pairs have; four blocks of 128 rows
+B, H, G, W, ROWS, BLOCK = 6, 8, 2, 128, 512, 128
+SCALE = 0.125
+LENGTHS = {
+    "one": (1,) * B,
+    "a-block-less-one": (BLOCK - 1,) * B,
+    "a-block": (BLOCK,) * B,
+    "a-block-and-one": (BLOCK + 1,) * B,
+    "the-whole-buffer": (ROWS,) * B,
+    "every-env-another": (1, BLOCK - 1, BLOCK, BLOCK + 1, 300, ROWS),
+}
+TOL = {jnp.float32: 2e-6, jnp.bfloat16: 0.01}
+
+
+@pytest.fixture
+def interpreted(monkeypatch):
+    monkeypatch.setattr(da, "INTERPRET", True)
+
+
+@pytest.fixture
+def blocks_of_128(monkeypatch):
+    """Four blocks a buffer, whatever the type's bytes a row."""
+    monkeypatch.setattr(da, "block_rows", lambda rows, row_bytes: BLOCK)
+
+
+def _operands(dtype, seed=0):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 3)
+    q = jax.random.normal(keys[0], (B, H, W), dtype)
+    k = jax.random.normal(keys[1], (B, ROWS, G * W), dtype)
+    v = jax.random.normal(keys[2], (B, ROWS, G * W), dtype)
+    return q, k, v
+
+
+def _masked(q, k, v, length):
+    """``layers.attend`` over whole buffers under the mask."""
+    rows = k.shape[1]
+    groups = k.shape[2] // q.shape[2]
+    mask = jnp.arange(rows)[None, None, :] < length[:, None, None]
+    shape = (k.shape[0], rows, groups, q.shape[2])
+    return layers.attend(q[:, None], k.reshape(shape), v.reshape(shape), mask,
+                         v.dtype, scale=SCALE).reshape(q.shape)
+
+
+def _gap(a, b):
+    return float(jnp.abs(a.astype(jnp.float32) - b.astype(jnp.float32)).max())
+
+
+def _has_kernel(fn, *args) -> bool:
+    return "pallas_call" in str(jax.make_jaxpr(fn)(*args))
+
+
+# -- the kernel in value ----------------------------------------------------------
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("case", LENGTHS)
+def test_the_kernel_is_attend_under_the_mask(interpreted, blocks_of_128, case, dtype):
+    q, k, v = _operands(dtype)
+    length = jnp.asarray(LENGTHS[case], jnp.int32)
+    assert da._block_of(q, k) == BLOCK
+    got = da.decode_attend(q, k, v, length, SCALE)
+    want = _masked(q, k, v, length)
+    assert got.dtype == jnp.float32 and got.shape == (B, H, W)
+    assert _gap(got, want) <= TOL[dtype] * max(float(jnp.abs(want).max()), 1.0)
+
+
+@pytest.mark.parametrize("kib,block", [(128, 128), (256, 256), (768, 512)])
+def test_the_block_does_not_change_the_value(interpreted, monkeypatch, kib, block):
+    monkeypatch.setattr(da, "BLOCK_BYTES", kib * 2**10)
+    q, k, v = _operands(jnp.float32, seed=1)
+    assert da._block_of(q, k) == block
+    length = jnp.asarray(LENGTHS["every-env-another"], jnp.int32)
+    want = _masked(q, k, v, length)
+    assert _gap(da.decode_attend(q, k, v, length, SCALE), want) <= 2e-6 * float(
+        jnp.abs(want).max())
+
+
+# -- rows at or past the length, poisoned -----------------------------------------
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("case", ["one", "a-block-less-one", "a-block",
+                                  "a-block-and-one", "every-env-another"])
+def test_rows_past_the_length_are_not_read_nor_weighed(
+        interpreted, blocks_of_128, case, dtype):
+    """NaN in every row of the blocks wholly past an env's length (they are
+    not fetched: a product with a probability of 0 would still be NaN);
+    1e30 in the rows past it inside the block that holds the boundary (they
+    are masked: their scores would win every maximum)."""
+    q, k, v = _operands(dtype, seed=2)
+    length = jnp.asarray(LENGTHS[case], jnp.int32)
+    row = jnp.arange(ROWS)[None, :, None]
+    live_blocks = (length[:, None, None] + BLOCK - 1) // BLOCK * BLOCK
+    poison = lambda x: jnp.where(  # noqa: E731
+        row >= live_blocks, jnp.nan,
+        jnp.where(row >= length[:, None, None], 1e30, x.astype(jnp.float32))
+    ).astype(dtype)
+    got = da.decode_attend(q, poison(k), poison(v), length, SCALE)
+    want = _masked(q, k, v, length)
+    assert np.isfinite(np.asarray(got)).all()
+    assert _gap(got, want) <= TOL[dtype] * max(float(jnp.abs(want).max()), 1.0)
+    if case != "a-block":  # and the plain path does read them
+        assert not np.isfinite(np.asarray(
+            _masked(q, poison(k), poison(v), length))).all()
+
+
+# -- which path runs is read off the backend and the shapes -----------------------
+def test_off_the_tpu_the_op_is_attend_under_the_mask():
+    assert jax.default_backend() != "tpu" and not da.INTERPRET
+    q, k, v = _operands(jnp.float32)
+    length = jnp.asarray(LENGTHS["every-env-another"], jnp.int32)
+    fn = lambda q, k, v: da.decode_attend(q, k, v, length, SCALE)  # noqa: E731
+    assert not _has_kernel(fn, q, k, v)
+    assert _gap(fn(q, k, v), _masked(q, k, v, length)) == 0.0
+
+
+@pytest.mark.parametrize("heads,width,rows,kernel", [
+    (8, 128, 512, True), (16, 128, 256, True), (2, 128, 128, True),
+    (8, 64, 512, False), (8, 128, 200, False), (8, 16, 24, False),
+    (32, 128, 512, False)],  # 16 queries a group: more than a tile's rows
+    ids=lambda x: str(x))
+def test_the_kernel_runs_only_on_whole_lanes_and_whole_blocks(
+        interpreted, heads, width, rows, kernel):
+    q = jnp.zeros((2, heads, width), jnp.bfloat16)
+    k = v = jnp.zeros((2, rows, 2 * width), jnp.bfloat16)
+    length = jnp.ones(2, jnp.int32)
+    assert _has_kernel(
+        lambda q, k, v: da.decode_attend(q, k, v, length, SCALE), q, k, v) is kernel
+    assert (da._block_of(q, k) is not None) is kernel
+
+
+def test_on_a_tpu_the_op_is_the_kernel(monkeypatch):
+    monkeypatch.setattr(da.jax, "default_backend", lambda: "tpu")
+    q, k = jnp.zeros((2, H, W), jnp.bfloat16), jnp.zeros((2, ROWS, G * W), jnp.bfloat16)
+    assert da._block_of(q, k) == ROWS  # 256 KB a buffer an env: one block
+    assert da._block_of(q[:, :, :64], k[:, :, :G * 64]) is None
+
+
+# -- the block is a function of the shapes ----------------------------------------
+@pytest.mark.parametrize("rows,row_bytes,block", [
+    (1024, 2560, 256),   # the hybrid's shared K/V: ten pairs of 128 in bf16
+    (512, 2560, 256),    # its ring
+    (1024, 256, 1024), (4096, 2560, 256), (384, 2560, 128), (1024, 8192, None),
+    (100, 256, None)])
+def test_a_block_divides_the_rows_and_fits(rows, row_bytes, block):
+    assert da.block_rows(rows, row_bytes) == block
+    if block:
+        assert rows % block == 0 and block % 128 == 0
+        assert block * row_bytes <= da.BLOCK_BYTES
+
+
+# -- Mosaic compiles it at the cell's shapes (no chip attached) -------------------
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure to describe is a skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_compile_cache():
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.timeout(300)
+@pytest.mark.parametrize("rows", [1024, 512], ids=["shared-kv", "ring"])
+def test_the_kernel_compiles_for_a_v5e_at_the_cells_shapes(
+        one_chip, no_compile_cache, monkeypatch, rows):
+    monkeypatch.setattr(da, "_backend_runs_mosaic", lambda: True)
+
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    text = jax.jit(lambda q, k, v, n: da.decode_attend(q, k, v, n, SCALE)).lower(
+        shape(32, 40, 128), shape(32, rows, 1280), shape(32, rows, 1280),
+        shape(32, dtype=jnp.int32)).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+
+
+#: an instruction that moves a whole K/V buffer of the cell (or a quarter of
+#: one: the compiler stages a buffer through fast memory in four slices)
+_MOVES = re.compile(
+    r"bf16\[(?:32|16|8),(?:1024|512),1280\]\S* "
+    r"(?:copy|copy-start|slice-start|transpose)\(")
+_ATTENTION = (profiling.OP_ATTN_WINDOW, profiling.OP_ATTN_FULL,
+              profiling.OP_ATTN_CROSS)
+
+
+def _loop_body(text):
+    """The lines of the computation that holds the kernel calls: the scan's
+    body (the compiler's own asynchronous copies carry no ``op_name`` that
+    would say where they stand)."""
+    lines, body = text.splitlines(), []
+    for line in lines:
+        if line.startswith(("%", "ENTRY")) and line.rstrip().endswith("{"):
+            body = []
+        body.append(line)
+        if line.startswith("}") and any("tpu_custom_call" in b for b in body):
+            return body
+    return []
+
+
+@pytest.fixture(scope="module")
+def compiled_decode(one_chip, no_compile_cache):
+    """The hybrid's decode at the cell's size (32 envs, 1,024 positions, the
+    published widths, the bf16 snapshot) in a scan under the trainer's
+    scopes, compiled for the described v5e."""
+    model = Phi4Flash(max_positions=1024)
+    placed = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), tree)
+    params = placed(jax.eval_shape(
+        lambda key: model.rollout_params(model.init_params(key)),
+        jax.random.PRNGKey(0)))
+    carry = placed(jax.eval_shape(lambda: model.init_carry(32)))
+    tokens = jax.ShapeDtypeStruct((1024, 32), jnp.int32, sharding=one_chip)
+    fresh = jax.ShapeDtypeStruct((1024, 32), jnp.bool_, sharding=one_chip)
+
+    def episode(params, carry, tokens, fresh):
+        def one(carry, x):
+            with device_scope(profiling.ROLLOUT_POLICY):
+                out, carry = model.step(params, x[0], carry, x[1])
+            return carry, out.value
+
+        with device_scope(profiling.ROLLOUT):
+            return jax.lax.scan(one, carry, (tokens, fresh))
+
+    before = da._backend_runs_mosaic
+    da._backend_runs_mosaic = lambda: True
+    try:
+        return jax.jit(episode, donate_argnums=1).lower(
+            params, carry, tokens, fresh).compile().as_text()
+    finally:
+        da._backend_runs_mosaic = before
+
+
+@pytest.mark.timeout(600)
+@pytest.mark.parametrize("layer", _ATTENTION)
+def test_the_compiled_decode_holds_the_kernel_under_an_attention_scope(
+        compiled_decode, layer):
+    names = re.findall(
+        r'custom_call_target="tpu_custom_call".*?op_name="([^"]*)"', compiled_decode)
+    assert len(names) == 3
+    scope = profiling.policy_scope(
+        profiling.ROLLOUT_POLICY, f"{layer}/{profiling.DECODE_ATTEND}")
+    assert scope in profiling.ALL_SCOPES
+    assert sum(profiling.scope_of(n) == scope for n in names) == 1, names
+
+
+@pytest.mark.timeout(600)
+def test_the_compiled_decode_moves_no_whole_buffer_in_its_loop(compiled_decode):
+    """The scatter that writes a position updates in place, and the kernel
+    reads the buffers where they lie: no copy, relayout or staging through
+    fast memory of a whole K/V buffer an iteration (PERF.md, PR 31 and 33)."""
+    body = _loop_body(compiled_decode)
+    assert len(body) > 100 and any("decode_attend" in line for line in body)
+    moved = [line.strip()[:160] for line in body if _MOVES.search(line)]
+    assert not moved, moved
+    scatters = [line for line in compiled_decode.splitlines()
+                if re.search(r"= bf16\[32,(1024|512),1280\]\S* scatter\(", line)]
+    assert len(scatters) == 4  # K and V of the ring and of the shared buffer

@@ -8,6 +8,7 @@ the fused step's gradient, the scopes, the refusals.
 """
 
 import dataclasses
+import functools
 import json
 import os
 import re
@@ -33,7 +34,7 @@ from distributed_ba3c_tpu.fused.loop import (  # noqa: E402
 from distributed_ba3c_tpu.models import phi4_flash, policy  # noqa: E402
 from distributed_ba3c_tpu.models.phi4_flash import (  # noqa: E402
     CROSS, CUTS, FULL, GMU, MAMBA, WINDOW, Phi4Flash)
-from distributed_ba3c_tpu.ops import ssm  # noqa: E402
+from distributed_ba3c_tpu.ops import decode_attention, ssm  # noqa: E402
 from distributed_ba3c_tpu.ops.gradproc import make_optimizer  # noqa: E402
 from distributed_ba3c_tpu.parallel.mesh import make_mesh  # noqa: E402
 from distributed_ba3c_tpu.utils import profiling  # noqa: E402
@@ -82,7 +83,7 @@ def decode(model, params, tokens, fresh_at=()):
     positions in ``fresh_at`` open a new episode."""
     B, T = tokens.shape
     fresh = jnp.zeros((T, B), bool).at[0].set(True)
-    for t in fresh_at:
+    for t in fresh_at:  # a position: every env; (position, env): that env
         fresh = fresh.at[t].set(True)
 
     def one(carry, x):
@@ -261,7 +262,7 @@ def test_fresh_resets_the_position_and_the_state_space_state_alone():
         np.testing.assert_allclose(tail[0], tail1[0], atol=1e-6)
         assert float(jnp.abs(state[1] - state1[1]).max()) > 1e-4
     # the rings and the shared K/V keep the last episode's rows (masked)
-    assert float(jnp.abs(after.shared_kv[0][0, :, 1:5]).max()) > 0
+    assert float(jnp.abs(after.shared_kv[0][0, 1:5]).max()) > 0
 
 
 def test_the_carrys_bytes_by_kind_are_its_shapes():
@@ -273,8 +274,8 @@ def test_the_carrys_bytes_by_kind_are_its_shapes():
         size(carry.ssm), size(carry.ring), size(carry.shared_kv), 4)
     assert len(carry.ssm) == 2 and len(carry.ring) == 1 and len(carry.shared_kv) == 2
     assert carry.ssm[0][0].shape == (1, 4, 128) and carry.ssm[0][1].shape == (1, 3, 128)
-    assert carry.ring[0][0].shape == (1, 2, WINDOW_LEN, 16)
-    assert carry.shared_kv[0].shape == (1, 2, EPISODE, 16)
+    assert carry.ring[0][0].shape == (1, WINDOW_LEN, 2 * 16)  # a slot's two pairs
+    assert carry.shared_kv[0].shape == (1, EPISODE, 2 * 16)
     # at the published widths: 16 x 5120 float32 a state, 1,280 bfloat16 a row
     full = Phi4Flash().carry_bytes()
     assert full == (2 * (16 * 5120 + 3 * 5120) * 4, 2 * 512 * 1280 * 2,
@@ -303,6 +304,88 @@ def test_the_ring_equals_a_banded_mask(window):
     if window < EPISODE:  # and a mask one wider is another function
         wider, _ = reference.forward(params, tokens, dict(spec, window=window + 1))
         assert float(jnp.abs(wider - want).max()) > 1e-3 * scale
+
+
+# -- the same through the kernel (ops/decode_attention.py) --------------------------
+# A whole-lane cut the CPU runs under Pallas's interpreter: pairs of 2 x 64 =
+# 128 lanes, 4 queries a pair, a ring of 128 slots = one block, 256 positions
+# = two blocks of the shared K/V, so the ring wraps and the shared buffer is
+# read one block, then two.
+LANES_EPISODE, LANES_WINDOW = 256, 128
+LANES_CONFIG = dict(
+    TINY_CONFIG, hidden_size=256, num_attention_heads=4, num_key_value_heads=2,
+    sliding_window=LANES_WINDOW)
+LANES_SPEC = reference.spec_of(LANES_CONFIG)
+
+
+def lanes(compute_dtype=jnp.float32, **kw) -> Phi4Flash:
+    fields = dict(CUTS["tiny"], hidden_size=256, num_attention_heads=4,
+                  num_key_value_heads=2, head_dim=64,
+                  sliding_window=LANES_WINDOW, max_positions=LANES_EPISODE)
+    return tiny(compute_dtype, **dict(fields, **kw))
+
+
+@pytest.fixture
+def kernel_path(monkeypatch):
+    """The decode's attention in the Pallas kernel, interpreted, in blocks
+    of 128 rows."""
+    monkeypatch.setattr(decode_attention, "INTERPRET", True)
+    monkeypatch.setattr(decode_attention, "block_rows", lambda rows, row_bytes: 128)
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5), (jnp.bfloat16, 0.03)])
+def test_step_through_the_carry_is_the_unroll_with_the_kernel(kernel_path, dtype, tol):
+    """256 positions = two windows: the ring has wrapped, the shared K/V has
+    grown past its first block."""
+    model = lanes(dtype)
+    params, tokens = params_of(5, LANES_SPEC), tokens_of(6, 3, LANES_EPISODE)
+    carry = model.init_carry(3)
+    assert carry.ring[0][0].shape == (3, LANES_WINDOW, 128)
+    assert "pallas_call" in str(jax.make_jaxpr(
+        lambda p: model.step(p, tokens[:, 0], carry, jnp.ones(3, bool)))(params))
+    logits, value = jax.jit(lambda p, t: decode(model, p, t))(params, tokens)
+    out, _ = jax.jit(model.unroll)(params, tokens)
+    scale = float(jnp.abs(out.logits).max())
+    gap = jnp.abs(logits - out.logits).max(axis=(0, 2))
+    assert float(gap.max()) < tol * scale, gap
+    assert float(jnp.abs(value - out.value).max()) < 1e-3 + tol
+
+
+@pytest.mark.parametrize("window", [128, 256])
+def test_the_ring_equals_a_banded_mask_with_the_kernel(kernel_path, window):
+    """The window layer alone, decoded through a ring of ``window`` slots
+    read by the kernel up to ``min(pos + 1, window)``, against the
+    reference's ``T x T`` mask: a ring that wraps at half the episode, and
+    one that the episode just fills."""
+    model = lanes(layer_ids=(3,), sliding_window=window)
+    spec = dict(LANES_SPEC, layers=((3, WINDOW),), window=window)
+    params, tokens = params_of(11, spec), tokens_of(12, 3, LANES_EPISODE)
+    logits, _ = jax.jit(lambda p, t: decode(model, p, t))(params, tokens)
+    with jax.default_matmul_precision("highest"):
+        want, _ = reference.forward(params, tokens, spec)
+    scale = float(jnp.abs(want).max())
+    assert float(jnp.abs(logits - want).max()) < 2e-5 * scale
+    if window < LANES_EPISODE:  # and a mask one wider is another function
+        wider, _ = reference.forward(params, tokens, dict(spec, window=window + 1))
+        assert float(jnp.abs(wider - want).max()) > 1e-3 * scale
+
+
+@pytest.mark.parametrize("at", [(1, 100, 200), (129, 128, 127), (255, 3, 130)])
+def test_a_fresh_token_forgets_the_episode_before_with_the_kernel(kernel_path, at):
+    """Every env reset at a position of its own, so the envs stand at
+    different positions (and lengths, and live blocks) from there on: each
+    env's logits are those of two episodes, the second from its reset."""
+    model = lanes()
+    params, tokens = params_of(7, LANES_SPEC), tokens_of(8, 3, LANES_EPISODE)
+    logits, _ = jax.jit(lambda p, t: decode(
+        model, p, t, fresh_at=tuple(zip(at, range(3)))))(params, tokens)
+    unroll = jax.jit(model.unroll)
+    for env, a in enumerate(at):
+        first, _ = unroll(params, tokens[env:env + 1, :a])
+        second, _ = unroll(params, tokens[env:env + 1, a:])
+        want = jnp.concatenate([first.logits, second.logits], axis=1)[0]
+        assert float(jnp.abs(logits[env] - want).max()) < 1e-5 * float(
+            jnp.abs(want).max()), env
 
 
 # -- the side channels between layers ---------------------------------------------
@@ -454,19 +537,21 @@ def test_the_sequence_forms_backward_keeps_no_state_a_position():
 
 
 # -- the fused step -----------------------------------------------------------------
-def _fused(n_shards, n_envs=8, dtype=jnp.float32, grad_chunk_samples=48, seed=11):
-    env = RecallEnv(IDS, PROMPT, EPISODE)
-    cfg = BA3CConfig(num_actions=IDS, batch_size=n_envs * EPISODE // n_shards)
-    model = tiny(dtype)
+def _fused(n_shards, n_envs=8, dtype=jnp.float32, grad_chunk_samples=48, seed=11,
+           model=None, spec=SPEC):
+    model = model or tiny(dtype)
+    episode = model.max_positions
+    env = RecallEnv(IDS, PROMPT, episode)
+    cfg = BA3CConfig(num_actions=IDS, batch_size=n_envs * episode // n_shards)
     opt = make_optimizer(HYPER["learning_rate"], HYPER["adam_epsilon"],
                          HYPER["grad_clip_norm"])
     mesh = make_mesh(num_data=n_shards, num_model=1,
                      devices=jax.devices()[:n_shards])
-    step = make_fused_step(model, opt, cfg, mesh, env, EPISODE,
+    step = make_fused_step(model, opt, cfg, mesh, env, episode,
                            grad_chunk_samples=grad_chunk_samples)
     state = create_fused_state(jax.random.PRNGKey(seed), model, cfg, opt, env,
                                n_envs, n_shards=n_shards)
-    params = params_of(seed)
+    params = params_of(seed, spec)
     state = state.replace(train=state.train.replace(params=params))
     return env, cfg, model, step, state, jax.device_get(params)
 
@@ -566,6 +651,13 @@ def compiled_op_names():
     return set(re.findall(r'op_name="([^"]*)"', hlo))
 
 
+#: open only round the Pallas kernel of the decode's attention, which this
+#: small step (pairs of 16 lanes, on the CPU) does not reach
+_BY_KERNEL = tuple(profiling.policy_scope(under, layer)
+                   for under in (profiling.ROLLOUT_POLICY, profiling.LEARNER)
+                   for layer in profiling.OP_ATTN_DECODE)
+
+
 @pytest.mark.parametrize("scope", profiling.SEQUENCE_SCOPES)
 def test_a_sequence_scope_is_in_the_compiled_step_if_it_is_this_policys(
         compiled_op_names, scope):
@@ -576,7 +668,32 @@ def test_a_sequence_scope_is_in_the_compiled_step_if_it_is_this_policys(
         scope == profiling.policy_scope(under, layer)
         for under in (profiling.ROLLOUT_POLICY, profiling.LEARNER)
         for layer in profiling.PHI4_FLASH_LAYERS)
-    assert there == mine, scope
+    assert there == (mine and scope not in _BY_KERNEL), scope
+
+
+def test_the_kernels_scope_is_in_the_rollout_where_the_kernel_is_lowered(kernel_path):
+    """``rollout/policy/op_attn_{window,full,cross}/decode_attend`` holds the
+    Pallas call of a decode whose buffers are whole lanes wide (time there
+    says the rows up to the position were read); the bootstrap's one decode
+    step has it under ``returns``; the learner unrolls whole episodes and
+    never reaches it. (Pallas's interpreter binds primitives on operands of
+    mixed varying axes, which ``shard_map``'s typing refuses: off here, as
+    tests/test_lfm2_moe.py has it.)"""
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(jax, "shard_map", functools.partial(
+            jax.shard_map, check_vma=False))
+        _, _, _, step, state, _ = _fused(
+            1, n_envs=2, grad_chunk_samples=LANES_WINDOW,
+            model=lanes(max_positions=LANES_WINDOW), spec=LANES_SPEC)
+        hlo = step.audit_jit.lower(
+            step.put(state), jnp.float32(0.01), jnp.float32(1e-3)
+        ).compile().as_text()
+    names = set(re.findall(r'op_name="([^"]*)"', hlo))
+    found = {profiling.scope_of(n) for n in names}
+    for scope in _BY_KERNEL:
+        assert (scope in found) == scope.startswith(profiling.ROLLOUT_POLICY), scope
+    assert any(profiling.scope_of(n) == profiling.RETURNS
+               and profiling.DECODE_ATTEND in n for n in names)
 
 
 def test_the_learners_scan_is_marked_forward_and_backward(compiled_op_names):
