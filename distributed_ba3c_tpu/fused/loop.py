@@ -434,14 +434,19 @@ def make_fused_step(
         # is so many envs (4,096 samples = 16 envs x 256, or 4 x 1,024): its
         # forward is the policy's causal unroll, which also counts
         # (``counters``: whatever the unroll's ``aux`` holds, summed over the
-        # chunks).
+        # chunks) and may hand over loss terms of its own under
+        # ``policy.LOSS_TERMS`` (added to the total, averaged like the loss).
         def chunk_grad(p, chunk):
             states_c, actions_c, returns_c = chunk
 
             def loss_fn(pp):
-                counters = {}
+                counters, own = {}, {}
                 if sequence:
                     out, counters = model.unroll(pp, states_c)
+                    # the terms the policy owns (models/policy.py): added to
+                    # what is differentiated, whatever they are
+                    counters = dict(counters)
+                    own = counters.pop(policy.LOSS_TERMS, {})
                 else:
                     out = model.apply({"params": pp}, states_c)
                 with device_scope(profiling.LEARNER_LOSS):
@@ -452,7 +457,10 @@ def make_fused_step(
                         value_loss_coef=cfg.value_loss_coef,
                         huber_delta=cfg.value_huber_delta,
                     )
-                return loss.total, (loss, counters)
+                total = loss.total
+                for term in own.values():
+                    total = total + jnp.sum(term)
+                return total, ((loss, own), counters)
 
             with device_scope(profiling.LEARNER):
                 return jax.value_and_grad(loss_fn, has_aux=True)(p)
@@ -509,6 +517,7 @@ def make_fused_step(
                 grads = jax.tree_util.tree_map(lambda g: g / n_chunks, grads)
             aux_sum, counters = aux_sum
             aux = jax.tree_util.tree_map(lambda a: a / n_chunks, aux_sum)
+        aux, own_terms = aux
         with device_scope(profiling.GRAD_REDUCE):
             grads = jax.lax.psum(grads, DATA_AXIS)
             n_data = jax.lax.axis_size(DATA_AXIS)
@@ -545,6 +554,9 @@ def make_fused_step(
                 **grad_summaries(grads),
                 "reward_per_step": jnp.mean(rewards_t),
             }
+            # a policy's own loss terms, under its own names: means over
+            # the chunks and the shards, as the loss's parts are
+            metrics.update(own_terms)
             metrics = {
                 k: jax.lax.pmean(v, DATA_AXIS) for k, v in metrics.items()
             }

@@ -1,0 +1,479 @@
+"""Keye-VL-2.0-30B-A3B's language model (``model_type KeyeVL2``) as a
+token-sequence policy: 128 routed experts top-8 beside grouped-query
+attention that reads only the keys a learned indexer selects.
+
+Published (Kwai-Keye/Keye-VL-2.0-30B-A3B ``config.json``): 48 layers of one
+kind, hidden 2048, 32 query heads over 4 key/value heads of 128, RMSNorm
+(eps 1e-6), RoPE theta 1e7, 128 experts of width 768 with 8 a token
+(``norm_topk_prob``), no shared expert, no dense layer, an untied head over
+151,936 ids, and ``sa_config``: an indexer of 16 heads of 64 over one key
+head that keeps ``topk`` 2,048 keys a query. For position ``t`` with
+residual ``x_t``, ``z = RMSNorm(x_t)``:
+
+- **main attention**: ``q = RoPE(RMSNorm_head(W_q z))`` [32, 128], ``k =
+  RoPE(RMSNorm_head(W_k z))`` [4, 128], ``v = W_v z``; one K/V head serves
+  8 query heads; scale ``128^-0.5``; no bias;
+- **indexer** (DeepSeek-V3.2-Exp's, reading ``stop_gradient(z)``): ``q^I =
+  RoPE(W_q^I z)`` [16, 64], ``k^I = RoPE(LayerNorm(W_k^I z))`` [64], ``w =
+  W_w z * 16^-0.5 * 64^-0.5``; ``I_{t,s} = sum_j w_{t,j} ReLU(q^I_{t,j} .
+  k^I_s)`` for ``s <= t``, float32;
+- **selection**: ``S_t`` = the ``min(t + 1, 2048)`` positions ``s <= t`` of
+  largest ``I_{t,s}``, exactly, a tie to the lower position; up to position
+  2,047 that is every past position;
+- ``o_t = W_o concat_h softmax_{s in S_t}(q_h . k_s * scale) v_s``; ``x_t +=
+  o_t``; then ``z' = RMSNorm(x_t)`` and the experts of ``ops/moe.py``
+  (``softmax`` scoring, no bias): ``x_t += sum_i w_i W2_i (silu(W1_i z') *
+  W3_i z')`` over the chosen ``i`` held here;
+- final RMSNorm, an untied head over the ids held here, and the trainer's
+  float32 value head;
+- **the indexer's loss**: ``L_I = mean_t KL(p_t || softmax_{s in S_t}
+  I_{t,s})``, ``p_t`` the main attention's probabilities summed over the 32
+  heads and normalised over ``S_t``, under ``stop_gradient``. The selection
+  is no function a gradient passes: the indexer's leaves (``idx_*``) are
+  trained by ``L_I`` alone and every other leaf by the A2C loss alone. The
+  unroll hands ``indexer_loss_coef * L_I``, one a layer, to the trainer
+  under ``policy.LOSS_TERMS``.
+
+The decode step scores the live rows of the indexer's key cache, takes the
+exact top-k as a mask (``ops/topk_select.py``: the unroll's own function, so
+the two choose alike) and attends over its K/V buffers under that mask,
+where they lie (:meth:`KeyeVL2._attend_rows`). It does NOT fetch the
+selected rows: gathered into ``[B, 2048, 512]`` copies and handed to
+``ops/decode_attention.py`` they cost 3.68 ms of a 5.05 ms decode step (XLA's
+gather of 1 KB rows runs at 73 GB/s; the top-k's sort another 0.14 ms; my
+chip runs, PR 34), against the whole buffers read in place; at an episode
+of twice the top-k a whole buffer is 2.7 times the selected rows' bytes.
+A kernel that fetches the selected rows in one pass is ROADMAP 2-A's. The
+unroll works in blocks of
+``q_chunk_size`` 512 queries, each over the keys its mask can reach: the
+indexer's scores, the exact top-k as a mask (``ops/topk_select.py``: no
+sort), the main scores under that mask, ``L_I`` from the same block; the
+products are masked-dense (the selection saves no product yet).
+
+The widths are the defaults below and are never cut. What IS cut is how
+much one chip holds (``benchmark/configs/keye-vl2-30b-a3b-recall-fused-
+a2c.json``): which published layers (``layer_ids``), how many experts of
+each (``experts_held`` from ``expert_offset``) and how many vocabulary ids
+(``num_actions``). ``--model_cut`` names such a cut (:data:`CUTS`).
+
+Precision: float32 parameters, residual stream, norms, router, softmaxes,
+indexer scores and heads' outputs; bfloat16 matrix operands with float32
+accumulation; the K/V and indexer-key caches bfloat16 (the published
+indexer cache is float8, which a v5e has not). The policy protocol is
+models/policy.py's.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+from typing import NamedTuple, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from distributed_ba3c_tpu.models import layers
+from distributed_ba3c_tpu.models.a3c import PolicyValue
+from distributed_ba3c_tpu.models.layers import layer_norm, rms_norm, rope
+from distributed_ba3c_tpu.models.policy import LOSS_TERMS
+from distributed_ba3c_tpu.ops import moe, ssm
+from distributed_ba3c_tpu.ops.topk_select import select_mask
+from distributed_ba3c_tpu.utils import profiling
+from distributed_ba3c_tpu.utils.profiling import device_scope
+
+VALUE_INIT_SCALE = 0.01
+#: the leaves of a layer that only ``L_I`` trains
+INDEXER_LEAVES = ("idx_wq", "idx_wk", "idx_k_norm", "idx_k_norm_b", "idx_ww")
+#: ``--model_cut``: what one chip holds. ``chip-share-8``: one of 8 chips
+#: that share each layer (16 of 128 experts; the vocabulary slice is the
+#: env's action space), published layers 0-3. ``tiny``: every mechanism at a
+#: size a CPU test runs (a top-k of 8, blocks of 8 queries).
+CUTS = {
+    "chip-share-8": {},
+    "tiny": dict(
+        hidden_size=64, moe_intermediate_size=32, num_attention_heads=4,
+        num_key_value_heads=2, head_dim=16, num_experts=16,
+        num_experts_per_tok=2, experts_held=2, indexer_num_heads=2,
+        indexer_head_dim=8, index_topk=8, q_chunk_size=8, layer_ids=(0, 1),
+    ),
+}
+
+
+def cut_fields(cut: str | None) -> dict:
+    cut = cut or "chip-share-8"
+    if cut not in CUTS:
+        raise ValueError(f"unknown --model_cut {cut!r}; have {sorted(CUTS)}")
+    return dict(CUTS[cut])
+
+
+class Carry(NamedTuple):
+    """What decoding carries from one position to the next, an env a row.
+    ``fresh`` resets ``pos``; the buffers keep their bytes and are masked by
+    the position (nothing at or past it is read)."""
+
+    pos: jax.Array      # [B] int32 position in the episode
+    kv: Tuple           # per layer (k, v), each [B, P, KV * D]: a position's
+                        # K/V heads side by side in one row of whole lanes;
+                        # attended over at the selected rows only
+    index_keys: Tuple   # per layer the indexer's keys [B, P, Di]: read whole
+                        # (under the mask of the position) every step
+
+
+@dataclasses.dataclass(frozen=True)
+class KeyeVL2:
+    num_actions: int = 18992            # vocabulary ids held (of 151,936)
+    hidden_size: int = 2048
+    moe_intermediate_size: int = 768
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 4
+    head_dim: int = 128
+    rms_norm_eps: float = 1e-6
+    rope_theta: float = 1e7
+    num_experts: int = 128
+    num_experts_per_tok: int = 8
+    norm_topk_prob: bool = True
+    indexer_num_heads: int = 16
+    indexer_head_dim: int = 64
+    index_topk: int = 2048
+    q_chunk_size: int = 512             # queries a block of the unroll takes
+    indexer_loss_coef: float = 1.0
+    # -- the chip's share ---------------------------------------------------
+    layer_ids: Tuple[int, ...] = (0, 1, 2, 3)
+    experts_held: int = 16
+    expert_offset: int = 0
+    # -- how it is run ------------------------------------------------------
+    max_positions: int = 4096           # cache rows: the episode length
+    compute_dtype: jnp.dtype = jnp.bfloat16
+
+    carries_state = True
+
+    def __post_init__(self):
+        assert self.num_attention_heads % self.num_key_value_heads == 0
+        assert 0 < self.experts_held <= self.num_experts
+
+    def for_env(self, env) -> "KeyeVL2":
+        """This policy over ``env``'s action space and episode length."""
+        return dataclasses.replace(
+            self, num_actions=env.num_actions, max_positions=env.episode_length
+        )
+
+    def layer_name(self, i: int) -> str:
+        return f"layer_{self.layer_ids[i]}"
+
+    # -- parameters -----------------------------------------------------------
+    def init_params(self, rng):
+        """Seeded float32 parameters, ``{layer: {leaf: array}}``: normal
+        kernels scaled by 1/sqrt(fan_in), unit gains, a zero bias."""
+        d, fe, D = self.hidden_size, self.moe_intermediate_size, self.head_dim
+        hq, hkv = self.num_attention_heads * D, self.num_key_value_heads * D
+        hi, di = self.indexer_num_heads, self.indexer_head_dim
+        e = self.experts_held
+        keys = iter(jax.random.split(rng, 16 * len(self.layer_ids) + 4))
+
+        def normal(shape, fan_in):
+            return jax.random.normal(next(keys), shape, jnp.float32) / math.sqrt(fan_in)
+
+        ones = lambda n: jnp.ones((n,), jnp.float32)  # noqa: E731
+        params = {"embed": {"table": normal((self.num_actions, d), d)}}
+        for i in range(len(self.layer_ids)):
+            params[self.layer_name(i)] = dict(
+                attn_norm=ones(d), ffn_norm=ones(d),
+                wq=normal((d, hq), d), wk=normal((d, hkv), d),
+                wv=normal((d, hkv), d), wo=normal((hq, d), hq),
+                q_norm=ones(D), k_norm=ones(D),
+                idx_wq=normal((d, hi * di), d), idx_wk=normal((d, di), d),
+                idx_k_norm=ones(di), idx_k_norm_b=jnp.zeros((di,), jnp.float32),
+                idx_ww=normal((d, hi), d),
+                router=normal((d, self.num_experts), d),
+                w1=normal((e, d, fe), d), w3=normal((e, d, fe), d),
+                w2=normal((e, fe, d), fe))
+        params["final"] = {"norm": ones(d)}
+        params["head"] = {"table": normal((self.num_actions, d), d)}
+        # a value head that starts near zero, as actor-critic code starts it
+        params["value"] = {"kernel": VALUE_INIT_SCALE * normal((d, 1), d),
+                           "bias": jnp.zeros((1,), jnp.float32)}
+        return params
+
+    def rollout_params(self, params):
+        """The matrices in the compute type, once for a whole rollout. Gains,
+        the bias, the router and the value head stay float32."""
+        return layers.matrices_in(params, self.compute_dtype, keep=("router",))
+
+    # -- pieces shared by the decode step and the unroll -----------------------
+    def _mm(self, x, w, out_dtype=jnp.float32):
+        return layers.mm(x, w, self.compute_dtype, out_dtype)
+
+    def _qkv(self, p, z, positions):
+        """z [B, T, d] -> q [B, T, H, D], k, v [B, T, KV, D] in the compute
+        type: per-head RMSNorm on q and k, then RoPE at ``positions``."""
+        D, eps, cd = self.head_dim, self.rms_norm_eps, self.compute_dtype
+        heads = lambda w: self._mm(z, w).reshape(*z.shape[:-1], -1, D)  # noqa: E731
+        q = rope(rms_norm(heads(p["wq"]), p["q_norm"], eps), positions, self.rope_theta)
+        k = rope(rms_norm(heads(p["wk"]), p["k_norm"], eps), positions, self.rope_theta)
+        return q.astype(cd), k.astype(cd), heads(p["wv"]).astype(cd)
+
+    def _index_qkw(self, p, z, positions):
+        """The indexer's side of ``z`` [B, T, d], which it reads under
+        ``stop_gradient``: (q^I [B, T, Hi, Di], k^I [B, T, Di], both in the
+        compute type; the heads' weights [B, T, Hi] float32, scaled)."""
+        z = jax.lax.stop_gradient(z)
+        hi, di, cd = self.indexer_num_heads, self.indexer_head_dim, self.compute_dtype
+        q = rope(self._mm(z, p["idx_wq"]).reshape(*z.shape[:-1], hi, di),
+                 positions, self.rope_theta)
+        k = layer_norm(self._mm(z, p["idx_wk"]), p["idx_k_norm"],
+                       p["idx_k_norm_b"], self.rms_norm_eps)
+        k = rope(k[..., None, :], positions, self.rope_theta)[..., 0, :]
+        w = self._mm(z, p["idx_ww"]) * (hi ** -0.5 * di ** -0.5)
+        return q.astype(cd), k.astype(cd), w
+
+    @staticmethod
+    def _index_scores(q, k, w):
+        """``I = sum_j w_j relu(q_j . k_s)``: q [B, Tq, Hi, Di], k [B, Tk, Di],
+        w [B, Tq, Hi] -> [B, Tq, Tk] float32."""
+        with device_scope(profiling.OP_INDEXER_SCORES):
+            dots = jnp.einsum("bqjd,bsd->bqjs", q, k,
+                              preferred_element_type=jnp.float32)
+            index = jnp.sum(jax.nn.relu(dots) * w[..., None], axis=2)
+            # one zero: -0.0 and 0.0 tie, whichever way a top-k compares
+            return jnp.where(index == 0, 0.0, index)
+
+    def _ffn(self, p, h):
+        """h [N, d] float32 -> (h + this chip's part of the experts'
+        FFN(RMSNorm(h)), (tokens routed to each held expert, the chosen
+        expert ids [N, k], the blocks of sorted rows run beyond the first))."""
+        with device_scope(profiling.MOE):
+            z = rms_norm(h, p["ffn_norm"], self.rms_norm_eps)
+            routing = moe.route(
+                z, p["router"], None, self.num_experts_per_tok,
+                self.norm_topk_prob, scoring="softmax")
+            cd = self.compute_dtype
+            out, counts, overflow = moe.expert_ffn(
+                z.astype(cd), routing, p["w1"].astype(cd), p["w3"].astype(cd),
+                p["w2"].astype(cd), self.expert_offset, self.num_experts)
+            return h + out, (counts, routing.experts, overflow)
+
+    def _head(self, params, x):
+        """x [N, d] float32 -> PolicyValue over the held vocabulary."""
+        with device_scope(profiling.HEAD):
+            h = rms_norm(x, params["final"]["norm"], self.rms_norm_eps)
+            logits, value = layers.tied_head(
+                h, params["head"]["table"], params["value"], self.compute_dtype)
+            return PolicyValue(logits=logits, value=value)
+
+    def _embed(self, params, tokens):
+        return layers.embed_rows(
+            params["embed"]["table"], tokens, self.compute_dtype)
+
+    def _attend_rows(self, q, k_rows, v_rows, kept):
+        """A decode step's attention over the rows of an env's K/V buffers
+        that ``kept`` marks: q [B, H, D]; k_rows, v_rows [B, P, KV * D] as the
+        carry holds them; kept [B, P] bool -> [B, H * D] float32. The buffers
+        are multiplied WHERE THEY LIE: each query is laid on its own K/V
+        head's lanes beside zeros (``ops/decode_attention.py``'s
+        block-diagonal product, in ``jax.numpy``), so a row's four heads are
+        never split into an axis of their own, which made the compiler
+        relayout both whole buffers every step (read off the program compiled
+        for a v5e); of the second product's ``[H, KV * D]`` each head's own
+        lanes are the answer. Four times the matrix unit's work on a product
+        that is bound by reading the rows."""
+        B, H, D = q.shape
+        KV = self.num_key_value_heads
+        own = jnp.eye(KV, dtype=q.dtype)[:, None, :, None]  # [KV, 1, KV, 1]
+        q = (q.reshape(B, KV, H // KV, 1, D) * own).reshape(B, H, KV * D)
+        scores = jnp.einsum("bhl,bpl->bhp", q, k_rows,
+                            preferred_element_type=jnp.float32) / math.sqrt(D)
+        scores = jnp.where(kept[:, None, :], scores, -jnp.inf)
+        probs = jax.nn.softmax(scores, axis=-1).astype(self.compute_dtype)
+        out = jnp.einsum("bhp,bpl->bhl", probs, v_rows,
+                         preferred_element_type=jnp.float32)
+        out = out.reshape(B, KV, H // KV, KV, D)
+        return jnp.einsum("bkgkd->bkgd", out).reshape(B, H * D)
+
+    # -- the rollout's decode step ---------------------------------------------
+    def init_carry(self, batch: int) -> Carry:
+        kv_shape = (batch, self.max_positions,
+                    self.num_key_value_heads * self.head_dim)
+        idx_shape = (batch, self.max_positions, self.indexer_head_dim)
+        cd = self.compute_dtype
+        n = len(self.layer_ids)
+        # a buffer each: the step donates its state
+        return Carry(
+            pos=jnp.zeros((batch,), jnp.int32),
+            kv=tuple((jnp.zeros(kv_shape, cd), jnp.zeros(kv_shape, cd))
+                     for _ in range(n)),
+            index_keys=tuple(jnp.zeros(idx_shape, cd) for _ in range(n)),
+        )
+
+    def carry_bytes(self) -> Tuple[int, ...]:
+        """Bytes of carry an env, by kind: (``kv``, ``index_keys``, ``pos``)."""
+        shapes = jax.eval_shape(lambda: self.init_carry(1))
+        size = lambda tree: sum(  # noqa: E731
+            x.size * x.dtype.itemsize for x in jax.tree_util.tree_leaves(tree))
+        return size(shapes.kv), size(shapes.index_keys), size(shapes.pos)
+
+    def carry_gauges(self, carry: Carry) -> dict:
+        del carry  # a constant of the shapes
+        return {"carry_bytes_per_env": jnp.asarray(self.carry_bytes(), jnp.float32)}
+
+    def epoch_stats(self, metrics: dict) -> dict:
+        """An epoch's scalars from the step's metrics of this policy."""
+        held = np.asarray(metrics["moe_tokens_per_expert"])
+        live = float(np.sum(metrics["dsa_keys_live"]))
+        return {
+            "moe_load_max_over_mean": float(np.max(
+                held.max(axis=-1) / np.maximum(held.mean(axis=-1), 1e-9))),
+            "moe_overflow_blocks": float(np.sum(metrics["moe_overflow_blocks"])),
+            # of the keys a query could see, the share its indexer kept
+            "dsa_kept_share": float(np.sum(metrics["dsa_keys_selected"])) / max(live, 1.0),
+            "indexer_kl": float(np.sum(metrics["indexer_kl"])),
+            "carry_bytes_per_env": float(np.sum(metrics["carry_bytes_per_env"])),
+        }
+
+    def step(self, params, obs, carry: Carry, fresh):
+        """One token an env: ``obs`` [B] int32, ``fresh`` [B] bool (the
+        token opens an episode: forget the last one first)."""
+        B = obs.shape[0]
+        pos = jnp.where(fresh, 0, carry.pos)
+        rows = jnp.arange(B)
+        at = pos[:, None]
+        live = jnp.arange(self.max_positions)[None, :] <= at
+        x = self._embed(params, obs)
+        kv_out, idx_out = [], []
+
+        def write(cache, new):  # in place: one row an env
+            return cache.at[rows, pos].set(
+                new.reshape(B, -1), indices_are_sorted=True, unique_indices=True)
+
+        for i, ((k_cache, v_cache), i_cache) in enumerate(
+                zip(carry.kv, carry.index_keys, strict=True)):
+            p = params[self.layer_name(i)]
+            with device_scope(profiling.OP_ATTN_SPARSE):
+                z = rms_norm(x, p["attn_norm"], self.rms_norm_eps)[:, None, :]
+                q, k, v = self._qkv(p, z, at)
+                k_cache, v_cache = write(k_cache, k), write(v_cache, v)
+            with device_scope(profiling.OP_INDEXER):
+                qi, ki, w = self._index_qkw(p, z, at)
+                i_cache = write(i_cache, ki)
+                scores = self._index_scores(qi, i_cache, w)[:, 0]
+                with device_scope(profiling.OP_INDEXER_SELECT):
+                    kept = select_mask(scores, live, self.index_topk)
+            with device_scope(profiling.OP_ATTN_SPARSE):
+                a = self._attend_rows(q[:, 0], k_cache, v_cache, kept)
+                h = x + self._mm(a, p["wo"])
+            x, _ = self._ffn(p, h)
+            kv_out.append((k_cache, v_cache))
+            idx_out.append(i_cache)
+        return self._head(params, x), Carry(
+            pos=pos + 1, kv=tuple(kv_out), index_keys=tuple(idx_out))
+
+    # -- the learner's unroll ----------------------------------------------------
+    def _block(self, q, k, v, qi, ki, w, lo: int):
+        """Queries ``[lo, lo + len(q))`` of an episode over keys ``[0, lo +
+        len(q))``: q [B, Tq, H, D]; k, v [B, Tk, KV, D]; the indexer's qi
+        [B, Tq, Hi, Di], ki [B, Tk, Di], w [B, Tq, Hi]. -> (attention out
+        [B, Tq, H * D] float32, ``sum_t KL_t`` of the block, keys selected,
+        keys live, the selection [B, Tq, Tk] bool)."""
+        B, Tq, H, D = q.shape
+        Tk, KV = k.shape[1], k.shape[2]
+        cd = self.compute_dtype
+        at_q = lo + jnp.arange(Tq)[:, None]
+        live = jnp.broadcast_to(jnp.arange(Tk)[None, :] <= at_q, (B, Tq, Tk))
+        with device_scope(profiling.OP_INDEXER):
+            index = self._index_scores(qi, ki, w)
+            with device_scope(profiling.OP_INDEXER_SELECT):
+                chosen = select_mask(
+                    jax.lax.stop_gradient(index), live, self.index_topk)
+        with device_scope(profiling.OP_ATTN_SPARSE):
+            scores = jnp.einsum(
+                "bqkgd,bskd->bkgqs", q.reshape(B, Tq, KV, H // KV, D), k,
+                preferred_element_type=jnp.float32) / math.sqrt(D)
+            scores = jnp.where(chosen[:, None, None], scores, -jnp.inf)
+            probs = jax.nn.softmax(scores, axis=-1)
+            out = jnp.einsum("bkgqs,bskd->bqkgd", probs.astype(cd), v,
+                             preferred_element_type=jnp.float32)
+        with device_scope(profiling.OP_INDEXER), device_scope(
+                profiling.OP_INDEXER_LOSS):
+            # the main attention's distribution over the selected keys, all
+            # heads together; each head's sums to one, so theirs to H
+            target = jax.lax.stop_gradient(jnp.sum(probs, axis=(1, 2))) / H
+            log_q = jax.nn.log_softmax(jnp.where(chosen, index, -jnp.inf), axis=-1)
+            there = chosen & (target > 0)
+            kl = jnp.sum(jnp.where(
+                there, target * (jnp.log(jnp.where(there, target, 1.0))
+                                 - jnp.where(there, log_q, 0.0)), 0.0))
+        count = lambda m: jnp.sum(m, dtype=jnp.int32)  # noqa: E731
+        return out.reshape(B, Tq, H * D), kl, count(chosen), count(live), chosen
+
+    def _layer_unroll(self, i: int, p, x, with_selection: bool):
+        """One layer over whole episodes: x [B, T, d] float32 -> (x, the
+        layer's ``L_I``, keys selected, keys live, what the experts' layer
+        counted, the selection [B, T, T] or None)."""
+        B, T, d = x.shape
+        positions = jnp.arange(T)[None, :]
+        with device_scope(profiling.OP_ATTN_SPARSE):
+            z = rms_norm(x, p["attn_norm"], self.rms_norm_eps)
+            q, k, v = self._qkv(p, z, positions)
+        with device_scope(profiling.OP_INDEXER):
+            qi, ki, w = self._index_qkw(p, z, positions)
+        size = ssm.chunk_length(T, self.q_chunk_size)
+        block = jax.checkpoint(self._block, static_argnums=(6,))
+        outs, kl, selected, live, masks = [], 0.0, 0, 0, []
+        for lo in range(0, T, size):
+            hi = lo + size
+            out, kl_b, sel_b, live_b, chosen = block(
+                q[:, lo:hi], k[:, :hi], v[:, :hi], qi[:, lo:hi], ki[:, :hi],
+                w[:, lo:hi], lo)
+            outs.append(out)
+            kl, selected, live = kl + kl_b, selected + sel_b, live + live_b
+            if with_selection:
+                masks.append(jnp.pad(chosen, ((0, 0), (0, 0), (0, T - hi))))
+        with device_scope(profiling.OP_ATTN_SPARSE):
+            h = x + self._mm(jnp.concatenate(outs, axis=1), p["wo"])
+        y, routed = self._ffn(p, h.reshape(B * T, d))
+        return (y.reshape(B, T, d), kl / (B * T), selected, live, routed,
+                jnp.concatenate(masks, axis=1) if with_selection else None)
+
+    def unroll(self, params, tokens, with_routes: bool = False):
+        """Whole episodes from a reset: ``tokens`` [B, T] int32 ->
+        (PolicyValue with logits [B, T, A] and value [B, T], aux). ``aux``
+        counts, a layer: the tokens routed to each held expert
+        (``moe_tokens_per_expert``), the blocks of sorted rows run beyond
+        the first (``moe_overflow_blocks``), the keys the queries could see
+        and those their indexer kept (``dsa_keys_live``,
+        ``dsa_keys_selected``); under ``LOSS_TERMS`` it hands the trainer
+        ``indexer_kl``: ``indexer_loss_coef * L_I``, a layer. Asked, it also
+        names every token's chosen experts (``routes`` [layers, B, T, k])
+        and every query's selected keys (``selected`` [layers, B, T, T / 8]
+        uint8: the mask's bits, ``jnp.packbits`` along the keys)."""
+        B, T = tokens.shape
+        x = self._embed(params, tokens)
+        kls, selected, live, counts, routes, overflow, masks = ([] for _ in range(7))
+        for i in range(len(self.layer_ids)):
+            # a layer is recomputed in the backward, as in the other two
+            # sequence policies
+            layer = jax.checkpoint(
+                lambda p, x, i=i: self._layer_unroll(i, p, x, with_routes))
+            x, kl, n_sel, n_live, routed, mask = layer(
+                params[self.layer_name(i)], x)
+            kls.append(kl)
+            selected.append(n_sel)
+            live.append(n_live)
+            counts.append(routed[0])
+            routes.append(routed[1].reshape(B, T, -1))
+            overflow.append(routed[2])
+            masks.append(mask)
+        out = self._head(params, x.reshape(B * T, -1))
+        aux = {
+            "moe_tokens_per_expert": jnp.stack(counts),
+            "moe_overflow_blocks": jnp.stack(overflow),
+            "dsa_keys_selected": jnp.stack(selected),
+            "dsa_keys_live": jnp.stack(live),
+            LOSS_TERMS: {"indexer_kl": self.indexer_loss_coef * jnp.stack(kls)},
+        }
+        if with_routes:
+            aux["routes"] = jnp.stack(routes)
+            aux["selected"] = jnp.packbits(jnp.stack(masks), axis=-1)
+        return PolicyValue(
+            logits=out.logits.reshape(B, T, -1), value=out.value.reshape(B, T)
+        ), aux

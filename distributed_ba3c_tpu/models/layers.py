@@ -5,7 +5,7 @@ Reference equivalent: ``tensorpack/models/nonlin.py`` (PReLU) and friends
 not re-wrap what the library already expresses idiomatically.
 
 Below ``PReLU``: what the token-sequence policies (models/lfm2_moe.py,
-models/phi4_flash.py) share, as plain functions of arrays. Their parameters
+models/phi4_flash.py, models/keye_vl2.py) share, as plain functions of arrays. Their parameters
 are float32 trees ``{layer: {leaf: array}}``; matrices multiply in the
 compute type (bfloat16) with float32 accumulation.
 """
@@ -61,6 +61,18 @@ def layer_norm(x, gain, bias, eps):
     return centred * jax.lax.rsqrt(var + eps) * gain + bias
 
 
+def rope(x, positions, theta):
+    """Rotate-half RoPE over the whole head. ``x`` [..., T, H, D] float32,
+    ``positions`` broadcastable to [..., T]."""
+    half = x.shape[-1] // 2
+    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    angle = positions[..., None].astype(jnp.float32) * inv_freq
+    cos = jnp.concatenate([jnp.cos(angle)] * 2, -1)[..., None, :]
+    sin = jnp.concatenate([jnp.sin(angle)] * 2, -1)[..., None, :]
+    rotated = jnp.concatenate([-x[..., half:], x[..., :half]], -1)
+    return x * cos + rotated * sin
+
+
 def swiglu(z, w_gate, w_up, w_down, compute_dtype):
     """``W_down (silu(W_gate z) * W_up z)``: z [N, d] float32 -> [N, d]
     float32. ``gate`` and ``up`` leave their products in the compute type."""
@@ -96,8 +108,9 @@ def embed_rows(table, tokens, compute_dtype):
 
 def tied_head(h, table, value_params, compute_dtype):
     """h [N, d] float32, already through the final norm -> (logits over the
-    vocabulary ids held here: the embedding's rows times ``h``, tied; the
-    trainer's float32 value head on the same ``h``)."""
+    vocabulary ids held here: ``table``'s rows times ``h``; the trainer's
+    float32 value head on the same ``h``). ``table`` [ids, d] is the
+    embedding where the head is tied, a head's own rows where it is not."""
     logits = jnp.dot(
         h.astype(compute_dtype), table.astype(compute_dtype).T,
         preferred_element_type=jnp.float32,

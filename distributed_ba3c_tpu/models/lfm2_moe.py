@@ -45,7 +45,7 @@ import numpy as np
 
 from distributed_ba3c_tpu.models import layers
 from distributed_ba3c_tpu.models.a3c import PolicyValue
-from distributed_ba3c_tpu.models.layers import rms_norm
+from distributed_ba3c_tpu.models.layers import rms_norm, rope
 from distributed_ba3c_tpu.ops import moe
 from distributed_ba3c_tpu.utils import profiling
 from distributed_ba3c_tpu.utils.profiling import device_scope
@@ -86,18 +86,6 @@ class Carry(NamedTuple):
     pos: jax.Array       # [B] int32 position in the episode
     conv: Tuple          # per conv layer (v_{t-1}, v_{t-2}), each [B, d] f32
     kv: Tuple            # per attention layer (k, v), each [B, P, KV, D]
-
-
-def _rope(x, positions, theta):
-    """Rotate-half RoPE over the whole head. ``x`` [..., T, H, D] float32,
-    ``positions`` broadcastable to [..., T]."""
-    half = x.shape[-1] // 2
-    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    angle = positions[..., None].astype(jnp.float32) * inv_freq
-    cos = jnp.concatenate([jnp.cos(angle)] * 2, -1)[..., None, :]
-    sin = jnp.concatenate([jnp.sin(angle)] * 2, -1)[..., None, :]
-    rotated = jnp.concatenate([-x[..., half:], x[..., :half]], -1)
-    return x * cos + rotated * sin
 
 
 @dataclasses.dataclass(frozen=True)
@@ -233,8 +221,8 @@ class LFM2MoE:
         q = self._mm(z, p["wq"]).reshape(*z.shape[:-1], -1, D)
         k = self._mm(z, p["wk"]).reshape(*z.shape[:-1], -1, D)
         v = self._mm(z, p["wv"]).reshape(*z.shape[:-1], -1, D)
-        q = _rope(rms_norm(q, p["q_norm"], self.norm_eps), positions, self.rope_theta)
-        k = _rope(rms_norm(k, p["k_norm"], self.norm_eps), positions, self.rope_theta)
+        q = rope(rms_norm(q, p["q_norm"], self.norm_eps), positions, self.rope_theta)
+        k = rope(rms_norm(k, p["k_norm"], self.norm_eps), positions, self.rope_theta)
         cd = self.compute_dtype
         return q.astype(cd), k.astype(cd), v
 

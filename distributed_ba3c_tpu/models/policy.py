@@ -23,7 +23,15 @@ A policy is what the trainers call to turn observations into
   ``aux`` is a dict of whatever the policy counts in its learner (summed
   over chunks and shards into the step's metrics; may be empty); ``step``
   and ``unroll`` agree position by position (tests/test_lfm2_moe.py,
-  tests/test_phi4_flash.py). Optional, for the trainer's reports:
+  tests/test_phi4_flash.py, tests/test_keye_vl2.py). Under the one reserved
+  key :data:`LOSS_TERMS` the unroll's ``aux`` may hold **loss terms the
+  policy owns**: ``{name: array}``, each entry a mean over the chunk's
+  tokens (a scalar, or one a layer) with its coefficient applied. The
+  trainer adds the sum of them to ``a3c_loss.total`` before it
+  differentiates, averages them over chunks and shards as it averages the
+  loss's parts, and reports each under the policy's name for it; it names
+  none of them (``keye-vl2``'s ``indexer_kl`` trains its indexer, which the
+  A2C loss cannot reach). Optional, for the trainer's reports:
 
       model.carry_gauges(carry) -> dict          of the carry as a rollout left
           it (the largest over the shards goes into the step's metrics)
@@ -41,6 +49,8 @@ from typing import Callable, Dict
 import jax.numpy as jnp
 
 DEFAULT_MODEL = "ba3cnet"
+#: the key of an unroll's ``aux`` that holds the policy's own loss terms
+LOSS_TERMS = "loss_terms"
 
 
 def carries_state(model) -> bool:
@@ -85,8 +95,15 @@ def _phi4_flash(cfg, cut=None):
     return Phi4Flash(num_actions=cfg.num_actions, **cut_fields(cut))
 
 
+def _keye_vl2(cfg, cut=None):
+    from distributed_ba3c_tpu.models.keye_vl2 import KeyeVL2, cut_fields
+
+    return KeyeVL2(num_actions=cfg.num_actions, **cut_fields(cut))
+
+
 MODELS: Dict[str, Callable] = {
     DEFAULT_MODEL: _ba3cnet, "lfm2-moe": _lfm2_moe, "phi4-flash": _phi4_flash,
+    "keye-vl2": _keye_vl2,
 }
 
 

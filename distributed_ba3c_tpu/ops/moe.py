@@ -8,6 +8,12 @@ it runs it (expert parallelism's own arithmetic, ROADMAP A3):
     w = s[chosen] / (sum s[chosen] + 1e-6) * scale
     out = sum_i w_i W2_i (silu(W1_i z) * W3_i z)     over the chosen i HELD here
 
+That is the ``sigmoid`` scoring (LFM2's: a bias that evens the load out).
+The ``softmax`` scoring (:func:`route` with ``scoring="softmax"``; Qwen3-MoE's
+and Keye-VL-2.0's) has no bias: ``s = softmax(z W_r)`` over all
+``num_experts``, ``chosen`` the top k of ``s`` itself, ``w = s[chosen] / sum
+s[chosen]`` (no ``1e-6``).
+
 ``expert_offset`` and the leading axis of ``w1``/``w3``/``w2`` say which
 experts live here: ids ``[offset, offset + held)``. Routing is over all of
 them; an assignment to an absent expert adds nothing here (on its own chip
@@ -93,19 +99,28 @@ class Routing(NamedTuple):
 
 
 def route(z, router_w, expert_bias, top_k: int, norm_topk_prob: bool = True,
-          scale: float = 1.0) -> Routing:
-    """Sigmoid scores in float32 (``z`` [N, d] float32, ``router_w``
-    [d, E]); the bias moves the choice and never the weight."""
+          scale: float = 1.0, scoring: str = "sigmoid") -> Routing:
+    """Scores in float32 (``z`` [N, d] float32, ``router_w`` [d, E]).
+    ``scoring`` ``sigmoid``: the bias moves the choice and never the weight;
+    ``softmax``: over all E, no bias (``expert_bias`` None)."""
     with device_scope(profiling.MOE_ROUTER):
-        scores = jax.nn.sigmoid(jnp.dot(
+        logits = jnp.dot(
             z.astype(jnp.float32), router_w.astype(jnp.float32),
             precision=jax.lax.Precision.HIGHEST,
-        ))
-        _, experts = jax.lax.top_k(
-            scores + jax.lax.stop_gradient(expert_bias)[None, :], top_k
         )
+        if scoring == "softmax":
+            assert expert_bias is None, "the softmax scoring has no bias"
+            scores = jax.nn.softmax(logits, axis=-1)
+            _, experts = jax.lax.top_k(scores, top_k)
+        else:
+            scores = jax.nn.sigmoid(logits)
+            _, experts = jax.lax.top_k(
+                scores + jax.lax.stop_gradient(expert_bias)[None, :], top_k
+            )
         weights = jnp.take_along_axis(scores, experts, axis=-1)
-        if norm_topk_prob:
+        if norm_topk_prob and scoring == "softmax":  # the published rule: no eps
+            weights = weights / jnp.sum(weights, -1, keepdims=True)
+        elif norm_topk_prob:
             weights = weights / (jnp.sum(weights, -1, keepdims=True) + NORM_EPS)
         return Routing(experts.astype(jnp.int32), weights * scale)
 

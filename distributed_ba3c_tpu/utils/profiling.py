@@ -101,8 +101,20 @@ DECODE_ATTEND = "decode_attend"
 OP_ATTN_DECODE = tuple(
     f"{layer}/{DECODE_ATTEND}"
     for layer in (OP_ATTN_WINDOW, OP_ATTN_FULL, OP_ATTN_CROSS))
+#: grouped-query attention over the keys an indexer selects
+#: (models/keye_vl2.py): projections, norms, RoPE, the attention under the
+#: selection's mask, ``W_o``
+OP_ATTN_SPARSE = "op_attn_sparse"
+#: the indexer beside it: its projections; ``scores`` (``sum_j w_j
+#: relu(q_j . k)`` against every live key), ``select`` (the exact top-k),
+#: ``loss`` (its KL against the main attention's distribution)
+OP_INDEXER = "op_indexer"
+OP_INDEXER_SCORES = "op_indexer/scores"
+OP_INDEXER_SELECT = "op_indexer/select"
+OP_INDEXER_LOSS = "op_indexer/loss"
 #: the layers each sequence policy opens (models/lfm2_moe.py with ops/moe.py;
-#: models/phi4_flash.py with ops/ssm.py): a step holds its own policy's
+#: models/phi4_flash.py with ops/ssm.py; models/keye_vl2.py with ops/moe.py):
+#: a step holds its own policy's
 LFM2_LAYERS = (
     EMBED, OP_CONV, OP_ATTN, FFN_DENSE, MOE, MOE_ROUTER, MOE_DISPATCH,
     MOE_EXPERTS, MOE_EXPERTS_GMM, MOE_COMBINE, HEAD,
@@ -112,8 +124,16 @@ PHI4_FLASH_LAYERS = (
     OP_GMU, OP_ATTN_WINDOW, OP_ATTN_FULL, OP_ATTN_CROSS, *OP_ATTN_DECODE,
     FFN_DENSE, HEAD,
 )
+KEYE_VL2_LAYERS = (
+    EMBED, OP_ATTN_SPARSE, OP_INDEXER, OP_INDEXER_SCORES, OP_INDEXER_SELECT,
+    OP_INDEXER_LOSS, MOE, MOE_ROUTER, MOE_DISPATCH, MOE_EXPERTS, MOE_EXPERTS_GMM,
+    MOE_COMBINE, HEAD,
+)
 POLICY_LAYERS = LFM2_LAYERS + tuple(
-    layer for layer in PHI4_FLASH_LAYERS if layer not in LFM2_LAYERS)
+    layer for layer in PHI4_FLASH_LAYERS if layer not in LFM2_LAYERS
+) + tuple(
+    layer for layer in KEYE_VL2_LAYERS
+    if layer not in LFM2_LAYERS + PHI4_FLASH_LAYERS)
 #: the rollout's once-an-update bfloat16 snapshot of the matrix weights
 ROLLOUT_WEIGHTS_BF16 = "rollout/weights_bf16"
 

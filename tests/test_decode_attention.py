@@ -295,3 +295,93 @@ def test_the_compiled_decode_moves_no_whole_buffer_in_its_loop(compiled_decode):
     scatters = [line for line in compiled_decode.splitlines()
                 if re.search(r"= bf16\[32,(1024|512),1280\]\S* scatter\(", line)]
     assert len(scatters) == 4  # K and V of the ring and of the shared buffer
+
+
+# -- the sparse-attention policy's decode: no kernel, and no whole buffer moved ----
+#: an instruction of the loop's body that MAKES a whole cache of the cell: K or
+#: V ([16, 4096, 512], also seen as [16, 4096, 4, 128]) or the indexer's keys
+#: ([16, 4096, 64]). All but the in-place scatter that writes a position is a
+#: copy, a relayout or a staging through fast memory, alone or in a fusion
+_WHOLE_CACHE = re.compile(
+    r"^\s*(?:ROOT )?%\S+ = bf16\[16,4096,(512|64|4,128)\]\S* ([\w\-]+)\((.*)")
+
+
+def _whole_caches_made(body, widths):
+    """[(opcode, is an in-place scatter)] of the body's instructions that
+    make a whole cache of one of ``widths``."""
+    made = []
+    for line in body:
+        m = _WHOLE_CACHE.match(line)
+        if m and m.group(1) in widths and m.group(2) not in (
+                "get-tuple-element", "parameter", "bitcast"):
+            made.append((m.group(2), bool(re.search(r'/scatter"', m.group(3)))))
+    return made
+
+
+@pytest.fixture(scope="module")
+def compiled_sparse_decode(one_chip, no_compile_cache):
+    """``keye-vl2``'s decode at the cell's size (16 envs, 4,096 positions, the
+    published widths, the bf16 snapshot) in a scan under the trainer's
+    scopes, compiled for the described v5e."""
+    from distributed_ba3c_tpu.models.keye_vl2 import KeyeVL2
+
+    model = KeyeVL2(max_positions=4096)
+    placed = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), tree)
+    params = placed(jax.eval_shape(
+        lambda key: model.rollout_params(model.init_params(key)),
+        jax.random.PRNGKey(0)))
+    carry = placed(jax.eval_shape(lambda: model.init_carry(16)))
+    tokens = jax.ShapeDtypeStruct((4096, 16), jnp.int32, sharding=one_chip)
+    fresh = jax.ShapeDtypeStruct((4096, 16), jnp.bool_, sharding=one_chip)
+
+    def episode(params, carry, tokens, fresh):
+        def one(carry, x):
+            with device_scope(profiling.ROLLOUT_POLICY):
+                out, carry = model.step(params, x[0], carry, x[1])
+            return carry, out.value
+
+        with device_scope(profiling.ROLLOUT):
+            return jax.lax.scan(one, carry, (tokens, fresh))
+
+    return jax.jit(episode, donate_argnums=1).lower(
+        params, carry, tokens, fresh).compile().as_text()
+
+
+def _scan_body(text):
+    """The lines of the decode scan's body: the computation the program's
+    largest ``while`` names as its ``body``."""
+    bodies = re.findall(r" while\(.*?body=(%[\w.\-]+)", text)
+    blocks = {}
+    for name in set(bodies):
+        start = text.index(f"\n{name} (")
+        blocks[name] = text[start:text.index("\n}\n", start)].splitlines()
+    return max(blocks.values(), key=len, default=[])
+
+
+@pytest.mark.timeout(600)
+def test_the_sparse_decode_moves_no_whole_buffer_in_its_loop(compiled_sparse_decode):
+    """The three scatters a layer (K, V, the indexer's key) update in place
+    and the products read K, V and the indexer's keys where they lie (each
+    query laid on its own K/V head's lanes): no copy, relayout or staging of
+    a whole cache an iteration. With the K/V heads split into an axis of
+    their own (``layers.attend`` on the reshaped buffers) the compiler
+    relaid both whole buffers out every step; gathered into copies the
+    selected rows cost 3.68 ms a step (PERF.md section 6, PR 34)."""
+    body = _scan_body(compiled_sparse_decode)
+    assert len(body) > 100
+    # K and V of four layers: the eight scatters and nothing else
+    assert _whole_caches_made(body, ("512", "4,128")) == [("fusion", True)] * 8
+    # the indexer's keys (8 MB, read whole every step by construction): the
+    # four scatters; the compiler also stages that cache through fast memory
+    # for the scores' product in three of the layers (slices in, a copy
+    # back: 16 MB moved for 8 read), which is its choice and is held here so
+    # that it does not grow unseen
+    keys = _whole_caches_made(body, ("64",))
+    assert sorted(keys) == sorted(
+        [("fusion", True)] * 4 + [("custom-call", False), ("copy-done", False)] * 3)
+    # no copy of the selected rows, and no sort of a row of scores: the
+    # selection is a mask (the router's top 8 of 128 is the only sort left)
+    assert not re.search(r"= bf16\[16,2048,512\]", compiled_sparse_decode)
+    assert not re.search(r"\[16,4096\]\S*\) sort\(", compiled_sparse_decode)
+    assert "tpu_custom_call" not in compiled_sparse_decode
