@@ -58,7 +58,7 @@ from distributed_ba3c_tpu.ops.loss import a3c_loss
 from distributed_ba3c_tpu.ops.returns import n_step_returns
 from distributed_ba3c_tpu.parallel.mesh import DATA_AXIS, shard_local
 from distributed_ba3c_tpu.parallel.train_step import TrainState
-from distributed_ba3c_tpu.utils import profiling
+from distributed_ba3c_tpu.utils import backend, profiling
 from distributed_ba3c_tpu.utils.profiling import device_scope, host_span
 
 #: metrics that accumulate IN STATE across an epoch (reset by the outer
@@ -637,6 +637,9 @@ def make_fused_step(
     jitted = tripwire_jit("fused.step", sharded, donate_argnums=(0,))
 
     def step(state, entropy_beta, learning_rate=None):
+        call = profiling.count_step_call()
+        if call <= profiling.RECORDED_STEP_CALLS:
+            return first_calls(call, state, entropy_beta, learning_rate)
         with host_span(profiling.SPAN_STEP):
             if learning_rate is None:
                 learning_rate = cfg.learning_rate
@@ -645,6 +648,31 @@ def make_fused_step(
                 learning_rate = jnp.asarray(learning_rate, jnp.float32)
             with host_span(profiling.SPAN_STEP_ENQUEUE):
                 return jitted(state, entropy_beta, learning_rate)
+
+    def first_calls(call, state, entropy_beta, learning_rate):
+        """``step()`` with the host's clock round its three spans: outside a
+        capture the spans are inert, and start-up runs outside one. The
+        durations go to the start-up record (utils/backend.py) as the event
+        ``fused.step#<call>``, on the clock of the compiler's intervals.
+
+        A second copy of ``step()``'s body, so that ``step()`` pays one
+        integer a call and no more: an edit to one goes into the other
+        (tests/test_startup_record.py holds the two to the same results)."""
+        t0 = time.monotonic()
+        with host_span(profiling.SPAN_STEP):
+            if learning_rate is None:
+                learning_rate = cfg.learning_rate
+            with host_span(profiling.SPAN_STEP_HYPER):
+                entropy_beta = jnp.asarray(entropy_beta, jnp.float32)
+                learning_rate = jnp.asarray(learning_rate, jnp.float32)
+            t1 = time.monotonic()
+            with host_span(profiling.SPAN_STEP_ENQUEUE):
+                out = jitted(state, entropy_beta, learning_rate)
+            t2 = time.monotonic()
+        backend.startup_event(
+            f"{backend.STEP_EVENT}{call}", t0, time.monotonic(),
+            hyper_s=t1 - t0, enqueue_s=t2 - t1)
+        return out
 
     replicated = NamedSharding(mesh, P())
     batched = NamedSharding(mesh, batch_spec)
@@ -834,14 +862,19 @@ def run_fused_training(args, cfg: BA3CConfig, model, optimizer) -> int:
     # calibration needs the run's actual starting params (restored ones
     # on a resume — calibrating against re-initialized weights would
     # freeze scales for a policy the actor never plays)
-    state = create_fused_state(
-        jax.random.PRNGKey(getattr(args, "seed", 0) or 0),
-        model, cfg, optimizer, env, n_envs, n_shards=n_data,
-    )
+    # the one-off phases below are ``startup`` events (utils/backend.py):
+    # what a resume spends before its first update, by name, on the start-up
+    # line; a dump of one that hung names the phase that did not end
+    with backend.startup_phase("state_init"):
+        state = create_fused_state(
+            jax.random.PRNGKey(getattr(args, "seed", 0) or 0),
+            model, cfg, optimizer, env, n_envs, n_shards=n_data,
+        )
     if args.load:
-        mgr = CheckpointManager(args.load)
-        restored = mgr.restore(jax.device_get(state.train))
-        state = state.replace(train=restored)
+        with backend.startup_phase("restore"):
+            mgr = CheckpointManager(args.load)
+            restored = mgr.restore(jax.device_get(state.train))
+            state = state.replace(train=restored)
         logger.info("resumed train state at step %d", int(restored.step))
     rollout_dtype = getattr(args, "rollout_dtype", "float32")
     quant_spec = None
@@ -854,39 +887,41 @@ def run_fused_training(args, cfg: BA3CConfig, model, optimizer) -> int:
         if getattr(args, "quant_spec", None):
             quant_spec = QuantSpec.load(args.quant_spec)
         else:
-            quant_spec = calibrate_from_env(
-                model, cfg, env, state.train.params,
-                jax.random.PRNGKey(getattr(args, "seed", 0) or 0),
-                n_envs=n_envs,
-                batches=int(getattr(args, "quant_calibrate", 0) or 0),
-                rollout_len=rollout_len,
-            )
+            with backend.startup_phase("calibrate"):
+                quant_spec = calibrate_from_env(
+                    model, cfg, env, state.train.params,
+                    jax.random.PRNGKey(getattr(args, "seed", 0) or 0),
+                    n_envs=n_envs,
+                    batches=int(getattr(args, "quant_calibrate", 0) or 0),
+                    rollout_len=rollout_len,
+                )
         logger.info(
             "int8 rollout forward: quant spec %s (%d calibration batches)",
             quant_spec.sha256()[:12], quant_spec.calibration_batches,
         )
-    if getattr(args, "overlap", False):
-        # two overlapped compiled programs (rollout k+1 concurrent with
-        # learner k, lag-1 V-trace correction) instead of the single fused
-        # program — docs/overlap.md. --fleet_accum K adds the macro
-        # learner: K rollout windows ("fleets") accumulated into ONE
-        # update (docs/actor_plane.md multi-fleet macro-batching)
-        from distributed_ba3c_tpu.fused.overlap import make_overlap_step
+    with backend.startup_phase("build_step"):
+        if getattr(args, "overlap", False):
+            # two overlapped compiled programs (rollout k+1 concurrent with
+            # learner k, lag-1 V-trace correction) instead of the single
+            # fused program — docs/overlap.md. --fleet_accum K adds the macro
+            # learner: K rollout windows ("fleets") accumulated into ONE
+            # update (docs/actor_plane.md multi-fleet macro-batching)
+            from distributed_ba3c_tpu.fused.overlap import make_overlap_step
 
-        step = make_overlap_step(
-            model, optimizer, cfg, mesh, env, rollout_len,
-            grad_chunk_samples=args.grad_chunk_samples,
-            steps_per_dispatch=k_dispatch,
-            rollout_dtype=rollout_dtype,
-            macro_fleets=fleet_accum,
-            quant_spec=quant_spec,
-        )
-    else:
-        step = make_fused_step(
-            model, optimizer, cfg, mesh, env, rollout_len,
-            grad_chunk_samples=args.grad_chunk_samples,
-            steps_per_dispatch=k_dispatch,
-        )
+            step = make_overlap_step(
+                model, optimizer, cfg, mesh, env, rollout_len,
+                grad_chunk_samples=args.grad_chunk_samples,
+                steps_per_dispatch=k_dispatch,
+                rollout_dtype=rollout_dtype,
+                macro_fleets=fleet_accum,
+                quant_spec=quant_spec,
+            )
+        else:
+            step = make_fused_step(
+                model, optimizer, cfg, mesh, env, rollout_len,
+                grad_chunk_samples=args.grad_chunk_samples,
+                steps_per_dispatch=k_dispatch,
+            )
     run_shape = {
         "steps_per_epoch": args.steps_per_epoch,
         "batch_size": cfg.batch_size,
@@ -907,7 +942,8 @@ def run_fused_training(args, cfg: BA3CConfig, model, optimizer) -> int:
                     "the LR/beta anneal will NOT continue where it left off",
                     k, prev[k], v,
                 )
-    state = step.put(state)
+    with backend.startup_phase("put"):
+        state = step.put(state)
     logger.info(
         "learner state on devices %s, env batch on devices %s",
         sorted(d.id for d in state.train.step.sharding.device_set),
@@ -1086,8 +1122,13 @@ def _fused_epoch_body(
                 # set-up time, recorded apart from the steady rate. One
                 # host sync, on this process's first dispatch only.
                 jax.block_until_ready(metrics)  # ba3clint: disable=J1 — first dispatch only, guarded above
-                first_dispatch_s = time.monotonic() - t0
+                done = time.monotonic()
+                first_dispatch_s = done - t0
                 holder.add_stat("first_dispatch_s", first_dispatch_s)
+                # the first update is complete: start-up ends here, and a
+                # compilation from now on is a steady-state one
+                backend.startup_event("first_update", t0, done)
+                backend.report_startup(done)
         with host_span(profiling.SPAN_EPOCH_FETCH):
             # scalars; a sequence policy's counters are small arrays
             metrics = {
@@ -1114,8 +1155,7 @@ def _fused_epoch_body(
         # reset the per-env episode accumulators for the next window
         # (step-provided hook: the fused and overlap steps keep these
         # fields in different state layouts)
-        with host_span(profiling.SPAN_EPOCH_RESET_STATS):
-            state = step.reset_episode_stats(state, n_envs)
+        state = step.reset_episode_stats(state, n_envs)
         if os.environ.get("BA3C_PARAM_DIGEST"):
             # divergence detector for multi-host runs: ranks log this line
             # per epoch; any mismatch across ranks means the psum'd update

@@ -162,9 +162,28 @@ SPAN_STEP = "fused.step"
 SPAN_STEP_HYPER = "fused.step.hyper"
 SPAN_STEP_ENQUEUE = "fused.step.enqueue"
 SPAN_EPOCH_FETCH = "fused.epoch.fetch"
-SPAN_EPOCH_RESET_STATS = "fused.epoch.reset_stats"
 SPAN_EPOCH_EVAL = "fused.epoch.eval"
 SPAN_EPOCH_CHECKPOINT = "fused.epoch.checkpoint"
+
+
+#: how many of ``step()``'s first calls also put the host's clock round their
+#: three spans (a flight-recorder ``startup`` event each, ``fused.step#<k>``:
+#: utils/backend.py); from the next call on ``step()`` reads no clock
+RECORDED_STEP_CALLS = 4
+_step_calls = 0
+
+
+def count_step_call() -> int:
+    """One more call of a fused ``step()`` in this process; -> which one."""
+    global _step_calls
+    _step_calls += 1
+    return _step_calls
+
+
+def step_calls() -> int:
+    """Calls of a fused ``step()`` in this process so far: the position of a
+    compiler event on the program's own timeline (utils/backend.py)."""
+    return _step_calls
 
 
 def device_scope(name: str):

@@ -204,7 +204,7 @@ def test_scope_names_nest_as_their_paths_say():
         assert parent == scope or parent in profiling.SCOPES
         assert scope.split("/")[0] in profiling.PHASES
     spans = [v for k, v in vars(profiling).items() if k.startswith("SPAN_") and k != "SPAN_PREFIX"]
-    assert len(spans) == 7 and all(s.startswith(profiling.SPAN_PREFIX) for s in spans)
+    assert len(spans) == 6 and all(s.startswith(profiling.SPAN_PREFIX) for s in spans)
 
 
 def _xplane(trace_dir):
