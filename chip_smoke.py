@@ -30,6 +30,13 @@ Phases (each fails the run; nothing is caught):
               an empty group: the Pallas kernel's forward and both gradients
               against ``jax.lax.ragged_dot`` on the same chip (tier-1 cannot
               run Mosaic), the largest gaps in the result.
+``sparse_attn`` the learner's attention under a selection's mask
+              (``ops/sparse_attention.py``) at the sparse-attention cell's
+              shapes (2 envs x 4,096 positions, 32 heads over 4 of 128, a
+              top-k of half the positions): the four Pallas kernels' output,
+              heads' sum and three gradients against the masked-dense form in
+              blocks of 512 queries on the same chip, the largest gaps in the
+              result.
 ``mesh``      only with more than one device: env state and batch sharded
               over every device, and after K updates every param leaf's
               replicas bit-identical — the on-chip form of audit rule T3.
@@ -99,6 +106,9 @@ class Shape:
     serve_batch: int
     # grouped expert product: sorted rows, hidden, expert width, experts held
     grouped_dims: tuple
+    # the learner's sparse attention: envs, positions, query heads, K/V
+    # heads, head width, queries a block of the masked-dense form
+    sparse_dims: tuple
 
 
 FULL = Shape(
@@ -109,6 +119,7 @@ FULL = Shape(
     plane_batch=128, plane_steps_per_epoch=20, staging_blocks=12,
     serve_batch=256,
     grouped_dims=(5120, 2048, 1792, 8),
+    sparse_dims=(2, 4096, 32, 4, 128, 512),
 )
 
 SMALL = Shape(
@@ -119,6 +130,7 @@ SMALL = Shape(
     plane_batch=32, plane_steps_per_epoch=20, staging_blocks=5,
     serve_batch=8,
     grouped_dims=(256, 128, 128, 4),
+    sparse_dims=(2, 64, 4, 2, 16, 16),
 )
 
 
@@ -680,6 +692,82 @@ def phase_grouped(shape: Shape, workdir: str, platform: str = "tpu") -> dict:
 
 
 # --------------------------------------------------------------------------
+# phase: the learner's attention under a selection against the masked-dense form
+# --------------------------------------------------------------------------
+
+
+def phase_sparse_attn(shape: Shape, workdir: str, platform: str = "tpu") -> dict:
+    del workdir
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from distributed_ba3c_tpu.ops.sparse_attention import attend_selected
+    from distributed_ba3c_tpu.ops.topk_select import select_mask
+
+    device = _require_device(platform)
+    B, T, H, KV, D, block = shape.sparse_dims
+    scale = D ** -0.5
+    keys = jax.random.split(jax.random.PRNGKey(0), 5)
+    q = jax.random.normal(keys[0], (B, T, H, D), jnp.bfloat16)
+    k, v = (jax.random.normal(key, (B, T, KV, D), jnp.bfloat16) for key in keys[1:3])
+    pull = jax.random.normal(keys[3], (B, T, H * D), jnp.float32)
+    # an indexer's selection: the top half of the positions by seeded scores,
+    # every past position up to there
+    live = jnp.arange(T)[None, :] <= jnp.arange(T)[:, None]
+    chosen = select_mask(
+        jax.random.normal(keys[4], (B, T, T)), jnp.broadcast_to(live, (B, T, T)),
+        T // 2)
+
+    def in_blocks(q, k, v, chosen, scale):
+        """The masked-dense form a block of queries at a time, each over the
+        keys its mask can reach, its scores recomputed in the backward."""
+        @jax.checkpoint
+        def one(q, k, v, mask):
+            Tq, Tk = q.shape[1], k.shape[1]
+            scores = jnp.einsum(
+                "bqkgd,bskd->bkgqs", q.reshape(B, Tq, KV, H // KV, D), k,
+                preferred_element_type=jnp.float32) * scale
+            probs = jax.nn.softmax(
+                jnp.where(mask[:, None, None], scores, -jnp.inf), axis=-1)
+            out = jnp.einsum("bkgqs,bskd->bqkgd", probs.astype(v.dtype), v,
+                             preferred_element_type=jnp.float32)
+            shared = jnp.pad(jnp.sum(probs, axis=(1, 2)), ((0, 0), (0, 0), (0, T - Tk)))
+            return out.reshape(B, Tq, H * D), shared
+
+        outs = [one(q[:, lo:lo + block], k[:, :lo + block], v[:, :lo + block],
+                    chosen[:, lo:lo + block, :lo + block])
+                for lo in range(0, T, block)]
+        return tuple(jnp.concatenate(x, axis=1) for x in zip(*outs))
+
+    def all_five(attend):
+        def run(q, k, v, chosen):
+            (out, shared), pull_back = jax.vjp(
+                lambda q, k, v: attend(q, k, v, chosen, scale), q, k, v)
+            return (out, shared) + pull_back((pull, jnp.zeros_like(shared)))
+        return jax.jit(run)
+
+    ours = all_five(attend_selected)
+    kernels = ours.lower(q, k, v, chosen).as_text().count("tpu_custom_call")
+    _check(kernels == (4 if platform == "tpu" else 0),
+           f"{kernels} Pallas kernels lowered on {platform}")
+    got = ours(q, k, v, chosen)
+    want = all_five(in_blocks)(q, k, v, chosen)
+    info = {"device": device, "pallas_kernels": kernels,
+            "kept_share": float(jnp.sum(chosen) / (B * jnp.sum(live)))}
+    for name, a, b in zip(("out", "heads_sum", "dq", "dk", "dv"), got, want):
+        a, b = (np.asarray(x.astype(jnp.float32)) for x in (a, b))
+        _check(bool(np.isfinite(a).all()), f"{name}: not finite")
+        gap, size = float(np.abs(a - b).max()), float(np.abs(b).max())
+        # probabilities rounded to bfloat16 before their division and not
+        # after it, and bfloat16 gradients out of float32 sums on both sides
+        _check(gap <= size / 32, f"{name}: off by {gap} of {size}")
+        info[f"{name}_max_abs_err"] = gap
+        info[f"{name}_max_abs"] = size
+    return info
+
+
+# --------------------------------------------------------------------------
 # phase: more than one device
 # --------------------------------------------------------------------------
 
@@ -762,6 +850,7 @@ PHASES = {
     "plane": phase_plane,
     "forwards": phase_forwards,
     "grouped": phase_grouped,
+    "sparse_attn": phase_sparse_attn,
     "mesh": phase_mesh,
 }
 

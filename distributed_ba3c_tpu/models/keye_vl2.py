@@ -48,11 +48,16 @@ so from position 2,048 on they are 262,144 separate fetches a decode step
 2048, 512]`` copies they cost 3.68 ms of a 5.05 ms decode step (73 GB/s; the
 top-k's sort another 0.14 ms; my chip runs, PR 34). Nor can the selection
 skip a block: past the top-k half or more of the live rows are kept, so no
-block of 128 rows or more is empty. The unroll works in blocks of
-``q_chunk_size`` 512 queries, each over the keys its mask can reach: the
-indexer's scores, the exact top-k as a mask (``ops/topk_select.py``: no
-sort), the main scores under that mask, ``L_I`` from the same block; the
-products are masked-dense (the selection saves no product yet).
+block of 128 rows or more is empty. The unroll takes the indexer in blocks
+of ``q_chunk_size`` 512 queries, each over the keys its mask can reach (its
+scores, the exact top-k as a mask, ``ops/topk_select.py``: no sort), and the
+main attention of a layer in one call under the whole selection
+(``ops/sparse_attention.py``): on a TPU at whole-lane widths blocked Pallas
+kernels that keep a tile's scores in fast memory, visit the tiles up to the
+diagonal and are differentiated from each row's log-sum-exp; anywhere else the
+masked-dense form. ``L_I`` reads the main attention's probabilities summed
+over the heads, which the same call hands over. The selection saves no
+product: past the top-k no tile is empty of selected keys.
 
 The widths are the defaults below and are never cut. What IS cut is how
 much one chip holds (``benchmark/configs/keye-vl2-30b-a3b-recall-fused-
@@ -81,7 +86,7 @@ from distributed_ba3c_tpu.models import layers
 from distributed_ba3c_tpu.models.a3c import PolicyValue
 from distributed_ba3c_tpu.models.layers import layer_norm, rms_norm, rope
 from distributed_ba3c_tpu.models.policy import LOSS_TERMS
-from distributed_ba3c_tpu.ops import decode_attention, moe, ssm
+from distributed_ba3c_tpu.ops import decode_attention, moe, sparse_attention, ssm
 from distributed_ba3c_tpu.ops.topk_select import select_mask
 from distributed_ba3c_tpu.utils import profiling
 from distributed_ba3c_tpu.utils.profiling import device_scope
@@ -306,6 +311,15 @@ class KeyeVL2:
             q, k, np.arange(1, self.max_positions + 1))
         return float(np.mean(read)) / self.max_positions
 
+    def learner_tiles_visited_share(self) -> float:
+        """Of the dense ``T x T`` square's (query tile, key tile) pairs, the
+        share the learner's attention visits over an episode: a function of
+        the shapes and of where the unroll runs (1.0 where no kernel does)."""
+        q, k = (jax.ShapeDtypeStruct(
+            (1, self.max_positions, heads, self.head_dim), self.compute_dtype)
+            for heads in (self.num_attention_heads, self.num_key_value_heads))
+        return sparse_attention.tiles_visited_share(q, k)
+
     def epoch_stats(self, metrics: dict) -> dict:
         """An epoch's scalars from the step's metrics of this policy."""
         held = np.asarray(metrics["moe_tokens_per_expert"])
@@ -318,6 +332,9 @@ class KeyeVL2:
             "dsa_kept_share": float(np.sum(metrics["dsa_keys_selected"])) / max(live, 1.0),
             # of the K/V buffers' rows, the share in blocks the decode fetched
             "dsa_decode_rows_read_share": self.decode_rows_read_share(),
+            # of the T x T (query tile, key tile) pairs, the share the
+            # learner's attention visited
+            "dsa_learner_tiles_visited_share": self.learner_tiles_visited_share(),
             "indexer_kl": float(np.sum(metrics["indexer_kl"])),
             "carry_bytes_per_env": float(np.sum(metrics["carry_bytes_per_env"])),
         }
@@ -362,15 +379,13 @@ class KeyeVL2:
             pos=pos + 1, kv=tuple(kv_out), index_keys=tuple(idx_out))
 
     # -- the learner's unroll ----------------------------------------------------
-    def _block(self, q, k, v, qi, ki, w, lo: int):
-        """Queries ``[lo, lo + len(q))`` of an episode over keys ``[0, lo +
-        len(q))``: q [B, Tq, H, D]; k, v [B, Tk, KV, D]; the indexer's qi
-        [B, Tq, Hi, Di], ki [B, Tk, Di], w [B, Tq, Hi]. -> (attention out
-        [B, Tq, H * D] float32, ``sum_t KL_t`` of the block, keys selected,
-        keys live, the selection [B, Tq, Tk] bool)."""
-        B, Tq, H, D = q.shape
-        Tk, KV = k.shape[1], k.shape[2]
-        cd = self.compute_dtype
+    def _select(self, qi, ki, w, lo: int):
+        """The indexer over queries ``[lo, lo + len(qi))`` of an episode and
+        keys ``[0, lo + len(qi))``: qi [B, Tq, Hi, Di], ki [B, Tk, Di], w [B,
+        Tq, Hi] -> (the index scores [B, Tq, Tk] float32, the selection [B,
+        Tq, Tk] bool, keys selected, keys live)."""
+        B, Tq = qi.shape[:2]
+        Tk = ki.shape[1]
         at_q = lo + jnp.arange(Tq)[:, None]
         live = jnp.broadcast_to(jnp.arange(Tk)[None, :] <= at_q, (B, Tq, Tk))
         with device_scope(profiling.OP_INDEXER):
@@ -378,26 +393,23 @@ class KeyeVL2:
             with device_scope(profiling.OP_INDEXER_SELECT):
                 chosen = select_mask(
                     jax.lax.stop_gradient(index), live, self.index_topk)
-        with device_scope(profiling.OP_ATTN_SPARSE):
-            scores = jnp.einsum(
-                "bqkgd,bskd->bkgqs", q.reshape(B, Tq, KV, H // KV, D), k,
-                preferred_element_type=jnp.float32) / math.sqrt(D)
-            scores = jnp.where(chosen[:, None, None], scores, -jnp.inf)
-            probs = jax.nn.softmax(scores, axis=-1)
-            out = jnp.einsum("bkgqs,bskd->bqkgd", probs.astype(cd), v,
-                             preferred_element_type=jnp.float32)
+        count = lambda m: jnp.sum(m, dtype=jnp.int32)  # noqa: E731
+        return index, chosen, count(chosen), count(live)
+
+    def _index_loss(self, index, chosen, shared):
+        """``sum_t KL_t`` of a block of queries: the index scores and the
+        selection [B, Tq, Tk] against the main attention's probabilities
+        summed over the heads, ``shared`` [B, Tq, Tk]."""
         with device_scope(profiling.OP_INDEXER), device_scope(
                 profiling.OP_INDEXER_LOSS):
             # the main attention's distribution over the selected keys, all
             # heads together; each head's sums to one, so theirs to H
-            target = jax.lax.stop_gradient(jnp.sum(probs, axis=(1, 2))) / H
+            target = jax.lax.stop_gradient(shared) / self.num_attention_heads
             log_q = jax.nn.log_softmax(jnp.where(chosen, index, -jnp.inf), axis=-1)
             there = chosen & (target > 0)
-            kl = jnp.sum(jnp.where(
+            return jnp.sum(jnp.where(
                 there, target * (jnp.log(jnp.where(there, target, 1.0))
                                  - jnp.where(there, log_q, 0.0)), 0.0))
-        count = lambda m: jnp.sum(m, dtype=jnp.int32)  # noqa: E731
-        return out.reshape(B, Tq, H * D), kl, count(chosen), count(live), chosen
 
     def _layer_unroll(self, i: int, p, x, with_selection: bool):
         """One layer over whole episodes: x [B, T, d] float32 -> (x, the
@@ -410,23 +422,30 @@ class KeyeVL2:
             q, k, v = self._qkv(p, z, positions)
         with device_scope(profiling.OP_INDEXER):
             qi, ki, w = self._index_qkw(p, z, positions)
+        # the indexer in blocks of queries, each over the keys its mask can
+        # reach; a block's [B, Tq, Hi, Tk] float32 dots are recomputed in the
+        # backward, not kept
         size = ssm.chunk_length(T, self.q_chunk_size)
-        block = jax.checkpoint(self._block, static_argnums=(6,))
-        outs, kl, selected, live, masks = [], 0.0, 0, 0, []
+        select = jax.checkpoint(self._select, static_argnums=(3,))
+        blocks, selected, live = [], 0, 0
         for lo in range(0, T, size):
             hi = lo + size
-            out, kl_b, sel_b, live_b, chosen = block(
-                q[:, lo:hi], k[:, :hi], v[:, :hi], qi[:, lo:hi], ki[:, :hi],
-                w[:, lo:hi], lo)
-            outs.append(out)
-            kl, selected, live = kl + kl_b, selected + sel_b, live + live_b
-            if with_selection:
-                masks.append(jnp.pad(chosen, ((0, 0), (0, 0), (0, T - hi))))
+            index, chosen, sel_b, live_b = select(
+                qi[:, lo:hi], ki[:, :hi], w[:, lo:hi], lo)
+            blocks.append((lo, hi, index, chosen))
+            selected, live = selected + sel_b, live + live_b
+        chosen = jnp.concatenate(
+            [jnp.pad(c, ((0, 0), (0, 0), (0, T - hi))) for _, hi, _, c in blocks],
+            axis=1)
         with device_scope(profiling.OP_ATTN_SPARSE):
-            h = x + self._mm(jnp.concatenate(outs, axis=1), p["wo"])
+            out, shared = sparse_attention.attend_selected(
+                q, k, v, chosen, 1.0 / math.sqrt(self.head_dim))
+            h = x + self._mm(out, p["wo"])
+        loss = jax.checkpoint(self._index_loss)
+        kl = sum(loss(index, c, shared[:, lo:hi, :hi]) for lo, hi, index, c in blocks)
         y, routed = self._ffn(p, h.reshape(B * T, d))
         return (y.reshape(B, T, d), kl / (B * T), selected, live, routed,
-                jnp.concatenate(masks, axis=1) if with_selection else None)
+                chosen if with_selection else None)
 
     def unroll(self, params, tokens, with_routes: bool = False):
         """Whole episodes from a reset: ``tokens`` [B, T] int32 ->

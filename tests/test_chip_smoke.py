@@ -51,6 +51,12 @@ def test_phase_at_small_shape_on_cpu_mesh(phase, tmp_path, child_env):
         # off the chip the product is ragged_dot itself: no kernel, no gap
         assert info["pallas_kernels"] == 0 and info["rows_held"] > 0
         assert info["forward_max_abs_err"] == info["dw_max_abs_err"] == 0.0
+    elif phase == "sparse_attn":
+        # off the chip the op is the masked-dense form, whole: no kernel, and
+        # float32 rounding's gap to the same form in blocks of queries
+        assert info["pallas_kernels"] == 0 and 0.5 < info["kept_share"] < 1.0
+        assert info["out_max_abs_err"] < 1e-2 * info["out_max_abs"]
+        assert info["dk_max_abs_err"] < 1e-2 * info["dk_max_abs"]
     else:
         assert info["sharded_over"] == list(range(8))
         assert info["replicated_leaves"] > 0

@@ -33,7 +33,7 @@ from distributed_ba3c_tpu.fused.loop import (  # noqa: E402
 )
 from distributed_ba3c_tpu.models import keye_vl2, layers, policy  # noqa: E402
 from distributed_ba3c_tpu.models.keye_vl2 import CUTS, INDEXER_LEAVES, KeyeVL2  # noqa: E402
-from distributed_ba3c_tpu.ops import decode_attention, moe  # noqa: E402
+from distributed_ba3c_tpu.ops import decode_attention, moe, sparse_attention  # noqa: E402
 from distributed_ba3c_tpu.ops.gradproc import make_optimizer  # noqa: E402
 from distributed_ba3c_tpu.ops.topk_select import select_mask  # noqa: E402
 from distributed_ba3c_tpu.parallel.mesh import make_mesh  # noqa: E402
@@ -347,8 +347,10 @@ def test_a_planted_key_only_the_indexer_can_find():
     qi, ki, w = 0.1 * normal(1, T, 2, 8), 0.1 * normal(1, T, 8), np.ones((1, T, 2), np.float32)
     qi[..., 0] += 1.0
     ki[:, planted, 0] = 50.0
-    out, _, n_sel, n_live, chosen = jax.jit(
-        lambda *a: model._block(*a, 0))(q, k, v, qi, ki, w)
+    _, chosen, n_sel, n_live = jax.jit(
+        lambda *a: model._select(*a, 0))(qi, ki, w)
+    out, _ = jax.jit(lambda *a: sparse_attention.attend_selected(*a, 0.25))(
+        q, k, v, chosen)
     chosen = np.asarray(chosen)[0]
     assert chosen[planted:, planted].all()
     at = np.arange(T)
@@ -463,6 +465,93 @@ def test_a_fresh_token_forgets_the_episode_before_with_the_kernel(kernel_path, a
         want = jnp.concatenate([first.logits, second.logits], axis=1)[0]
         assert float(jnp.abs(logits[env] - want).max()) < 2e-5 * float(
             jnp.abs(want).max()), env
+
+
+# -- the learner's attention through its kernels (ops/sparse_attention.py) -------------
+@pytest.fixture(scope="module")
+def unroll_both_ways():
+    """The whole-lane cut's unroll and the gradient of a differentiated
+    total, through the masked-dense form and through the learner's Pallas
+    kernels (interpreted, in tiles of 128 positions: two a side), in float32
+    and in bfloat16."""
+    def run(dtype):
+        model = lanes(dtype)
+        params, tokens = params_of(7, LANES_SPEC), tokens_of(17, 2, LANES_EPISODE)
+
+        def forward(p, t):
+            out, aux = model.unroll(p, t, with_routes=True)
+            return out.logits, out.value, jnp.sum(
+                aux[policy.LOSS_TERMS]["indexer_kl"]), aux
+
+        def total(p, t):
+            logits, value, kl, aux = forward(p, t)
+            return _total(lambda p, t: (logits, value, kl))(p, t), (logits, value, aux)
+
+        (_, (logits, value, aux)), grads = jax.jit(
+            jax.value_and_grad(total, has_aux=True))(params, tokens)
+        return logits, value, aux, grads, model.learner_tiles_visited_share()
+
+    found = {}
+    for dtype in (jnp.float32, jnp.bfloat16):
+        dense = run(dtype)
+        with pytest.MonkeyPatch.context() as patch:
+            patch.setattr(sparse_attention, "INTERPRET", True)
+            patch.setattr(sparse_attention, "TILE", 128)
+            found[dtype] = (dense, run(dtype))
+    return found
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5), (jnp.bfloat16, 0.02)])
+def test_unroll_through_the_kernels_is_the_masked_dense_unroll(
+        unroll_both_ways, dtype, tol):
+    """Logits, value, ``L_I`` and the counts; the selections bit for bit in
+    float32 (in bfloat16 a rounding that differs flips a near-tie of a
+    selection or of a router at a token here and there, as in the decode's
+    test above: nine tokens in ten are held to the tolerance there)."""
+    (logits, value, aux, _, share), (k_logits, k_value, k_aux, _, k_share) = \
+        unroll_both_ways[dtype]
+    assert share == 1.0 and k_share == 3 / 4  # of 2 x 2 tiles, the causal 3
+    scale = float(jnp.abs(logits).max())
+    gap = np.asarray(jnp.abs(k_logits - logits).max(axis=2))
+    held = gap.max() if dtype == jnp.float32 else np.quantile(gap, 0.9)
+    assert np.isfinite(gap).all() and held < tol * scale, np.sort(gap.ravel())[-8:]
+    kl, k_kl = (np.asarray(a[policy.LOSS_TERMS]["indexer_kl"]) for a in (aux, k_aux))
+    np.testing.assert_allclose(k_kl, kl, rtol=50 * tol)
+    for count in ("dsa_keys_selected", "dsa_keys_live"):
+        np.testing.assert_array_equal(k_aux[count], aux[count])
+    assert aux["selected"].dtype == jnp.uint8
+    assert aux["selected"].shape == (2, 2, LANES_EPISODE, LANES_EPISODE // 8)
+    if dtype == jnp.float32:
+        assert float(jnp.abs(k_value - value).max()) < tol
+        np.testing.assert_array_equal(k_aux["selected"], aux["selected"])
+        np.testing.assert_array_equal(k_aux["routes"], aux["routes"])
+    else:
+        assert float(np.mean(unpacked(k_aux["selected"], LANES_EPISODE)
+                             != unpacked(aux["selected"], LANES_EPISODE))) < 0.01
+
+
+_LANES_LEAVES = [(layer, leaf) for layer, leaves in sorted(jax.eval_shape(
+    lambda k: reference.init_params(k, LANES_SPEC), jax.random.PRNGKey(0)).items())
+    for leaf in sorted(leaves)]
+
+
+@pytest.mark.parametrize("layer,leaf", _LANES_LEAVES)
+def test_a_leafs_gradient_through_the_kernels_is_the_masked_dense_forms(
+        unroll_both_ways, layer, leaf):
+    """Every leaf of the differentiated total (A2C-shaped loss + ``L_I``):
+    float32 tightly. In bfloat16 the two roundings flip 0.2 % of the
+    selections' bits and 1.5 % of the routes, which at this size (two held
+    experts, a top-k of 8) moves a leaf's gradient by 5-25 % of its norm:
+    held there to finite and to under a third (the kernels' own bfloat16
+    gradients are held to rounding in tests/test_sparse_attention.py)."""
+    (*_, want, _), (*_, got, _) = unroll_both_ways[jnp.float32]
+    want, got = np.asarray(want[layer][leaf]), np.asarray(got[layer][leaf])
+    assert np.abs(want).max() > 0, "a leaf the loss does not reach"
+    np.testing.assert_allclose(got, want, atol=2e-4 * np.abs(want).max())
+    (*_, want, _), (*_, got, _) = unroll_both_ways[jnp.bfloat16]
+    want, got = np.asarray(want[layer][leaf]), np.asarray(got[layer][leaf])
+    assert np.isfinite(got).all()
+    assert np.linalg.norm(got - want) < 0.33 * np.linalg.norm(want)
 
 
 # -- the experts: softmax scoring, and the shares add up -------------------------------
@@ -605,6 +694,8 @@ def test_a_fused_update_moves_the_state_and_reports_its_counters(one_update):
     assert stats["indexer_kl"] == pytest.approx(float(np.sum(metrics["indexer_kl"])))
     # no kernel at this cut on the CPU: the decode read whole buffers
     assert stats["dsa_decode_rows_read_share"] == 1.0
+    # nor for the learner: the masked-dense form weighed every tile
+    assert stats["dsa_learner_tiles_visited_share"] == 1.0
     assert stats["carry_bytes_per_env"] == float(sum(model.carry_bytes()))
     moved = jax.tree_util.tree_map(
         lambda a, b: float(jnp.abs(a - b).max()), new.train.params,
