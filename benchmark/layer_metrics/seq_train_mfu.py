@@ -6,17 +6,11 @@ against their keys and values at the episode's mean context; the scans'
 the window's updates trained on, over the window and the chip's bf16 peak.
 Recomputed forwards are not counted.
 
-Beside it the line prints the update's device time and the trainer's phases
-on this cell: the shared metrics that report them (``update_device_ms``,
-``rollout_time_share``, ..) cannot list this cell until a ``benchmark``
-issue relaxes ``tests/benchmark/test_benchmark_lm.py`` (PERF.md section 7),
-and a later change to this cell has to start from them."""
+The update's device time and the trainer's phases on this cell are the
+shared metrics' (``update_device_ms``, ``rollout_time_share``, ..), which
+list it since PR 40."""
 
 from benchmark import opcount_phi4flash as opcount
-from benchmark import scopes
-
-PHASES = ("ROLLOUT", "RETURNS", "LEARNER_FWD", "LEARNER_BWD", "GRAD_REDUCE",
-          "OPTIMIZER", "METRICS", "UNSCOPED")
 
 ROW = {
     "name": "seq_train_mfu", "unit": "%", "better": "higher",
@@ -35,13 +29,5 @@ def read(ctx):
     a_step = opcount.flops_per_env_step(cfg, int(c["rollout_len"]))
     print(f"seq_train_mfu: {a_step / 1e6:.1f} MFLOP an env-step, "
           f"{env_steps:.0f} env-steps in {tr.window_s():.3f} s")
-    update_ms = tr.module_ms(cfg["trace"]["update_module"])
-    if scopes.capture(ctx) is not None and update_ms is not None:
-        try:
-            phases = scopes.shares_line(ctx, *PHASES)
-        except (AttributeError, KeyError):
-            phases = "no phases: a program from before these scopes"
-        print(f"seq_train_mfu: an update {update_ms:.1f} ms on the chip, busy "
-              f"{tr.busy_s():.3f} s of the window; {phases}")
     return 100.0 * env_steps * a_step / (
         tr.window_s() * ctx["peaks"]["bf16_flops_per_s"])

@@ -81,8 +81,10 @@ def test_the_cell_its_files_and_its_driver_are_found_by_name(bench, config):
 
 @pytest.mark.parametrize("name", NEW_METRICS)
 def test_a_new_metric_lists_the_new_cell_alone_and_its_row_agrees(bench, name):
+    # first in its list; which later cells are appended after it is not held
     entry = [m for m in bench.doc["per_layer"] if m["name"] == name]
-    assert len(entry) == 1 and entry[0]["workloads"] == [CELL]
+    assert len(entry) == 1 and entry[0]["workloads"][0] == CELL
+    assert entry[0]["workloads"].count(CELL) == 1
     assert entry[0]["moves"] == "env_steps_per_s_per_chip"
     module = bench.layer_metric(name)  # raises where ROW and entry differ
     assert callable(module.read)
@@ -92,10 +94,11 @@ def test_a_new_metric_lists_the_new_cell_alone_and_its_row_agrees(bench, name):
 
 @pytest.mark.parametrize("name", SHARED_METRICS)
 def test_a_shared_metric_has_the_new_cell_appended(bench, name):
+    # once, after the three conv cells; later cells are appended after it
     entry = [m for m in bench.doc["per_layer"] if m["name"] == name][0]
-    assert entry["workloads"][-1] == CELL and entry["workloads"].count(CELL) == 1
-    assert entry["workloads"][:3] == [
-        "fused-pong-256x20", "fused-pong-4096x20", "fused-pong-4chip-1024x20"]
+    assert entry["workloads"].count(CELL) == 1
+    assert entry["workloads"][:4] == [
+        "fused-pong-256x20", "fused-pong-4096x20", "fused-pong-4chip-1024x20", CELL]
 
 
 @pytest.mark.parametrize("name", ["train_mfu", "conv_time_share",

@@ -142,9 +142,9 @@ def test_a_new_metric_reads_nothing_where_there_is_nothing_to_read(bench, name):
 
 @pytest.mark.parametrize("name", SHARED_METRICS)
 def test_a_shared_metric_lost_no_cell(bench, name):
-    """Whether a shared metric lists this cell is not held here (PERF.md
-    section 7 has why this PR could not add it, and what a ``benchmark``
-    issue has to do); that none lost a cell it had is."""
+    """Whether a shared metric lists this cell is for the reader's value on
+    the chip to decide (PR 40 appended it to all eleven) and is not held
+    here; that none lost a cell it had is."""
     entry = [m for m in bench.doc["per_layer"] if m["name"] == name][0]
     had = set(ACCEPTED_CELLS) if name != "head_loss_time_share" else {
         "fused-lfm2moe-recall-128x256"}

@@ -58,7 +58,7 @@ def test_entries_have_just_the_contract_keys(bench):
     for c in doc["configs"]:
         assert set(c) == {"name", "source", "file", "reduced", "why"}
         assert c["file"].startswith("benchmark/") and os.path.isfile(
-            os.path.join(ROOT, c["file"]))
+            os.path.join(bench.root, c["file"]))
         assert all(NAME.match(k) for k in c["reduced"]) and len(c["reduced"]) <= 16
     for w in doc["workloads"]:
         assert set(w) == {"name", "config", "traffic", "chips", "why"}

@@ -86,10 +86,16 @@ def test_a_start_up_metric_lists_every_cell_and_its_row_agrees(bench, name):
 
 
 def test_the_five_entries_are_appended_and_nothing_that_was_there_moved(bench):
+    """Once each, in their order among themselves, after what PR 34 had put
+    last; how many entries a later PR appends after them is not held."""
     names = [m["name"] for m in bench.doc["per_layer"]]
-    assert names[-5:] == list(NEW_METRICS)
-    assert names[0] == "first_dispatch_s" and "fused-keyevl2-recall-16x4096" not in [
-        m for m in bench.doc["per_layer"] if m["name"] == "first_dispatch_s"][0]["workloads"]
+    at = [names.index(n) for n in NEW_METRICS]
+    assert all(names.count(n) == 1 for n in NEW_METRICS)
+    assert at == sorted(at) and at[0] > names.index("select_kept_share")
+    first = bench.doc["per_layer"][0]
+    assert first["name"] == "first_dispatch_s" and first["workloads"][:4] == [
+        "fused-pong-256x20", "fused-pong-4096x20", "fused-pong-4chip-1024x20",
+        "fused-lfm2moe-recall-128x256"]
 
 
 @pytest.mark.parametrize("name,value", [
