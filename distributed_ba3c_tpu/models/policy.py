@@ -23,7 +23,8 @@ A policy is what the trainers call to turn observations into
   ``aux`` is a dict of whatever the policy counts in its learner (summed
   over chunks and shards into the step's metrics; may be empty); ``step``
   and ``unroll`` agree position by position (tests/test_lfm2_moe.py,
-  tests/test_phi4_flash.py, tests/test_keye_vl2.py). Under the one reserved
+  tests/test_phi4_flash.py, tests/test_keye_vl2.py,
+  tests/test_olmo_hybrid.py). Under the one reserved
   key :data:`LOSS_TERMS` the unroll's ``aux`` may hold **loss terms the
   policy owns**: ``{name: array}``, each entry a mean over the chunk's
   tokens (a scalar, or one a layer) with its coefficient applied. The
@@ -101,9 +102,15 @@ def _keye_vl2(cfg, cut=None):
     return KeyeVL2(num_actions=cfg.num_actions, **cut_fields(cut))
 
 
+def _olmo_hybrid(cfg, cut=None):
+    from distributed_ba3c_tpu.models.olmo_hybrid import OlmoHybrid, cut_fields
+
+    return OlmoHybrid(num_actions=cfg.num_actions, **cut_fields(cut))
+
+
 MODELS: Dict[str, Callable] = {
     DEFAULT_MODEL: _ba3cnet, "lfm2-moe": _lfm2_moe, "phi4-flash": _phi4_flash,
-    "keye-vl2": _keye_vl2,
+    "keye-vl2": _keye_vl2, "olmo-hybrid": _olmo_hybrid,
 }
 
 

@@ -114,9 +114,20 @@ OP_INDEXER = "op_indexer"
 OP_INDEXER_SCORES = "op_indexer/scores"
 OP_INDEXER_SELECT = "op_indexer/select"
 OP_INDEXER_LOSS = "op_indexer/loss"
+#: a gated delta-rule linear-attention mixer (models/olmo_hybrid.py with
+#: ops/delta_rule.py): ``in_proj`` (``W_qkv``, ``W_z``, the gates' ``W_a`` and
+#: ``W_b``), ``conv``, ``delta`` (the recurrence alone: one position from the
+#: carried state in the decode step, the chunked form in the unroll), ``out``
+#: (the gated per-head norm and ``W_o``). That policy's full-attention layer
+#: opens ``op_attn_full``, with ``decode_attend`` inside it round the kernel
+OP_LINATTN = "op_linattn"
+OP_LINATTN_IN_PROJ = "op_linattn/in_proj"
+OP_LINATTN_CONV = "op_linattn/conv"
+OP_LINATTN_DELTA = "op_linattn/delta"
+OP_LINATTN_OUT = "op_linattn/out"
 #: the layers each sequence policy opens (models/lfm2_moe.py with ops/moe.py;
-#: models/phi4_flash.py with ops/ssm.py; models/keye_vl2.py with ops/moe.py):
-#: a step holds its own policy's
+#: models/phi4_flash.py with ops/ssm.py; models/keye_vl2.py with ops/moe.py;
+#: models/olmo_hybrid.py with ops/delta_rule.py): a step holds its own policy's
 LFM2_LAYERS = (
     EMBED, OP_CONV, OP_ATTN, FFN_DENSE, MOE, MOE_ROUTER, MOE_DISPATCH,
     MOE_EXPERTS, MOE_EXPERTS_GMM, MOE_COMBINE, HEAD,
@@ -131,11 +142,14 @@ KEYE_VL2_LAYERS = (
     OP_INDEXER_SCORES, OP_INDEXER_SELECT, OP_INDEXER_LOSS, MOE, MOE_ROUTER,
     MOE_DISPATCH, MOE_EXPERTS, MOE_EXPERTS_GMM, MOE_COMBINE, HEAD,
 )
-POLICY_LAYERS = LFM2_LAYERS + tuple(
-    layer for layer in PHI4_FLASH_LAYERS if layer not in LFM2_LAYERS
-) + tuple(
-    layer for layer in KEYE_VL2_LAYERS
-    if layer not in LFM2_LAYERS + PHI4_FLASH_LAYERS)
+OLMO_HYBRID_LAYERS = (
+    EMBED, OP_LINATTN, OP_LINATTN_IN_PROJ, OP_LINATTN_CONV, OP_LINATTN_DELTA,
+    OP_LINATTN_OUT, OP_ATTN_FULL, f"{OP_ATTN_FULL}/{DECODE_ATTEND}", FFN_DENSE,
+    HEAD,
+)
+#: every policy's layers, each once, in the order they are first named
+POLICY_LAYERS = tuple(dict.fromkeys(
+    LFM2_LAYERS + PHI4_FLASH_LAYERS + KEYE_VL2_LAYERS + OLMO_HYBRID_LAYERS))
 #: the rollout's once-an-update bfloat16 snapshot of the matrix weights
 ROLLOUT_WEIGHTS_BF16 = "rollout/weights_bf16"
 

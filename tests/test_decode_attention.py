@@ -354,7 +354,8 @@ def no_compile_cache():
 @pytest.mark.timeout(300)
 @pytest.mark.parametrize("envs,heads,rows,width,selected", [
     (32, 40, 1024, 1280, False), (32, 40, 512, 1280, False),
-    (16, 32, 4096, 512, True)], ids=["shared-kv", "ring", "sparse"])
+    (16, 32, 4096, 512, True), (32, 10, 2048, 1280, False)],
+    ids=["shared-kv", "ring", "sparse", "ten-heads-ungrouped"])
 def test_the_kernel_compiles_for_a_v5e_at_the_cells_shapes(
         one_chip, no_compile_cache, monkeypatch, envs, heads, rows, width, selected):
     monkeypatch.setattr(da, "_backend_runs_mosaic", lambda: True)

@@ -331,6 +331,30 @@ def test_the_kernels_compile_for_a_v5e_at_the_cells_shapes(
     assert not _SCORES.search(text)
 
 
+@pytest.mark.timeout(300)
+def test_the_kernels_compile_ungrouped_and_without_a_selection(
+        one_chip, no_compile_cache, on_a_tpu):
+    """The linear-attention hybrid's full layer (models/olmo_hybrid.py): [2,
+    2048, 10, 128] against as many key/value heads, no selection: the first
+    cell to run that form."""
+    placed = lambda shape, dtype: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=one_chip)
+    q = placed((2, 2048, 10, 128), jnp.bfloat16)
+    weights = placed((2, 2048, 1280), jnp.float32)
+    assert sa.tile_of(q, q) == 512
+
+    def both(q, k, v, weights):
+        return jax.value_and_grad(lambda q, k, v: jnp.sum(
+            sa.attend_selected(q, k, v, None, 128 ** -0.5)[0] * weights),
+            (0, 1, 2))(q, k, v)
+
+    text = jax.jit(both).lower(q, q, q, weights).compile().as_text()
+    # the heads' sum is not asked for, so its kernel is not in the program
+    assert sorted(n.split("/")[-2] for n in _kernel_names(text)) == sorted(
+        set(_KERNELS) - {"sparse_attend_shared"})
+    assert not re.search(r"= (?:f32|bf16)\[2,10,(?:512|2048),\d{3,4}\]", text)
+
+
 #: an instruction that makes a score matrix of the cell's chunk: every head's
 #: [queries, keys] in float32 or bfloat16, the heads grouped or not
 _SCORES = re.compile(
