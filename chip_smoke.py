@@ -37,6 +37,14 @@ Phases (each fails the run; nothing is caught):
               heads' sum and three gradients against the masked-dense form in
               blocks of 512 queries on the same chip, the largest gaps in the
               result.
+``select``    the exact top-k as a mask (``ops/topk_select.py``) at the same
+              cell's shapes, a decode step's 16 rows of 4,096 keys and a
+              block of the learner's: rows no longer than the top-k, rows
+              that all fit, rows that do not and a batch of both, each the
+              plain searches' mask bit for bit; two Pallas kernels under the
+              scope ``op_indexer/select/radix`` in the compiled text (its
+              lines, and those in fast memory, in the result); a call's time
+              where every row fits under a call's where none does.
 ``mesh``      only with more than one device: env state and batch sharded
               over every device, and after K updates every param leaf's
               replicas bit-identical — the on-chip form of audit rule T3.
@@ -109,6 +117,9 @@ class Shape:
     # the learner's sparse attention: envs, positions, query heads, K/V
     # heads, head width, queries a block of the masked-dense form
     sparse_dims: tuple
+    # the selection: a decode step's rows and keys, the top-k, a block of
+    # the learner's (envs, queries, keys)
+    select_dims: tuple
 
 
 FULL = Shape(
@@ -120,6 +131,7 @@ FULL = Shape(
     serve_batch=256,
     grouped_dims=(5120, 2048, 1792, 8),
     sparse_dims=(2, 4096, 32, 4, 128, 512),
+    select_dims=(16, 4096, 2048, (2, 512, 2560)),
 )
 
 SMALL = Shape(
@@ -131,6 +143,7 @@ SMALL = Shape(
     serve_batch=8,
     grouped_dims=(256, 128, 128, 4),
     sparse_dims=(2, 64, 4, 2, 16, 16),
+    select_dims=(4, 64, 16, (2, 8, 40)),
 )
 
 
@@ -768,6 +781,95 @@ def phase_sparse_attn(shape: Shape, workdir: str, platform: str = "tpu") -> dict
 
 
 # --------------------------------------------------------------------------
+# phase: the exact top-k as a mask, its three regimes against the plain searches
+# --------------------------------------------------------------------------
+
+
+def phase_select(shape: Shape, workdir: str, platform: str = "tpu") -> dict:
+    del workdir
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from distributed_ba3c_tpu.ops import topk_select
+    from distributed_ba3c_tpu.ops.topk_select import select_mask
+    from distributed_ba3c_tpu.utils import profiling
+
+    device = _require_device(platform)
+    rows, keys, k, (envs, queries, block_keys) = shape.select_dims
+    seeds = jax.random.split(jax.random.PRNGKey(0), 2)
+    # an indexer's scores: rounded, so that values tie, and a row of one value
+    scores = jnp.round(jax.random.normal(seeds[0], (rows, keys)) * 64) / 64
+    scores = scores.at[0].set(0.0)
+    block = jax.random.normal(seeds[1], (envs, queries, block_keys))
+    at = (block_keys - queries) + jnp.arange(queries)[:, None]
+    causal = jnp.broadcast_to(jnp.arange(block_keys)[None, :] <= at, block.shape)
+    up_to = lambda pos: jnp.arange(keys)[None, :] <= pos[:, None]  # noqa: E731
+    spread = jnp.arange(rows)
+    decode_live = {
+        # every env in an episode's first k positions; every env past them;
+        # one env that fits beside others that do not
+        "fits": up_to(k - 1 - spread), "overflows": up_to(k + spread * 7),
+        "mixed": up_to(jnp.where(spread == 0, k // 2, keys - 1 - spread)),
+    }
+    ours = jax.jit(lambda s, a: select_mask(s, a, k))
+    plain = jax.jit(functools.partial(topk_select._searches, k=k))
+
+    both = jax.jit(lambda s, a, b, c: (select_mask(s, a, k), select_mask(b, c, k)))
+    text = both.lower(scores, decode_live["mixed"], block, causal).compile().as_text()
+    lines = [line for line in text.splitlines()
+             if f"/{profiling.OP_INDEXER_SELECT_RADIX.rsplit('/', 1)[-1]}/" in line]
+    kernels = sum("tpu_custom_call" in line for line in lines)
+    _check(kernels == (2 if platform == "tpu" else 0),
+           f"{kernels} Pallas kernels under the radix scope on {platform}")
+    info = {"device": device, "pallas_kernels": kernels,
+            "radix_lines": len(lines),
+            "radix_lines_in_fast_memory": sum("S(1)" in line for line in lines)}
+
+    head = decode_live["mixed"][:, :k]  # regime 1: keys no longer than k
+    _check("while" not in ours.lower(scores[:, :k], head).as_text(),
+           "a row no longer than the top-k lowered a loop")
+    _check(bool((ours(scores[:, :k], head) == head).all()),
+           "a row no longer than the top-k is not its live entries")
+    got = {name: ours(scores, live) for name, live in decode_live.items()}
+    for name, live in decode_live.items():
+        _check(bool((got[name] == plain(scores, live)).all()),
+               f"decode rows, {name}: not the plain searches' mask")
+        _check(bool((jnp.sum(got[name], -1)
+                     == jnp.minimum(jnp.sum(live, -1), k)).all()),
+               f"decode rows, {name}: not min(k, live) a row")
+        info[f"decode_{name}_selected"] = int(jnp.sum(got[name]))
+    _check(bool((got["fits"] == decode_live["fits"]).all()),
+           "rows that fit are not their live entries")
+    _check(bool(got["overflows"][0, :k].all()),
+           "a row of one value does not keep its first k positions")
+    _check(bool((ours(block, causal) == plain(block, causal)).all()),
+           "a learner's block: not the plain searches' mask")
+
+    def ms_a_call(live, calls=256):
+        def body(x, _):
+            mask = select_mask(x, live, k)
+            return x + mask * 1e-3, None
+        run = jax.jit(lambda x: jax.lax.scan(body, x, None, length=calls)[0])
+        jax.block_until_ready(run(scores))
+        best = float("inf")
+        for _ in range(3):
+            t0 = time.perf_counter()
+            jax.block_until_ready(run(scores))  # ba3clint: disable=J1 — the wait IS the measurement: 256 calls by the wall clock
+            best = min(best, time.perf_counter() - t0)
+        return 1e3 * best / calls
+
+    info["decode_fits_ms"] = ms_a_call(decode_live["fits"])
+    info["decode_overflows_ms"] = ms_a_call(decode_live["overflows"])
+    if platform == "tpu":  # the skip is a skip: not the searches' time
+        _check(info["decode_fits_ms"] < info["decode_overflows_ms"],
+               f"rows that fit took {info['decode_fits_ms']} ms a call, rows "
+               f"that do not {info['decode_overflows_ms']}")
+    return info
+
+
+# --------------------------------------------------------------------------
 # phase: more than one device
 # --------------------------------------------------------------------------
 
@@ -851,6 +953,7 @@ PHASES = {
     "forwards": phase_forwards,
     "grouped": phase_grouped,
     "sparse_attn": phase_sparse_attn,
+    "select": phase_select,
     "mesh": phase_mesh,
 }
 

@@ -86,7 +86,8 @@ from distributed_ba3c_tpu.models import layers
 from distributed_ba3c_tpu.models.a3c import PolicyValue
 from distributed_ba3c_tpu.models.layers import layer_norm, rms_norm, rope
 from distributed_ba3c_tpu.models.policy import LOSS_TERMS
-from distributed_ba3c_tpu.ops import decode_attention, moe, sparse_attention, ssm
+from distributed_ba3c_tpu.ops import (
+    decode_attention, moe, sparse_attention, ssm, topk_select)
 from distributed_ba3c_tpu.ops.topk_select import select_mask
 from distributed_ba3c_tpu.utils import profiling
 from distributed_ba3c_tpu.utils.profiling import device_scope
@@ -320,6 +321,25 @@ class KeyeVL2:
             for heads in (self.num_attention_heads, self.num_key_value_heads))
         return sparse_attention.tiles_visited_share(q, k)
 
+    def decode_selects_run_share(self) -> float:
+        """Of an episode's decode steps from a reset, the share whose row can
+        hold more than the top-k live keys, so that the selection's searches
+        run (``ops/topk_select.py``; at the others the mask is ``live``): a
+        function of the shapes."""
+        P = self.max_positions
+        return float(np.mean([
+            topk_select.runs_searches(P, pos + 1, self.index_topk)
+            for pos in range(P)]))
+
+    def learner_selects_run_share(self) -> float:
+        """The same of the unroll's blocks of queries, each over the keys up
+        to its last query."""
+        T = self.max_positions
+        size = ssm.chunk_length(T, self.q_chunk_size)
+        return float(np.mean([
+            topk_select.runs_searches(hi, hi, self.index_topk)
+            for hi in range(size, T + 1, size)]))
+
     def epoch_stats(self, metrics: dict) -> dict:
         """An epoch's scalars from the step's metrics of this policy."""
         held = np.asarray(metrics["moe_tokens_per_expert"])
@@ -335,6 +355,10 @@ class KeyeVL2:
             # of the T x T (query tile, key tile) pairs, the share the
             # learner's attention visited
             "dsa_learner_tiles_visited_share": self.learner_tiles_visited_share(),
+            # of the decode steps, and of the learner's blocks of queries,
+            # the share whose selection ran its searches
+            "dsa_decode_selects_run_share": self.decode_selects_run_share(),
+            "dsa_learner_selects_run_share": self.learner_selects_run_share(),
             "indexer_kl": float(np.sum(metrics["indexer_kl"])),
             "carry_bytes_per_env": float(np.sum(metrics["carry_bytes_per_env"])),
         }

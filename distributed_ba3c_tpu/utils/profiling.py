@@ -109,10 +109,14 @@ OP_ATTN_SPARSE = "op_attn_sparse"
 OP_ATTN_SPARSE_DECODE = f"{OP_ATTN_SPARSE}/{DECODE_ATTEND}"
 #: the indexer beside it: its projections; ``scores`` (``sum_j w_j
 #: relu(q_j . k)`` against every live key), ``select`` (the exact top-k),
-#: ``loss`` (its KL against the main attention's distribution)
+#: ``loss`` (its KL against the main attention's distribution). Inside
+#: ``select``, ``radix`` is open only round the two searches by bits
+#: (ops/topk_select.py): time there says they ran, none in an episode's
+#: first top-k positions that the mask was ``live`` and they were skipped
 OP_INDEXER = "op_indexer"
 OP_INDEXER_SCORES = "op_indexer/scores"
 OP_INDEXER_SELECT = "op_indexer/select"
+OP_INDEXER_SELECT_RADIX = "op_indexer/select/radix"
 OP_INDEXER_LOSS = "op_indexer/loss"
 #: a gated delta-rule linear-attention mixer (models/olmo_hybrid.py with
 #: ops/delta_rule.py): ``in_proj`` (``W_qkv``, ``W_z``, the gates' ``W_a`` and
@@ -139,8 +143,9 @@ PHI4_FLASH_LAYERS = (
 )
 KEYE_VL2_LAYERS = (
     EMBED, OP_ATTN_SPARSE, OP_ATTN_SPARSE_DECODE, OP_INDEXER,
-    OP_INDEXER_SCORES, OP_INDEXER_SELECT, OP_INDEXER_LOSS, MOE, MOE_ROUTER,
-    MOE_DISPATCH, MOE_EXPERTS, MOE_EXPERTS_GMM, MOE_COMBINE, HEAD,
+    OP_INDEXER_SCORES, OP_INDEXER_SELECT, OP_INDEXER_SELECT_RADIX,
+    OP_INDEXER_LOSS, MOE, MOE_ROUTER, MOE_DISPATCH, MOE_EXPERTS,
+    MOE_EXPERTS_GMM, MOE_COMBINE, HEAD,
 )
 OLMO_HYBRID_LAYERS = (
     EMBED, OP_LINATTN, OP_LINATTN_IN_PROJ, OP_LINATTN_CONV, OP_LINATTN_DELTA,

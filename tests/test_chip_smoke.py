@@ -57,6 +57,14 @@ def test_phase_at_small_shape_on_cpu_mesh(phase, tmp_path, child_env):
         assert info["pallas_kernels"] == 0 and 0.5 < info["kept_share"] < 1.0
         assert info["out_max_abs_err"] < 1e-2 * info["out_max_abs"]
         assert info["dk_max_abs_err"] < 1e-2 * info["dk_max_abs"]
+    elif phase == "select":
+        # off the chip the searches are the plain form itself: no kernel; the
+        # regimes' masks are counted a row: min(k, live)
+        rows, keys, k, _ = chip_smoke.SMALL.select_dims
+        assert info["pallas_kernels"] == 0 and info["radix_lines"] > 0
+        assert info["decode_fits_selected"] == sum(k - r for r in range(rows))
+        assert info["decode_overflows_selected"] == rows * k
+        assert info["decode_mixed_selected"] == k // 2 + 1 + (rows - 1) * k
     else:
         assert info["sharded_over"] == list(range(8))
         assert info["replicated_leaves"] > 0

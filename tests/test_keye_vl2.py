@@ -696,6 +696,10 @@ def test_a_fused_update_moves_the_state_and_reports_its_counters(one_update):
     assert stats["dsa_decode_rows_read_share"] == 1.0
     # nor for the learner: the masked-dense form weighed every tile
     assert stats["dsa_learner_tiles_visited_share"] == 1.0
+    # an episode of four times this cut's top-k: the selection's searches run
+    # in three decode steps of four and three of the learner's four blocks
+    assert stats["dsa_decode_selects_run_share"] == 1 - TOPK / EPISODE
+    assert stats["dsa_learner_selects_run_share"] == 1 - TOPK / EPISODE
     assert stats["carry_bytes_per_env"] == float(sum(model.carry_bytes()))
     moved = jax.tree_util.tree_map(
         lambda a, b: float(jnp.abs(a - b).max()), new.train.params,
