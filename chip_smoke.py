@@ -114,6 +114,9 @@ class Shape:
     serve_batch: int
     # grouped expert product: sorted rows, hidden, expert width, experts held
     grouped_dims: tuple
+    # the same at an expert width off whole lanes (1,856 = 14.5 lanes), which
+    # the kernels take as one whole tile
+    grouped_off_lane_dims: tuple
     # the learner's sparse attention: envs, positions, query heads, K/V
     # heads, head width, queries a block of the masked-dense form
     sparse_dims: tuple
@@ -130,6 +133,7 @@ FULL = Shape(
     plane_batch=128, plane_steps_per_epoch=20, staging_blocks=12,
     serve_batch=256,
     grouped_dims=(5120, 2048, 1792, 8),
+    grouped_off_lane_dims=(1024, 2688, 1856, 8),
     sparse_dims=(2, 4096, 32, 4, 128, 512),
     select_dims=(16, 4096, 2048, (2, 512, 2560)),
 )
@@ -142,6 +146,7 @@ SMALL = Shape(
     plane_batch=32, plane_steps_per_epoch=20, staging_blocks=5,
     serve_batch=8,
     grouped_dims=(256, 128, 128, 4),
+    grouped_off_lane_dims=(256, 128, 192, 4),
     sparse_dims=(2, 64, 4, 2, 16, 16),
     select_dims=(4, 64, 16, (2, 8, 40)),
 )
@@ -656,15 +661,27 @@ def phase_forwards(shape: Shape, workdir: str, platform: str = "tpu") -> dict:
 
 
 def phase_grouped(shape: Shape, workdir: str, platform: str = "tpu") -> dict:
+    """The product at the language-model cell's shapes; then, at a width off
+    whole lanes, the up product (the columns off the lane) and the down
+    product (the contracted dimension off the lane)."""
     del workdir
+    device = _require_device(platform)
+    info = _grouped_against_ragged_dot(*shape.grouped_dims, platform)
+    m, d, f, groups = shape.grouped_off_lane_dims
+    info["off_lane"] = {
+        "columns": _grouped_against_ragged_dot(m, d, f, groups, platform),
+        "contracted": _grouped_against_ragged_dot(m, f, d, groups, platform),
+    }
+    return dict(info, device=device)
+
+
+def _grouped_against_ragged_dot(m, d, f, groups, platform) -> dict:
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from distributed_ba3c_tpu.ops.grouped_matmul import grouped_dot
 
-    device = _require_device(platform)
-    m, d, f, groups = shape.grouped_dims
     # a seeded router's rows for the experts held here, one of them empty
     share = np.random.default_rng(0).dirichlet(np.full(groups, 50.0))
     sizes = np.floor(share * 0.8 * m).astype(np.int32)
@@ -692,7 +709,7 @@ def phase_grouped(shape: Shape, workdir: str, platform: str = "tpu") -> dict:
            f"{kernels} Pallas kernels lowered on {platform}")
     got = ours(lhs, rhs, jnp.asarray(sizes))
     want = all_three(jax.lax.ragged_dot)(lhs, rhs, jnp.asarray(sizes))
-    info = {"device": device, "rows_held": held, "pallas_kernels": kernels}
+    info = {"rows_held": held, "pallas_kernels": kernels}
     for name, a, b in zip(("forward", "dx", "dw"), got, want):
         a, b = (np.asarray(x.astype(jnp.float32)) for x in (a, b))
         _check(bool(np.isfinite(a).all()), f"{name}: not finite")

@@ -342,12 +342,9 @@ class KeyeVL2:
 
     def epoch_stats(self, metrics: dict) -> dict:
         """An epoch's scalars from the step's metrics of this policy."""
-        held = np.asarray(metrics["moe_tokens_per_expert"])
         live = float(np.sum(metrics["dsa_keys_live"]))
         return {
-            "moe_load_max_over_mean": float(np.max(
-                held.max(axis=-1) / np.maximum(held.mean(axis=-1), 1e-9))),
-            "moe_overflow_blocks": float(np.sum(metrics["moe_overflow_blocks"])),
+            **moe.load_stats(metrics),
             # of the keys a query could see, the share its indexer kept
             "dsa_kept_share": float(np.sum(metrics["dsa_keys_selected"])) / max(live, 1.0),
             # of the K/V buffers' rows, the share in blocks the decode fetched

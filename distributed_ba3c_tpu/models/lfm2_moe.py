@@ -41,7 +41,6 @@ from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from distributed_ba3c_tpu.models import layers
 from distributed_ba3c_tpu.models.a3c import PolicyValue
@@ -244,16 +243,7 @@ class LFM2MoE:
 
     def epoch_stats(self, metrics: dict) -> dict:
         """An epoch's scalars from the step's metrics of this policy."""
-        held = np.asarray(metrics["moe_tokens_per_expert"])
-        return {
-            # how evenly the router loads the experts held here: the fullest
-            # one's tokens over the mean, in the worst layer
-            "moe_load_max_over_mean": float(np.max(
-                held.max(axis=-1) / np.maximum(held.mean(axis=-1), 1e-9))),
-            # blocks of sorted rows the expert layers ran beyond their first
-            # (ops/moe.py): 0 while the rows routed here fit the bound
-            "moe_overflow_blocks": float(np.sum(metrics["moe_overflow_blocks"])),
-        }
+        return moe.load_stats(metrics)
 
     # -- the rollout's decode step ---------------------------------------------
     def init_carry(self, batch: int) -> Carry:

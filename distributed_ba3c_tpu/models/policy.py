@@ -24,7 +24,7 @@ A policy is what the trainers call to turn observations into
   over chunks and shards into the step's metrics; may be empty); ``step``
   and ``unroll`` agree position by position (tests/test_lfm2_moe.py,
   tests/test_phi4_flash.py, tests/test_keye_vl2.py,
-  tests/test_olmo_hybrid.py). Under the one reserved
+  tests/test_olmo_hybrid.py, tests/test_nemotron_h.py). Under the one reserved
   key :data:`LOSS_TERMS` the unroll's ``aux`` may hold **loss terms the
   policy owns**: ``{name: array}``, each entry a mean over the chunk's
   tokens (a scalar, or one a layer) with its coefficient applied. The
@@ -108,9 +108,16 @@ def _olmo_hybrid(cfg, cut=None):
     return OlmoHybrid(num_actions=cfg.num_actions, **cut_fields(cut))
 
 
+def _nemotron_h(cfg, cut=None):
+    from distributed_ba3c_tpu.models.nemotron_h import NemotronH, cut_fields
+
+    return NemotronH(num_actions=cfg.num_actions, **cut_fields(cut))
+
+
 MODELS: Dict[str, Callable] = {
     DEFAULT_MODEL: _ba3cnet, "lfm2-moe": _lfm2_moe, "phi4-flash": _phi4_flash,
     "keye-vl2": _keye_vl2, "olmo-hybrid": _olmo_hybrid,
+    "nemotron-h": _nemotron_h,
 }
 
 

@@ -37,10 +37,17 @@ products came to 110-230 KB of code a kernel, and the step holds 96 of them
 (4 expert layers x 2 places x 12 products): 10.7 MB more of executable to
 read back at every start, +2.2 s of set-up (PERF.md, PR 30).
 
+**A width off whole lanes is taken whole.** A block that spans a whole
+dimension is legal in Mosaic whatever its size, so where ``k`` or ``n`` is
+no multiple of 128 but is whole half-lanes and wider than a lane (an expert
+width of 1,856 = 14.5 lanes, ``models/nemotron_h.py``'s), that dimension is
+one tile and the loop inside the kernel ends on a shorter last chunk (1,856
+= 3 x 512 + 320). No padded copy of a matrix is made.
+
 **Which path runs is read off the input.** Mosaic needs a TPU and tiles
-that divide the shapes: on another backend, or where ``m``, ``k`` or ``n``
-is off a multiple of 128 (the ``tiny`` cut), ``grouped_dot`` *is*
-``jax.lax.ragged_dot``.
+that fit the shapes: on another backend, or where ``m`` is off a multiple
+of 128 or ``k`` or ``n`` is neither whole lanes nor such a width (the
+``tiny`` cut), ``grouped_dot`` *is* ``jax.lax.ragged_dot``.
 """
 
 from __future__ import annotations
@@ -81,6 +88,16 @@ def _divisors(x: int, cap: int):
             if x % t == 0]
 
 
+def _widths(x: int):
+    """Tiles a contracted dimension or the columns may take: the multiples
+    of LANE that divide ``x``, the largest first; of an ``x`` off whole
+    lanes, ``x`` itself where it is whole half-lanes and wider than a lane
+    (a block may span a whole dimension whatever its size), else none."""
+    if x % LANE:
+        return [x] if x > LANE and x % (LANE // 2) == 0 else []
+    return _divisors(x, x)
+
+
 def _vmem_bytes(form: str, tm: int, tk: int, tn: int, itemsize: int) -> int:
     """Double-buffered tiles of both operands and the output, and the
     float32 sum."""
@@ -97,10 +114,10 @@ def tiling(form: str, m: int, k: int, n: int, itemsize: int = 2):
     tiles the budget holds, the contracted dimension first: at the cell's
     shapes each form takes a whole expert matrix at once."""
     tms = _divisors(m, ROW_TILE)
-    if not tms or k % LANE or n % LANE:
+    if not tms:
         return None
-    for tk in _divisors(k, k):
-        for tn in _divisors(n, n):
+    for tk in _widths(k):
+        for tn in _widths(n):
             if _vmem_bytes(form, tms[0], tk, tn, itemsize) <= VMEM_BUDGET:
                 return tms[0], tk, tn
     return None
@@ -125,8 +142,16 @@ def _compiler_params(form: str, tiles, itemsize: int):
 
 
 def _chunk(width: int) -> int:
-    """Columns (or rows) of a tile a kernel's inner loop works on at once."""
-    return next(c for c in (512, 256, LANE) if width % c == 0)
+    """Columns (or rows) of a tile a kernel's inner loop works on at once:
+    a chunk that divides the width, or, of a width off whole lanes, 512 with
+    a shorter last chunk (:func:`_last_chunk`)."""
+    return next((c for c in (512, 256, LANE) if width % c == 0), 512)
+
+
+def _last_chunk(width: int) -> int:
+    """What is left of ``width`` after its whole chunks: 0 where they cover
+    it (every width of whole lanes)."""
+    return width % _chunk(width)
 
 
 def _rows_of_group(offsets, group, first_row, shape):
@@ -154,7 +179,7 @@ def gmm(lhs, rhs, group_sizes, tiles, transpose_rhs=False, interpret=False):
         num_nonzero_groups=rhs.shape[0], visit_empty_groups=False)
     contract = (((1,), (1 if transpose_rhs else 0,)), ((), ()))
 
-    chunk = _chunk(tn)
+    chunk, tail = _chunk(tn), _last_chunk(tn)
 
     def kernel(offsets, group_ids, m_tile_ids, lhs_ref, rhs_ref, out_ref,
                *acc_ref):
@@ -168,7 +193,9 @@ def gmm(lhs, rhs, group_sizes, tiles, transpose_rhs=False, interpret=False):
             # a tile's columns ``chunk`` at a time in a loop, not unrolled:
             # the kernel's code is a seventh of what the whole tile's is,
             # and the step holds 64 of these (set-up, PERF.md PR 30)
-            cols = pl.ds(pl.multiple_of(j * chunk, chunk), chunk)
+            work(pl.ds(pl.multiple_of(j * chunk, chunk), chunk), mine)
+
+        def work(cols, mine):
             product = jax.lax.dot_general(
                 lhs_ref[...], rhs_ref[cols, :] if transpose_rhs
                 else rhs_ref[:, cols], contract,
@@ -192,6 +219,9 @@ def gmm(lhs, rhs, group_sizes, tiles, transpose_rhs=False, interpret=False):
                 ).astype(out_ref.dtype)
 
         jax.lax.fori_loop(0, tn // chunk, columns, None)
+        if tail:  # a width off whole lanes: its last, shorter chunk
+            work(pl.ds(tn - tail, tail), _rows_of_group(
+                offsets, group_ids[i], m_tile_ids[i] * tm, (tm, tail)))
 
     def rhs_index(n_i, i, k_i, offsets, group_ids, m_tile_ids):
         return (group_ids[i],) + ((n_i, k_i) if transpose_rhs else (k_i, n_i))
@@ -237,7 +267,7 @@ def tgmm(lhs, g, group_sizes, tiles, interpret=False):
         group_sizes=group_sizes, m=m, tm=tm, start_group=jnp.int32(0),
         num_nonzero_groups=groups, visit_empty_groups=True)
 
-    chunk = _chunk(tk)
+    chunk, tail = _chunk(tk), _last_chunk(tk)
 
     def kernel(offsets, group_ids, m_tile_ids, lhs_ref, g_ref, out_ref, acc):
         i = pl.program_id(2)
@@ -252,6 +282,8 @@ def tgmm(lhs, g, group_sizes, tiles, interpret=False):
             # a fraction of the whole tile's code (see ``gmm``)
             jax.lax.fori_loop(0, tk // chunk, lambda j, _: fn(
                 pl.ds(pl.multiple_of(j * chunk, chunk), chunk)), None)
+            if tail:  # a width off whole lanes: its last, shorter chunk
+                fn(pl.ds(tk - tail, tail))
 
         def add(rows, lhs_rows, g_rows):
             acc[rows, :] += jax.lax.dot_general(
@@ -265,7 +297,7 @@ def tgmm(lhs, g, group_sizes, tiles, interpret=False):
         @pl.when((i == 0) | (group_ids[jnp.maximum(i - 1, 0)] != group))
         def _():
             over_rows(lambda rows: acc.__setitem__(
-                (rows, slice(None)), jnp.zeros((chunk, tn), jnp.float32)))
+                (rows, slice(None)), jnp.zeros((rows.size, tn), jnp.float32)))
 
         @pl.when(whole)
         def _():

@@ -9,6 +9,11 @@ it runs it (expert parallelism's own arithmetic, ROADMAP A3):
     out = sum_i w_i W2_i (silu(W1_i z) * W3_i z)     over the chosen i HELD here
 
 That is the ``sigmoid`` scoring (LFM2's: a bias that evens the load out).
+**Which expert form runs is read off the operands**: where ``w3`` is None
+an expert is ``W2_i relu(W1_i z)^2`` (Nemotron-H's: no gate matrix, two
+grouped products forward and not three); everything round the products is
+the same. A shared expert that every token takes is the policy's own dense
+product (``models/nemotron_h.py``): this layer does not learn of it.
 The ``softmax`` scoring (:func:`route` with ``scoring="softmax"``; Qwen3-MoE's
 and Keye-VL-2.0's) has no bias: ``s = softmax(z W_r)`` over all
 ``num_experts``, ``chosen`` the top k of ``s`` itself, ``w = s[chosen] / sum
@@ -70,6 +75,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from distributed_ba3c_tpu.ops.grouped_matmul import grouped_dot
 from distributed_ba3c_tpu.utils import profiling
@@ -136,14 +142,33 @@ def held_counts(experts, expert_offset: int, held: int, num_experts: int):
     return local, counts
 
 
-def block_rows(n: int, k: int, held: int, num_experts: int) -> int:
+def load_stats(metrics: dict) -> dict:
+    """An epoch's scalars from a step's two expert counters (a policy's
+    ``epoch_stats``): ``moe_tokens_per_expert`` [expert layers, held] and
+    ``moe_overflow_blocks`` [expert layers]."""
+    held = np.asarray(metrics["moe_tokens_per_expert"])
+    return {
+        # how evenly the router loads the experts held here: the fullest
+        # one's tokens over the mean, in the worst layer
+        "moe_load_max_over_mean": float(np.max(
+            held.max(axis=-1) / np.maximum(held.mean(axis=-1), 1e-9))),
+        # blocks of sorted rows the expert layers ran beyond their first:
+        # 0 while the rows routed here fit the bound
+        "moe_overflow_blocks": float(np.sum(metrics["moe_overflow_blocks"])),
+    }
+
+
+def block_rows(n: int, k: int, held: int, num_experts: int,
+               margin: float | None = None) -> int:
     """``R``: the sorted rows one block of the grouped form works on, from
     the shapes alone: the ``n * k * held / num_experts`` rows an even router
-    sends here, plus the margin, in whole tiles of the grouped kernel, and
-    never more than all ``n * k`` (a chip that holds every expert: one
-    block of everything, the layer as it was before there were blocks)."""
+    sends here, plus the margin (:data:`HELD_ROWS_MARGIN` where None), in
+    whole tiles of the grouped kernel, and never more than all ``n * k`` (a
+    chip that holds every expert: one block of everything, the layer as it
+    was before there were blocks)."""
     expected = n * k * held / num_experts
-    tiles = math.ceil(expected * (1 + HELD_ROWS_MARGIN) / ROW_TILE)
+    margin = HELD_ROWS_MARGIN if margin is None else margin
+    tiles = math.ceil(expected * (1 + margin) / ROW_TILE)
     return min(n * k, ROW_TILE * tiles)
 
 
@@ -218,10 +243,19 @@ def _combine_bwd(res, g):
 _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
+def _activation(gate, up, dtype):
+    """An expert's hidden rows from its first products, in float32: ``silu(
+    gate) * up``, or ``relu(gate)^2`` where the expert has no ``W3``."""
+    gate = gate.astype(jnp.float32)
+    if up is None:
+        return jnp.square(jax.nn.relu(gate)).astype(dtype)
+    return (jax.nn.silu(gate) * up.astype(jnp.float32)).astype(dtype)
+
+
 def _block(b, z, weights, w1, w3, w2, s: _Sorted, block: int):
     """Sorted rows ``[b * block, (b + 1) * block)`` through the experts and
     back to their tokens (``weights`` [k, N]): this block's part of the
-    layer, [N, d] float32."""
+    layer, [N, d] float32. ``w3`` None: the two-matrix expert."""
     k = weights.shape[0]
     lo = b * block
     with device_scope(profiling.MOE_DISPATCH):
@@ -242,10 +276,8 @@ def _block(b, z, weights, w1, w3, w2, s: _Sorted, block: int):
         rows = jnp.where(here[:, None], rows, 0)
     with device_scope(profiling.MOE_EXPERTS):
         gate = grouped_dot(rows, w1, sizes)
-        up = grouped_dot(rows, w3, sizes)
-        act = (jax.nn.silu(gate.astype(jnp.float32))
-               * up.astype(jnp.float32)).astype(z.dtype)
-        y = grouped_dot(act, w2, sizes)
+        up = None if w3 is None else grouped_dot(rows, w3, sizes)
+        y = grouped_dot(_activation(gate, up, z.dtype), w2, sizes)
     with device_scope(profiling.MOE_COMBINE):
         # the rows go back in the compute type (half the bytes of the
         # gather); the weighted sum over a token's k experts is float32
@@ -297,7 +329,9 @@ def _blocks_bwd(block, res, g):
 
     # a token's k rows may lie in different blocks: its cotangent adds up in
     # float32; the experts' matrices add up as their products leave them
-    acc = (_zeros(z, jnp.float32),) + tuple(_zeros(x) for x in operands[1:])
+    # (an absent ``w3`` has no cotangent: None, an empty tree, all through)
+    acc = (_zeros(z, jnp.float32),) + tuple(
+        None if x is None else _zeros(x) for x in operands[1:])
     d_z, *rest = jax.lax.fori_loop(0, _blocks_needed(s, block), body, acc)
     return (d_z.astype(z.dtype), *rest, None)
 
@@ -306,17 +340,21 @@ _blocks.defvjp(_blocks_fwd, _blocks_bwd)
 
 
 def expert_ffn(z, routing: Routing, w1, w3, w2, expert_offset: int,
-               num_experts: int):
-    """This chip's part of the routed feed-forward.
+               num_experts: int, rows_margin: float | None = None):
+    """This chip's part of the routed feed-forward. ``rows_margin``: a
+    policy's own room in a block of sorted rows over an even router's share
+    (:func:`block_rows`), where its router's load on the held experts is
+    known to stray further than :data:`HELD_ROWS_MARGIN`.
 
     ``z`` [N, d] in the compute type; ``w1``/``w3`` [held, d, f] and ``w2``
-    [held, f, d] in the compute type. -> (out [N, d] float32, tokens routed
+    [held, f, d] in the compute type; ``w3`` None for an expert of two
+    matrices, ``W2 relu(W1 z)^2``. -> (out [N, d] float32, tokens routed
     to each held expert [held] int32, blocks run beyond the first int32)."""
     n, k = routing.experts.shape
     if n <= DENSE_ROWS:
         return _every_token(z, routing, w1, w3, w2, expert_offset, num_experts)
     return _sorted_rows(z, routing, w1, w3, w2, expert_offset, num_experts,
-                        block_rows(n, k, w1.shape[0], num_experts))
+                        block_rows(n, k, w1.shape[0], num_experts, rows_margin))
 
 
 # a jit of its own, so that the places a step holds this layer (4 expert
@@ -357,10 +395,8 @@ def _every_token(z, routing: Routing, w1, w3, w2, expert_offset: int,
                       routing.weights[:, :, None], 0.0), axis=1)
     with device_scope(profiling.MOE_EXPERTS):
         gate = jnp.einsum("nd,edf->enf", z, w1)
-        up = jnp.einsum("nd,edf->enf", z, w3)
-        act = (jax.nn.silu(gate.astype(jnp.float32))
-               * up.astype(jnp.float32)).astype(z.dtype)
-        y = jnp.einsum("enf,efd->end", act, w2)
+        up = None if w3 is None else jnp.einsum("nd,edf->enf", z, w3)
+        y = jnp.einsum("enf,efd->end", _activation(gate, up, z.dtype), w2)
     with device_scope(profiling.MOE_COMBINE):
         out = jnp.einsum("end,ne->nd", y.astype(jnp.float32), share)
     return out, counts, jnp.zeros((), jnp.int32)

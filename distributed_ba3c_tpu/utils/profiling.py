@@ -75,6 +75,9 @@ MOE_EXPERTS = "moe/experts"
 #: products went to ``jax.lax.ragged_dot`` (another backend, a small cut)
 MOE_EXPERTS_GMM = "moe/experts/gmm"
 MOE_COMBINE = "moe/combine"
+#: a shared expert beside the routed ones (models/nemotron_h.py): a dense
+#: product every token takes, the policy's own and none of ops/moe.py's
+MOE_SHARED = "moe/shared"
 HEAD = "head"
 #: a state-space (Mamba) mixer and its parts: norm + input projection, the
 #: causal depthwise conv + silu, the selective scan alone (ops/ssm.py; the
@@ -129,9 +132,21 @@ OP_LINATTN_IN_PROJ = "op_linattn/in_proj"
 OP_LINATTN_CONV = "op_linattn/conv"
 OP_LINATTN_DELTA = "op_linattn/delta"
 OP_LINATTN_OUT = "op_linattn/out"
+#: a Mamba-2 mixer (models/nemotron_h.py with ops/ssd.py): ``in_proj`` (the
+#: block's norm and ``W_in``, the step sizes), ``conv``, ``ssd`` (the
+#: recurrence alone: one position from the carried state in the decode step,
+#: the chunked form in the unroll), ``out`` (the gated group norm and
+#: ``W_out``). That policy's attention block opens ``op_attn_full`` with
+#: ``decode_attend`` inside it, its expert blocks ``moe`` with ``moe/shared``
+OP_MAMBA2 = "op_mamba2"
+OP_MAMBA2_IN_PROJ = "op_mamba2/in_proj"
+OP_MAMBA2_CONV = "op_mamba2/conv"
+OP_MAMBA2_SSD = "op_mamba2/ssd"
+OP_MAMBA2_OUT = "op_mamba2/out"
 #: the layers each sequence policy opens (models/lfm2_moe.py with ops/moe.py;
 #: models/phi4_flash.py with ops/ssm.py; models/keye_vl2.py with ops/moe.py;
-#: models/olmo_hybrid.py with ops/delta_rule.py): a step holds its own policy's
+#: models/olmo_hybrid.py with ops/delta_rule.py; models/nemotron_h.py with
+#: ops/ssd.py and ops/moe.py): a step holds its own policy's
 LFM2_LAYERS = (
     EMBED, OP_CONV, OP_ATTN, FFN_DENSE, MOE, MOE_ROUTER, MOE_DISPATCH,
     MOE_EXPERTS, MOE_EXPERTS_GMM, MOE_COMBINE, HEAD,
@@ -152,9 +167,17 @@ OLMO_HYBRID_LAYERS = (
     OP_LINATTN_OUT, OP_ATTN_FULL, f"{OP_ATTN_FULL}/{DECODE_ATTEND}", FFN_DENSE,
     HEAD,
 )
+#: (no ``op_attn_full/decode_attend``: that scope is open round the decode's
+#: kernel alone, which takes 8 query heads a K/V head and this policy has 16)
+NEMOTRON_H_LAYERS = (
+    EMBED, OP_MAMBA2, OP_MAMBA2_IN_PROJ, OP_MAMBA2_CONV, OP_MAMBA2_SSD,
+    OP_MAMBA2_OUT, OP_ATTN_FULL, MOE, MOE_ROUTER, MOE_DISPATCH, MOE_EXPERTS,
+    MOE_EXPERTS_GMM, MOE_COMBINE, MOE_SHARED, HEAD,
+)
 #: every policy's layers, each once, in the order they are first named
 POLICY_LAYERS = tuple(dict.fromkeys(
-    LFM2_LAYERS + PHI4_FLASH_LAYERS + KEYE_VL2_LAYERS + OLMO_HYBRID_LAYERS))
+    LFM2_LAYERS + PHI4_FLASH_LAYERS + KEYE_VL2_LAYERS + OLMO_HYBRID_LAYERS
+    + NEMOTRON_H_LAYERS))
 #: the rollout's once-an-update bfloat16 snapshot of the matrix weights
 ROLLOUT_WEIGHTS_BF16 = "rollout/weights_bf16"
 

@@ -129,6 +129,43 @@ def test_the_inner_loops_chunks_cover_the_tile(k, n, chunk):
 
 
 # -- gradients of both operands, through grouped_dot ----------------------------
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("k,n", [(128, 1856), (1856, 128)],
+                         ids=["columns-off-the-lane", "contracted-off-the-lane"])
+def test_at_fourteen_and_a_half_lanes_the_kernel_is_ragged_dot(interpreted, k, n, dtype):
+    """Forward, dx and dW through ``grouped_dot`` at a width of 1,856 under
+    the interpreter: three kernels, no ``ragged_dot``, its values (forward
+    and dx in bfloat16 to one place: float32 sums rounded once)."""
+    m = 256
+    sizes = jnp.asarray((100, 0, 120), jnp.int32)
+    keys = jax.random.split(jax.random.PRNGKey(k), 3)
+    lhs = jax.random.normal(keys[0], (m, k), dtype)
+    rhs = (jax.random.normal(keys[1], (3, k, n)) / 16).astype(dtype)
+    held = (jnp.arange(m) < 220)[:, None]
+    pull = jnp.where(held, jax.random.normal(keys[2], (m, n), dtype), 0)
+
+    def all_three(dot):
+        def run(lhs, rhs):
+            out, pull_back = jax.vjp(lambda l, r: dot(l, r, sizes), lhs, rhs)
+            d_lhs, d_rhs = pull_back(pull)
+            return jnp.where(held, out, 0), jnp.where(held, d_lhs, 0), d_rhs
+        return run
+
+    calls = _calls(all_three(gm.grouped_dot), lhs, rhs)
+    assert calls["pallas_call"] == 3 and "ragged_dot_general" not in calls
+    with jax.default_matmul_precision("highest"):
+        got = jax.jit(all_three(gm.grouped_dot))(lhs, rhs)
+        want = jax.jit(all_three(jax.lax.ragged_dot))(lhs, rhs)
+    for name, a, b in zip(("forward", "dx", "dw"), got, want, strict=True):
+        assert np.isfinite(np.asarray(a.astype(jnp.float32))).all(), name
+        scale = max(float(jnp.abs(b.astype(jnp.float32)).max()), 1.0)
+        assert _gap(a, b) <= TOL[dtype] * scale, name
+        if dtype == jnp.bfloat16 and name != "dw":
+            # float32 sums rounded once on both sides: the order of a sum's
+            # terms moves a result by one place of bfloat16 at most
+            assert _gap(a, b) <= scale / 128, name
+
+
 def _loss(dot, sizes, pull, transpose_rhs=False):
     held = _held(tuple(int(s) for s in sizes))
 
@@ -271,6 +308,81 @@ def test_a_block_keeps_poison_out_of_the_layer(interpreted, monkeypatch, held_ro
             a, b, atol=1e-4 * float(jnp.abs(b).max()), err_msg=name)
 
 
+def _plain_relu2_share(z, experts, weights, w1, w2):
+    out = jnp.zeros(z.shape, jnp.float32)
+    for e in range(w1.shape[0]):
+        share = jnp.sum(jnp.where(experts == e, weights, 0.0), axis=1)
+        out = out + share[:, None] * (jnp.square(jax.nn.relu(z @ w1[e])) @ w2[e])
+    return out
+
+
+@pytest.mark.parametrize("form", ["sorted-rows", "every-token"])
+def test_the_two_matrix_expert_is_a_plain_product_over_every_token(monkeypatch, form):
+    """``w3`` None: ``W2 relu(W1 z)^2``, the layer's value and its four
+    gradients against every held expert computing every token, in the sorted
+    rows' blocks and in the ``DENSE_ROWS`` form alike."""
+    n, d, f = 96, 32, 24  # a width off every lane: ragged_dot on the CPU
+    monkeypatch.setattr(moe, "DENSE_ROWS", 0 if form == "sorted-rows" else 256)
+    monkeypatch.setattr(moe, "ROW_TILE", 32)
+    monkeypatch.setattr(moe, "HELD_ROWS_MARGIN", -0.5)  # blocks too small: a loop
+    keys = jax.random.split(jax.random.PRNGKey(9), 6)
+    experts = jax.random.randint(keys[0], (n, 3), 0, 8)  # held: 0 and 1 of 8
+    z = jax.random.normal(keys[1], (n, d))
+    weights = jax.nn.softmax(jax.random.normal(keys[2], (n, 3)))
+    w1 = jax.random.normal(keys[3], (2, d, f)) / 6
+    w2 = jax.random.normal(keys[4], (2, f, d)) / 5
+    pull = jax.random.normal(keys[5], (n, d))
+
+    def ours(z, weights, w1, w2):
+        out, counts, ran = moe.expert_ffn(
+            z, moe.Routing(experts, weights), w1, None, w2, 0, 8)
+        return jnp.sum(out * pull), (out, counts, ran)
+
+    def theirs(z, weights, w1, w2):
+        out = _plain_relu2_share(z, experts, weights, w1, w2)
+        return jnp.sum(out * pull), out
+
+    moe._sorted_rows.clear_cache()
+    with jax.default_matmul_precision("highest"):
+        (_, (out, counts, ran)), got = jax.jit(jax.value_and_grad(
+            ours, argnums=range(4), has_aux=True))(z, weights, w1, w2)
+        (_, want_out), want = jax.jit(jax.value_and_grad(
+            theirs, argnums=range(4), has_aux=True))(z, weights, w1, w2)
+    moe._sorted_rows.clear_cache()
+    assert int(counts.sum()) == int((experts < 2).sum()) > 32
+    block = moe.block_rows(n, 3, 2, 8)
+    assert block == 64 < int(counts.sum())
+    # blocks beyond the first: one where the rows are sorted, none where
+    # every held expert computes every token
+    assert int(ran) == ((int(counts.sum()) - 1) // block if form == "sorted-rows" else 0)
+    np.testing.assert_allclose(out, want_out, atol=2e-5)
+    for name, a, b in zip(("z", "weights", "w1", "w2"), got, want, strict=True):
+        np.testing.assert_allclose(
+            a, b, atol=1e-4 * float(jnp.abs(b).max()), err_msg=name)
+
+
+@pytest.mark.parametrize("form,tokens", [("sorted-rows", 512), ("every-token", 64)])
+def test_the_expert_form_is_read_off_the_operands(form, tokens):
+    """Three matrices: three grouped products (or batched ones) forward and a
+    ``logistic`` (silu), as before there was a second form; ``w3`` None: two,
+    and a ``max`` and a square in its place."""
+    d, f = 32, 24
+    z = jnp.zeros((tokens, d))
+    routing = moe.Routing(jnp.zeros((tokens, 2), jnp.int32), jnp.ones((tokens, 2)))
+    w1 = w3 = jnp.zeros((2, d, f))
+    w2 = jnp.zeros((2, f, d))
+    product = "ragged_dot_general" if form == "sorted-rows" else "dot_general"
+    moe._sorted_rows.clear_cache()
+    gated = _calls(lambda *a: moe.expert_ffn(z, routing, *a, 0, 8), w1, w3, w2)
+    plain = _calls(lambda a, b: moe.expert_ffn(z, routing, a, None, b, 0, 8), w1, w2)
+    moe._sorted_rows.clear_cache()
+    extra = gated[product] - plain[product]
+    assert extra == 1 and plain[product] >= 2
+    assert gated["logistic"] == 1 and "logistic" not in plain
+    assert "square" in plain or "integer_pow" in plain
+    assert "square" not in gated and "integer_pow" not in gated
+
+
 # -- which path runs is read off the backend and the shapes ----------------------
 @pytest.mark.parametrize("k,n,kernel", [
     (256, 384, True), (64, 384, False), (256, 32, False), (200, 384, False)])
@@ -333,6 +445,37 @@ def test_no_tiles_off_whole_lanes(m, k, n):
     assert gm.tiling("forward", m, k, n) is None
 
 
+@pytest.mark.parametrize("rows,d,f", [(5120, 2048, 1792), (10240, 2048, 768)],
+                         ids=["lfm2-cell", "sparse-attention-cell"])
+def test_the_cells_that_were_there_keep_their_tiles(rows, d, f):
+    """What ``tiling`` returned at the two expert cells' shapes before a
+    width off whole lanes was taken: a whole expert matrix at once."""
+    assert gm.tiling("forward", rows, d, f) == (256, d, f)
+    assert gm.tiling("forward", rows, f, d) == (256, f, d)
+    assert gm.tiling("dw", rows, d, f) == (256, d, f)
+    assert gm.tiling("dw", rows, f, d) == (256, f, d)
+    assert gm._chunk(f) == 256 and gm._chunk(d) == 512
+    assert gm._last_chunk(f) == gm._last_chunk(d) == 0
+
+
+def test_a_width_off_whole_lanes_is_one_tile():
+    """1,856 = 14.5 lanes (the Mamba-2 hybrid's expert width): the dimension
+    whole, columns or contracted, the inner loop ending on a chunk of 320;
+    dW, whose float32 sum of a whole matrix does not fit, takes the other
+    dimension in thirds."""
+    m, d, f = 1024, 2688, 1856
+    assert gm._widths(f) == [f] and gm._widths(d)[0] == d
+    assert gm._widths(200) == gm._widths(64) == gm._widths(32) == []
+    assert gm.tiling("forward", m, d, f) == (256, d, f)
+    assert gm.tiling("forward", m, f, d) == (256, f, d)
+    assert gm.tiling("dw", m, d, f) == (256, 896, f)
+    assert gm.tiling("dw", m, f, d) == (256, f, 896)
+    for form, k, n in (("forward", d, f), ("forward", f, d), ("dw", d, f), ("dw", f, d)):
+        assert gm._vmem_bytes(form, *gm.tiling(form, m, k, n), 2) <= gm.VMEM_BUDGET
+    assert (gm._chunk(f), gm._last_chunk(f)) == (512, 320)
+    assert 3 * 512 + 320 == f
+
+
 # -- Mosaic compiles the kernels at the cell's widths (no chip attached) ---------
 @pytest.fixture(scope="module")
 def one_chip():
@@ -360,11 +503,12 @@ def no_compile_cache():
 
 
 @pytest.mark.timeout(300)
-@pytest.mark.parametrize("k,n", [(2048, 1792), (1792, 2048)], ids=["d-f", "f-d"])
+@pytest.mark.parametrize("m,k,n", [
+    (5120, 2048, 1792), (5120, 1792, 2048), (1024, 2688, 1856), (1024, 1856, 2688)],
+    ids=["d-f", "f-d", "d-f-off-the-lane", "f-d-off-the-lane"])
 def test_the_three_forms_compile_for_a_v5e_at_the_cells_shapes(
-        one_chip, no_compile_cache, monkeypatch, k, n):
+        one_chip, no_compile_cache, monkeypatch, m, k, n):
     monkeypatch.setattr(gm, "_backend_runs_mosaic", lambda: True)
-    m = CELL["m"]
 
     def shape(*dims, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
