@@ -82,6 +82,9 @@ from typing import Dict, List, Optional
 
 #: the contract's limit is 1200 s, compilation included
 TOTAL_BUDGET_S = 1150.0
+#: the recurrence's kernels against its plain form, of a value's largest:
+#: float32 sums in another order (a bfloat16 operand would read 4e-3)
+SSD_BAND = 2e-4
 
 #: parity bands of the rollout-forward ladder vs the f32 forward — the same
 #: numbers tests/test_quantize.py and tests/test_staging.py hold the bf16 and
@@ -123,6 +126,9 @@ class Shape:
     # the selection: a decode step's rows and keys, the top-k, a block of
     # the learner's (envs, queries, keys)
     select_dims: tuple
+    # the Mamba-2 recurrence: envs, positions, heads, channels a head,
+    # groups, numbers a state's row, positions a chunk
+    ssd_dims: tuple
 
 
 FULL = Shape(
@@ -136,6 +142,7 @@ FULL = Shape(
     grouped_off_lane_dims=(1024, 2688, 1856, 8),
     sparse_dims=(2, 4096, 32, 4, 128, 512),
     select_dims=(16, 4096, 2048, (2, 512, 2560)),
+    ssd_dims=(2, 2048, 64, 64, 8, 128, 128),
 )
 
 SMALL = Shape(
@@ -149,6 +156,7 @@ SMALL = Shape(
     grouped_off_lane_dims=(256, 128, 192, 4),
     sparse_dims=(2, 64, 4, 2, 16, 16),
     select_dims=(4, 64, 16, (2, 8, 40)),
+    ssd_dims=(2, 40, 4, 8, 2, 16, 16),
 )
 
 
@@ -798,6 +806,94 @@ def phase_sparse_attn(shape: Shape, workdir: str, platform: str = "tpu") -> dict
 
 
 # --------------------------------------------------------------------------
+# phase: the Mamba-2 recurrence's kernels against its plain form
+# --------------------------------------------------------------------------
+
+
+def phase_ssd(shape: Shape, workdir: str, platform: str = "tpu") -> dict:
+    """``ops/ssd.py:ssd_chunked`` (on the chip: the two Pallas kernels, at a
+    learner chunk's shapes) against ``ssd_chunked_plain``: ``y``, the last
+    state and every gradient leaf. Under the interpreter a float32 product
+    is exact whatever it asks for; Mosaic rounds the operands of one that
+    does not ask for the highest precision: here is where the two part."""
+    del workdir
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from distributed_ba3c_tpu.ops import ssd
+
+    device = _require_device(platform)
+    b, T, h, P, g, N, chunk = shape.ssd_dims
+    keys = jax.random.split(jax.random.PRNGKey(0), 8)
+    # step sizes as a trained mixer's: most small, some that forget a chunk
+    # whole (the mask before the exp), a small one after large ones
+    u = jax.random.uniform(keys[1], (b, T, h))
+    dt = jnp.where(u < 0.7, 0.05 * u, jnp.where(u < 0.95, u, 30.0 * u))
+    # operands and results lie as they do in the layer, a position's heads
+    # side by side in one row: handed ``[.., h, P]`` arrays XLA copies each
+    # into that layout round the kernels (P = 64 is padded to 128 lanes in
+    # HBM), a quarter of a millisecond an array that the layer does not pay
+    args = (jax.random.normal(keys[0], (b, T, h * P)), dt,
+            -jnp.exp(jax.random.normal(keys[2], (h,))),
+            *(jax.random.normal(k, (b, T, g * N)) / N ** 0.25 for k in keys[3:5]),
+            jax.random.normal(keys[5], (h,)))
+    pull = jax.random.normal(keys[6], (b, T, h * P))
+    pull_last = jax.random.normal(keys[7], (b, h, P, N)) / 8
+
+    def flat(form):
+        def run(x, dt, A, Bm, Cm, D):
+            y, last = form(
+                x.reshape(b, T, h, P), dt, A, Bm.reshape(b, T, g, N),
+                Cm.reshape(b, T, g, N), D, chunk=chunk)
+            return y.reshape(b, T, h * P), last
+        return run
+
+    def all_eight(form):
+        def run(*args):
+            out, pull_back = jax.vjp(flat(form), *args)
+            return out + pull_back((pull, pull_last))
+        return jax.jit(run)
+
+    ours = all_eight(ssd.ssd_chunked)
+    text = ours.lower(*args).as_text()
+    kernels = text.count("tpu_custom_call")
+    _check(kernels == (2 if platform == "tpu" else 0),
+           f"{kernels} Pallas kernels lowered on {platform}")
+    if kernels:
+        _check(ssd.FORWARD_KERNEL in text and ssd.BACKWARD_KERNEL in text,
+               "the kernels' names are not in the lowered program")
+    got = ours(*args)
+    want = all_eight(ssd.ssd_chunked_plain)(*args)
+    info = {"device": device, "pallas_kernels": kernels}
+    for name, a, w in zip(
+            ("y", "last_state", "dx", "ddt", "dA", "dB", "dC", "dD"), got, want):
+        a, w = np.asarray(a), np.asarray(w)
+        _check(bool(np.isfinite(a).all()), f"{name}: not finite")
+        gap, size = float(np.abs(a - w).max()), float(np.abs(w).max())
+        # float32 at the highest precision on both sides: sums in another
+        # order, no rounded operand (a bfloat16 operand would read 4e-3)
+        _check(gap <= SSD_BAND * size, f"{name}: off by {gap} of {size}")
+        info[f"{name}_max_rel_err"] = gap / size
+
+    if platform == "tpu":  # what each form takes alone, forward and both ways
+        def ms_a_call(fn, reps=5):
+            jax.block_until_ready(fn(*args))
+            best = float("inf")
+            for _ in range(reps):
+                t0 = time.perf_counter()
+                jax.block_until_ready(fn(*args))  # ba3clint: disable=J1 — the wait IS the measurement: a call by the wall clock
+                best = min(best, time.perf_counter() - t0)
+            return round(best * 1e3, 3)
+
+        info["forward_ms"] = ms_a_call(jax.jit(flat(ssd.ssd_chunked)))
+        info["forward_plain_ms"] = ms_a_call(jax.jit(flat(ssd.ssd_chunked_plain)))
+        info["both_ways_ms"] = ms_a_call(ours)
+        info["both_ways_plain_ms"] = ms_a_call(all_eight(ssd.ssd_chunked_plain))
+    return info
+
+
+# --------------------------------------------------------------------------
 # phase: the exact top-k as a mask, its three regimes against the plain searches
 # --------------------------------------------------------------------------
 
@@ -971,6 +1067,7 @@ PHASES = {
     "grouped": phase_grouped,
     "sparse_attn": phase_sparse_attn,
     "select": phase_select,
+    "ssd": phase_ssd,
     "mesh": phase_mesh,
 }
 

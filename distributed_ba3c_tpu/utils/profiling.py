@@ -136,12 +136,18 @@ OP_LINATTN_OUT = "op_linattn/out"
 #: block's norm and ``W_in``, the step sizes), ``conv``, ``ssd`` (the
 #: recurrence alone: one position from the carried state in the decode step,
 #: the chunked form in the unroll), ``out`` (the gated group norm and
-#: ``W_out``). That policy's attention block opens ``op_attn_full`` with
-#: ``decode_attend`` inside it, its expert blocks ``moe`` with ``moe/shared``
+#: ``W_out``). Inside ``ssd``, ``ssd_chunks`` is open only round the
+#: chunked form's Pallas kernels (ops/ssd.py), forward and backward: time
+#: there says they ran, none that the plain ``jax.numpy`` form did (another
+#: backend, a small cut). That policy's attention block opens
+#: ``op_attn_full`` with ``decode_attend`` inside it, its expert blocks
+#: ``moe`` with ``moe/shared``
 OP_MAMBA2 = "op_mamba2"
 OP_MAMBA2_IN_PROJ = "op_mamba2/in_proj"
 OP_MAMBA2_CONV = "op_mamba2/conv"
 OP_MAMBA2_SSD = "op_mamba2/ssd"
+SSD_CHUNKS = "ssd_chunks"
+OP_MAMBA2_SSD_KERNEL = f"{OP_MAMBA2_SSD}/{SSD_CHUNKS}"
 OP_MAMBA2_OUT = "op_mamba2/out"
 #: the layers each sequence policy opens (models/lfm2_moe.py with ops/moe.py;
 #: models/phi4_flash.py with ops/ssm.py; models/keye_vl2.py with ops/moe.py;
@@ -172,7 +178,7 @@ OLMO_HYBRID_LAYERS = (
 NEMOTRON_H_LAYERS = (
     EMBED, OP_MAMBA2, OP_MAMBA2_IN_PROJ, OP_MAMBA2_CONV, OP_MAMBA2_SSD,
     OP_MAMBA2_OUT, OP_ATTN_FULL, MOE, MOE_ROUTER, MOE_DISPATCH, MOE_EXPERTS,
-    MOE_EXPERTS_GMM, MOE_COMBINE, MOE_SHARED, HEAD,
+    MOE_EXPERTS_GMM, MOE_COMBINE, MOE_SHARED, HEAD, OP_MAMBA2_SSD_KERNEL,
 )
 #: every policy's layers, each once, in the order they are first named
 POLICY_LAYERS = tuple(dict.fromkeys(

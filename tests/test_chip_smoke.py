@@ -65,6 +65,10 @@ def test_phase_at_small_shape_on_cpu_mesh(phase, tmp_path, child_env):
         assert info["decode_fits_selected"] == sum(k - r for r in range(rows))
         assert info["decode_overflows_selected"] == rows * k
         assert info["decode_mixed_selected"] == k // 2 + 1 + (rows - 1) * k
+    elif phase == "ssd":
+        # off the chip the recurrence is its plain form itself: no kernel
+        assert info["pallas_kernels"] == 0
+        assert info["y_max_rel_err"] == info["dB_max_rel_err"] == 0.0
     else:
         assert info["sharded_over"] == list(range(8))
         assert info["replicated_leaves"] > 0

@@ -37,7 +37,7 @@ from distributed_ba3c_tpu.fused.loop import (  # noqa: E402
 from distributed_ba3c_tpu.models import policy  # noqa: E402
 from distributed_ba3c_tpu.models.nemotron_h import (  # noqa: E402
     ATTENTION, CUTS, EXPERTS, MAMBA, PATTERN, NemotronH)
-from distributed_ba3c_tpu.ops import moe  # noqa: E402
+from distributed_ba3c_tpu.ops import moe, ssd  # noqa: E402
 from distributed_ba3c_tpu.ops.gradproc import make_optimizer  # noqa: E402
 from distributed_ba3c_tpu.parallel.mesh import make_mesh  # noqa: E402
 from distributed_ba3c_tpu.utils import profiling  # noqa: E402
@@ -249,6 +249,47 @@ def test_a_leafs_gradient_of_the_loss_is_the_references(both_gradients, name):
         return
     assert scale > 0, "a leaf the loss does not reach"
     np.testing.assert_allclose(g, w, atol=2e-3 * scale, err_msg=name)
+
+
+# -- the learner's recurrence in its kernels ------------------------------------------
+#: the small cut with the recurrence at the kernels' shapes: 4 heads of 64
+#: channels in 2 groups, states of 128 numbers a row, chunks of 128 positions
+LANES = dict(mamba_head_dim=64, ssm_state_size=128, chunk_size=128)
+
+
+def test_the_unroll_through_the_kernels_is_the_plain_forms_and_the_decode_is_untouched(
+        monkeypatch):
+    """The loss and every leaf's gradient with ``ssd_chunked``'s kernels
+    (interpreted) against its plain form, within float32 rounding, over
+    episodes that are no whole chunks; ``step`` traces the same program
+    either way, with no kernel in it."""
+    length = 160
+    model = tiny(max_positions=length, **LANES)
+    params = params_of(31, reference.spec_of(dict(TINY_CONFIG, **LANES)))
+    tokens, actions = tokens_of(32, 2, length), tokens_of(33, 2, length)
+    returns = jax.random.normal(jax.random.PRNGKey(34), tokens.shape)
+
+    def both_ways():  # a function of its own: which path a trace took is kept
+        return jax.jit(jax.value_and_grad(_loss(
+            lambda p, t: tuple(model.unroll(p, t)[0]))))
+
+    def decode_step():
+        return str(jax.make_jaxpr(lambda p, t, carry, fresh: model.step(
+            p, t, carry, fresh))(
+            params, tokens[:, 0], model.init_carry(2), jnp.ones((2,), bool)))
+
+    plain, plain_step = both_ways()(params, tokens, actions, returns), decode_step()
+    monkeypatch.setattr(ssd, "INTERPRET", True)
+    assert "pallas_call" in str(jax.make_jaxpr(both_ways())(
+        params, tokens, actions, returns))
+    kernels = both_ways()(params, tokens, actions, returns)
+    assert abs(float(kernels[0]) - float(plain[0])) < 1e-5 * abs(float(plain[0]))
+    for (path, got), want in zip(jax.tree_util.tree_leaves_with_path(kernels[1]),
+                                 jax.tree_util.tree_leaves(plain[1])):
+        scale = float(jnp.abs(want).max())
+        np.testing.assert_allclose(
+            got, want, atol=1e-4 * scale, err_msg=jax.tree_util.keystr(path))
+    assert decode_step() == plain_step and "pallas_call" not in plain_step
 
 
 # -- the decode through the carry ---------------------------------------------------
@@ -567,9 +608,10 @@ def test_the_fused_loop_names_no_model():
 
 
 # -- the scopes ----------------------------------------------------------------------
-#: open only round the Pallas kernels (the grouped products), which this
-#: small step (experts of 24, on the CPU) does not reach
-_BY_KERNEL = (profiling.MOE_EXPERTS_GMM,)
+#: open only round the Pallas kernels (the grouped products, the recurrence's
+#: chunks), which this small step (experts of 24, chunks of 8, on the CPU)
+#: does not reach
+_BY_KERNEL = (profiling.MOE_EXPERTS_GMM, profiling.OP_MAMBA2_SSD_KERNEL)
 
 
 def test_this_policys_layers_are_among_the_policies_layers():
@@ -584,13 +626,18 @@ def test_this_policys_layers_are_among_the_policies_layers():
         "rematted_computation/op_mamba2/ssd/dot_general"
     ) == "learner/op_mamba2/ssd"
     assert profiling.scope_of(
+        "jit(multi_step)/learner/transpose(jvp(learner))/checkpoint/op_mamba2/"
+        "ssd/transpose(jvp(ssd_chunks))/jit(_backward)/ssd_chunks_backward/"
+        "pallas_call") == "learner/op_mamba2/ssd/ssd_chunks"
+    assert profiling.scope_of(
         "jit(multi_step)/rollout/while/body/policy/moe/shared/dot_general"
     ) == "rollout/policy/moe/shared"
     # what was there keeps its place: the new layers come after
     before = [l for l in profiling.POLICY_LAYERS
               if l not in (profiling.OP_MAMBA2, profiling.OP_MAMBA2_IN_PROJ,
                            profiling.OP_MAMBA2_CONV, profiling.OP_MAMBA2_SSD,
-                           profiling.OP_MAMBA2_OUT, profiling.MOE_SHARED)]
+                           profiling.OP_MAMBA2_OUT, profiling.MOE_SHARED,
+                           profiling.OP_MAMBA2_SSD_KERNEL)]
     assert list(profiling.POLICY_LAYERS[:len(before)]) == before
     # the decode's kernel takes 8 query heads a K/V head and never runs here
     assert f"{profiling.OP_ATTN_FULL}/{profiling.DECODE_ATTEND}" not in (
