@@ -85,6 +85,8 @@ TOTAL_BUDGET_S = 1150.0
 #: the recurrence's kernels against its plain form, of a value's largest:
 #: float32 sums in another order (a bfloat16 operand would read 4e-3)
 SSD_BAND = 2e-4
+#: the same for the gated delta rule's
+DELTA_BAND = 2e-4
 
 #: parity bands of the rollout-forward ladder vs the f32 forward — the same
 #: numbers tests/test_quantize.py and tests/test_staging.py hold the bf16 and
@@ -129,6 +131,9 @@ class Shape:
     # the Mamba-2 recurrence: envs, positions, heads, channels a head,
     # groups, numbers a state's row, positions a chunk
     ssd_dims: tuple
+    # the gated delta rule: envs, positions, heads, keys a head, values a
+    # head, positions a chunk
+    delta_dims: tuple
 
 
 FULL = Shape(
@@ -143,6 +148,7 @@ FULL = Shape(
     sparse_dims=(2, 4096, 32, 4, 128, 512),
     select_dims=(16, 4096, 2048, (2, 512, 2560)),
     ssd_dims=(2, 2048, 64, 64, 8, 128, 128),
+    delta_dims=(2, 2048, 10, 96, 192, 64),
 )
 
 SMALL = Shape(
@@ -157,6 +163,7 @@ SMALL = Shape(
     sparse_dims=(2, 64, 4, 2, 16, 16),
     select_dims=(4, 64, 16, (2, 8, 40)),
     ssd_dims=(2, 40, 4, 8, 2, 16, 16),
+    delta_dims=(2, 40, 3, 8, 16, 16),
 )
 
 
@@ -819,7 +826,6 @@ def phase_ssd(shape: Shape, workdir: str, platform: str = "tpu") -> dict:
     del workdir
     import jax
     import jax.numpy as jnp
-    import numpy as np
 
     from distributed_ba3c_tpu.ops import ssd
 
@@ -865,31 +871,142 @@ def phase_ssd(shape: Shape, workdir: str, platform: str = "tpu") -> dict:
                "the kernels' names are not in the lowered program")
     got = ours(*args)
     want = all_eight(ssd.ssd_chunked_plain)(*args)
-    info = {"device": device, "pallas_kernels": kernels}
-    for name, a, w in zip(
-            ("y", "last_state", "dx", "ddt", "dA", "dB", "dC", "dD"), got, want):
+    info = {"device": device, "pallas_kernels": kernels, **_gaps_within(
+        SSD_BAND, ("y", "last_state", "dx", "ddt", "dA", "dB", "dC", "dD"),
+        got, want)}
+
+    if platform == "tpu":  # what each form takes alone, forward and both ways
+        info["forward_ms"] = _ms_a_call(jax.jit(flat(ssd.ssd_chunked)), args)
+        info["forward_plain_ms"] = _ms_a_call(
+            jax.jit(flat(ssd.ssd_chunked_plain)), args)
+        info["both_ways_ms"] = _ms_a_call(ours, args)
+        info["both_ways_plain_ms"] = _ms_a_call(
+            all_eight(ssd.ssd_chunked_plain), args)
+    return info
+
+
+def _gaps_within(band, names, got, want, of: str = "") -> dict:
+    """``{name}_max_rel_err`` of each array of ``got`` against ``want``'s:
+    the largest gap over the largest value, every one printed (under ``of``,
+    where a phase holds several cases) before the first out of ``band``
+    fails the phase. Float32 at the highest precision on both sides reads
+    sums in another order and no rounded operand (a bfloat16 operand would
+    read 4e-3)."""
+    import numpy as np
+
+    gaps, off = {}, []
+    for name, a, w in zip(names, got, want):
         a, w = np.asarray(a), np.asarray(w)
         _check(bool(np.isfinite(a).all()), f"{name}: not finite")
         gap, size = float(np.abs(a - w).max()), float(np.abs(w).max())
-        # float32 at the highest precision on both sides: sums in another
-        # order, no rounded operand (a bfloat16 operand would read 4e-3)
-        _check(gap <= SSD_BAND * size, f"{name}: off by {gap} of {size}")
-        info[f"{name}_max_rel_err"] = gap / size
+        # against ``band * size``, so a reference that is all zeros (a state
+        # forgotten whole) holds the other form to zeros and divides nothing
+        if not gap <= band * size:
+            off.append(f"{of} {name}: off by {gap} of {size}".strip())
+        gaps[f"{name}_max_rel_err"] = gap / max(size, float(np.finfo(np.float32).tiny))
+    print(json.dumps({of: gaps} if of else gaps), flush=True)
+    _check(not off, "; ".join(off))
+    return gaps
+
+
+def _ms_a_call(fn, args, reps=5):
+    """The best of ``reps`` calls of ``fn(*args)`` by the wall clock, in ms,
+    after one that compiles."""
+    import jax
+
+    jax.block_until_ready(fn(*args))
+    best = float("inf")
+    for _ in range(reps):
+        t0 = time.perf_counter()
+        jax.block_until_ready(fn(*args))  # ba3clint: disable=J1 — the wait IS the measurement: a call by the wall clock
+        best = min(best, time.perf_counter() - t0)
+    return round(best * 1e3, 3)
+
+
+# --------------------------------------------------------------------------
+# phase: the gated delta rule's kernels against its plain form
+# --------------------------------------------------------------------------
+
+
+def phase_delta(shape: Shape, workdir: str, platform: str = "tpu") -> dict:
+    """``ops/delta_rule.py:delta_chunked`` (on the chip: the two Pallas
+    kernels, at a learner chunk's shapes) against ``delta_chunked_plain``:
+    ``o``, the last state and every gradient leaf, as ``phase_ssd`` holds the
+    Mamba-2 recurrence's."""
+    del workdir
+    import jax
+    import jax.numpy as jnp
+
+    from distributed_ba3c_tpu.ops import delta_rule
+
+    device = _require_device(platform)
+    b, T, h, K, V, chunk = shape.delta_dims
+    keys = jax.random.split(jax.random.PRNGKey(0), 7)
+    heads = lambda x: x.reshape(b, T, h, -1)  # noqa: E731
+    unit = lambda x: (heads(x) / jnp.linalg.norm(  # noqa: E731
+        heads(x), axis=-1, keepdims=True)).reshape(x.shape)
+    # The least gates stand seven decimal places above ``LEAST_GATE``: a
+    # gate's gradient is a sum of terms that each hold the gate as a factor,
+    # over the gate, and within a place or two of the least float32 the chip
+    # flushes some of those terms to zero, not the same ones in both forms
+    # (PR 46's first run here, at 1e-37: 0.43 off of 9.6 on ``dalpha`` alone;
+    # ``tests/test_delta_rule.py`` holds that regime on its own)
+    u = jax.random.uniform(keys[3], (b, T, h))
+    least = 1e7 * delta_rule.LEAST_GATE * (1 + u)
+    regimes = {
+        # as a trained mixer's: most near 1, some that forget a chunk whole
+        # (the mask before the exp), one near 1 after one near 0
+        "trained": jnp.where(u < 0.7, 1.0 - 0.05 * u, jnp.where(u < 0.95, u, least)),
+        "near_one": 1.0 - 1e-4 * u,  # a state that forgets nothing
+        "near_least": least,         # one that forgets everything, every position
+    }
+    # operands and results lie as they do in the layer, a position's heads
+    # side by side in one row (handed ``[.., h, K]`` arrays XLA copies each
+    # into that layout round the kernels, which the layer does not pay);
+    # step sizes up to 2 (past 1 the rule mirrors what the state held along k)
+    qkv = (unit(jax.random.normal(keys[0], (b, T, h * K))) / K ** 0.5,
+           unit(jax.random.normal(keys[1], (b, T, h * K))),
+           jax.random.normal(keys[2], (b, T, h * V)))
+    beta = 2.0 * jax.random.uniform(keys[4], (b, T, h))
+    args = (*qkv, regimes["trained"], beta)
+    pull = jax.random.normal(keys[5], (b, T, h * V))
+    pull_last = jax.random.normal(keys[6], (b, h, K, V)) / 8
+
+    def flat(form):
+        def run(q, k, v, alpha, beta):
+            o, last = form(heads(q), heads(k), heads(v), alpha, beta, chunk=chunk)
+            return o.reshape(b, T, h * V), last
+        return run
+
+    def all_seven(form):
+        def run(*args):
+            out, pull_back = jax.vjp(flat(form), *args)
+            return out + pull_back((pull, pull_last))
+        return jax.jit(run)
+
+    ours = all_seven(delta_rule.delta_chunked)
+    text = ours.lower(*args).as_text()
+    kernels = text.count("tpu_custom_call")
+    _check(kernels == (2 if platform == "tpu" else 0),
+           f"{kernels} Pallas kernels lowered on {platform}")
+    if kernels:
+        _check(delta_rule.FORWARD_KERNEL in text
+               and delta_rule.BACKWARD_KERNEL in text,
+               "the kernels' names are not in the lowered program")
+    plain = all_seven(delta_rule.delta_chunked_plain)
+    info = {"device": device, "pallas_kernels": kernels, "gaps": {}}
+    for regime, alpha in regimes.items():
+        gated = (*qkv, alpha, beta)
+        info["gaps"][regime] = _gaps_within(
+            DELTA_BAND, ("o", "last_state", "dq", "dk", "dv", "dalpha", "dbeta"),
+            ours(*gated), plain(*gated), of=regime)
 
     if platform == "tpu":  # what each form takes alone, forward and both ways
-        def ms_a_call(fn, reps=5):
-            jax.block_until_ready(fn(*args))
-            best = float("inf")
-            for _ in range(reps):
-                t0 = time.perf_counter()
-                jax.block_until_ready(fn(*args))  # ba3clint: disable=J1 — the wait IS the measurement: a call by the wall clock
-                best = min(best, time.perf_counter() - t0)
-            return round(best * 1e3, 3)
-
-        info["forward_ms"] = ms_a_call(jax.jit(flat(ssd.ssd_chunked)))
-        info["forward_plain_ms"] = ms_a_call(jax.jit(flat(ssd.ssd_chunked_plain)))
-        info["both_ways_ms"] = ms_a_call(ours)
-        info["both_ways_plain_ms"] = ms_a_call(all_eight(ssd.ssd_chunked_plain))
+        info["forward_ms"] = _ms_a_call(jax.jit(flat(delta_rule.delta_chunked)), args)
+        info["forward_plain_ms"] = _ms_a_call(
+            jax.jit(flat(delta_rule.delta_chunked_plain)), args)
+        info["both_ways_ms"] = _ms_a_call(ours, args)
+        info["both_ways_plain_ms"] = _ms_a_call(plain, args)
     return info
 
 
@@ -1068,6 +1185,7 @@ PHASES = {
     "sparse_attn": phase_sparse_attn,
     "select": phase_select,
     "ssd": phase_ssd,
+    "delta": phase_delta,
     "mesh": phase_mesh,
 }
 

@@ -131,6 +131,8 @@ OP_LINATTN = "op_linattn"
 OP_LINATTN_IN_PROJ = "op_linattn/in_proj"
 OP_LINATTN_CONV = "op_linattn/conv"
 OP_LINATTN_DELTA = "op_linattn/delta"
+DELTA_CHUNKS = "delta_chunks"
+OP_LINATTN_DELTA_KERNEL = f"{OP_LINATTN_DELTA}/{DELTA_CHUNKS}"
 OP_LINATTN_OUT = "op_linattn/out"
 #: a Mamba-2 mixer (models/nemotron_h.py with ops/ssd.py): ``in_proj`` (the
 #: block's norm and ``W_in``, the step sizes), ``conv``, ``ssd`` (the
@@ -171,7 +173,7 @@ KEYE_VL2_LAYERS = (
 OLMO_HYBRID_LAYERS = (
     EMBED, OP_LINATTN, OP_LINATTN_IN_PROJ, OP_LINATTN_CONV, OP_LINATTN_DELTA,
     OP_LINATTN_OUT, OP_ATTN_FULL, f"{OP_ATTN_FULL}/{DECODE_ATTEND}", FFN_DENSE,
-    HEAD,
+    HEAD, OP_LINATTN_DELTA_KERNEL,
 )
 #: (no ``op_attn_full/decode_attend``: that scope is open round the decode's
 #: kernel alone, which takes 8 query heads a K/V head and this policy has 16)

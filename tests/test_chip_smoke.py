@@ -69,6 +69,12 @@ def test_phase_at_small_shape_on_cpu_mesh(phase, tmp_path, child_env):
         # off the chip the recurrence is its plain form itself: no kernel
         assert info["pallas_kernels"] == 0
         assert info["y_max_rel_err"] == info["dB_max_rel_err"] == 0.0
+    elif phase == "delta":
+        # off the chip the rule is its plain form itself: no kernel
+        assert info["pallas_kernels"] == 0
+        assert sorted(info["gaps"]) == ["near_least", "near_one", "trained"]
+        for gaps in info["gaps"].values():
+            assert gaps["o_max_rel_err"] == gaps["dalpha_max_rel_err"] == 0.0
     else:
         assert info["sharded_over"] == list(range(8))
         assert info["replicated_leaves"] > 0

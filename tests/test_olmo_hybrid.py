@@ -37,6 +37,7 @@ from distributed_ba3c_tpu.fused.loop import (  # noqa: E402
 from distributed_ba3c_tpu.models import policy  # noqa: E402
 from distributed_ba3c_tpu.models.olmo_hybrid import (  # noqa: E402
     CUTS, FULL, LAYER_TYPES, LINEAR, OlmoHybrid)
+from distributed_ba3c_tpu.ops import delta_rule  # noqa: E402
 from distributed_ba3c_tpu.ops.gradproc import make_optimizer  # noqa: E402
 from distributed_ba3c_tpu.parallel.mesh import make_mesh  # noqa: E402
 from distributed_ba3c_tpu.utils import profiling  # noqa: E402
@@ -206,6 +207,68 @@ def test_a_leafs_gradient_of_the_loss_is_the_references(both_gradients, name):
     scale = float(jnp.abs(w).max())
     assert scale > 0, "a leaf the loss does not reach"
     np.testing.assert_allclose(g, w, atol=2e-3 * scale, err_msg=name)
+
+
+# -- the learner's delta rule in its kernels ------------------------------------------
+#: the small cut with the rule at the kernels' shapes: 2 heads of 96 keys and
+#: 192 values (the cell's), chunks of 64 positions
+LANES = dict(linear_key_head_dim=96, linear_value_head_dim=192)
+#: (the tiny cut's chunks are 8 positions; the reference has no chunks)
+CHUNKS = dict(delta_chunk=delta_rule.CHUNK)
+
+
+def _at_the_kernels_shapes(length):
+    """(model, params, tokens, actions) of that cut over 2 episodes."""
+    return (tiny(max_positions=length, **LANES, **CHUNKS),
+            params_of(31, reference.spec_of(dict(TINY_CONFIG, **LANES))),
+            tokens_of(32, 2, length), tokens_of(33, 2, length))
+
+
+def test_the_unroll_through_the_kernels_is_the_plain_forms_and_the_decode_is_untouched(
+        monkeypatch):
+    """The loss and every leaf's gradient with ``delta_chunked``'s kernels
+    (interpreted) against its plain form, within float32 rounding, over
+    episodes that are no whole chunks; ``step`` traces the same program
+    either way, with no kernel in it."""
+    model, params, tokens, actions = _at_the_kernels_shapes(160)
+    returns = jax.random.normal(jax.random.PRNGKey(34), tokens.shape)
+
+    def both_ways():  # a function of its own: which path a trace took is kept
+        return jax.jit(jax.value_and_grad(_loss(
+            lambda p, t: tuple(model.unroll(p, t)[0]))))
+
+    def decode_step():
+        return str(jax.make_jaxpr(lambda p, t, carry, fresh: model.step(
+            p, t, carry, fresh))(
+            params, tokens[:, 0], model.init_carry(2), jnp.ones((2,), bool)))
+
+    plain, plain_step = both_ways()(params, tokens, actions, returns), decode_step()
+    monkeypatch.setattr(delta_rule, "INTERPRET", True)
+    assert "pallas_call" in str(jax.make_jaxpr(both_ways())(
+        params, tokens, actions, returns))
+    kernels = both_ways()(params, tokens, actions, returns)
+    assert abs(float(kernels[0]) - float(plain[0])) < 1e-5 * abs(float(plain[0]))
+    for (path, got), want in zip(jax.tree_util.tree_leaves_with_path(kernels[1]),
+                                 jax.tree_util.tree_leaves(plain[1])):
+        scale = float(jnp.abs(want).max())
+        np.testing.assert_allclose(
+            got, want, atol=1e-4 * scale, err_msg=jax.tree_util.keystr(path))
+    assert decode_step() == plain_step and "pallas_call" not in plain_step
+
+
+@pytest.mark.parametrize("engaged", [True, False], ids=["kernels", "plain"])
+def test_the_kernels_scope_in_the_lowered_unroll_says_whether_they_engaged(
+        monkeypatch, engaged):
+    """``learner``-side op names of the unroll's backward: under
+    ``op_linattn/delta`` either way, under ``delta_chunks`` inside it only
+    where the kernels took the shapes."""
+    monkeypatch.setattr(delta_rule, "INTERPRET", engaged)
+    model, params, tokens, actions = _at_the_kernels_shapes(128)
+    text = jax.jit(jax.grad(_loss(lambda p, t: tuple(model.unroll(p, t)[0])))).lower(
+        params, tokens, actions, jnp.zeros(tokens.shape)).as_text(debug_info=True)
+    assert profiling.OP_LINATTN_DELTA in text
+    assert (profiling.OP_LINATTN_DELTA_KERNEL in text) == engaged
+    assert ("triangular_solve" in text) != engaged
 
 
 # -- the decode through the carry ---------------------------------------------------
@@ -487,9 +550,11 @@ def test_the_fused_loop_names_no_model():
 
 
 # -- the scopes ----------------------------------------------------------------------
-#: open only round the Pallas kernel of the decode's attention, which this
-#: small step (heads of 16 lanes, on the CPU) does not reach
-_BY_KERNEL = f"{profiling.OP_ATTN_FULL}/{profiling.DECODE_ATTEND}"
+#: open only round the Pallas kernels of the decode's attention and of the
+#: learner's delta rule, which this small step (heads of 16 lanes, chunks of
+#: 8 positions, on the CPU) does not reach
+_BY_KERNEL = (f"{profiling.OP_ATTN_FULL}/{profiling.DECODE_ATTEND}",
+              profiling.OP_LINATTN_DELTA_KERNEL)
 
 
 def test_this_policys_layers_are_among_the_policies_layers():
@@ -497,11 +562,17 @@ def test_this_policys_layers_are_among_the_policies_layers():
     assert len(set(profiling.POLICY_LAYERS)) == len(profiling.POLICY_LAYERS)
     assert {profiling.OP_LINATTN, profiling.OP_LINATTN_IN_PROJ,
             profiling.OP_LINATTN_CONV, profiling.OP_LINATTN_DELTA,
-            profiling.OP_LINATTN_OUT, _BY_KERNEL} <= set(profiling.OLMO_HYBRID_LAYERS)
+            profiling.OP_LINATTN_OUT, *_BY_KERNEL} <= set(profiling.OLMO_HYBRID_LAYERS)
     assert profiling.scope_of(
         "jit(multi_step)/learner/transpose(jvp(learner))/jvp()/checkpoint/"
         "rematted_computation/op_linattn/delta/triangular_solve"
     ) == "learner/op_linattn/delta"
+    assert profiling.scope_of(
+        "jit(multi_step)/learner/transpose(jvp(learner))/checkpoint/op_linattn/"
+        "delta/transpose(jvp(delta_chunks))/jit(_backward)/delta_chunks_backward/"
+        "pallas_call") == "learner/op_linattn/delta/delta_chunks"
+    # what was there keeps its place: the rule's kernels' scope comes last
+    assert profiling.OLMO_HYBRID_LAYERS[-1] == profiling.OP_LINATTN_DELTA_KERNEL
 
 
 @pytest.mark.parametrize("scope", profiling.SEQUENCE_SCOPES)
