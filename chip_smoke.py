@@ -364,7 +364,8 @@ def _staging_equivalence(shape: Shape) -> dict:
 
     from distributed_ba3c_tpu import telemetry
     from distributed_ba3c_tpu.config import BA3CConfig
-    from distributed_ba3c_tpu.data.staging import DeviceIngest, HostStagingRing
+    from distributed_ba3c_tpu.data.staging import (
+        DeviceIngest, HostStagingRing, ingest_counts)
     from distributed_ba3c_tpu.models.policy import DEFAULT_MODEL, build_model
     from distributed_ba3c_tpu.ops.gradproc import make_optimizer
     from distributed_ba3c_tpu.parallel.mesh import make_mesh
@@ -440,12 +441,11 @@ def _staging_equivalence(shape: Shape) -> dict:
         return np.asarray(jax.device_get(losses))
 
     tele = telemetry.registry("learner")
-    copies0 = tele.counter("ingest_copies_total").value()
-    blocks0 = tele.counter("ingest_blocks_total").value()
+    copies0, blocks0 = ingest_counts("learner").values()
     waits0 = tele.counter("staging_waits_total").value()
     staged = run_staged()
-    copies = tele.counter("ingest_copies_total").value() - copies0
-    n_blocks = tele.counter("ingest_blocks_total").value() - blocks0
+    copies, n_blocks = ingest_counts("learner").values()
+    copies, n_blocks = copies - copies0, n_blocks - blocks0
     plain = run_plain()
     _check(bool(np.all(np.isfinite(plain))), f"plain losses {plain}")
     _check(
@@ -507,10 +507,8 @@ def phase_plane(shape: Shape, workdir: str, platform: str = "tpu") -> dict:
                     "grad_norm", "mean_rho"))
     copies = last["tele/learner/ingest_copies_total"]
     n_blocks = last["tele/learner/ingest_blocks_total"]
-    # the ingest thread counts a block's copy, then the block: an epoch's
-    # snapshot may fall between the two (data/staging.py), one copy ahead
     _check(
-        n_blocks >= 2 * steps and copies - n_blocks in (0, 1),
+        n_blocks >= 2 * steps and copies == n_blocks,
         f"ingest: {copies} host copies for {n_blocks} blocks (want 1.0 each)",
     )
     _check(

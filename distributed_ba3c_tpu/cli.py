@@ -25,8 +25,41 @@ from typing import Optional
 from distributed_ba3c_tpu.config import BA3CConfig
 
 
+_MODEL_HELP = (
+    "the policy, by its name in models/policy.py's registry: {models}. "
+    "{default} is the reference's conv stack; the others are token-sequence "
+    "policies that carry state (each one's module says what it is): "
+    "--trainer tpu_fused_ba3c with a token env such as jax:recall, "
+    "--rollout_len = the episode length")
+_MODEL_CUT_HELP = (
+    "what one chip holds of --model, by name, from the policy module's CUTS "
+    "(which says what each holds), the default first: {cuts}; the widths "
+    "are the model file's, as published")
+
+
+class _Parser(argparse.ArgumentParser):
+    """Names --model's and --model_cut's choices when help is asked for:
+    they are the registry's and each policy module's, and listing the cuts
+    imports every policy, which no run does only to parse its flags."""
+
+    def format_help(self):
+        from distributed_ba3c_tpu.models import policy
+
+        cuts = "; ".join(f"{name}: {' | '.join(names)}"
+                         for name, names in policy.cuts_by_model().items())
+        for action in self._actions:
+            if action.dest == "model":
+                action.help = _MODEL_HELP.format(
+                    models=" | ".join(policy.MODELS), default=policy.DEFAULT_MODEL)
+            elif action.dest == "model_cut":
+                action.help = _MODEL_CUT_HELP.format(cuts=cuts)
+        return super().format_help()
+
+
 def make_parser() -> argparse.ArgumentParser:
-    p = argparse.ArgumentParser(
+    from distributed_ba3c_tpu.models import policy
+
+    p = _Parser(
         description="TPU-native Distributed-BA3C",
         formatter_class=argparse.ArgumentDefaultsHelpFormatter,
     )
@@ -98,8 +131,8 @@ def make_parser() -> argparse.ArgumentParser:
     p.add_argument("--num_actions", type=int, default=4)
     p.add_argument("--mesh_data", type=int, default=None, help="data-axis size (defaults to all devices)")
     p.add_argument("--publish_every", type=int, default=1)
-    p.add_argument("--model", default="ba3cnet", help="the policy, by its name in models/policy.py's registry: ba3cnet (the reference's conv stack) | lfm2-moe (LFM2-8B-A1B) | phi4-flash (Phi-4-mini-flash-reasoning) | keye-vl2 (Keye-VL-2.0-30B-A3B's language model) | olmo-hybrid (Olmo-Hybrid-7B: gated delta-rule linear attention beside full attention) | nemotron-h (Nemotron-3-Nano-30B-A3B: Mamba-2 layers, relu2 experts beside a shared expert, one attention layer in nine): token-sequence policies that carry state; --trainer tpu_fused_ba3c with a token env such as jax:recall, --rollout_len = the episode length")
-    p.add_argument("--model_cut", default=None, help="what one chip holds of --model, by name (the model file's CUTS; lfm2-moe: chip-share-4 = one of 4 chips sharing each layer, the default; phi4-flash: stage-14-19 = published layers 14-19 of a pipeline stage, the default; keye-vl2: chip-share-8 = one of 8 chips sharing each layer, the default; olmo-hybrid: head-share-3 = one of 3 chips sharing each layer by heads, 10 of every mixer's 30, the default; nemotron-h: chip-share-16 = one of 16 chips sharing each layer expert parallel, 8 of 128 routed experts, the default; any: tiny = a CPU test's size); the widths are the model file's, as published")
+    p.add_argument("--model", default=policy.DEFAULT_MODEL, help=_MODEL_HELP)
+    p.add_argument("--model_cut", default=None, help=_MODEL_CUT_HELP)
     p.add_argument("--rollout_len", type=int, default=20, help="fused-trainer rollout length per update")
     p.add_argument("--grad_chunk_samples", type=int, default=4096, help="fused-trainer learner chunk size (HBM activation cap)")
     p.add_argument("--actor_timeout", type=float, default=120.0, help="seconds of actor silence before its state is dropped (0=off)")

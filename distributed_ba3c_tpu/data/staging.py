@@ -43,7 +43,11 @@ batch's obs bytes on the train-ingest path, ``ingest_blocks_total``
 counts collated batches — copies-per-block is their ratio. The staged
 path increments exactly 1.0 per batch (the staging write); the legacy
 collates self-report their stack/transpose passes. H2D transfers are not
-host copies and are never counted.
+host copies and are never counted. The two series are ONE paired counter
+(``telemetry.metrics.CounterPair``): a block's copy and the block are one
+increment and a snapshot reads both totals from one read, so an epoch's
+record taken while the ingest thread runs never shows one ahead of the
+other.
 """
 
 from __future__ import annotations
@@ -67,12 +71,10 @@ Spec = Dict[str, Tuple[tuple, Any]]
 DEFAULT_SLOTS = 4
 
 
-def _counters(tele_role: str):
-    tele = telemetry.registry(tele_role)
-    return (
-        tele.counter("ingest_copies_total"),
-        tele.counter("ingest_blocks_total"),
-    )
+def ingest_counts(tele_role: str = "learner"):
+    """(copies, blocks) of ``tele_role``'s train-ingest path, as one pair."""
+    return telemetry.registry(tele_role).counter_pair(
+        "ingest_copies_total", "ingest_blocks_total")
 
 
 def count_legacy_copies(
@@ -82,10 +84,7 @@ def count_legacy_copies(
     passes over one batch's obs bytes, ``blocks`` batches (0 for an
     EXTRA pass on already-counted batches — the fleet-axis stack). ONE
     call per site — the copy budget must stay a per-batch ratio."""
-    c_copies, c_blocks = _counters(tele_role)
-    c_copies.inc(passes)
-    if blocks:
-        c_blocks.inc(blocks)
+    ingest_counts(tele_role).inc(passes, blocks)
 
 
 class _Slot:
@@ -171,7 +170,7 @@ class HostStagingRing:
         self._busy: set = set()  # slot indices acquired or queued, unfenced
         self.tele_role = tele_role
         tele = telemetry.registry(tele_role)
-        self._c_copies, self._c_blocks = _counters(tele_role)
+        self._c_ingest = ingest_counts(tele_role)
         self._c_waits = tele.counter("staging_waits_total")
         self._c_realloc = tele.counter("staging_realloc_total")
         self._h_wait = tele.histogram("staging_wait_s", unit=1e-6)
@@ -263,8 +262,7 @@ class HostStagingRing:
     def count_staged_copy(self) -> None:
         """The ONE host copy of a staged batch (called by the in-place
         collates, once per batch)."""
-        self._c_copies.inc(1.0)
-        self._c_blocks.inc()
+        self._c_ingest.inc(1.0, 1)
 
     # -- consumer side -----------------------------------------------------
     def _owns(self, slot: _Slot) -> bool:
@@ -597,7 +595,7 @@ class BlockStager:
         self._rings: Dict[tuple, List[list]] = {}
         self._cursors: Dict[tuple, int] = {}
         self.tele_role = tele_role
-        self._c_copies, self._c_blocks = _counters(tele_role)
+        self._c_ingest = ingest_counts(tele_role)
         tele = telemetry.registry(tele_role)
         self._c_alloc = tele.counter("staging_alloc_total")
         self._c_waits = tele.counter("staging_waits_total")
@@ -679,8 +677,7 @@ class BlockStager:
         bufs, idx = self._slot_for(key, shapes)
         for k, dst in bufs.items():
             np.copyto(dst, batch[k], casting="unsafe")
-        self._c_copies.inc(1.0)
-        self._c_blocks.inc()
+        self._c_ingest.inc(1.0, 1)
         return StagedBlock(bufs, key, idx, self)
 
     def to_device(self, staged: StagedBlock, block_sharding=None):

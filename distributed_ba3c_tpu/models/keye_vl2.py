@@ -75,6 +75,7 @@ models/policy.py's.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import NamedTuple, Tuple
 
@@ -82,8 +83,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from distributed_ba3c_tpu.models import layers
-from distributed_ba3c_tpu.models.a3c import PolicyValue
+from distributed_ba3c_tpu.models import sequence
 from distributed_ba3c_tpu.models.layers import layer_norm, rms_norm, rope
 from distributed_ba3c_tpu.models.policy import LOSS_TERMS
 from distributed_ba3c_tpu.ops import (
@@ -92,13 +92,12 @@ from distributed_ba3c_tpu.ops.topk_select import select_mask
 from distributed_ba3c_tpu.utils import profiling
 from distributed_ba3c_tpu.utils.profiling import device_scope
 
-VALUE_INIT_SCALE = 0.01
 #: the leaves of a layer that only ``L_I`` trains
 INDEXER_LEAVES = ("idx_wq", "idx_wk", "idx_k_norm", "idx_k_norm_b", "idx_ww")
-#: ``--model_cut``: what one chip holds. ``chip-share-8``: one of 8 chips
-#: that share each layer (16 of 128 experts; the vocabulary slice is the
-#: env's action space), published layers 0-3. ``tiny``: every mechanism at a
-#: size a CPU test runs (a top-k of 8, blocks of 8 queries).
+#: ``--model_cut``: what one chip holds, the default first. ``chip-share-8``:
+#: one of 8 chips that share each layer (16 of 128 experts; the vocabulary
+#: slice is the env's action space), published layers 0-3. ``tiny``: every
+#: mechanism at a size a CPU test runs (a top-k of 8, blocks of 8 queries).
 CUTS = {
     "chip-share-8": {},
     "tiny": dict(
@@ -110,11 +109,7 @@ CUTS = {
 }
 
 
-def cut_fields(cut: str | None) -> dict:
-    cut = cut or "chip-share-8"
-    if cut not in CUTS:
-        raise ValueError(f"unknown --model_cut {cut!r}; have {sorted(CUTS)}")
-    return dict(CUTS[cut])
+cut_fields = functools.partial(sequence.cut_fields, CUTS)
 
 
 class Carry(NamedTuple):
@@ -131,7 +126,7 @@ class Carry(NamedTuple):
 
 
 @dataclasses.dataclass(frozen=True)
-class KeyeVL2:
+class KeyeVL2(sequence.SequencePolicy):
     num_actions: int = 18992            # vocabulary ids held (of 151,936)
     hidden_size: int = 2048
     moe_intermediate_size: int = 768
@@ -156,64 +151,37 @@ class KeyeVL2:
     max_positions: int = 4096           # cache rows: the episode length
     compute_dtype: jnp.dtype = jnp.bfloat16
 
-    carries_state = True
+    head_table = "head"
+    #: the router stays float32
+    float32_leaves = ("router",)
+    final_norm_eps = property(lambda self: self.rms_norm_eps)
 
     def __post_init__(self):
         assert self.num_attention_heads % self.num_key_value_heads == 0
         assert 0 < self.experts_held <= self.num_experts
 
-    def for_env(self, env) -> "KeyeVL2":
-        """This policy over ``env``'s action space and episode length."""
-        return dataclasses.replace(
-            self, num_actions=env.num_actions, max_positions=env.episode_length
-        )
-
-    def layer_name(self, i: int) -> str:
-        return f"layer_{self.layer_ids[i]}"
-
     # -- parameters -----------------------------------------------------------
-    def init_params(self, rng):
-        """Seeded float32 parameters, ``{layer: {leaf: array}}``: normal
+    def _init_layer(self, i: int, init):
+        """A held layer's seeded leaves (every layer is of one kind): normal
         kernels scaled by 1/sqrt(fan_in), unit gains, a zero bias."""
         d, fe, D = self.hidden_size, self.moe_intermediate_size, self.head_dim
         hq, hkv = self.num_attention_heads * D, self.num_key_value_heads * D
         hi, di = self.indexer_num_heads, self.indexer_head_dim
         e = self.experts_held
-        keys = iter(jax.random.split(rng, 16 * len(self.layer_ids) + 4))
-
-        def normal(shape, fan_in):
-            return jax.random.normal(next(keys), shape, jnp.float32) / math.sqrt(fan_in)
-
-        ones = lambda n: jnp.ones((n,), jnp.float32)  # noqa: E731
-        params = {"embed": {"table": normal((self.num_actions, d), d)}}
-        for i in range(len(self.layer_ids)):
-            params[self.layer_name(i)] = dict(
-                attn_norm=ones(d), ffn_norm=ones(d),
-                wq=normal((d, hq), d), wk=normal((d, hkv), d),
-                wv=normal((d, hkv), d), wo=normal((hq, d), hq),
-                q_norm=ones(D), k_norm=ones(D),
-                idx_wq=normal((d, hi * di), d), idx_wk=normal((d, di), d),
-                idx_k_norm=ones(di), idx_k_norm_b=jnp.zeros((di,), jnp.float32),
-                idx_ww=normal((d, hi), d),
-                router=normal((d, self.num_experts), d),
-                w1=normal((e, d, fe), d), w3=normal((e, d, fe), d),
-                w2=normal((e, fe, d), fe))
-        params["final"] = {"norm": ones(d)}
-        params["head"] = {"table": normal((self.num_actions, d), d)}
-        # a value head that starts near zero, as actor-critic code starts it
-        params["value"] = {"kernel": VALUE_INIT_SCALE * normal((d, 1), d),
-                           "bias": jnp.zeros((1,), jnp.float32)}
-        return params
-
-    def rollout_params(self, params):
-        """The matrices in the compute type, once for a whole rollout. Gains,
-        the bias, the router and the value head stay float32."""
-        return layers.matrices_in(params, self.compute_dtype, keep=("router",))
+        normal, ones = init.normal, init.ones
+        return dict(
+            attn_norm=ones(d), ffn_norm=ones(d),
+            wq=normal((d, hq), d), wk=normal((d, hkv), d),
+            wv=normal((d, hkv), d), wo=normal((hq, d), hq),
+            q_norm=ones(D), k_norm=ones(D),
+            idx_wq=normal((d, hi * di), d), idx_wk=normal((d, di), d),
+            idx_k_norm=ones(di), idx_k_norm_b=init.zeros(di),
+            idx_ww=normal((d, hi), d),
+            router=normal((d, self.num_experts), d),
+            w1=normal((e, d, fe), d), w3=normal((e, d, fe), d),
+            w2=normal((e, fe, d), fe))
 
     # -- pieces shared by the decode step and the unroll -----------------------
-    def _mm(self, x, w, out_dtype=jnp.float32):
-        return layers.mm(x, w, self.compute_dtype, out_dtype)
-
     def _qkv(self, p, z, positions):
         """z [B, T, d] -> q [B, T, H, D], k, v [B, T, KV, D] in the compute
         type: per-head RMSNorm on q and k, then RoPE at ``positions``."""
@@ -257,23 +225,10 @@ class KeyeVL2:
             routing = moe.route(
                 z, p["router"], None, self.num_experts_per_tok,
                 self.norm_topk_prob, scoring="softmax")
-            cd = self.compute_dtype
-            out, counts, overflow = moe.expert_ffn(
-                z.astype(cd), routing, p["w1"].astype(cd), p["w3"].astype(cd),
-                p["w2"].astype(cd), self.expert_offset, self.num_experts)
-            return h + out, (counts, routing.experts, overflow)
-
-    def _head(self, params, x):
-        """x [N, d] float32 -> PolicyValue over the held vocabulary."""
-        with device_scope(profiling.HEAD):
-            h = rms_norm(x, params["final"]["norm"], self.rms_norm_eps)
-            logits, value = layers.tied_head(
-                h, params["head"]["table"], params["value"], self.compute_dtype)
-            return PolicyValue(logits=logits, value=value)
-
-    def _embed(self, params, tokens):
-        return layers.embed_rows(
-            params["embed"]["table"], tokens, self.compute_dtype)
+            out, counted = moe.held_experts(
+                z, routing, p, self.compute_dtype, self.expert_offset,
+                self.num_experts)
+            return h + out, counted
 
     # -- the rollout's decode step ---------------------------------------------
     def init_carry(self, batch: int) -> Carry:
@@ -292,10 +247,7 @@ class KeyeVL2:
 
     def carry_bytes(self) -> Tuple[int, ...]:
         """Bytes of carry an env, by kind: (``kv``, ``index_keys``, ``pos``)."""
-        shapes = jax.eval_shape(lambda: self.init_carry(1))
-        size = lambda tree: sum(  # noqa: E731
-            x.size * x.dtype.itemsize for x in jax.tree_util.tree_leaves(tree))
-        return size(shapes.kv), size(shapes.index_keys), size(shapes.pos)
+        return self._carry_bytes(lambda c: (c.kv, c.index_keys, c.pos))
 
     def carry_gauges(self, carry: Carry) -> dict:
         del carry  # a constant of the shapes
@@ -361,19 +313,17 @@ class KeyeVL2:
         }
 
     def step(self, params, obs, carry: Carry, fresh):
-        """One token an env: ``obs`` [B] int32, ``fresh`` [B] bool (the
-        token opens an episode: forget the last one first)."""
-        B = obs.shape[0]
+        # nothing of this carry is zeroed where ``fresh``: the position
+        # masks every buffer, so the opening is the position alone
         pos = jnp.where(fresh, 0, carry.pos)
-        rows = jnp.arange(B)
+        rows = jnp.arange(obs.shape[0])
         at = pos[:, None]
         live = jnp.arange(self.max_positions)[None, :] <= at
         x = self._embed(params, obs)
         kv_out, idx_out = [], []
 
-        def write(cache, new):  # in place: one row an env
-            return cache.at[rows, pos].set(
-                new.reshape(B, -1), indices_are_sorted=True, unique_indices=True)
+        def write(cache, new):
+            return sequence.write_row(rows, cache, pos, new)
 
         for i, ((k_cache, v_cache), i_cache) in enumerate(
                 zip(carry.kv, carry.index_keys, strict=True)):
@@ -389,10 +339,7 @@ class KeyeVL2:
                 with device_scope(profiling.OP_INDEXER_SELECT):
                     kept = select_mask(scores, live, self.index_topk)
             with device_scope(profiling.OP_ATTN_SPARSE):
-                a = decode_attention.decode_attend(
-                    q[:, 0], k_cache, v_cache, pos + 1,
-                    1.0 / math.sqrt(self.head_dim), kept)
-                h = x + self._mm(a.reshape(B, -1), p["wo"])
+                h = x + self._attend_rows(p, q, k_cache, v_cache, pos, kept)
             x, _ = self._ffn(p, h)
             kv_out.append((k_cache, v_cache))
             idx_out.append(i_cache)
@@ -433,9 +380,9 @@ class KeyeVL2:
                                  - jnp.where(there, log_q, 0.0)), 0.0))
 
     def _layer_unroll(self, i: int, p, x, with_selection: bool):
-        """One layer over whole episodes: x [B, T, d] float32 -> (x, the
+        """One layer over whole episodes: x [B, T, d] float32 -> (x, (the
         layer's ``L_I``, keys selected, keys live, what the experts' layer
-        counted, the selection [B, T, T] or None)."""
+        counted, the selection [B, T, T] or None))."""
         B, T, d = x.shape
         positions = jnp.arange(T)[None, :]
         with device_scope(profiling.OP_ATTN_SPARSE):
@@ -465,8 +412,8 @@ class KeyeVL2:
         loss = jax.checkpoint(self._index_loss)
         kl = sum(loss(index, c, shared[:, lo:hi, :hi]) for lo, hi, index, c in blocks)
         y, routed = self._ffn(p, h.reshape(B * T, d))
-        return (y.reshape(B, T, d), kl / (B * T), selected, live, routed,
-                chosen if with_selection else None)
+        return y.reshape(B, T, d), (kl / (B * T), selected, live, routed,
+                                    chosen if with_selection else None)
 
     def unroll(self, params, tokens, with_routes: bool = False):
         """Whole episodes from a reset: ``tokens`` [B, T] int32 ->
@@ -480,34 +427,29 @@ class KeyeVL2:
         names every token's chosen experts (``routes`` [layers, B, T, k])
         and every query's selected keys (``selected`` [layers, B, T, T / 8]
         uint8: the mask's bits, ``jnp.packbits`` along the keys)."""
-        B, T = tokens.shape
-        x = self._embed(params, tokens)
-        kls, selected, live, counts, routes, overflow, masks = ([] for _ in range(7))
-        for i in range(len(self.layer_ids)):
-            # a layer is recomputed in the backward, as in the other two
-            # sequence policies
-            layer = jax.checkpoint(
-                lambda p, x, i=i: self._layer_unroll(i, p, x, with_routes))
-            x, kl, n_sel, n_live, routed, mask = layer(
-                params[self.layer_name(i)], x)
+        routed = moe.RoutedLayers(*tokens.shape)
+        kls, selected, live, masks = [], [], [], []
+
+        def took(counted):
+            kl, n_sel, n_live, of_experts, mask = counted
             kls.append(kl)
             selected.append(n_sel)
             live.append(n_live)
-            counts.append(routed[0])
-            routes.append(routed[1].reshape(B, T, -1))
-            overflow.append(routed[2])
+            routed.take(of_experts)
             masks.append(mask)
-        out = self._head(params, x.reshape(B * T, -1))
-        aux = {
-            "moe_tokens_per_expert": jnp.stack(counts),
-            "moe_overflow_blocks": jnp.stack(overflow),
-            "dsa_keys_selected": jnp.stack(selected),
-            "dsa_keys_live": jnp.stack(live),
-            LOSS_TERMS: {"indexer_kl": self.indexer_loss_coef * jnp.stack(kls)},
-        }
-        if with_routes:
-            aux["routes"] = jnp.stack(routes)
-            aux["selected"] = jnp.packbits(jnp.stack(masks), axis=-1)
-        return PolicyValue(
-            logits=out.logits.reshape(B, T, -1), value=out.value.reshape(B, T)
-        ), aux
+
+        def aux():
+            out = {
+                **routed.aux(),
+                "dsa_keys_selected": jnp.stack(selected),
+                "dsa_keys_live": jnp.stack(live),
+                LOSS_TERMS: {"indexer_kl": self.indexer_loss_coef * jnp.stack(kls)},
+            }
+            if with_routes:
+                out["routes"] = jnp.stack(routed.routes)
+                out["selected"] = jnp.packbits(jnp.stack(masks), axis=-1)
+            return out
+
+        return self._unroll(
+            params, tokens,
+            lambda i, p, x: self._layer_unroll(i, p, x, with_routes), took, aux)

@@ -4,10 +4,11 @@ Reference equivalent: ``tensorpack/models/nonlin.py`` (PReLU) and friends
 (SURVEY.md §2.6 #17). Conv/Dense/Pooling come from flax.linen directly — we do
 not re-wrap what the library already expresses idiomatically.
 
-Below ``PReLU``: what the token-sequence policies (models/lfm2_moe.py,
-models/phi4_flash.py, models/keye_vl2.py) share, as plain functions of arrays. Their parameters
-are float32 trees ``{layer: {leaf: array}}``; matrices multiply in the
-compute type (bfloat16) with float32 accumulation.
+Below ``PReLU``: what the token-sequence policies share as plain functions
+of arrays (what they share as policies, from the cut lookup to the unroll's
+skeleton, is models/sequence.py's). Their parameters are float32 trees
+``{layer: {leaf: array}}``; matrices multiply in the compute type
+(bfloat16) with float32 accumulation.
 """
 
 from __future__ import annotations
@@ -96,6 +97,26 @@ def attend(q, k, v, mask, compute_dtype, scale=None):
     out = jnp.einsum("bkgqs,bskd->bqkgd", probs, v,
                      preferred_element_type=jnp.float32)
     return out.reshape(B, Tq, -1)
+
+
+def causal_conv(taps, u):
+    """A depthwise causal convolution over whole episodes, zero before the
+    episode: ``taps`` [n, c] (``taps[k]`` weighs the input ``k`` back), ``u``
+    [B, T, c] -> ``sum_k taps[k] u_{t-k}`` [B, T, c]. A bias is the caller's
+    to add, on the side of the sum its policy adds it."""
+    n, T = taps.shape[0], u.shape[1]
+    padded = jnp.pad(u, ((0, 0), (n - 1, 0), (0, 0)))
+    return sum(taps[k] * padded[:, n - 1 - k:n - 1 - k + T] for k in range(n))
+
+
+def conv_step(first, taps, u, tail):
+    """The same convolution a position at a time: ``first`` is the caller's
+    ``taps[0] * u`` (with its bias, where it has one, on the side its policy
+    adds it), ``tail`` [B, n - 1, c] the last inputs, the newest first ->
+    (``first + sum_{k >= 1} taps[k] u_{t-k}`` [B, c], the next tail)."""
+    conv = first + sum(
+        taps[k] * tail[:, k - 1] for k in range(1, taps.shape[0]))
+    return conv, jnp.concatenate([u[:, None], tail[:, :-1]], 1)
 
 
 def embed_rows(table, tokens, compute_dtype):

@@ -36,14 +36,14 @@ accumulation. The policy protocol is models/policy.py's.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from distributed_ba3c_tpu.models import layers
-from distributed_ba3c_tpu.models.a3c import PolicyValue
+from distributed_ba3c_tpu.models import layers, sequence
 from distributed_ba3c_tpu.models.layers import rms_norm, rope
 from distributed_ba3c_tpu.ops import moe
 from distributed_ba3c_tpu.utils import profiling
@@ -51,15 +51,14 @@ from distributed_ba3c_tpu.utils.profiling import device_scope
 
 CONV, ATTN = "conv", "full_attention"
 DENSE, EXPERTS = "dense", "experts"
-VALUE_INIT_SCALE = 0.01
 #: spread of the seeded ``use_expert_bias`` buffer: small beside the gaps
 #: between router scores, as a bias that exists to even the load out is
 EXPERT_BIAS_SCALE = 0.01
 
-#: ``--model_cut``: what one chip holds. ``chip-share-4``: one of 4 chips
-#: that share each layer (8 of 32 experts; the vocabulary slice is the
-#: env's action space), published layer 0 and the whole period 2-5.
-#: ``tiny``: every mechanism at a size a CPU test runs.
+#: ``--model_cut``: what one chip holds, the default first. ``chip-share-4``:
+#: one of 4 chips that share each layer (8 of 32 experts; the vocabulary
+#: slice is the env's action space), published layer 0 and the whole period
+#: 2-5. ``tiny``: every mechanism at a size a CPU test runs.
 CUTS = {
     "chip-share-4": {},
     "tiny": dict(
@@ -72,11 +71,7 @@ CUTS = {
 }
 
 
-def cut_fields(cut: str | None) -> dict:
-    cut = cut or "chip-share-4"
-    if cut not in CUTS:
-        raise ValueError(f"unknown --model_cut {cut!r}; have {sorted(CUTS)}")
-    return dict(CUTS[cut])
+cut_fields = functools.partial(sequence.cut_fields, CUTS)
 
 
 class Carry(NamedTuple):
@@ -88,7 +83,7 @@ class Carry(NamedTuple):
 
 
 @dataclasses.dataclass(frozen=True)
-class LFM2MoE:
+class LFM2MoE(sequence.SequencePolicy):
     num_actions: int = 16384            # vocabulary ids held (of 65,536)
     hidden_size: int = 2048
     intermediate_size: int = 7168
@@ -114,9 +109,10 @@ class LFM2MoE:
     # -- how it is run ------------------------------------------------------
     max_positions: int = 256            # K/V cache rows: the episode length
     compute_dtype: jnp.dtype = jnp.bfloat16
-    remat: bool = True                  # recompute a layer in the backward
 
-    carries_state = True
+    #: the router with its bias and the conv's taps stay float32
+    float32_leaves = ("router", "expert_bias", "conv_taps")
+    final_norm_eps = property(lambda self: self.norm_eps)
 
     def __post_init__(self):
         assert len(self.layer_ids) == len(self.layer_kinds)
@@ -124,73 +120,40 @@ class LFM2MoE:
         assert self.num_attention_heads % self.num_key_value_heads == 0
         assert 0 < self.experts_held <= self.num_experts
 
-    def for_env(self, env) -> "LFM2MoE":
-        """This policy over ``env``'s action space and episode length."""
-        return dataclasses.replace(
-            self, num_actions=env.num_actions, max_positions=env.episode_length
-        )
-
-    def layer_name(self, i: int) -> str:
-        return f"layer_{self.layer_ids[i]}"
-
     # -- parameters -----------------------------------------------------------
-    def init_params(self, rng):
-        """Seeded float32 parameters, ``{layer: {leaf: array}}``: normal
-        kernels scaled by 1/sqrt(fan_in), unit gains. ``expert_bias`` is
-        the published buffer: it only chooses, so its gradient is
-        identically zero and Adam never moves it."""
+    def _init_layer(self, i: int, init):
+        """Held layer ``i``'s seeded leaves: normal kernels scaled by
+        1/sqrt(fan_in), unit gains. ``expert_bias`` is the published buffer:
+        it only chooses, so its gradient is identically zero and Adam never
+        moves it."""
         d, f, fe = self.hidden_size, self.intermediate_size, self.moe_intermediate_size
         hq = self.num_attention_heads * self.head_dim
         hkv = self.num_key_value_heads * self.head_dim
-        keys = iter(jax.random.split(rng, 16 * len(self.layer_ids) + 4))
-
-        def normal(shape, fan_in):
-            return jax.random.normal(next(keys), shape, jnp.float32) / math.sqrt(fan_in)
-
-        params = {"embed": {"table": normal((self.num_actions, d), d)}}
-        for i, (op, ffn) in enumerate(self.layer_kinds):
-            layer = {"op_norm": jnp.ones((d,), jnp.float32),
-                     "ffn_norm": jnp.ones((d,), jnp.float32)}
-            if op == CONV:
-                layer.update(conv_in=normal((d, 3 * d), d),
-                             conv_taps=normal((self.conv_L_cache, d), self.conv_L_cache),
-                             conv_out=normal((d, d), d))
-            else:
-                layer.update(wq=normal((d, hq), d), wk=normal((d, hkv), d),
-                             wv=normal((d, hkv), d), wo=normal((hq, d), hq),
-                             q_norm=jnp.ones((self.head_dim,), jnp.float32),
-                             k_norm=jnp.ones((self.head_dim,), jnp.float32))
-            if ffn == DENSE:
-                layer.update(w1=normal((d, f), d), w3=normal((d, f), d),
-                             w2=normal((f, d), f))
-            else:
-                e = self.experts_held
-                layer.update(
-                    router=normal((d, self.num_experts), d),
-                    expert_bias=EXPERT_BIAS_SCALE * jax.random.normal(
-                        next(keys), (self.num_experts,), jnp.float32),
-                    w1=normal((e, d, fe), d), w3=normal((e, d, fe), d),
-                    w2=normal((e, fe, d), fe))
-            params[self.layer_name(i)] = layer
-        params["final"] = {"norm": jnp.ones((d,), jnp.float32)}
-        # a value head that starts near zero, as actor-critic code starts it:
-        # at unit scale V ~ N(0, 1) against returns of 0 swamps the advantage
-        params["value"] = {"kernel": VALUE_INIT_SCALE * normal((d, 1), d),
-                           "bias": jnp.zeros((1,), jnp.float32)}
-        return params
-
-    def rollout_params(self, params):
-        """The matrices in the compute type, once for a whole rollout: a
-        decode step then reads 2 bytes a weight and not 4. Gains, taps,
-        the router with its bias and the value head stay float32."""
-        return layers.matrices_in(
-            params, self.compute_dtype,
-            keep=("router", "expert_bias", "conv_taps"))
+        normal, ones = init.normal, init.ones
+        op, ffn = self.layer_kinds[i]
+        layer = {"op_norm": ones(d), "ffn_norm": ones(d)}
+        if op == CONV:
+            layer.update(conv_in=normal((d, 3 * d), d),
+                         conv_taps=normal((self.conv_L_cache, d), self.conv_L_cache),
+                         conv_out=normal((d, d), d))
+        else:
+            layer.update(wq=normal((d, hq), d), wk=normal((d, hkv), d),
+                         wv=normal((d, hkv), d), wo=normal((hq, d), hq),
+                         q_norm=ones(self.head_dim), k_norm=ones(self.head_dim))
+        if ffn == DENSE:
+            layer.update(w1=normal((d, f), d), w3=normal((d, f), d),
+                         w2=normal((f, d), f))
+        else:
+            e = self.experts_held
+            layer.update(
+                router=normal((d, self.num_experts), d),
+                expert_bias=EXPERT_BIAS_SCALE * jax.random.normal(
+                    next(init.keys), (self.num_experts,), jnp.float32),
+                w1=normal((e, d, fe), d), w3=normal((e, d, fe), d),
+                w2=normal((e, fe, d), fe))
+        return layer
 
     # -- pieces shared by the decode step and the unroll -----------------------
-    def _mm(self, x, w, out_dtype=None):
-        return layers.mm(x, w, self.compute_dtype, out_dtype)
-
     def _ffn(self, p, ffn: str, h):
         """h [N, d] float32 -> (h + FFN(RMSNorm(h)), None or (tokens routed
         to each held expert, the chosen expert ids [N, k], the blocks of
@@ -206,40 +169,25 @@ class LFM2MoE:
                 z, p["router"], p["expert_bias"], self.num_experts_per_tok,
                 self.norm_topk_prob, self.routed_scaling_factor,
             )
-            cd = self.compute_dtype
-            out, counts, overflow = moe.expert_ffn(
-                z.astype(cd), routing, p["w1"].astype(cd), p["w3"].astype(cd),
-                p["w2"].astype(cd), self.expert_offset, self.num_experts,
-            )
-            return h + out, (counts, routing.experts, overflow)
+            out, counted = moe.held_experts(
+                z, routing, p, self.compute_dtype, self.expert_offset,
+                self.num_experts)
+            return h + out, counted
 
     def _qkv(self, p, z, positions):
         """z [..., d] -> q [..., H, D], k, v [..., KV, D] in the compute
         type: per-head RMSNorm on q and k, then RoPE at ``positions``."""
-        D = self.head_dim
-        q = self._mm(z, p["wq"]).reshape(*z.shape[:-1], -1, D)
-        k = self._mm(z, p["wk"]).reshape(*z.shape[:-1], -1, D)
-        v = self._mm(z, p["wv"]).reshape(*z.shape[:-1], -1, D)
+        D, cd = self.head_dim, self.compute_dtype
+        # the three products leave in the compute type
+        q = self._mm(z, p["wq"], cd).reshape(*z.shape[:-1], -1, D)
+        k = self._mm(z, p["wk"], cd).reshape(*z.shape[:-1], -1, D)
+        v = self._mm(z, p["wv"], cd).reshape(*z.shape[:-1], -1, D)
         q = rope(rms_norm(q, p["q_norm"], self.norm_eps), positions, self.rope_theta)
         k = rope(rms_norm(k, p["k_norm"], self.norm_eps), positions, self.rope_theta)
-        cd = self.compute_dtype
         return q.astype(cd), k.astype(cd), v
 
     def _attend(self, q, k, v, mask):
         return layers.attend(q, k, v, mask, self.compute_dtype)
-
-    def _head(self, params, x):
-        """x [N, d] float32 -> PolicyValue over the held vocabulary."""
-        with device_scope(profiling.HEAD):
-            h = rms_norm(x, params["final"]["norm"], self.norm_eps)
-            logits, value = layers.tied_head(
-                h, params["embed"]["table"], params["value"],
-                self.compute_dtype)
-            return PolicyValue(logits=logits, value=value)
-
-    def _embed(self, params, tokens):
-        return layers.embed_rows(
-            params["embed"]["table"], tokens, self.compute_dtype)
 
     def epoch_stats(self, metrics: dict) -> dict:
         """An epoch's scalars from the step's metrics of this policy."""
@@ -265,15 +213,12 @@ class LFM2MoE:
         )
 
     def step(self, params, obs, carry: Carry, fresh):
-        """One token an env: ``obs`` [B] int32, ``fresh`` [B] bool (the
-        token opens an episode: forget the last one first)."""
-        B = obs.shape[0]
-        pos = jnp.where(fresh, 0, carry.pos)
-        keep = (~fresh).astype(jnp.float32)[:, None]
+        pos, keep = sequence.decode_opening(carry.pos, fresh)
+        keep = keep.astype(jnp.float32)[:, None]
         x = self._embed(params, obs)
         conv_out, kv_out = [], []
         conv_in, kv_in = iter(carry.conv), iter(carry.kv)
-        rows = jnp.arange(B)
+        rows = jnp.arange(obs.shape[0])
         for i, (op, ffn) in enumerate(self.layer_kinds):
             p = params[self.layer_name(i)]
             if op == CONV:
@@ -281,8 +226,8 @@ class LFM2MoE:
                     v1, v2 = next(conv_in)
                     v1, v2 = v1 * keep, v2 * keep
                     z = rms_norm(x, p["op_norm"], self.norm_eps)
-                    b, c, u = jnp.split(
-                        self._mm(z, p["conv_in"]).astype(jnp.float32), 3, -1)
+                    b, c, u = jnp.split(self._mm(
+                        z, p["conv_in"], self.compute_dtype).astype(jnp.float32), 3, -1)
                     v = b * u
                     taps = p["conv_taps"]
                     s = taps[0] * v + taps[1] * v1 + taps[2] * v2
@@ -293,10 +238,8 @@ class LFM2MoE:
                     k_cache, v_cache = next(kv_in)
                     z = rms_norm(x, p["op_norm"], self.norm_eps)
                     q, k, v = self._qkv(p, z[:, None, :], pos[:, None])
-                    k_cache = k_cache.at[rows, pos].set(
-                        k[:, 0], indices_are_sorted=True, unique_indices=True)
-                    v_cache = v_cache.at[rows, pos].set(
-                        v[:, 0], indices_are_sorted=True, unique_indices=True)
+                    k_cache = sequence.write_row(rows, k_cache, pos, k[:, 0])
+                    v_cache = sequence.write_row(rows, v_cache, pos, v[:, 0])
                     mask = jnp.arange(self.max_positions)[None, None, :] <= pos[:, None, None]
                     a = self._attend(q, k_cache, v_cache, mask)[:, 0]
                     h = x + self._mm(a, p["wo"], jnp.float32)
@@ -313,8 +256,8 @@ class LFM2MoE:
         if op == CONV:
             with device_scope(profiling.OP_CONV):
                 z = rms_norm(x, p["op_norm"], self.norm_eps)
-                b, c, u = jnp.split(
-                    self._mm(z, p["conv_in"]).astype(jnp.float32), 3, -1)
+                b, c, u = jnp.split(self._mm(
+                    z, p["conv_in"], self.compute_dtype).astype(jnp.float32), 3, -1)
                 v = b * u
                 padded = jnp.pad(v, ((0, 0), (2, 0), (0, 0)))
                 taps = p["conv_taps"]
@@ -340,23 +283,6 @@ class LFM2MoE:
         more rows were routed here than ``ops/moe.py``'s bound) and, asked,
         names every token's chosen experts (``routes`` [expert layers, B,
         T, k])."""
-        B, T = tokens.shape
-        x = self._embed(params, tokens)
-        counts, routes, overflow = [], [], []
-        for i in range(len(self.layer_kinds)):
-            layer = lambda p, x, i=i: self._layer_unroll(i, p, x)  # noqa: E731
-            if self.remat:
-                layer = jax.checkpoint(layer)
-            x, routed = layer(params[self.layer_name(i)], x)
-            if routed is not None:
-                counts.append(routed[0])
-                routes.append(routed[1].reshape(B, T, -1))
-                overflow.append(routed[2])
-        out = self._head(params, x.reshape(B * T, -1))
-        aux = {"moe_tokens_per_expert": jnp.stack(counts),
-               "moe_overflow_blocks": jnp.stack(overflow)} if counts else {}
-        if with_routes:
-            aux["routes"] = jnp.stack(routes)
-        return PolicyValue(
-            logits=out.logits.reshape(B, T, -1), value=out.value.reshape(B, T)
-        ), aux
+        routed = moe.RoutedLayers(*tokens.shape)
+        return self._unroll(params, tokens, self._layer_unroll, routed.take,
+                            lambda: routed.aux(with_routes))

@@ -48,23 +48,22 @@ mm``); the K/V cache bfloat16. The policy protocol is models/policy.py's.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from distributed_ba3c_tpu.models import layers
-from distributed_ba3c_tpu.models.a3c import PolicyValue
+from distributed_ba3c_tpu.models import layers, sequence
 from distributed_ba3c_tpu.models.layers import rms_norm
-from distributed_ba3c_tpu.ops import decode_attention, moe, sparse_attention, ssd
+from distributed_ba3c_tpu.ops import moe, sparse_attention, ssd
 from distributed_ba3c_tpu.utils import profiling
 from distributed_ba3c_tpu.utils.profiling import device_scope
 
 MAMBA, EXPERTS, ATTENTION = "M", "E", "*"
 #: ``hybrid_override_pattern`` as published: 52 blocks
 PATTERN = "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"
-VALUE_INIT_SCALE = 0.01
 #: the seeded start of a Mamba-2 layer: ``exp(A_log)`` uniform in [A_MIN,
 #: A_MAX], ``dt_bias`` the inverse softplus of a step size log-uniform in
 #: [time_step_min, time_step_max] floored at time_step_floor (the config's
@@ -90,12 +89,13 @@ KERNEL_QUERY_HEADS = 8
 #: 1-2 % longer on a third of the seeds. PERF.md section 7 has what was
 #: tried in its place.
 EXPERT_ROWS_MARGIN = 1.0
-#: ``--model_cut``: what one chip holds. ``chip-share-16``: one of 16 chips
-#: that share each layer expert parallel (8 of 128 routed experts; the
-#: vocabulary slice is the env's action space), published blocks 0-8 (one
-#: whole period, 4 Mamba-2 : 4 expert : 1 attention). ``tiny``: every
-#: mechanism at a size a CPU test runs, 2 of 32 experts (sixteen shares),
-#: an expert width off whole lanes as the published one is.
+#: ``--model_cut``: what one chip holds, the default first.
+#: ``chip-share-16``: one of 16 chips that share each layer expert parallel
+#: (8 of 128 routed experts; the vocabulary slice is the env's action
+#: space), published blocks 0-8 (one whole period, 4 Mamba-2 : 4 expert : 1
+#: attention). ``tiny``: every mechanism at a size a CPU test runs, 2 of 32
+#: experts (sixteen shares), an expert width off whole lanes as the
+#: published one is.
 CUTS = {
     "chip-share-16": {},
     "tiny": dict(
@@ -121,11 +121,7 @@ def _with_its_input(p, x):
     return jax.lax.optimization_barrier((p, x))
 
 
-def cut_fields(cut: str | None) -> dict:
-    cut = cut or "chip-share-16"
-    if cut not in CUTS:
-        raise ValueError(f"unknown --model_cut {cut!r}; have {sorted(CUTS)}")
-    return dict(CUTS[cut])
+cut_fields = functools.partial(sequence.cut_fields, CUTS)
 
 
 class Carry(NamedTuple):
@@ -144,7 +140,7 @@ class Carry(NamedTuple):
 
 
 @dataclasses.dataclass(frozen=True)
-class NemotronH:
+class NemotronH(sequence.SequencePolicy):
     num_actions: int = 16384            # vocabulary ids held (of 131,072)
     hidden_size: int = 2688
     mamba_num_heads: int = 64
@@ -177,7 +173,11 @@ class NemotronH:
     state_dtype: jnp.dtype = jnp.float32  # the recurrence's state (a
                                           # control keeps it in bfloat16)
 
-    carries_state = True
+    head_table = "head"
+    #: the conv's taps and the router stay float32 (the conv's bias,
+    #: ``A_log``, ``D``, ``dt_bias`` and the router's bias are vectors)
+    float32_leaves = ("conv_w", "router")
+    final_norm_eps = property(lambda self: self.layer_norm_epsilon)
 
     def __post_init__(self):
         assert self.conv_kernel == 4, "the causal conv is written for 4 taps"
@@ -200,84 +200,49 @@ class NemotronH:
         and ``C``."""
         return self.d_inner + 2 * self.n_groups * self.ssm_state_size
 
-    def for_env(self, env) -> "NemotronH":
-        """This policy over ``env``'s action space and episode length."""
-        return dataclasses.replace(
-            self, num_actions=env.num_actions, max_positions=env.episode_length
-        )
-
-    def layer_name(self, i: int) -> str:
-        return f"layer_{self.layer_ids[i]}"
-
     # -- parameters -----------------------------------------------------------
-    def init_params(self, rng):
-        """Seeded float32 parameters, ``{layer: {leaf: array}}``: normal
-        kernels scaled by 1/sqrt(fan_in), unit gains, ``D`` 1; ``exp(A_log)``
-        uniform in [1, 16], ``dt_bias`` the inverse softplus of step sizes
-        log-uniform in [time_step_min, time_step_max] floored at
-        time_step_floor. ``expert_bias`` only chooses, so its gradient is
-        identically zero and Adam never moves it."""
+    def _init_layer(self, i: int, init):
+        """Held block ``i``'s seeded leaves: normal kernels scaled by
+        1/sqrt(fan_in), unit gains, ``D`` 1; ``exp(A_log)`` uniform in [1,
+        16], ``dt_bias`` the inverse softplus of step sizes log-uniform in
+        [time_step_min, time_step_max] floored at time_step_floor.
+        ``expert_bias`` only chooses, so its gradient is identically zero
+        and Adam never moves it."""
         d, h = self.hidden_size, self.mamba_num_heads
         hq = self.num_attention_heads * self.head_dim
         hkv = self.num_key_value_heads * self.head_dim
         fe, fs = self.moe_intermediate_size, self.moe_shared_expert_intermediate_size
         taps, width = self.conv_kernel, self.conv_width
-        keys = iter(jax.random.split(rng, 16 * len(self.layer_ids) + 4))
-
-        def normal(shape, fan_in):
-            return jax.random.normal(next(keys), shape, jnp.float32) / math.sqrt(fan_in)
-
-        def uniform(shape, low, high):
-            return low + (high - low) * jax.random.uniform(
-                next(keys), shape, jnp.float32)
-
-        ones = lambda n: jnp.ones((n,), jnp.float32)  # noqa: E731
-        params = {"embed": {"table": normal((self.num_actions, d), d)}}
-        for i, kind in enumerate(self.layer_kinds):
-            layer = {"norm": ones(d)}
-            if kind == MAMBA:
-                step = jnp.maximum(jnp.exp(uniform(
-                    (h,), math.log(self.time_step_min),
-                    math.log(self.time_step_max))), self.time_step_floor)
-                layer.update(
-                    in_proj=normal((d, self.d_inner + width + h), d),
-                    conv_w=normal((taps, width), taps),
-                    conv_b=normal((width,), taps),
-                    A_log=jnp.log(uniform((h,), A_MIN, A_MAX)), D=ones(h),
-                    dt_bias=step + jnp.log(-jnp.expm1(-step)),
-                    gate_norm=ones(self.d_inner),
-                    out_proj=normal((self.d_inner, d), self.d_inner))
-            elif kind == ATTENTION:
-                layer.update(
-                    wq=normal((d, hq), d), wk=normal((d, hkv), d),
-                    wv=normal((d, hkv), d), wo=normal((hq, d), hq))
-            else:
-                e = self.experts_held
-                layer.update(
-                    router=normal((d, self.n_routed_experts), d),
-                    expert_bias=EXPERT_BIAS_SCALE * jax.random.normal(
-                        next(keys), (self.n_routed_experts,), jnp.float32),
-                    w1=normal((e, d, fe), d), w2=normal((e, fe, d), fe),
-                    shared_w1=normal((d, fs), d), shared_w2=normal((fs, d), fs))
-            params[self.layer_name(i)] = layer
-        params["final"] = {"norm": ones(d)}
-        params["head"] = {"table": normal((self.num_actions, d), d)}
-        # a value head that starts near zero, as actor-critic code starts it
-        params["value"] = {"kernel": VALUE_INIT_SCALE * normal((d, 1), d),
-                           "bias": jnp.zeros((1,), jnp.float32)}
-        return params
-
-    def rollout_params(self, params):
-        """The matrices in the compute type, once for a whole rollout. Gains,
-        the conv's taps and bias, ``A_log``, ``D``, ``dt_bias``, the router
-        with its bias and the value head stay float32."""
-        return layers.matrices_in(
-            params, self.compute_dtype, keep=("conv_w", "router"))
+        normal, uniform, ones = init.normal, init.uniform, init.ones
+        kind = self.layer_kinds[i]
+        layer = {"norm": ones(d)}
+        if kind == MAMBA:
+            step = jnp.maximum(jnp.exp(uniform(
+                (h,), math.log(self.time_step_min),
+                math.log(self.time_step_max))), self.time_step_floor)
+            layer.update(
+                in_proj=normal((d, self.d_inner + width + h), d),
+                conv_w=normal((taps, width), taps),
+                conv_b=normal((width,), taps),
+                A_log=jnp.log(uniform((h,), A_MIN, A_MAX)), D=ones(h),
+                dt_bias=step + jnp.log(-jnp.expm1(-step)),
+                gate_norm=ones(self.d_inner),
+                out_proj=normal((self.d_inner, d), self.d_inner))
+        elif kind == ATTENTION:
+            layer.update(
+                wq=normal((d, hq), d), wk=normal((d, hkv), d),
+                wv=normal((d, hkv), d), wo=normal((hq, d), hq))
+        else:
+            e = self.experts_held
+            layer.update(
+                router=normal((d, self.n_routed_experts), d),
+                expert_bias=EXPERT_BIAS_SCALE * jax.random.normal(
+                    next(init.keys), (self.n_routed_experts,), jnp.float32),
+                w1=normal((e, d, fe), d), w2=normal((e, fe, d), fe),
+                shared_w1=normal((d, fs), d), shared_w2=normal((fs, d), fs))
+        return layer
 
     # -- pieces shared by the decode step and the unroll -----------------------
-    def _mm(self, x, w, out_dtype=jnp.float32):
-        return layers.mm(x, w, self.compute_dtype, out_dtype)
-
     def _mamba_in(self, p, x):
         """x [..., d] float32 -> (z [..., h P], the conv's input [..., h P +
         2 g N], the step sizes dt [..., h]), float32."""
@@ -312,15 +277,11 @@ class NemotronH:
     def mamba_mixer(self, p, x):
         """A Mamba-2 block's mixer over whole episodes from a reset: x [B,
         T, d] float32 -> [B, T, d]."""
-        T = x.shape[1]
-        taps = self.conv_kernel
         with device_scope(profiling.OP_MAMBA2):
             z, xbc, dt = self._mamba_in(p, x)
             with device_scope(profiling.OP_MAMBA2_CONV):
-                padded = jnp.pad(xbc, ((0, 0), (taps - 1, 0), (0, 0)))
-                xbc = jax.nn.silu(p["conv_b"] + sum(
-                    p["conv_w"][k] * padded[:, taps - 1 - k:taps - 1 - k + T]
-                    for k in range(taps)))
+                xbc = jax.nn.silu(
+                    p["conv_b"] + layers.causal_conv(p["conv_w"], xbc))
             heads, B, C = self._mamba_heads(xbc)
             with device_scope(profiling.OP_MAMBA2_SSD):
                 y, _ = ssd.ssd_chunked(
@@ -368,25 +329,10 @@ class NemotronH:
             routing = moe.route(
                 u, p["router"], p["expert_bias"], self.num_experts_per_tok,
                 self.norm_topk_prob, self.routed_scaling_factor)
-            cd = self.compute_dtype
-            out, counts, overflow = moe.expert_ffn(
-                u.astype(cd), routing, p["w1"].astype(cd), None,
-                p["w2"].astype(cd), self.expert_offset, self.n_routed_experts,
-                EXPERT_ROWS_MARGIN)
-            return out + self.shared_expert(p, u), (
-                counts, routing.experts, overflow)
-
-    def _head(self, params, x):
-        """x [N, d] float32 -> PolicyValue over the held vocabulary."""
-        with device_scope(profiling.HEAD):
-            h = rms_norm(x, params["final"]["norm"], self.layer_norm_epsilon)
-            logits, value = layers.tied_head(
-                h, params["head"]["table"], params["value"], self.compute_dtype)
-            return PolicyValue(logits=logits, value=value)
-
-    def _embed(self, params, tokens):
-        return layers.embed_rows(
-            params["embed"]["table"], tokens, self.compute_dtype)
+            out, counted = moe.held_experts(
+                u, routing, p, self.compute_dtype, self.expert_offset,
+                self.n_routed_experts, EXPERT_ROWS_MARGIN)
+            return out + self.shared_expert(p, u), counted
 
     # -- the rollout's decode step ---------------------------------------------
     def init_carry(self, batch: int) -> Carry:
@@ -410,13 +356,12 @@ class NemotronH:
     def carry_bytes(self) -> Tuple[int, ...]:
         """Bytes of carry an env, by kind: (the recurrence's states, the
         convs' tails, the K/V buffers, the position and the last step sizes)."""
-        shapes = jax.eval_shape(lambda: self.init_carry(1))
-        size = lambda tree: sum(  # noqa: E731
-            x.size * x.dtype.itemsize for x in jax.tree_util.tree_leaves(tree))
-        states, tails, steps = (
-            [layer[i] for layer in shapes.mamba] for i in range(3))
-        return (size(states), size(tails), size(shapes.kv),
-                size(shapes.pos) + size(steps))
+        def kinds(carry):
+            states, tails, steps = (
+                [layer[i] for layer in carry.mamba] for i in range(3))
+            return states, tails, carry.kv, (carry.pos, steps)
+
+        return self._carry_bytes(kinds)
 
     def carry_gauges(self, carry: Carry) -> dict:
         """What the trainer reports of the carry at an update's end: its
@@ -443,20 +388,11 @@ class NemotronH:
         }
 
     def step(self, params, obs, carry: Carry, fresh):
-        """One token an env: ``obs`` [B] int32, ``fresh`` [B] bool (the
-        token opens an episode: forget the last one first)."""
-        B = obs.shape[0]
-        pos = jnp.where(fresh, 0, carry.pos)
-        keep = ~fresh
-        rows = jnp.arange(B)
+        pos, keep = sequence.decode_opening(carry.pos, fresh)
+        rows = jnp.arange(obs.shape[0])
         x = self._embed(params, obs)
         mamba_in, kv_in = iter(carry.mamba), iter(carry.kv)
         mamba_out, kv_out = [], []
-
-        def write(cache, new):  # in place: one row an env
-            return cache.at[rows, pos].set(
-                new.reshape(B, -1), indices_are_sorted=True, unique_indices=True)
-
         for i, kind in enumerate(self.layer_kinds):
             p = params[self.layer_name(i)]
             if kind == MAMBA:
@@ -466,11 +402,9 @@ class NemotronH:
                     tail = tail * keep[:, None, None]
                     z, xbc, dt = self._mamba_in(p, x)
                     with device_scope(profiling.OP_MAMBA2_CONV):
-                        taps = p["conv_w"]  # taps[k] weighs the input k back
-                        conv = p["conv_b"] + taps[0] * xbc + sum(
-                            taps[k] * tail[:, k - 1]
-                            for k in range(1, self.conv_kernel))
-                        tail = jnp.concatenate([xbc[:, None], tail[:, :-1]], 1)
+                        taps = p["conv_w"]
+                        conv, tail = layers.conv_step(
+                            p["conv_b"] + taps[0] * xbc, taps, xbc, tail)
                     heads, Bm, Cm = self._mamba_heads(jax.nn.silu(conv))
                     with device_scope(profiling.OP_MAMBA2_SSD):
                         state, y = ssd.ssd_step(
@@ -479,14 +413,10 @@ class NemotronH:
                     mamba_out.append((state, tail, dt))
             elif kind == ATTENTION:
                 with device_scope(profiling.OP_ATTN_FULL):
-                    k_cache, v_cache = next(kv_in)
-                    q, k, v = self._qkv(p, x[:, None, :])
-                    k_cache, v_cache = write(k_cache, k), write(v_cache, v)
-                    out = decode_attention.decode_attend(
-                        q[:, 0], k_cache, v_cache, pos + 1,
-                        1.0 / math.sqrt(self.head_dim))
-                    mixed = self._mm(out.reshape(B, -1), p["wo"])
-                    kv_out.append((k_cache, v_cache))
+                    caches = next(kv_in)
+                    mixed, caches = self._decode_attention(
+                        p, self._qkv(p, x[:, None, :]), caches, rows, pos)
+                    kv_out.append(caches)
             else:
                 mixed, _ = self.experts_mixer(p, x)
             x = x + mixed
@@ -512,27 +442,9 @@ class NemotronH:
         (``moe_tokens_per_expert``) and the blocks of sorted rows each ran
         beyond its first (``moe_overflow_blocks``) and, asked, names every
         token's chosen experts (``routes`` [expert blocks, B, T, k])."""
-        B, T = tokens.shape
-        x = self._embed(params, tokens)
-        counts, routes, overflow = [], [], []
-        for i in range(len(self.layer_kinds)):
-            # a block is recomputed in the backward, as in the other
-            # sequence policies. Its weights are tied to its input (see
-            # ``_with_its_input``)
-            layer = jax.checkpoint(lambda p, x, i=i: self._layer_unroll(
-                i, *_with_its_input(p, x)))
-            x, routed = layer(params[self.layer_name(i)], x)
-            if routed is not None:
-                counts.append(routed[0])
-                routes.append(routed[1].reshape(B, T, -1))
-                overflow.append(routed[2])
-        top, x = _with_its_input(
-            {k: params[k] for k in ("final", "head", "value")}, x)
-        out = self._head(top, x.reshape(B * T, -1))
-        aux = {"moe_tokens_per_expert": jnp.stack(counts),
-               "moe_overflow_blocks": jnp.stack(overflow)} if counts else {}
-        if with_routes:
-            aux["routes"] = jnp.stack(routes)
-        return PolicyValue(
-            logits=out.logits.reshape(B, T, -1), value=out.value.reshape(B, T)
-        ), aux
+        routed = moe.RoutedLayers(*tokens.shape)
+        # a block's weights, and the head's, are tied to their input (see
+        # ``_with_its_input``)
+        return self._unroll(
+            params, tokens, self._layer_unroll, routed.take,
+            lambda: routed.aux(with_routes), tie=_with_its_input)

@@ -49,34 +49,34 @@ bfloat16. The policy protocol is models/policy.py's.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from distributed_ba3c_tpu.models import layers
-from distributed_ba3c_tpu.models.a3c import PolicyValue
+from distributed_ba3c_tpu.models import layers, sequence
 from distributed_ba3c_tpu.models.layers import rms_norm
-from distributed_ba3c_tpu.ops import decode_attention, delta_rule, sparse_attention
+from distributed_ba3c_tpu.ops import delta_rule, sparse_attention
 from distributed_ba3c_tpu.utils import profiling
 from distributed_ba3c_tpu.utils.profiling import device_scope
 
 LINEAR, FULL = "linear_attention", "full_attention"
 #: ``layer_types`` as published: 32 layers, every fourth one full attention
 LAYER_TYPES = (LINEAR, LINEAR, LINEAR, FULL) * 8
-VALUE_INIT_SCALE = 0.01
 #: the seeded gates: ``exp(A_log)`` uniform in [A_MIN, A_MAX], ``softplus(
 #: dt_bias)`` log-uniform in [DT_MIN, DT_MAX] (the family's start)
 A_MIN, A_MAX = 1.0, 16.0
 DT_MIN, DT_MAX = 1e-3, 1e-1
 #: under the square root of a head's ``|q|`` and ``|k|``
 L2_EPS = 1e-6
-#: ``--model_cut``: what one chip holds. ``head-share-3``: one of 3 chips
-#: that share each layer by heads (10 of 30 of every mixer), published
-#: layers 0-3 (one whole period); the vocabulary slice is the env's action
-#: space. ``tiny``: every mechanism at a size a CPU test runs, 2 heads of
-#: an uncut 6 of both kinds, the learner's delta rule in chunks of 8.
+#: ``--model_cut``: what one chip holds, the default first. ``head-share-3``:
+#: one of 3 chips that share each layer by heads (10 of 30 of every mixer),
+#: published layers 0-3 (one whole period); the vocabulary slice is the
+#: env's action space. ``tiny``: every mechanism at a size a CPU test runs,
+#: 2 heads of an uncut 6 of both kinds, the learner's delta rule in chunks
+#: of 8.
 CUTS = {
     "head-share-3": {},
     "tiny": dict(
@@ -87,11 +87,7 @@ CUTS = {
 }
 
 
-def cut_fields(cut: str | None) -> dict:
-    cut = cut or "head-share-3"
-    if cut not in CUTS:
-        raise ValueError(f"unknown --model_cut {cut!r}; have {sorted(CUTS)}")
-    return dict(CUTS[cut])
+cut_fields = functools.partial(sequence.cut_fields, CUTS)
 
 
 class Carry(NamedTuple):
@@ -110,7 +106,7 @@ class Carry(NamedTuple):
 
 
 @dataclasses.dataclass(frozen=True)
-class OlmoHybrid:
+class OlmoHybrid(sequence.SequencePolicy):
     num_actions: int = 12544            # vocabulary ids held (of 100,352)
     hidden_size: int = 3840
     intermediate_size: int = 11008
@@ -132,7 +128,10 @@ class OlmoHybrid:
     state_dtype: jnp.dtype = jnp.float32  # the delta rule's state (a
                                           # control keeps it in bfloat16)
 
-    carries_state = True
+    head_table = "head"
+    #: the conv's taps stay float32 (``A_log`` and ``dt_bias`` are vectors)
+    float32_leaves = ("conv_w",)
+    final_norm_eps = property(lambda self: self.rms_norm_eps)
 
     def __post_init__(self):
         assert self.linear_conv_kernel_dim == 4, "the causal conv is written for 4 taps"
@@ -148,74 +147,39 @@ class OlmoHybrid:
         return self.linear_num_heads * (
             2 * self.linear_key_head_dim + self.linear_value_head_dim)
 
-    def for_env(self, env) -> "OlmoHybrid":
-        """This policy over ``env``'s action space and episode length."""
-        return dataclasses.replace(
-            self, num_actions=env.num_actions, max_positions=env.episode_length
-        )
-
-    def layer_name(self, i: int) -> str:
-        return f"layer_{self.layer_ids[i]}"
-
     # -- parameters -----------------------------------------------------------
-    def init_params(self, rng):
-        """Seeded float32 parameters, ``{layer: {leaf: array}}``: normal
-        kernels scaled by 1/sqrt(fan_in), unit gains; ``exp(A_log)`` uniform
-        in [1, 16], ``dt_bias`` the inverse softplus of step sizes
-        log-uniform in [1e-3, 1e-1]."""
+    def _init_layer(self, i: int, init):
+        """Held layer ``i``'s seeded leaves: normal kernels scaled by
+        1/sqrt(fan_in), unit gains; ``exp(A_log)`` uniform in [1, 16],
+        ``dt_bias`` the inverse softplus of step sizes log-uniform in [1e-3,
+        1e-1]."""
         d, f = self.hidden_size, self.intermediate_size
         H, K, V = (self.linear_num_heads, self.linear_key_head_dim,
                    self.linear_value_head_dim)
         hq = self.num_attention_heads * self.head_dim
         taps = self.linear_conv_kernel_dim
-        keys = iter(jax.random.split(rng, 16 * len(self.layer_ids) + 4))
-
-        def normal(shape, fan_in):
-            return jax.random.normal(next(keys), shape, jnp.float32) / math.sqrt(fan_in)
-
-        def uniform(shape, low, high):
-            return low + (high - low) * jax.random.uniform(
-                next(keys), shape, jnp.float32)
-
-        ones = lambda n: jnp.ones((n,), jnp.float32)  # noqa: E731
-        params = {"embed": {"table": normal((self.num_actions, d), d)}}
-        for i, kind in enumerate(self.layer_kinds):
-            layer = {"mix_norm": ones(d), "ffn_norm": ones(d),
-                     "w_gate": normal((d, f), d), "w_up": normal((d, f), d),
-                     "w_down": normal((f, d), f)}
-            if kind == LINEAR:
-                step = jnp.exp(uniform((H,), math.log(DT_MIN), math.log(DT_MAX)))
-                layer.update(
-                    wqkv=normal((d, self.conv_width), d),
-                    wz=normal((d, H * V), d), wa=normal((d, H), d),
-                    wb=normal((d, H), d),
-                    conv_w=normal((taps, self.conv_width), taps),
-                    A_log=jnp.log(uniform((H,), A_MIN, A_MAX)),
-                    dt_bias=step + jnp.log(-jnp.expm1(-step)),
-                    o_norm=ones(V), wo=normal((H * V, d), H * V))
-            else:
-                layer.update(
-                    wq=normal((d, hq), d), wk=normal((d, hq), d),
-                    wv=normal((d, hq), d), wo=normal((hq, d), hq),
-                    q_norm=ones(hq), k_norm=ones(hq))
-            params[self.layer_name(i)] = layer
-        params["final"] = {"norm": ones(d)}
-        params["head"] = {"table": normal((self.num_actions, d), d)}
-        # a value head that starts near zero, as actor-critic code starts it
-        params["value"] = {"kernel": VALUE_INIT_SCALE * normal((d, 1), d),
-                           "bias": jnp.zeros((1,), jnp.float32)}
-        return params
-
-    def rollout_params(self, params):
-        """The matrices in the compute type, once for a whole rollout. Gains,
-        the conv's taps, ``A_log``, ``dt_bias`` and the value head stay
-        float32."""
-        return layers.matrices_in(params, self.compute_dtype, keep=("conv_w",))
+        normal, uniform, ones = init.normal, init.uniform, init.ones
+        layer = {"mix_norm": ones(d), "ffn_norm": ones(d),
+                 "w_gate": normal((d, f), d), "w_up": normal((d, f), d),
+                 "w_down": normal((f, d), f)}
+        if self.layer_kinds[i] == LINEAR:
+            step = jnp.exp(uniform((H,), math.log(DT_MIN), math.log(DT_MAX)))
+            layer.update(
+                wqkv=normal((d, self.conv_width), d),
+                wz=normal((d, H * V), d), wa=normal((d, H), d),
+                wb=normal((d, H), d),
+                conv_w=normal((taps, self.conv_width), taps),
+                A_log=jnp.log(uniform((H,), A_MIN, A_MAX)),
+                dt_bias=step + jnp.log(-jnp.expm1(-step)),
+                o_norm=ones(V), wo=normal((H * V, d), H * V))
+        else:
+            layer.update(
+                wq=normal((d, hq), d), wk=normal((d, hq), d),
+                wv=normal((d, hq), d), wo=normal((hq, d), hq),
+                q_norm=ones(hq), k_norm=ones(hq))
+        return layer
 
     # -- pieces shared by the decode step and the unroll -----------------------
-    def _mm(self, x, w, out_dtype=jnp.float32):
-        return layers.mm(x, w, self.compute_dtype, out_dtype)
-
     def _after(self, p, x, mixed):
         """x [N, d] and its mixer's output -> the layer's output: both
         sub-blocks' norms lie on their OUTPUT, before the residual add."""
@@ -258,15 +222,10 @@ class OlmoHybrid:
     def linear_mixer(self, p, x):
         """A linear-attention layer's mixer over whole episodes from a reset:
         x [B, T, d] float32 -> this chip's part of its output [B, T, d]."""
-        T = x.shape[1]
-        taps = self.linear_conv_kernel_dim
         with device_scope(profiling.OP_LINATTN):
             u, z, alpha, beta = self._linear_in(p, x)
             with device_scope(profiling.OP_LINATTN_CONV):
-                padded = jnp.pad(u, ((0, 0), (taps - 1, 0), (0, 0)))
-                u = jax.nn.silu(sum(
-                    p["conv_w"][k] * padded[:, taps - 1 - k:taps - 1 - k + T]
-                    for k in range(taps)))
+                u = jax.nn.silu(layers.causal_conv(p["conv_w"], u))
             q, k, v = self._linear_heads(u)
             with device_scope(profiling.OP_LINATTN_DELTA):
                 o, _ = delta_rule.delta_chunked(
@@ -307,18 +266,6 @@ class OlmoHybrid:
                 q, k, v, None, 1.0 / math.sqrt(self.head_dim))
             return self._mm(out, p["wo"])
 
-    def _head(self, params, x):
-        """x [N, d] float32 -> PolicyValue over the held vocabulary."""
-        with device_scope(profiling.HEAD):
-            h = rms_norm(x, params["final"]["norm"], self.rms_norm_eps)
-            logits, value = layers.tied_head(
-                h, params["head"]["table"], params["value"], self.compute_dtype)
-            return PolicyValue(logits=logits, value=value)
-
-    def _embed(self, params, tokens):
-        return layers.embed_rows(
-            params["embed"]["table"], tokens, self.compute_dtype)
-
     # -- the rollout's decode step ---------------------------------------------
     def init_carry(self, batch: int) -> Carry:
         H, K, V = (self.linear_num_heads, self.linear_key_head_dim,
@@ -343,13 +290,12 @@ class OlmoHybrid:
     def carry_bytes(self) -> Tuple[int, ...]:
         """Bytes of carry an env, by kind: (the delta rule's states, the
         convs' tails, the K/V buffers, the position and the last gates)."""
-        shapes = jax.eval_shape(lambda: self.init_carry(1))
-        size = lambda tree: sum(  # noqa: E731
-            x.size * x.dtype.itemsize for x in jax.tree_util.tree_leaves(tree))
-        states, tails, gates = (
-            [layer[i] for layer in shapes.linear] for i in range(3))
-        return (size(states), size(tails), size(shapes.kv),
-                size(shapes.pos) + size(gates))
+        def kinds(carry):
+            states, tails, gates = (
+                [layer[i] for layer in carry.linear] for i in range(3))
+            return states, tails, carry.kv, (carry.pos, gates)
+
+        return self._carry_bytes(kinds)
 
     def carry_gauges(self, carry: Carry) -> dict:
         """What the trainer reports of the carry at an update's end: its
@@ -375,20 +321,11 @@ class OlmoHybrid:
         }
 
     def step(self, params, obs, carry: Carry, fresh):
-        """One token an env: ``obs`` [B] int32, ``fresh`` [B] bool (the
-        token opens an episode: forget the last one first)."""
-        B = obs.shape[0]
-        pos = jnp.where(fresh, 0, carry.pos)
-        keep = ~fresh
-        rows = jnp.arange(B)
+        pos, keep = sequence.decode_opening(carry.pos, fresh)
+        rows = jnp.arange(obs.shape[0])
         x = self._embed(params, obs)
         linear_in, kv_in = iter(carry.linear), iter(carry.kv)
         linear_out, kv_out = [], []
-
-        def write(cache, new):  # in place: one row an env
-            return cache.at[rows, pos].set(
-                new.reshape(B, -1), indices_are_sorted=True, unique_indices=True)
-
         for i, kind in enumerate(self.layer_kinds):
             p = params[self.layer_name(i)]
             if kind == LINEAR:
@@ -398,11 +335,8 @@ class OlmoHybrid:
                     tail = tail * keep[:, None, None]
                     u, z, alpha, beta = self._linear_in(p, x)
                     with device_scope(profiling.OP_LINATTN_CONV):
-                        taps = p["conv_w"]  # taps[k] weighs the input k back
-                        conv = taps[0] * u + sum(
-                            taps[k] * tail[:, k - 1]
-                            for k in range(1, self.linear_conv_kernel_dim))
-                        tail = jnp.concatenate([u[:, None], tail[:, :-1]], 1)
+                        taps = p["conv_w"]
+                        conv, tail = layers.conv_step(taps[0] * u, taps, u, tail)
                     q, k, v = self._linear_heads(jax.nn.silu(conv))
                     with device_scope(profiling.OP_LINATTN_DELTA):
                         state, o = delta_rule.delta_step(
@@ -411,39 +345,26 @@ class OlmoHybrid:
                     linear_out.append((state, tail, alpha))
             else:
                 with device_scope(profiling.OP_ATTN_FULL):
-                    k_cache, v_cache = next(kv_in)
-                    q, k, v = self._qkv(p, x[:, None, :])
-                    k_cache, v_cache = write(k_cache, k), write(v_cache, v)
-                    out = decode_attention.decode_attend(
-                        q[:, 0], k_cache, v_cache, pos + 1,
-                        1.0 / math.sqrt(self.head_dim))
-                    mixed = self._mm(out.reshape(B, -1), p["wo"])
-                    kv_out.append((k_cache, v_cache))
+                    caches = next(kv_in)
+                    mixed, caches = self._decode_attention(
+                        p, self._qkv(p, x[:, None, :]), caches, rows, pos)
+                    kv_out.append(caches)
             x = self._after(p, x, mixed)
         return self._head(params, x), Carry(
             pos=pos + 1, linear=tuple(linear_out), kv=tuple(kv_out))
 
     # -- the learner's unroll ----------------------------------------------------
     def _layer_unroll(self, i: int, p, x):
-        """One layer over whole episodes: x [B, T, d] float32 -> the same."""
+        """One layer over whole episodes: x [B, T, d] float32 -> (the same,
+        None: it counts nothing)."""
         B, T, d = x.shape
         mixer = self.linear_mixer if self.layer_kinds[i] == LINEAR else self.full_mixer
         return self._after(
             p, x.reshape(B * T, d), mixer(p, x).reshape(B * T, d)
-        ).reshape(B, T, d)
+        ).reshape(B, T, d), None
 
     def unroll(self, params, tokens):
         """Whole episodes from a reset: ``tokens`` [B, T] int32 ->
         (PolicyValue with logits [B, T, A] and value [B, T], aux). ``aux``
         is empty: this policy counts nothing in its learner."""
-        B, T = tokens.shape
-        x = self._embed(params, tokens)
-        for i in range(len(self.layer_kinds)):
-            # a layer is recomputed in the backward, as in the other
-            # sequence policies
-            layer = jax.checkpoint(lambda p, x, i=i: self._layer_unroll(i, p, x))
-            x = layer(params[self.layer_name(i)], x)
-        out = self._head(params, x.reshape(B * T, -1))
-        return PolicyValue(
-            logits=out.logits.reshape(B, T, -1), value=out.value.reshape(B, T)
-        ), {}
+        return self._unroll(params, tokens, self._layer_unroll)

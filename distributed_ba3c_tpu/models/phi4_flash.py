@@ -60,14 +60,14 @@ policy protocol is models/policy.py's.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from distributed_ba3c_tpu.models import layers
-from distributed_ba3c_tpu.models.a3c import PolicyValue
+from distributed_ba3c_tpu.models import layers, sequence
 from distributed_ba3c_tpu.models.layers import layer_norm, rms_norm
 from distributed_ba3c_tpu.ops import decode_attention, ssm
 from distributed_ba3c_tpu.utils import profiling
@@ -76,7 +76,6 @@ from distributed_ba3c_tpu.utils.profiling import device_scope
 MAMBA, WINDOW, FULL, GMU, CROSS = (
     "mamba", "window_attention", "full_attention", "memory_unit",
     "cross_attention")
-VALUE_INIT_SCALE = 0.01
 #: spread of the four learned vectors of a differential attention's ``lam``
 LAMBDA_INIT_SCALE = 0.1
 #: the seeded step sizes: ``softplus(b_dt)`` log-uniform between these
@@ -86,10 +85,11 @@ DT_MIN, DT_MAX = 1e-3, 1e-1
 #: backward holds (168 MB at 4 envs x 256 x 1,024), and a block reads only
 #: the keys its mask can reach
 ATTN_QUERY_BLOCK = 256
-#: ``--model_cut``: what one chip holds. ``stage-14-19``: published layers
-#: 14-19 (Mamba, window, Mamba that hands on ``m``, full that hands on K/V,
-#: memory unit, cross: every kind, contiguous), the vocabulary slice the
-#: env's action space. ``tiny``: every mechanism at a size a CPU test runs.
+#: ``--model_cut``: what one chip holds, the default first. ``stage-14-19``:
+#: published layers 14-19 (Mamba, window, Mamba that hands on ``m``, full
+#: that hands on K/V, memory unit, cross: every kind, contiguous), the
+#: vocabulary slice the env's action space. ``tiny``: every mechanism at a
+#: size a CPU test runs.
 CUTS = {
     "stage-14-19": {},
     "tiny": dict(
@@ -100,11 +100,7 @@ CUTS = {
 }
 
 
-def cut_fields(cut: str | None) -> dict:
-    cut = cut or "stage-14-19"
-    if cut not in CUTS:
-        raise ValueError(f"unknown --model_cut {cut!r}; have {sorted(CUTS)}")
-    return dict(CUTS[cut])
+cut_fields = functools.partial(sequence.cut_fields, CUTS)
 
 
 def kind_of(i: int, n: int) -> str:
@@ -138,7 +134,7 @@ class Carry(NamedTuple):
 
 
 @dataclasses.dataclass(frozen=True)
-class Phi4Flash:
+class Phi4Flash(sequence.SequencePolicy):
     num_actions: int = 25008            # vocabulary ids held (of 200,064)
     hidden_size: int = 2560
     intermediate_size: int = 10240
@@ -158,7 +154,9 @@ class Phi4Flash:
     max_positions: int = 1024           # shared K/V rows: the episode length
     compute_dtype: jnp.dtype = jnp.bfloat16
 
-    carries_state = True
+    #: the conv's taps and ``A_log`` stay float32
+    float32_leaves = ("conv_w", "A_log")
+    final_norm_eps = property(lambda self: self.layer_norm_eps)
 
     def __post_init__(self):
         assert self.d_conv == 4, "the causal conv is written for 4 taps"
@@ -184,82 +182,57 @@ class Phi4Flash:
         last = kinds.index(GMU) if GMU in kinds else len(kinds)
         return max((i for i in range(last) if kinds[i] == MAMBA), default=-1)
 
-    def for_env(self, env) -> "Phi4Flash":
-        """This policy over ``env``'s action space and episode length."""
-        return dataclasses.replace(
-            self, num_actions=env.num_actions, max_positions=env.episode_length
-        )
-
-    def layer_name(self, i: int) -> str:
-        return f"layer_{self.layer_ids[i]}"
-
     # -- parameters -----------------------------------------------------------
     def init_params(self, rng):
-        """Seeded float32 parameters, ``{layer: {leaf: array}}``: normal
-        kernels scaled by 1/sqrt(fan_in), unit gains, zero biases; ``A_log``
-        the family's ``log(1 .. d_state)``, ``D`` ones, ``dt_bias`` the
-        inverse softplus of step sizes log-uniform in [1e-3, 1e-1]."""
+        """The scaffold's, and the final LayerNorm's zero bias."""
+        params = super().init_params(rng)
+        params["final"]["norm_b"] = jnp.zeros((self.hidden_size,), jnp.float32)
+        return params
+
+    def _init_layer(self, i: int, init):
+        """Held layer ``i``'s seeded leaves: normal kernels scaled by
+        1/sqrt(fan_in), unit gains, zero biases; ``A_log`` the family's
+        ``log(1 .. d_state)``, ``D`` ones, ``dt_bias`` the inverse softplus
+        of step sizes log-uniform in [1e-3, 1e-1]."""
         d, f, c = self.hidden_size, self.intermediate_size, self.d_inner
         D = self.head_dim
         hq, hkv = self.num_attention_heads * D, self.num_key_value_heads * D
-        keys = iter(jax.random.split(rng, 16 * len(self.layer_ids) + 4))
-
-        def normal(shape, fan_in):
-            return jax.random.normal(next(keys), shape, jnp.float32) / math.sqrt(fan_in)
-
-        ones = lambda n: jnp.ones((n,), jnp.float32)  # noqa: E731
-        zeros = lambda n: jnp.zeros((n,), jnp.float32)  # noqa: E731
-        params = {"embed": {"table": normal((self.num_actions, d), d)}}
-        for i, kind in enumerate(self.layer_kinds):
-            layer = {"mix_norm": ones(d), "mix_norm_b": zeros(d),
-                     "ffn_norm": ones(d), "ffn_norm_b": zeros(d),
-                     "w_gate": normal((d, f), d), "w_up": normal((d, f), d),
-                     "w_down": normal((f, d), f)}
-            if kind == MAMBA:
-                step = jnp.exp(
-                    jax.random.uniform(next(keys), (c,), jnp.float32)
-                    * (math.log(DT_MAX) - math.log(DT_MIN)) + math.log(DT_MIN))
-                layer.update(
-                    in_proj=normal((d, 2 * c), d),
-                    conv_w=normal((self.d_conv, c), self.d_conv),
-                    conv_b=zeros(c),
-                    x_proj=normal((c, self.dt_rank + 2 * self.d_state), c),
-                    dt_proj=normal((self.dt_rank, c), self.dt_rank),
-                    dt_bias=step + jnp.log(-jnp.expm1(-step)),
-                    A_log=jnp.broadcast_to(jnp.log(jnp.arange(
-                        1, self.d_state + 1, dtype=jnp.float32)), (c, self.d_state)),
-                    D=ones(c), out_proj=normal((c, d), c))
-            elif kind == GMU:
-                layer.update(gmu_in=normal((d, c), d), gmu_out=normal((c, d), c))
+        normal, ones, zeros = init.normal, init.ones, init.zeros
+        kind = self.layer_kinds[i]
+        layer = {"mix_norm": ones(d), "mix_norm_b": zeros(d),
+                 "ffn_norm": ones(d), "ffn_norm_b": zeros(d),
+                 "w_gate": normal((d, f), d), "w_up": normal((d, f), d),
+                 "w_down": normal((f, d), f)}
+        if kind == MAMBA:
+            step = jnp.exp(
+                jax.random.uniform(next(init.keys), (c,), jnp.float32)
+                * (math.log(DT_MAX) - math.log(DT_MIN)) + math.log(DT_MIN))
+            layer.update(
+                in_proj=normal((d, 2 * c), d),
+                conv_w=normal((self.d_conv, c), self.d_conv),
+                conv_b=zeros(c),
+                x_proj=normal((c, self.dt_rank + 2 * self.d_state), c),
+                dt_proj=normal((self.dt_rank, c), self.dt_rank),
+                dt_bias=step + jnp.log(-jnp.expm1(-step)),
+                A_log=jnp.broadcast_to(jnp.log(jnp.arange(
+                    1, self.d_state + 1, dtype=jnp.float32)), (c, self.d_state)),
+                D=ones(c), out_proj=normal((c, d), c))
+        elif kind == GMU:
+            layer.update(gmu_in=normal((d, c), d), gmu_out=normal((c, d), c))
+        else:
+            if kind == CROSS:
+                layer.update(wq=normal((d, hq), d), bq=zeros(hq))
             else:
-                if kind == CROSS:
-                    layer.update(wq=normal((d, hq), d), bq=zeros(hq))
-                else:
-                    layer.update(wqkv=normal((d, hq + 2 * hkv), d),
-                                 bqkv=zeros(hq + 2 * hkv))
-                layer.update(wo=normal((hq, d), hq), bo=zeros(d),
-                             sub_norm=ones(2 * D))
-                for name in ("lam_q1", "lam_k1", "lam_q2", "lam_k2"):
-                    layer[name] = LAMBDA_INIT_SCALE * jax.random.normal(
-                        next(keys), (D,), jnp.float32)
-            params[self.layer_name(i)] = layer
-        params["final"] = {"norm": ones(d), "norm_b": zeros(d)}
-        # a value head that starts near zero, as actor-critic code starts it
-        params["value"] = {"kernel": VALUE_INIT_SCALE * normal((d, 1), d),
-                           "bias": jnp.zeros((1,), jnp.float32)}
-        return params
-
-    def rollout_params(self, params):
-        """The matrices in the compute type, once for a whole rollout.
-        Gains, biases, the conv's taps, ``A_log`` and the value head stay
-        float32."""
-        return layers.matrices_in(
-            params, self.compute_dtype, keep=("conv_w", "A_log"))
+                layer.update(wqkv=normal((d, hq + 2 * hkv), d),
+                             bqkv=zeros(hq + 2 * hkv))
+            layer.update(wo=normal((hq, d), hq), bo=zeros(d),
+                         sub_norm=ones(2 * D))
+            for name in ("lam_q1", "lam_k1", "lam_q2", "lam_k2"):
+                layer[name] = LAMBDA_INIT_SCALE * jax.random.normal(
+                    next(init.keys), (D,), jnp.float32)
+        return layer
 
     # -- pieces shared by the decode step and the unroll -----------------------
-    def _mm(self, x, w, out_dtype=jnp.float32):
-        return layers.mm(x, w, self.compute_dtype, out_dtype)
-
     def _ffn(self, p, h):
         """h [N, d] float32 -> h + SwiGLU(LN(h))."""
         with device_scope(profiling.FFN_DENSE):
@@ -344,20 +317,6 @@ class Phi4Flash:
             scale=1.0 / math.sqrt(self.head_dim))
         return self._diff_out(p, i, out.reshape(B, 1, -1))[:, 0]
 
-    def _head(self, params, x):
-        """x [N, d] float32 -> PolicyValue over the held vocabulary."""
-        with device_scope(profiling.HEAD):
-            final = params["final"]
-            h = layer_norm(x, final["norm"], final["norm_b"], self.layer_norm_eps)
-            logits, value = layers.tied_head(
-                h, params["embed"]["table"], params["value"],
-                self.compute_dtype)
-            return PolicyValue(logits=logits, value=value)
-
-    def _embed(self, params, tokens):
-        return layers.embed_rows(
-            params["embed"]["table"], tokens, self.compute_dtype)
-
     # -- the rollout's decode step ---------------------------------------------
     def _kv_shape(self, batch: int, rows: int):
         # a position's pairs side by side in ONE row of whole lanes: the
@@ -390,11 +349,7 @@ class Phi4Flash:
     def carry_bytes(self) -> Tuple[int, ...]:
         """Bytes of carry an env, by kind: (``ssm``, ``ring``, ``shared_kv``,
         ``pos``)."""
-        shapes = jax.eval_shape(lambda: self.init_carry(1))
-        size = lambda tree: sum(  # noqa: E731
-            x.size * x.dtype.itemsize for x in jax.tree_util.tree_leaves(tree))
-        return (size(shapes.ssm), size(shapes.ring), size(shapes.shared_kv),
-                size(shapes.pos))
+        return self._carry_bytes(lambda c: (c.ssm, c.ring, c.shared_kv, c.pos))
 
     def carry_gauges(self, carry: Carry) -> dict:
         """What the trainer reports of the carry at an update's end: its
@@ -416,21 +371,15 @@ class Phi4Flash:
         }
 
     def step(self, params, obs, carry: Carry, fresh):
-        """One token an env: ``obs`` [B] int32, ``fresh`` [B] bool (the
-        token opens an episode: forget the last one first)."""
-        B = obs.shape[0]
-        pos = jnp.where(fresh, 0, carry.pos)
-        keep = (~fresh).astype(jnp.float32)[:, None, None]
-        rows = jnp.arange(B)
+        pos, keep = sequence.decode_opening(carry.pos, fresh)
+        keep = keep.astype(jnp.float32)[:, None, None]
+        rows = jnp.arange(obs.shape[0])
         x = self._embed(params, obs)
         ssm_in, ring_in = iter(carry.ssm), iter(carry.ring)
         ssm_out, ring_out = [], []
         shared_kv = carry.shared_kv
         memory = None
-
-        def write(cache, at, new):  # in place: one row an env
-            return cache.at[rows, at].set(
-                new.reshape(B, -1), indices_are_sorted=True, unique_indices=True)
+        write = functools.partial(sequence.write_row, rows)
 
         for i, kind in enumerate(self.layer_kinds):
             p = params[self.layer_name(i)]
@@ -440,10 +389,9 @@ class Phi4Flash:
                     state, tail = state * keep, tail * keep
                     u, z = self._ssm_in(p, x)
                     with device_scope(profiling.OP_SSM_CONV):
-                        taps = p["conv_w"]  # taps[k] weighs the input k back
-                        conv = taps[0] * u + p["conv_b"] + sum(
-                            taps[k] * tail[:, k - 1] for k in range(1, self.d_conv))
-                        tail = jnp.concatenate([u[:, None], tail[:, :-1]], 1)
+                        taps = p["conv_w"]
+                        conv, tail = layers.conv_step(
+                            taps[0] * u + p["conv_b"], taps, u, tail)
                         u = jax.nn.silu(conv)
                     dt, Bs, Cs, A = self._ssm_select(p, u)
                     with device_scope(profiling.OP_SSM_SCAN):
@@ -511,20 +459,15 @@ class Phi4Flash:
     def _layer_unroll(self, i: int, p, x, memory, shared_kv):
         """One layer over whole episodes: x [B, T, d] float32; ``memory``
         and ``shared_kv`` are the side channels as the layers before left
-        them. -> (x, memory, shared_kv)."""
+        them. -> (x, (memory, shared_kv))."""
         kind = self.layer_kinds[i]
         B, T, d = x.shape
         if kind == MAMBA:
             with device_scope(profiling.OP_SSM):
                 u, z = self._ssm_in(p, x)
                 with device_scope(profiling.OP_SSM_CONV):
-                    taps = p["conv_w"]
-                    padded = jnp.pad(u, ((0, 0), (self.d_conv - 1, 0), (0, 0)))
-                    conv = p["conv_b"] + sum(
-                        taps[k] * padded[:, self.d_conv - 1 - k:
-                                         self.d_conv - 1 - k + T]
-                        for k in range(self.d_conv))
-                    u = jax.nn.silu(conv)
+                    u = jax.nn.silu(
+                        p["conv_b"] + layers.causal_conv(p["conv_w"], u))
                 dt, Bs, Cs, A = self._ssm_select(p, u)
                 with device_scope(profiling.OP_SSM_SCAN):
                     y, _ = ssm.selective_scan(u, dt, A, Bs, Cs, p["D"])
@@ -550,23 +493,12 @@ class Phi4Flash:
                     q = self._q(p, a)
                 h = x + self._attend_blocks(p, i, q, *shared_kv, None)
         y = self._ffn(p, h.reshape(B * T, d))
-        return y.reshape(B, T, d), memory, tuple(shared_kv)
+        return y.reshape(B, T, d), (memory, tuple(shared_kv))
 
     def unroll(self, params, tokens):
         """Whole episodes from a reset: ``tokens`` [B, T] int32 ->
         (PolicyValue with logits [B, T, A] and value [B, T], aux). ``aux``
-        is empty: this policy counts nothing in its learner."""
-        B, T = tokens.shape
-        x = self._embed(params, tokens)
-        memory, shared_kv = None, ()
-        for i in range(len(self.layer_kinds)):
-            # recomputed in the backward: the published cut does not fit
-            # the chip with every layer's activations kept
-            layer = jax.checkpoint(
-                lambda p, x, m, kv, i=i: self._layer_unroll(i, p, x, m, kv))
-            x, memory, shared_kv = layer(
-                params[self.layer_name(i)], x, memory, shared_kv)
-        out = self._head(params, x.reshape(B * T, -1))
-        return PolicyValue(
-            logits=out.logits.reshape(B, T, -1), value=out.value.reshape(B, T)
-        ), {}
+        is empty: this policy counts nothing in its learner. The two side
+        channels go from layer to layer beside the residual stream."""
+        return self._unroll(
+            params, tokens, self._layer_unroll, side=(None, ()))

@@ -22,9 +22,8 @@ A policy is what the trainers call to turn observations into
 
   ``aux`` is a dict of whatever the policy counts in its learner (summed
   over chunks and shards into the step's metrics; may be empty); ``step``
-  and ``unroll`` agree position by position (tests/test_lfm2_moe.py,
-  tests/test_phi4_flash.py, tests/test_keye_vl2.py,
-  tests/test_olmo_hybrid.py, tests/test_nemotron_h.py). Under the one reserved
+  and ``unroll`` agree position by position (each policy's own test file,
+  tests/test_<module>.py). Under the one reserved
   key :data:`LOSS_TERMS` the unroll's ``aux`` may hold **loss terms the
   policy owns**: ``{name: array}``, each entry a mean over the chunk's
   tokens (a scalar, or one a layer) with its coefficient applied. The
@@ -40,12 +39,16 @@ A policy is what the trainers call to turn observations into
           policy's own counters and gauges, for stat.json
 
 Only the fused trainer drives a policy that carries state; every other
-trainer refuses one through :func:`refuse_carry`. docs/policy_protocol.md.
+trainer refuses one through :func:`refuse_carry`. What the carrying
+policies share beyond this protocol (a base class, the unroll's skeleton,
+the decode step's common parts) is models/sequence.py's; what a new one
+writes is in docs/policy_protocol.md, "Adding a policy".
 """
 
 from __future__ import annotations
 
-from typing import Callable, Dict
+import importlib
+from typing import Dict, Tuple
 
 import jax.numpy as jnp
 
@@ -76,53 +79,40 @@ def init_params(model, rng, cfg):
     return model.init(rng, dummy)["params"]
 
 
-def _ba3cnet(cfg, cut=None):
-    from distributed_ba3c_tpu.models.a3c import BA3CNet
-
-    if cut is not None:
-        raise ValueError("ba3cnet has no --model_cut")
-    return BA3CNet(num_actions=cfg.num_actions, fc_units=cfg.fc_units)
-
-
-def _lfm2_moe(cfg, cut=None):
-    from distributed_ba3c_tpu.models.lfm2_moe import LFM2MoE, cut_fields
-
-    return LFM2MoE(num_actions=cfg.num_actions, **cut_fields(cut))
-
-
-def _phi4_flash(cfg, cut=None):
-    from distributed_ba3c_tpu.models.phi4_flash import Phi4Flash, cut_fields
-
-    return Phi4Flash(num_actions=cfg.num_actions, **cut_fields(cut))
-
-
-def _keye_vl2(cfg, cut=None):
-    from distributed_ba3c_tpu.models.keye_vl2 import KeyeVL2, cut_fields
-
-    return KeyeVL2(num_actions=cfg.num_actions, **cut_fields(cut))
-
-
-def _olmo_hybrid(cfg, cut=None):
-    from distributed_ba3c_tpu.models.olmo_hybrid import OlmoHybrid, cut_fields
-
-    return OlmoHybrid(num_actions=cfg.num_actions, **cut_fields(cut))
-
-
-def _nemotron_h(cfg, cut=None):
-    from distributed_ba3c_tpu.models.nemotron_h import NemotronH, cut_fields
-
-    return NemotronH(num_actions=cfg.num_actions, **cut_fields(cut))
-
-
-MODELS: Dict[str, Callable] = {
-    DEFAULT_MODEL: _ba3cnet, "lfm2-moe": _lfm2_moe, "phi4-flash": _phi4_flash,
-    "keye-vl2": _keye_vl2, "olmo-hybrid": _olmo_hybrid,
-    "nemotron-h": _nemotron_h,
+#: name -> (module of this package, class): the policies ``--model`` names.
+#: A module is imported when its policy is built or described and not
+#: before (a ``ba3cnet`` run imports no Pallas). A policy that carries state
+#: is models/sequence.py's: its module has ``CUTS`` and ``cut_fields``.
+MODELS: Dict[str, Tuple[str, str]] = {
+    DEFAULT_MODEL: ("a3c", "BA3CNet"),
+    "lfm2-moe": ("lfm2_moe", "LFM2MoE"),
+    "phi4-flash": ("phi4_flash", "Phi4Flash"),
+    "keye-vl2": ("keye_vl2", "KeyeVL2"),
+    "olmo-hybrid": ("olmo_hybrid", "OlmoHybrid"),
+    "nemotron-h": ("nemotron_h", "NemotronH"),
 }
 
 
+def _module_of(name: str):
+    return importlib.import_module(f"{__package__}.{MODELS[name][0]}")
+
+
 def build_model(name: str, cfg, cut: str | None = None):
-    """The policy ``--model name`` names, its action space from ``cfg``."""
+    """The policy ``--model name`` names, its action space from ``cfg``; of
+    a policy that carries state, the share of it ``--model_cut cut`` names."""
     if name not in MODELS:
         raise ValueError(f"unknown model {name!r}; have {sorted(MODELS)}")
-    return MODELS[name](cfg, cut)
+    module = _module_of(name)
+    model = getattr(module, MODELS[name][1])
+    if not carries_state(model):
+        if cut is not None:
+            raise ValueError(f"{name} has no --model_cut")
+        return model(num_actions=cfg.num_actions, fc_units=cfg.fc_units)
+    return model(num_actions=cfg.num_actions, **module.cut_fields(cut))
+
+
+def cuts_by_model() -> Dict[str, Tuple[str, ...]]:
+    """Every carrying policy's ``--model_cut`` names, its default first
+    (``cli.py``'s help: this imports every policy's module)."""
+    return {name: tuple(_module_of(name).CUTS)
+            for name in MODELS if name != DEFAULT_MODEL}

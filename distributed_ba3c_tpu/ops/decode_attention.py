@@ -78,8 +78,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from distributed_ba3c_tpu.models import layers
-# ba3clint: disable=A5 — how a pallas_call says over which mesh axes it varies under shard_map: one copy, for both modules of kernels
-from distributed_ba3c_tpu.ops.grouped_matmul import LANE, _vary_alike
+from distributed_ba3c_tpu.ops.pallas_tpu import LANE, runs_mosaic, vary_alike
 from distributed_ba3c_tpu.utils import profiling
 from distributed_ba3c_tpu.utils.profiling import device_scope
 
@@ -103,16 +102,12 @@ def block_rows(rows: int, row_bytes: int):
     return max(fit, default=None)
 
 
-def _backend_runs_mosaic() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 def _block_of(q, k):
     """The kernel's block for queries ``q`` over buffers shaped like ``k``,
     or None where ``layers.attend`` runs."""
     _, H, W = q.shape
     _, rows, width = k.shape
-    if not (INTERPRET or _backend_runs_mosaic()):
+    if not (INTERPRET or runs_mosaic()):
         return None
     groups = width // W
     if W % LANE or H % groups or H // groups > GROUP_ROWS:
@@ -128,7 +123,7 @@ def _kernel_attend(q, k, v, length, scale, block, interpret=False, kept=None):
     """q [B, G, Hg, W]; k, v [B, rows, G * W]; length [B]; kept None or
     [B, 1, rows] int32 (nonzero: selected) -> q's shape, float32."""
     selection = () if kept is None else (kept,)
-    vma, (q, k, v, length, *selection) = _vary_alike(q, k, v, length, *selection)
+    vma, (q, k, v, length, *selection) = vary_alike(q, k, v, length, *selection)
     B, G, Hg, W = q.shape
     blocks = k.shape[1] // block
     M = G * GROUP_ROWS

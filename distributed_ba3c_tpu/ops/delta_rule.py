@@ -125,10 +125,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# ba3clint: disable=A5 — how a pallas_call says over which mesh axes it varies under shard_map: one copy, for every module of kernels
-from distributed_ba3c_tpu.ops.grouped_matmul import LANE, _vary_alike
-# ba3clint: disable=A5 — a float32 product at the highest precision and a running sum by doubling steps inside a TPU kernel, and which backend runs Mosaic: one copy each, the Mamba-2 recurrence's
-from distributed_ba3c_tpu.ops.ssd import _backend_runs_mosaic, _dot, _running
+from distributed_ba3c_tpu.ops.pallas_tpu import (
+    HIGHEST, LANE, NT, TN, dot_f32, running_sum, runs_mosaic, vary_alike)
 from distributed_ba3c_tpu.utils import profiling
 from distributed_ba3c_tpu.utils.profiling import device_scope
 
@@ -143,9 +141,6 @@ FORWARD_KERNEL, BACKWARD_KERNEL = "delta_chunks_forward", "delta_chunks_backward
 #: finite for the sums of logarithms to be
 LEAST_GATE = 1e-37
 
-_HIGHEST = jax.lax.Precision.HIGHEST
-_NT = (((1,), (1,)), ((), ()))  # [m, k] x [n, k] -> [m, n]
-_TN = (((0,), (0,)), ((), ()))  # [k, m] x [k, n] -> [m, n]
 
 
 def delta_step(S, q, k, v, alpha, beta):
@@ -171,7 +166,7 @@ def _chunk_step(state_dtype, S, xs):
     h, C, C], ``g_last`` [b, h] -> (S after the chunk, kept in
     ``state_dtype`` between chunks; o [b, h, C, V])."""
     u0, w, q_in, k_out, qk, g_last = xs
-    dot = lambda spec, a, b: jnp.einsum(spec, a, b, precision=_HIGHEST)  # noqa: E731
+    dot = lambda spec, a, b: jnp.einsum(spec, a, b, precision=HIGHEST)  # noqa: E731
     u = u0 - dot("bhck,bhkv->bhcv", w, S)
     o = dot("bhck,bhkv->bhcv", q_in, S) + dot("bhcj,bhjv->bhcv", qk, u)
     S = g_last[..., None, None] * S + dot("bhck,bhcv->bhkv", k_out, u)
@@ -213,7 +208,7 @@ def delta_chunked_plain(q, k, v, alpha, beta, chunk: int = CHUNK,
         at[:, None] >= at[None, :],
         jnp.cumsum(jnp.where(below, log_alpha[..., :, None], 0.0), axis=-2),
         -jnp.inf))
-    dot = lambda spec, a, b: jnp.einsum(spec, a, b, precision=_HIGHEST)  # noqa: E731
+    dot = lambda spec, a, b: jnp.einsum(spec, a, b, precision=HIGHEST)  # noqa: E731
     A = jnp.where(
         below, beta[..., None] * ratio * dot("...ik,...jk->...ij", k, k), 0.0)
     g = jnp.exp(jnp.cumsum(log_alpha, axis=-1))[..., None]
@@ -286,7 +281,7 @@ def kernels_take(q, v, chunk: int = CHUNK) -> bool:
     x 192, 12 of 128 x 256)."""
     _, T, h, K = q.shape
     V = v.shape[-1]
-    if not (INTERPRET or _backend_runs_mosaic()):
+    if not (INTERPRET or runs_mosaic()):
         return False
     return (chunk == CHUNK and T >= chunk and h % 2 == 0
             and K % 8 == 0 and K <= LANE and _heads_in_window(h, K)
@@ -398,7 +393,7 @@ class _Chunk:
                        & (self.row % both >= half) & (self.col % both < half))
             E = jnp.where(quarter, A, 0.0)
             D = D - (E if half == 1
-                     else _dot(_dot(D, self.each(E)), self.each(D)))
+                     else dot_f32(dot_f32(D, self.each(E)), self.each(D)))
             half = both
         return D
 
@@ -410,7 +405,7 @@ class _Chunk:
         Two products against the same matrix are one, their rows stacked."""
         C, pair = CHUNK, (0, 1)
         log_alpha = [_log_gate(a) for a in alpha]
-        seg = _running(jnp.where(self.below, self.pair(*log_alpha), 0.0))
+        seg = running_sum(jnp.where(self.below, self.pair(*log_alpha), 0.0))
         ratio = jnp.exp(jnp.where(self.live, seg, -jnp.inf))       # g_i / g_j
         # g_i: column 0's sum (log alpha_1 .. log alpha_i) and log alpha_0
         g = [jnp.exp(self.total(jnp.where(self.col == 0, seg, 0.0), i)
@@ -418,18 +413,18 @@ class _Chunk:
         to_end = [self.as_column(ratio[C - 1:C, :], i) for i in pair]  # g_C / g_j
         kq = [jnp.concatenate([k[i], q[i]], axis=0) for i in pair]
         keys = [self.rows(k[i], i) for i in pair]
-        both = sum(_dot(kq[i], keys[i], _NT) for i in pair)
+        both = sum(dot_f32(kq[i], keys[i], NT) for i in pair)
         kk, qk = both[:C], both[C:]
         inv = self.inverse(jnp.where(
             self.below, self.pair(*beta) * ratio * kk, 0.0))
         out = []
         for i in pair:
-            on_state = _dot(kq[i], S[i])
+            on_state = dot_f32(kq[i], S[i])
             kS, qS = on_state[:C], on_state[C:]
             rest = v[i] - g[i] * kS
             out.append(_Head(
                 g=g[i], to_end=to_end[i], kq=kq[i], keys=keys[i], kS=kS, qS=qS,
-                rest=rest, U=_dot(inv, self.rows(beta[i] * rest, i)),
+                rest=rest, U=dot_f32(inv, self.rows(beta[i] * rest, i)),
                 # g_C down a state's rows (a select: Mosaic spreads [1, 1]
                 # one way at a time)
                 g_last=jnp.where(self.state_row >= 0, g[i][C - 1:C, :], 0.0)))
@@ -494,7 +489,7 @@ def _forward(q, k, v, alpha, beta, state_dtype=jnp.float32, interpret=False):
     sequence's)."""
     b, T, h, K = q.shape
     V, n = v.shape[-1], T // CHUNK
-    vma, (q, k, v, alpha, beta) = _vary_alike(*_flat(q, k, v), alpha, beta)
+    vma, (q, k, v, alpha, beta) = vary_alike(*_flat(q, k, v), alpha, beta)
 
     def kernel(q_ref, k_ref, v_ref, alpha_ref, beta_ref, o_ref, closed_ref, S):
         of = _Chunk(h)
@@ -516,8 +511,8 @@ def _forward(q, k, v, alpha, beta, state_dtype=jnp.float32, interpret=False):
                 [of.column(sizes, i) for i in at], S_h)
             mix = both.ratio * both.qk
             for j, (i, hd) in enumerate(zip(at, hds)):
-                of.put(o_ref, i, V, hd.g * hd.qS + _dot(mix, of.rows(hd.U, j)))
-                new = hd.g_last * S_h[j] + _dot(hd.to_end * k_h[j], hd.U, _TN)
+                of.put(o_ref, i, V, hd.g * hd.qS + dot_f32(mix, of.rows(hd.U, j)))
+                new = hd.g_last * S_h[j] + dot_f32(hd.to_end * k_h[j], hd.U, TN)
                 new = new.astype(state_dtype).astype(jnp.float32)
                 S[i] = new
                 closed_ref[i] = new[:K]
@@ -553,7 +548,7 @@ def _backward(q, k, v, alpha, beta, closed, d_o, d_last,
     as the operands lie."""
     b, T, h, K = q.shape
     V, n, C = v.shape[-1], T // CHUNK, CHUNK
-    vma, (q, k, v, d_o, alpha, beta, closed, d_last) = _vary_alike(
+    vma, (q, k, v, d_o, alpha, beta, closed, d_last) = vary_alike(
         *_flat(q, k, v), d_o.reshape(b, T, h * V), alpha, beta, closed, d_last)
 
     def kernel(q_ref, k_ref, v_ref, do_ref, alpha_ref, beta_ref, open_ref,
@@ -595,13 +590,13 @@ def _backward(q, k, v, alpha, beta, closed, d_o, d_last,
             # U = (I + A)^-1 beta (V - g K S_0)
             d_R, d_kout, against_U = [], [], 0.0
             for j, hd in enumerate(hds):
-                d_U = (halves(_dot(mix, do_h[j], _TN), j)
-                       + _dot(hd.to_end * k_h[j], dS_h[j]))
-                d_R.append(halves(_dot(both.inv, d_U, _TN), j))
-                d_kout.append(_dot(hd.U, dS_h[j], _NT))
-                against_U = against_U + _dot(
+                d_U = (halves(dot_f32(mix, do_h[j], TN), j)
+                       + dot_f32(hd.to_end * k_h[j], dS_h[j]))
+                d_R.append(halves(dot_f32(both.inv, d_U, TN), j))
+                d_kout.append(dot_f32(hd.U, dS_h[j], NT))
+                against_U = against_U + dot_f32(
                     jnp.concatenate([do_h[j], d_R[j]], axis=0),
-                    of.rows(hd.U, j), _NT)
+                    of.rows(hd.U, j), NT)
             d_mix = jnp.where(of.live, against_U[:C], 0.0)
             d_A = jnp.where(of.below, -against_U[C:], 0.0)
             # A = beta ratio K K^T below the diagonal
@@ -621,14 +616,14 @@ def _backward(q, k, v, alpha, beta, closed, d_o, d_last,
                     + of.total(d_A * both.ratio * both.kk, j))
                 of.put(dv_ref, i, V, d_rest)
                 d_on_state = jnp.concatenate([d_kS, g_do], axis=0)
-                from_state = _dot(d_on_state, S_h[j], _NT)      # dk over dq
-                from_keys = _dot(d_keys, hd.keys)                # dk over dq
-                from_rows = halves(_dot(d_keys, hd.kq, _TN), j)  # dk
+                from_state = dot_f32(d_on_state, S_h[j], NT)      # dk over dq
+                from_keys = dot_f32(d_keys, hd.keys)                # dk over dq
+                from_rows = halves(dot_f32(d_keys, hd.kq, TN), j)  # dk
                 of.put(dq_ref, i, K, from_state[C:] + from_keys[C:])
                 of.put(dk_ref, i, K,
                        from_state[:C] + from_keys[:C] + from_rows
                        + hd.to_end * d_kout[j])
-                dS[i] = hd.g_last * dS_h[j] + _dot(hd.kq, d_on_state, _TN)
+                dS[i] = hd.g_last * dS_h[j] + dot_f32(hd.kq, d_on_state, TN)
                 d_L.append(d_g * hd.g)
                 d_end.append(row_sum(d_kout[j] * k_h[j]) * hd.to_end)
             # the cotangent of each sum of logarithms: the ratio's, g_i's in
@@ -637,7 +632,7 @@ def _backward(q, k, v, alpha, beta, closed, d_o, d_last,
             d_seg = ((d_A * sizes_both * both.kk + d_mix * both.qk) * both.ratio
                      + jnp.where(of.col == 0, of.pair(*d_L), 0.0)
                      + jnp.where(of.row == C - 1, of.as_row(*d_end), 0.0))
-            held = jnp.where(of.below, _running(d_seg, up=True), 0.0)
+            held = jnp.where(of.below, running_sum(d_seg, up=True), 0.0)
             for j, i in enumerate(at):
                 of.set_column(dla_ref, i, of.total(held, j) + jnp.where(
                     of.at == 0, jnp.sum(d_L[j], keepdims=True), 0.0))

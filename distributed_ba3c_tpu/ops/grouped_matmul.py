@@ -60,10 +60,10 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.experimental.pallas.ops.tpu.megablox.gmm import make_group_metadata
 
+from distributed_ba3c_tpu.ops.pallas_tpu import LANE, runs_mosaic, vary_alike
 from distributed_ba3c_tpu.utils import profiling
 from distributed_ba3c_tpu.utils.profiling import device_scope
 
-LANE = 128  # every tile is whole lanes: the shapes have to be
 #: rows of a tile. Eight groups of about 512 rows start on no tile's edge, so
 #: a grid visits each group's last tile twice: 15 tiles of 512 for 8 tiles of
 #: rows, 23 of 256 for 16. On the v5e 256 and 128 tie and 512 costs a fifth
@@ -123,16 +123,6 @@ def tiling(form: str, m: int, k: int, n: int, itemsize: int = 2):
     return None
 
 
-def _vary_alike(*arrays):
-    """(the mesh axes any of ``arrays`` varies over, ``arrays`` all varying
-    over those): under ``shard_map`` a ``pallas_call`` has to say over which
-    axes its output varies, and its body's operands have to agree."""
-    vma = frozenset().union(*(jax.typeof(a).vma for a in arrays))
-    return vma, tuple(
-        jax.lax.pcast(a, tuple(vma - jax.typeof(a).vma), to="varying")
-        if vma - jax.typeof(a).vma else a for a in arrays)
-
-
 def _compiler_params(form: str, tiles, itemsize: int):
     """The grid's first axis is free to split over cores; the scope of fast
     memory is the tiles' and room for the body's float32 temporaries."""
@@ -169,7 +159,7 @@ def _rows_of_group(offsets, group, first_row, shape):
 def gmm(lhs, rhs, group_sizes, tiles, transpose_rhs=False, interpret=False):
     """``lhs`` [m, k] x ``rhs`` [G, k, n] (``transpose_rhs``: [G, n, k]) by
     group -> [m, n] in ``lhs``'s type; rows outside every group unwritten."""
-    vma, (lhs, rhs, group_sizes) = _vary_alike(lhs, rhs, group_sizes)
+    vma, (lhs, rhs, group_sizes) = vary_alike(lhs, rhs, group_sizes)
     m, k = lhs.shape
     n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
     tm, tk, tn = tiles
@@ -258,7 +248,7 @@ def tgmm(lhs, g, group_sizes, tiles, interpret=False):
     """``lhs`` [m, k], ``g`` [m, n] -> [G, k, n] in ``lhs``'s type: each
     group's rows of ``lhs``, transposed, times its rows of ``g``; zeros for
     an empty group."""
-    vma, (lhs, g, group_sizes) = _vary_alike(lhs, g, group_sizes)
+    vma, (lhs, g, group_sizes) = vary_alike(lhs, g, group_sizes)
     m, k = lhs.shape
     n = g.shape[1]
     groups = group_sizes.shape[0]
@@ -340,14 +330,10 @@ def tgmm(lhs, g, group_sizes, tiles, interpret=False):
     )(*metadata, lhs, g)
 
 
-def _backend_runs_mosaic() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 def _tiles_of(lhs, rhs, transpose_rhs: bool):
     """The tiles of the product, of its dx and of its dW (as
     :func:`_kernel_dot_bwd` calls them) where the kernel runs, else None."""
-    if not (INTERPRET or _backend_runs_mosaic()):
+    if not (INTERPRET or runs_mosaic()):
         return None
     m, k = lhs.shape
     n = rhs.shape[1] if transpose_rhs else rhs.shape[2]

@@ -158,6 +158,36 @@ def load_stats(metrics: dict) -> dict:
     }
 
 
+class RoutedLayers:
+    """What the expert layers of one unroll over ``[B, T]`` tokens counted,
+    taken a layer at a time as the unroll hands it over (:func:`expert_ffn`'s
+    counts, the chosen expert ids ``[B * T, k]`` and the blocks run beyond
+    the first; ``None`` from a layer without experts), and made the
+    ``aux`` the two counters above are read from."""
+
+    def __init__(self, batch: int, length: int):
+        self.shape = (batch, length)
+        self.counts, self.routes, self.overflow = [], [], []
+
+    def take(self, routed) -> None:
+        if routed is not None:
+            counts, experts, overflow = routed
+            self.counts.append(counts)
+            self.routes.append(experts.reshape(*self.shape, -1))
+            self.overflow.append(overflow)
+
+    def aux(self, with_routes: bool = False) -> dict:
+        """``moe_tokens_per_expert`` [expert layers, held] and
+        ``moe_overflow_blocks`` [expert layers] (nothing of a policy's cut
+        without an expert layer) and, asked, every token's chosen experts:
+        ``routes`` [expert layers, B, T, k]."""
+        aux = {"moe_tokens_per_expert": jnp.stack(self.counts),
+               "moe_overflow_blocks": jnp.stack(self.overflow)} if self.counts else {}
+        if with_routes:
+            aux["routes"] = jnp.stack(self.routes)
+        return aux
+
+
 def block_rows(n: int, k: int, held: int, num_experts: int,
                margin: float | None = None) -> int:
     """``R``: the sorted rows one block of the grouped form works on, from
@@ -355,6 +385,22 @@ def expert_ffn(z, routing: Routing, w1, w3, w2, expert_offset: int,
         return _every_token(z, routing, w1, w3, w2, expert_offset, num_experts)
     return _sorted_rows(z, routing, w1, w3, w2, expert_offset, num_experts,
                         block_rows(n, k, w1.shape[0], num_experts, rows_margin))
+
+
+def held_experts(z, routing: Routing, p, compute_dtype, expert_offset: int,
+                 num_experts: int, rows_margin: float | None = None):
+    """:func:`expert_ffn` as a policy's expert layer calls it: ``z`` [N, d]
+    float32 and the layer's leaves ``p`` (``w1``, ``w2`` and, of an expert
+    of three matrices, ``w3``), cast to the compute type here -> (out [N, d]
+    float32, what the layer counts for :class:`RoutedLayers`: the tokens
+    routed to each held expert, the chosen expert ids [N, k], the blocks of
+    sorted rows run beyond the first)."""
+    cd = compute_dtype
+    out, counts, overflow = expert_ffn(
+        z.astype(cd), routing, p["w1"].astype(cd),
+        p["w3"].astype(cd) if "w3" in p else None, p["w2"].astype(cd),
+        expert_offset, num_experts, rows_margin)
+    return out, (counts, routing.experts, overflow)
 
 
 # a jit of its own, so that the places a step holds this layer (4 expert

@@ -69,8 +69,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# ba3clint: disable=A5 — how a pallas_call says over which mesh axes it varies under shard_map: one copy, for every module of kernels
-from distributed_ba3c_tpu.ops.grouped_matmul import LANE, _vary_alike
+from distributed_ba3c_tpu.ops.pallas_tpu import LANE, NT, runs_mosaic, vary_alike
 
 #: positions a side of a (query tile, key tile) pair, at most
 TILE = 512
@@ -84,11 +83,6 @@ VMEM_BYTES = 32 * 2**20
 #: way to run them on the CPU (tier-1 cannot run Mosaic)
 INTERPRET = False
 
-_NT = (((1,), (1,)), ((), ()))  # [m, d] x [n, d] -> [m, n]
-
-
-def _backend_runs_mosaic() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def tile_of(q, k):
@@ -96,7 +90,7 @@ def tile_of(q, k):
     T, KV, D]: the most whole lanes' worth of positions that divide ``T``
     and fit :data:`TILE`; None where the masked-dense form runs."""
     _, T, H, D = q.shape
-    if not (INTERPRET or _backend_runs_mosaic()):
+    if not (INTERPRET or runs_mosaic()):
         return None
     if k.shape[1] != T or D % LANE or H % k.shape[2]:
         return None
@@ -187,7 +181,7 @@ def _forward(q, k, v, selection, scale, tile, interpret=False):
     1] float32)."""
     B, T, KV, G, D = _split(q, k)
     marks = () if selection is None else (selection,)
-    vma, (q, k, v, *marks) = _vary_alike(_lanes(q), _lanes(k), _lanes(v), *marks)
+    vma, (q, k, v, *marks) = vary_alike(_lanes(q), _lanes(k), _lanes(v), *marks)
     n = T // tile
 
     def kernel(q_ref, k_ref, v_ref, *refs):
@@ -207,7 +201,7 @@ def _forward(q, k, v, selection, scale, tile, interpret=False):
             def head(g):
                 lanes = pl.ds(pl.multiple_of(g * D, D), D)
                 scores = jax.lax.dot_general(
-                    q_ref[:, lanes], k_ref[...], _NT,
+                    q_ref[:, lanes], k_ref[...], NT,
                     preferred_element_type=jnp.float32) * scale + bias[...]
                 before = top[g]
                 now = jnp.maximum(before, scores.max(axis=-1, keepdims=True))
@@ -269,7 +263,7 @@ def _shared(q, k, lse, selection_t, scale, tile, interpret=False):
     rows; ``selection_t`` [B, keys, queries]."""
     B, T, KV, G, D = _split(q, k)
     marks = () if selection_t is None else (selection_t,)
-    vma, (q, k, lse, *marks) = _vary_alike(_lanes(q), _lanes(k), lse, *marks)
+    vma, (q, k, lse, *marks) = vary_alike(_lanes(q), _lanes(k), lse, *marks)
     n = T // tile
 
     def kernel(q_ref, k_ref, lse_ref, *refs):
@@ -285,7 +279,7 @@ def _shared(q, k, lse, selection_t, scale, tile, interpret=False):
                 lanes = pl.ds(pl.multiple_of(h * D, D), D)
                 group = pl.ds(pl.multiple_of((h // G) * D, D), D)
                 scores = jax.lax.dot_general(
-                    k_ref[:, group], q_ref[:, lanes], _NT,
+                    k_ref[:, group], q_ref[:, lanes], NT,
                     preferred_element_type=jnp.float32) * scale + bias[...]
                 out_ref[...] += jnp.exp(scores - lse_ref[pl.ds(h, 1), :])
 
@@ -321,7 +315,7 @@ def _backward_kv(q, k, v, d_out, lse, delta, selection_t, scale, tile,
     operands' type."""
     B, T, KV, G, D = _split(q, k)
     marks = () if selection_t is None else (selection_t,)
-    vma, (q, k, v, d_out, lse, delta, *marks) = _vary_alike(
+    vma, (q, k, v, d_out, lse, delta, *marks) = vary_alike(
         _lanes(q), _lanes(k), _lanes(v), d_out, lse, delta, *marks)
     n = T // tile
 
@@ -343,14 +337,14 @@ def _backward_kv(q, k, v, d_out, lse, delta, selection_t, scale, tile,
                 row = pl.ds(g, 1)
                 q_g, do_g = q_ref[:, lanes], do_ref[:, lanes]
                 scores = jax.lax.dot_general(
-                    k_ref[...], q_g, _NT,
+                    k_ref[...], q_g, NT,
                     preferred_element_type=jnp.float32) * scale + bias[...]
                 probs = jnp.exp(scores - lse_ref[row, :])  # [keys, queries]
                 dv_ref[...] += jnp.dot(
                     probs.astype(do_g.dtype), do_g,
                     preferred_element_type=jnp.float32)
                 d_probs = jax.lax.dot_general(
-                    v_ref[...], do_g, _NT, preferred_element_type=jnp.float32)
+                    v_ref[...], do_g, NT, preferred_element_type=jnp.float32)
                 d_scores = probs * (d_probs - delta_ref[row, :]) * scale
                 dk_ref[...] += jnp.dot(
                     d_scores.astype(q_g.dtype), q_g,
@@ -388,7 +382,7 @@ def _backward_q(q, k, v, d_out, lse, delta, selection, scale, tile,
     columns."""
     B, T, KV, G, D = _split(q, k)
     marks = () if selection is None else (selection,)
-    vma, (q, k, v, d_out, lse, delta, *marks) = _vary_alike(
+    vma, (q, k, v, d_out, lse, delta, *marks) = vary_alike(
         _lanes(q), _lanes(k), _lanes(v), d_out, lse, delta, *marks)
     n = T // tile
 
@@ -407,11 +401,11 @@ def _backward_q(q, k, v, d_out, lse, delta, selection, scale, tile,
             def head(g):
                 lanes = pl.ds(pl.multiple_of(g * D, D), D)
                 scores = jax.lax.dot_general(
-                    q_ref[:, lanes], k_ref[...], _NT,
+                    q_ref[:, lanes], k_ref[...], NT,
                     preferred_element_type=jnp.float32) * scale + bias[...]
                 probs = jnp.exp(scores - lse_ref[g])
                 d_probs = jax.lax.dot_general(
-                    do_ref[:, lanes], v_ref[...], _NT,
+                    do_ref[:, lanes], v_ref[...], NT,
                     preferred_element_type=jnp.float32)
                 d_scores = probs * (d_probs - delta_ref[g]) * scale
                 dq_ref[:, lanes] += jnp.dot(

@@ -97,8 +97,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# ba3clint: disable=A5 — how a pallas_call says over which mesh axes it varies under shard_map: one copy, for every module of kernels
-from distributed_ba3c_tpu.ops.grouped_matmul import LANE, _vary_alike
+from distributed_ba3c_tpu.ops.pallas_tpu import (
+    HIGHEST, LANE, NT, TN, dot_f32, running_sum, runs_mosaic, vary_alike)
 from distributed_ba3c_tpu.utils import profiling
 from distributed_ba3c_tpu.utils.profiling import device_scope
 
@@ -110,9 +110,6 @@ INTERPRET = False
 #: the kernels' names in a compiled program and in a capture
 FORWARD_KERNEL, BACKWARD_KERNEL = "ssd_chunks_forward", "ssd_chunks_backward"
 
-_HIGHEST = jax.lax.Precision.HIGHEST
-_NT = (((1,), (1,)), ((), ()))  # [m, k] x [n, k] -> [m, n]
-_TN = (((0,), (0,)), ((), ()))  # [k, m] x [k, n] -> [m, n]
 
 
 def ssd_step(H, x, dt, A, B, C, D):
@@ -143,7 +140,7 @@ def ssd_chunked_plain(x, dt, A, B, C, D, chunk: int = CHUNK,
     if pad:
         x, dt, B, C = _whole_chunks(pad, x, dt, B, C)
     n = (T + pad) // Q
-    dot = lambda spec, *ops: jnp.einsum(spec, *ops, precision=_HIGHEST)  # noqa: E731
+    dot = lambda spec, *ops: jnp.einsum(spec, *ops, precision=HIGHEST)  # noqa: E731
 
     # the chunks leading, then the heads (by group, where B and C meet them)
     # so that a head's [Q, Q] and [Q, P] matrices lie together
@@ -195,10 +192,6 @@ def _whole_chunks(pad: int, *arrays):
         jnp.pad(v, ((0, 0), (0, pad)) + ((0, 0),) * (v.ndim - 2)) for v in arrays)
 
 
-def _backend_runs_mosaic() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 def _heads_a_tile(P: int) -> int:
     """Heads whose ``P`` channels lie side by side in one tile of whole
     lanes, the kernels' unit of work: two of 64; 0 where no whole number of
@@ -215,35 +208,11 @@ def kernels_take(x, B, chunk: int = CHUNK) -> bool:
     lanes, ``P`` whole sublanes and a group's heads whole tiles."""
     _, T, h, P = x.shape
     g, N = B.shape[2:]
-    if not (INTERPRET or _backend_runs_mosaic()):
+    if not (INTERPRET or runs_mosaic()):
         return False
     side = _heads_a_tile(P)
     return (chunk == LANE and T >= chunk and N % LANE == 0 and P % 8 == 0
             and h % g == 0 and side > 0 and (h // g) % side == 0)
-
-
-def _dot(a, b, dims=(((1,), (0,)), ((), ()))):
-    """A float32 product at the highest precision. Mosaic rounds the operands
-    of a product that does not ask (the interpreter does not: tier-1 cannot
-    tell; ``chip_smoke.py --phase ssd`` does)."""
-    return jax.lax.dot_general(
-        a, b, dims, precision=_HIGHEST, preferred_element_type=jnp.float32)
-
-
-def _running(m, up: bool = False):
-    """The running sum of ``m`` [Q, Q] down its rows (``up``: from the last
-    row up), by doubling steps: every entry the sum of its own terms and of
-    nothing that has to cancel."""
-    Q = m.shape[0]
-    row = jax.lax.broadcasted_iota(jnp.int32, m.shape, 0)
-    step = 1
-    while step < Q:
-        if up:  # row t takes row t + step
-            m = m + jnp.where(row < Q - step, pltpu.roll(m, Q - step, 0), 0.0)
-        else:  # row t takes row t - step
-            m = m + jnp.where(row >= step, pltpu.roll(m, step, 0), 0.0)
-        step *= 2
-    return m
 
 
 class _Chunk:
@@ -290,7 +259,7 @@ class _Chunk:
             head = first + i
             dt = self.column(steps, head)
             a = dt * A_ref[head]
-            seg = _running(jnp.where(self.row > self.col, a, 0.0))
+            seg = running_sum(jnp.where(self.row > self.col, a, 0.0))
             ratio = jnp.exp(jnp.where(self.row >= self.col, seg, -jnp.inf))
             out.append(_Head(
                 head=head, A=A_ref[head], dt=dt, ratio=ratio,
@@ -360,7 +329,7 @@ def _forward(x, dt, A, B, C, D, state_dtype=jnp.float32, interpret=False):
     on [b, T / Q, h, P, N]: the last one is the sequence's)."""
     b, T, h, P, g, N, r, n, side = _sizes(x, B)
     Q, W = CHUNK, P * side
-    vma, (A, D, x, dt, B, C) = _vary_alike(
+    vma, (A, D, x, dt, B, C) = vary_alike(
         A, D, x.reshape(b, T, h * P), dt, B.reshape(b, T, g * N),
         C.reshape(b, T, g * N))
 
@@ -374,7 +343,7 @@ def _forward(x, dt, A, B, C, D, state_dtype=jnp.float32, interpret=False):
             H[pl.ds(group, r * P), :] = jnp.zeros((r * P, N), jnp.float32)
 
         Bm, Cm = B_ref[...], C_ref[...]
-        cb = _dot(Cm, Bm, _NT)                           # [t, s]
+        cb = dot_f32(Cm, Bm, NT)                           # [t, s]
         steps = dt_ref[...]                              # [Q, h]
 
         def tile(k, _):
@@ -385,11 +354,11 @@ def _forward(x, dt, A, B, C, D, state_dtype=jnp.float32, interpret=False):
             from_open, to_end, last, skip, written = of.tile(heads)
             # what the chunk opened on, the chunk's own part, the skip
             y_ref[:, lanes] = (
-                from_open * _dot(Cm, H_t, _NT)
-                + sum(_dot(cb * hd.ratio, hd.written) for hd in heads)
+                from_open * dot_f32(Cm, H_t, NT)
+                + sum(dot_f32(cb * hd.ratio, hd.written) for hd in heads)
                 + skip * x_t)
             # the chunk's state and the boundary
-            H_t = last * H_t + _dot(to_end * written, Bm, _TN)         # [W, N]
+            H_t = last * H_t + dot_f32(to_end * written, Bm, TN)         # [W, N]
             H_t = H_t.astype(state_dtype).astype(jnp.float32)
             H[held, :] = H_t
             closed_ref[lanes, :] = H_t
@@ -434,7 +403,7 @@ def _backward(x, dt, A, B, C, D, closed, d_y, d_last, state_dtype=jnp.float32,
     ``dD`` are their sums over envs and positions)."""
     b, T, h, P, g, N, r, n, side = _sizes(x, B)
     Q, W = CHUNK, P * side
-    vma, (A, D, x, dt, B, C, closed, d_y, d_last) = _vary_alike(
+    vma, (A, D, x, dt, B, C, closed, d_y, d_last) = vary_alike(
         A, D, x.reshape(b, T, h * P), dt, B.reshape(b, T, g * N),
         C.reshape(b, T, g * N), closed.reshape(b, n, h * P, N),
         d_y.reshape(b, T, h * P), d_last.reshape(b, h * P, N))
@@ -456,7 +425,7 @@ def _backward(x, dt, A, B, C, D, closed, d_y, d_last, state_dtype=jnp.float32,
                 ref[...] = jnp.zeros((Q, h), jnp.float32)
 
         Bm, Cm = B_ref[...], C_ref[...]
-        cb = _dot(Cm, Bm, _NT)
+        cb = dot_f32(Cm, Bm, NT)
         steps = dt_ref[...]
         dcb[...] = jnp.zeros((Q, Q), jnp.float32)
         dB_ref[...] = jnp.zeros((Q, N), jnp.float32)
@@ -471,8 +440,8 @@ def _backward(x, dt, A, B, C, D, closed, d_y, d_last, state_dtype=jnp.float32,
             H_t = jnp.where(c == n - 1, 0.0, open_ref[lanes, :])
             # through the boundary's rounding (float32: nothing)
             dH_t = dH[held, :].astype(state_dtype).astype(jnp.float32)
-            from_H = _dot(Cm, H_t, _NT)                    # [t, W]: H C_t
-            to_S = _dot(Bm, dH_t, _NT)                     # [s, W]: dS B_s
+            from_H = dot_f32(Cm, H_t, NT)                    # [t, W]: H C_t
+            to_S = dot_f32(Bm, dH_t, NT)                     # [s, W]: dS B_s
             heads = of.heads(steps, grp * r + k * side, A_ref, D_ref, x_t)
             from_open, to_end, last, skip, written = of.tile(heads)
             d_written = to_end * to_S
@@ -480,9 +449,9 @@ def _backward(x, dt, A, B, C, D, closed, d_y, d_last, state_dtype=jnp.float32,
             for i, hd in enumerate(heads):
                 dy_mine = jnp.where(of.lane_head == i, dy_t, 0.0)
                 # y = (cb ratio) written + ...
-                d_mix = _dot(dy_t, hd.written, _NT)        # [t, s]
+                d_mix = dot_f32(dy_t, hd.written, NT)        # [t, s]
                 mix = cb * hd.ratio
-                d_written = d_written + _dot(mix, dy_mine, _TN)
+                d_written = d_written + dot_f32(mix, dy_mine, TN)
                 dcb[...] += d_mix * hd.ratio
                 # ... + exp(L_t) H C_t; H' = exp(L_Q) H + S
                 d_open = jnp.sum(dy_mine * from_H, axis=1, keepdims=True)
@@ -498,13 +467,13 @@ def _backward(x, dt, A, B, C, D, closed, d_y, d_last, state_dtype=jnp.float32,
                 d_seg = (d_mix * mix + jnp.where(of.col == 0, d_L, 0.0)
                          + jnp.where(of.row == Q - 1, of.as_row(d_end), 0.0))
                 d_a.append(
-                    jnp.sum(jnp.where(of.row > of.col, _running(d_seg, up=True), 0.0),
+                    jnp.sum(jnp.where(of.row > of.col, running_sum(d_seg, up=True), 0.0),
                             axis=1, keepdims=True)
                     + jnp.where(of.at == 0, jnp.sum(d_L, keepdims=True), 0.0))
             d_from_H = from_open * dy_t
-            dC_ref[...] += _dot(d_from_H, H_t)
-            dB_ref[...] += _dot(to_end * written, dH_t)
-            dH[held, :] = last * dH_t + _dot(d_from_H, Cm, _TN)
+            dC_ref[...] += dot_f32(d_from_H, H_t)
+            dB_ref[...] += dot_f32(to_end * written, dH_t)
+            dH[held, :] = last * dH_t + dot_f32(d_from_H, Cm, TN)
             dx_ref[:, lanes] = (
                 of.of_heads([hd.dt for hd in heads]) * d_written + skip * dy_t)
             for i, hd in enumerate(heads):
@@ -518,8 +487,8 @@ def _backward(x, dt, A, B, C, D, closed, d_y, d_last, state_dtype=jnp.float32,
                     ref[...] = jnp.where(of.head_lane == hd.head, value, ref[...])
 
         jax.lax.fori_loop(0, r // side, tile, None)
-        dC_ref[...] += _dot(dcb[...], Bm)
-        dB_ref[...] += _dot(dcb[...], Cm, _TN)
+        dC_ref[...] += dot_f32(dcb[...], Bm)
+        dB_ref[...] += dot_f32(dcb[...], Cm, TN)
 
     back = lambda c: n - 1 - c  # noqa: E731
     chunk_of_group = lambda w: pl.BlockSpec(  # noqa: E731

@@ -57,8 +57,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# ba3clint: disable=A5 — how a pallas_call says over which mesh axes it varies under shard_map: one copy, for every module of kernels
-from distributed_ba3c_tpu.ops.grouped_matmul import LANE, _vary_alike
+from distributed_ba3c_tpu.ops.pallas_tpu import LANE, runs_mosaic, vary_alike
 from distributed_ba3c_tpu.utils import profiling
 from distributed_ba3c_tpu.utils.profiling import device_scope
 
@@ -101,15 +100,11 @@ def runs_searches(n: int, most_live: int, k: int) -> bool:
     return n > k and most_live > k
 
 
-def _backend_runs_mosaic() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 def block_rows(rows: int, n: int):
     """Rows of the kernel's block for ``rows`` rows of ``n`` entries: the
     most whole tiles that divide ``rows`` and fit :data:`BLOCK_BYTES`, or
     None where the ``jax.numpy`` form runs."""
-    if not (INTERPRET or _backend_runs_mosaic()) or n % LANE:
+    if not (INTERPRET or runs_mosaic()) or n % LANE:
         return None
     fit = [r for r in range(ROW_TILE, rows + 1, ROW_TILE)
            if rows % r == 0 and r * n * 4 <= BLOCK_BYTES]
@@ -152,7 +147,7 @@ def _searches(scores, live, k: int):
 def _kernel_searches(scores, live, k: int, block: int, interpret=False):
     """Regime 3 in a Pallas kernel: scores [rows, n] float32, live [rows, n]
     int32 (nonzero: live) -> [rows, n] int32 (nonzero: selected)."""
-    vma, (scores, live) = _vary_alike(scores, live)
+    vma, (scores, live) = vary_alike(scores, live)
     rows, n = scores.shape
     bits = max(1, (n - 1).bit_length())
 
@@ -220,7 +215,7 @@ def select_mask(scores, live, k: int):
     """The top ``k`` of each row's live entries, ties to the lower position."""
     # the mask varies over the mesh axes either input varies over, whichever
     # regime makes it
-    _, (scores, live) = _vary_alike(scores, live)
+    _, (scores, live) = vary_alike(scores, live)
     if scores.shape[-1] <= k:
         return live
     # the scores are made before the choice and whole: left free, the TPU

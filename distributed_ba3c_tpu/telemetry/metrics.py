@@ -31,7 +31,7 @@ from __future__ import annotations
 
 import os
 import threading
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Dict, List, Optional, Tuple
 
 #: number of log2 buckets a histogram keeps. With unit=1e-6 (microseconds)
 #: bucket 39 covers ~2^39 us ≈ 6.4 days — nothing a run produces overflows.
@@ -95,6 +95,42 @@ class Counter:
 
     def collect(self) -> dict:
         return {"type": "counter", "value": self.value()}
+
+    def reset(self) -> None:
+        self._cells = {}
+
+
+class CounterPair:
+    """Two monotonic counters that a writer advances TOGETHER and a reader
+    has to see together: their ratio is a budget (data/staging.py: a
+    block's host copies and the block, exactly 1.0 staged), and two
+    separate counters show a snapshot that falls between their increments,
+    or between its own two reads, one total a block ahead of the other.
+
+    A writer thread's cell is ONE tuple ``(first, second)``, replaced whole
+    by ``inc``: a dict store under the GIL, one writer per key, so the ONE
+    rule stands (no lock, no syscall). ``values()`` reads every cell once,
+    so each thread's pair is a state that was true a moment ago and the
+    sums are of whole increments. A snapshot exports the pair under its two
+    series' names (``Registry.collect`` / ``scalars``), from one read.
+    """
+
+    __slots__ = ("names", "_cells")
+
+    def __init__(self, names: Tuple[str, str]):
+        self.names = names
+        self._cells: Dict[int, Tuple[float, float]] = {}
+
+    def inc(self, first: float = 0, second: float = 0) -> None:
+        if not _enabled:
+            return
+        tid = threading.get_ident()
+        a, b = self._cells.get(tid, (0, 0))
+        self._cells[tid] = (a + first, b + second)
+
+    def values(self) -> Tuple[float, float]:
+        cells = list(self._cells.values())
+        return sum(c[0] for c in cells), sum(c[1] for c in cells)
 
     def reset(self) -> None:
         self._cells = {}
@@ -224,6 +260,11 @@ class Registry:
     def histogram(self, name: str, unit: float = 1e-6) -> Histogram:
         return self._get(name, lambda n: Histogram(n, unit=unit))
 
+    def counter_pair(self, first: str, second: str) -> CounterPair:
+        """The two series ``first`` and ``second`` as one paired counter."""
+        return self._get(
+            f"{first}+{second}", lambda _: CounterPair((first, second)))
+
     def _get(self, name: str, ctor):
         m = self._metrics.get(name)
         if m is None:
@@ -238,7 +279,15 @@ class Registry:
 
     def collect(self) -> Dict[str, dict]:
         """``{name: {"type": ..., "value"/"buckets": ...}}`` snapshot."""
-        return {n: self._metrics[n].collect() for n in self.names()}
+        out: Dict[str, dict] = {}
+        for n in self.names():
+            m = self._metrics[n]
+            if isinstance(m, CounterPair):
+                out.update((name, {"type": "counter", "value": v})
+                           for name, v in zip(m.names, m.values()))
+            else:
+                out[n] = m.collect()
+        return out
 
     def scalars(self) -> Dict[str, float]:
         """Counters + gauges as plain floats (histograms as _count/_sum) —
@@ -249,6 +298,8 @@ class Registry:
             if isinstance(m, Histogram):
                 out[f"{n}_count"] = float(m.count)
                 out[f"{n}_sum"] = m.sum
+            elif isinstance(m, CounterPair):
+                out.update(zip(m.names, map(float, m.values())))
             else:
                 out[n] = float(m.value())
         return out

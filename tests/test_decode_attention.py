@@ -284,7 +284,7 @@ def test_without_a_selection_the_call_has_the_four_operands_it_had(
     rows]`` int32, and another kernel (its body guards a block with no
     selected row) whose grid ends at the furthest env's last live block: a
     bound of its own, the call's first operand."""
-    monkeypatch.setattr(da, "_backend_runs_mosaic", lambda: True)
+    monkeypatch.setattr(da, "runs_mosaic", lambda: True)
     q = jax.ShapeDtypeStruct((32, 40, 128), jnp.bfloat16)
     k = jax.ShapeDtypeStruct((32, rows, 1280), jnp.bfloat16)
     n = jax.ShapeDtypeStruct((32,), jnp.int32)
@@ -358,7 +358,7 @@ def no_compile_cache():
     ids=["shared-kv", "ring", "sparse", "ten-heads-ungrouped"])
 def test_the_kernel_compiles_for_a_v5e_at_the_cells_shapes(
         one_chip, no_compile_cache, monkeypatch, envs, heads, rows, width, selected):
-    monkeypatch.setattr(da, "_backend_runs_mosaic", lambda: True)
+    monkeypatch.setattr(da, "runs_mosaic", lambda: True)
 
     def shape(*dims, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
@@ -418,13 +418,13 @@ def compiled_decode(one_chip, no_compile_cache):
         with device_scope(profiling.ROLLOUT):
             return jax.lax.scan(one, carry, (tokens, fresh))
 
-    before = da._backend_runs_mosaic
-    da._backend_runs_mosaic = lambda: True
+    before = da.runs_mosaic
+    da.runs_mosaic = lambda: True
     try:
         return jax.jit(episode, donate_argnums=1).lower(
             params, carry, tokens, fresh).compile().as_text()
     finally:
-        da._backend_runs_mosaic = before
+        da.runs_mosaic = before
 
 
 @pytest.mark.timeout(600)
@@ -501,13 +501,13 @@ def compiled_sparse_decode(one_chip, no_compile_cache):
         with device_scope(profiling.ROLLOUT):
             return jax.lax.scan(one, carry, (tokens, fresh))
 
-    before = da._backend_runs_mosaic
-    da._backend_runs_mosaic = lambda: True
+    before = da.runs_mosaic
+    da.runs_mosaic = lambda: True
     try:
         return jax.jit(episode, donate_argnums=1).lower(
             params, carry, tokens, fresh).compile().as_text()
     finally:
-        da._backend_runs_mosaic = before
+        da.runs_mosaic = before
 
 
 def _scan_body(text):

@@ -364,7 +364,7 @@ def test_which_path_runs_is_read_off_the_shapes(monkeypatch, shape, chunk, takes
     q = jax.ShapeDtypeStruct((b, T, h, K), jnp.float32)
     v = jax.ShapeDtypeStruct((b, T, h, V), jnp.float32)
     assert not delta_rule.kernels_take(q, v, chunk)  # a backend without Mosaic
-    monkeypatch.setattr(delta_rule, "_backend_runs_mosaic", lambda: True)
+    monkeypatch.setattr(delta_rule, "runs_mosaic", lambda: True)
     assert delta_rule.kernels_take(q, v, chunk) == takes
     gate = jax.ShapeDtypeStruct((b, T, h), jnp.float32)
     jaxpr = str(jax.make_jaxpr(fresh(delta_rule.delta_chunked, chunk=chunk))(
@@ -397,7 +397,7 @@ def _compiled_for_a_v5e(one_chip, monkeypatch, dims, state_dtype=jnp.float32):
     afresh for the described chip at ``dims`` (b, T, h, K, V)."""
     from jax.experimental.compilation_cache import compilation_cache
 
-    monkeypatch.setattr(delta_rule, "_backend_runs_mosaic", lambda: True)
+    monkeypatch.setattr(delta_rule, "runs_mosaic", lambda: True)
     cache_was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()

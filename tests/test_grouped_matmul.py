@@ -508,7 +508,7 @@ def no_compile_cache():
     ids=["d-f", "f-d", "d-f-off-the-lane", "f-d-off-the-lane"])
 def test_the_three_forms_compile_for_a_v5e_at_the_cells_shapes(
         one_chip, no_compile_cache, monkeypatch, m, k, n):
-    monkeypatch.setattr(gm, "_backend_runs_mosaic", lambda: True)
+    monkeypatch.setattr(gm, "runs_mosaic", lambda: True)
 
     def shape(*dims, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)

@@ -724,7 +724,6 @@ def test_the_registry_builds_by_name():
     model = policy.build_model("lfm2-moe", cfg.replace(num_actions=16384))
     assert policy.carries_state(model) and model.hidden_size == 2048
     assert model.experts_held == 8 and model.num_experts == 32
-    assert dataclasses.replace(model, remat=False).remat is False
     with pytest.raises(ValueError):
         policy.build_model("no-such-model", cfg)
     with pytest.raises(ValueError):

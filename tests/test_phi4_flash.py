@@ -402,7 +402,7 @@ def layer_by_layer():
     with jax.default_matmul_precision("highest"):
         for i, (layer_id, kind) in enumerate(SPEC["layers"]):
             p = params[f"layer_{layer_id}"]
-            x, memory, shared = model._layer_unroll(i, p, x, memory, shared)
+            x, (memory, shared) = model._layer_unroll(i, p, x, memory, shared)
             rx, rmemory, rshared = reference._layer(
                 layer_id, kind, SPEC, None, p, rx, rmemory, rshared)
             rows.append(dict(kind=kind, x=x, memory=memory, shared=shared,

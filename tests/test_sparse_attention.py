@@ -238,7 +238,7 @@ def test_off_the_tpu_the_op_is_the_masked_dense_form():
     ((B, 640, H, D), 128), ((B, T, H, 64), None), ((B, 100, H, D), None),
     ((B, 32, H, 16), None)], ids=str)
 def test_the_tile_is_read_off_the_shapes(monkeypatch, shape, tile):
-    monkeypatch.setattr(sa, "_backend_runs_mosaic", lambda: True)
+    monkeypatch.setattr(sa, "runs_mosaic", lambda: True)
     q = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
     k = jax.ShapeDtypeStruct((*shape[:2], shape[2] // 2, shape[3]), jnp.bfloat16)
     assert sa.tile_of(q, k) == tile
@@ -287,7 +287,7 @@ def on_a_tpu(monkeypatch):
     """Every module of kernels takes its TPU path (the code asks the backend,
     which is the CPU here)."""
     for module in (sa, decode_attention, grouped_matmul):
-        monkeypatch.setattr(module, "_backend_runs_mosaic", lambda: True)
+        monkeypatch.setattr(module, "runs_mosaic", lambda: True)
 
 
 _KERNELS = ("sparse_attend_forward", "sparse_attend_shared",

@@ -286,7 +286,7 @@ def test_which_path_runs_is_read_off_the_shapes(monkeypatch, shape, chunk, takes
     x = jax.ShapeDtypeStruct((b, T, h, P), jnp.float32)
     Bm = jax.ShapeDtypeStruct((b, T, g, N), jnp.float32)
     assert not ssd.kernels_take(x, Bm, chunk)  # a backend without Mosaic
-    monkeypatch.setattr(ssd, "_backend_runs_mosaic", lambda: True)
+    monkeypatch.setattr(ssd, "runs_mosaic", lambda: True)
     assert ssd.kernels_take(x, Bm, chunk) == takes
     dt = jax.ShapeDtypeStruct((b, T, h), jnp.float32)
     head = jax.ShapeDtypeStruct((h,), jnp.float32)
@@ -322,7 +322,7 @@ def test_the_kernels_compile_for_a_v5e_at_the_cells_shapes(
     channels in 8 groups; both kernels, each under the recurrence's scope."""
     from jax.experimental.compilation_cache import compilation_cache
 
-    monkeypatch.setattr(ssd, "_backend_runs_mosaic", lambda: True)
+    monkeypatch.setattr(ssd, "runs_mosaic", lambda: True)
     cache_was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()

@@ -564,3 +564,31 @@ def test_predictor_block_staging_parity_and_reuse(pong_parts):
     assert scal["stage_copies_total"] >= 3
     # the pow-2-8 bucket buffer allocated ONCE and recycled
     assert scal["stage_alloc_total"] == 1
+
+
+def test_snapshot_beside_the_writer_never_splits_a_block():
+    """A reader snapshotting in a tight loop beside the ingest thread never
+    sees a block's copy without the block (ROADMAP D18: two counters showed
+    an epoch's record one copy ahead; the pair is one increment, one read)."""
+    ring = staging.HostStagingRing(slots=2)
+    reg = telemetry.registry("learner")
+    done = threading.Event()
+
+    def ingest():
+        for _ in range(100_000):
+            ring.count_staged_copy()
+        done.set()
+
+    writer = threading.Thread(target=ingest)
+    writer.start()
+    split, reads = [], 0
+    while not done.is_set() and not split:
+        snap = reg.scalars()
+        pair = (snap.get("ingest_copies_total", 0.0),
+                snap.get("ingest_blocks_total", 0.0))
+        if pair[0] != pair[1]:
+            split.append(pair)
+        reads += 1
+    writer.join()
+    assert not split, f"copies, blocks = {split[0]} after {reads} snapshots"
+    assert reads > 0 and reg.scalars()["ingest_blocks_total"] == 100_000

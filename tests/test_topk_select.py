@@ -285,7 +285,7 @@ def test_off_the_tpu_and_at_odd_widths_the_searches_are_jax_numpy():
 
 
 def test_on_a_tpu_the_searches_are_one_kernel_under_the_radix_scope(monkeypatch):
-    monkeypatch.setattr(topk_select, "_backend_runs_mosaic", lambda: True)
+    monkeypatch.setattr(topk_select, "runs_mosaic", lambda: True)
     topk_select._kernel_searches.clear_cache()
     scores = jax.ShapeDtypeStruct(CELL, jnp.float32)
     live = jax.ShapeDtypeStruct(CELL, jnp.bool_)
@@ -348,7 +348,7 @@ def test_the_kernel_compiles_for_a_v5e_at_the_cells_shapes(
         one_chip, monkeypatch, shape):
     from jax.experimental.compilation_cache import compilation_cache
 
-    monkeypatch.setattr(topk_select, "_backend_runs_mosaic", lambda: True)
+    monkeypatch.setattr(topk_select, "runs_mosaic", lambda: True)
     topk_select._kernel_searches.clear_cache()
     cache_was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)

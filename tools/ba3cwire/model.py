@@ -705,6 +705,14 @@ def collect_series(project: Project) -> List[SeriesDecl]:
                     isinstance(node.args[0].value, str):
                 out.append(SeriesDecl(node.args[0].value, node.func.attr,
                                       path, node))
+            elif isinstance(node, ast.Call) and \
+                    isinstance(node.func, ast.Attribute) and \
+                    node.func.attr == "counter_pair":
+                # two counter series behind one paired counter
+                out.extend(SeriesDecl(arg.value, "counter", path, node)
+                           for arg in node.args
+                           if isinstance(arg, ast.Constant)
+                           and isinstance(arg.value, str))
     return out
 
 
