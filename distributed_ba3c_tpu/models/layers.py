@@ -18,6 +18,7 @@ import math
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from distributed_ba3c_tpu.utils import profiling
 from distributed_ba3c_tpu.utils.profiling import device_scope
@@ -62,11 +63,44 @@ def layer_norm(x, gain, bias, eps):
     return centred * jax.lax.rsqrt(var + eps) * gain + bias
 
 
-def rope(x, positions, theta):
+def yarn_inv_freq(dim: int, theta: float, factor: float, original: int,
+                  beta_fast: float, beta_slow: float):
+    """YaRN's ``dim / 2`` rotary frequencies (arXiv:2309.00071, as the
+    DeepSeek-V3 family computes them): ``theta^(-2i/dim)`` where a frequency
+    turns more than ``beta_fast`` times within the ``original`` positions,
+    that over ``factor`` where it turns fewer than ``beta_slow`` times, and
+    a linear ramp between the two dimensions where those counts fall. A
+    constant of the shapes, float32."""
+    extrapolated = theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+
+    def dimension_of(turns):  # the dimension that turns so often in `original`
+        return dim * math.log(original / (turns * 2 * math.pi)) / (
+            2 * math.log(theta))
+
+    low = max(math.floor(dimension_of(beta_fast)), 0)
+    high = min(math.ceil(dimension_of(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001  # the family's own guard against a ramp of no width
+    ramp = np.clip((np.arange(dim // 2) - low) / (high - low), 0.0, 1.0)
+    return jnp.asarray(
+        extrapolated / factor * ramp + extrapolated * (1.0 - ramp), jnp.float32)
+
+
+def yarn_attention_scale(factor: float, mscale_all_dim: float) -> float:
+    """What YaRN multiplies the softmax's scale by (the DeepSeek-V3 family's
+    rule under ``rope_scaling``): ``m^2``, ``m = 0.1 mscale_all_dim
+    ln(factor) + 1``."""
+    m = 0.1 * mscale_all_dim * math.log(factor) + 1.0 if factor > 1 else 1.0
+    return m * m
+
+
+def rope(x, positions, theta, inv_freq=None):
     """Rotate-half RoPE over the whole head. ``x`` [..., T, H, D] float32,
-    ``positions`` broadcastable to [..., T]."""
+    ``positions`` broadcastable to [..., T]. ``inv_freq`` [D / 2]: the
+    frequencies where they are not ``theta``'s own (:func:`yarn_inv_freq`)."""
     half = x.shape[-1] // 2
-    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    if inv_freq is None:
+        inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
     angle = positions[..., None].astype(jnp.float32) * inv_freq
     cos = jnp.concatenate([jnp.cos(angle)] * 2, -1)[..., None, :]
     sin = jnp.concatenate([jnp.sin(angle)] * 2, -1)[..., None, :]

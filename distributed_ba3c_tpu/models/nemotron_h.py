@@ -108,19 +108,6 @@ CUTS = {
 }
 
 
-def _with_its_input(p, x):
-    """A block's weights ``p`` and its input ``x``, tied together: what the
-    block computes from ``p`` then waits for ``x``. The trainer's learner
-    runs ``unroll`` once a chunk of envs in a loop, where a weight's cast to
-    the compute type is the same in every trip, and the compiler lifts
-    every one of them out of the loop and holds them through it, in two
-    layouts (forward and transposed): 2.6 GB at this policy's 667 M
-    parameters, with which a chunk of 2 envs compiled to 18.0 GB of a
-    v5e's 16.9 and without which to 14.7 (PERF.md section 4, PR 44). Tied to
-    the input a cast is made where it is used and dies there."""
-    return jax.lax.optimization_barrier((p, x))
-
-
 cut_fields = functools.partial(sequence.cut_fields, CUTS)
 
 
@@ -444,7 +431,7 @@ class NemotronH(sequence.SequencePolicy):
         token's chosen experts (``routes`` [expert blocks, B, T, k])."""
         routed = moe.RoutedLayers(*tokens.shape)
         # a block's weights, and the head's, are tied to their input (see
-        # ``_with_its_input``)
+        # ``sequence.with_its_input``: without it this step does not fit the chip)
         return self._unroll(
             params, tokens, self._layer_unroll, routed.take,
-            lambda: routed.aux(with_routes), tie=_with_its_input)
+            lambda: routed.aux(with_routes), tie=sequence.with_its_input)

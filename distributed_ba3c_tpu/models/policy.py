@@ -90,6 +90,7 @@ MODELS: Dict[str, Tuple[str, str]] = {
     "keye-vl2": ("keye_vl2", "KeyeVL2"),
     "olmo-hybrid": ("olmo_hybrid", "OlmoHybrid"),
     "nemotron-h": ("nemotron_h", "NemotronH"),
+    "xing4": ("xing4", "Xing4"),
 }
 
 

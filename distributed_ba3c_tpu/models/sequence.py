@@ -1,6 +1,6 @@
 """What a token-sequence policy is apart from its mixers: the one home of
-what models/{lfm2_moe,phi4_flash,keye_vl2,olmo_hybrid,nemotron_h}.py share
-above models/layers.py's functions of arrays.
+what models/{lfm2_moe,phi4_flash,keye_vl2,olmo_hybrid,nemotron_h,xing4}.py
+share above models/layers.py's functions of arrays.
 
 A policy is a frozen dataclass of its published fields that inherits
 :class:`SequencePolicy` and writes its own layers' leaves, ``init_carry``,
@@ -84,6 +84,20 @@ def write_row(rows, cache, at, new):
         indices_are_sorted=True, unique_indices=True)
 
 
+def with_its_input(p, x):
+    """A layer's weights ``p`` and its input ``x``, tied together: what the
+    layer computes from ``p`` then waits for ``x`` (an unroll's ``tie``).
+    The trainer's learner runs ``unroll`` once a chunk of envs in a loop,
+    where a weight's cast to the compute type is the same in every trip, and
+    the compiler lifts every one of them out of the loop and holds them
+    through it, in two layouts (forward and transposed): 2.6 GB at
+    ``nemotron-h``'s 667 M parameters, with which a chunk of 2 envs compiled
+    to 18.0 GB of a v5e's 16.9 and without which to 14.7 (PERF.md section 4,
+    PR 44). Tied to the input a cast is made where it is used and dies
+    there."""
+    return jax.lax.optimization_barrier((p, x))
+
+
 class SequencePolicy:
     """The base of the token-sequence policies' dataclasses. A policy sets
     the three class attributes below where it differs and names its norm's
@@ -158,6 +172,17 @@ class SequencePolicy:
         return layers.embed_rows(
             params["embed"]["table"], tokens, self.compute_dtype)
 
+    def streams_in(self, x):
+        """The residual path a layer is handed, from the embedding's rows
+        ``x`` [..., d]. One stream, the rows themselves, unless the policy
+        keeps several (models/xing4.py: a tuple of ``n`` arrays [..., d])."""
+        return x
+
+    def streams_out(self, x):
+        """What the head reads [..., d], from the residual path the last
+        layer left: the inverse of :meth:`streams_in` in shape."""
+        return x
+
     def _head(self, params, x):
         """x [N, d] float32 -> PolicyValue over the held vocabulary: the
         final norm (a LayerNorm where the parameters hold its bias, else an
@@ -210,17 +235,19 @@ class SequencePolicy:
         embedding, the held layers one at a time, the head.
 
         ``layer(i, p, x) -> (x, second)`` is held layer ``i`` over x [B, T,
-        d] float32. It is recomputed in the backward, always: no published
-        cut fits the chip with every layer's activations kept. ``second``
-        is the policy's: ``took(second)`` is handed it as each layer
-        returns and ``aux()`` makes the unroll's ``aux`` of what was taken,
-        after the head. A policy whose layers hand each other more than the
+        d] float32 (of a policy with several residual streams: what its
+        :meth:`streams_in` makes of the embedding, until its
+        :meth:`streams_out` hands the head one row a token). It is recomputed
+        in the backward, always: no published cut fits the chip with every
+        layer's activations kept. ``second`` is the policy's:
+        ``took(second)`` is handed it as each layer returns and ``aux()``
+        makes the unroll's ``aux`` of what was taken, after the head. A policy whose layers hand each other more than the
         residual stream names those channels' start in ``side``; its layer
         is then ``layer(i, p, x, *side) -> (x, side)``. ``tie(p, x) -> (p,
         x)`` ties a layer's, and the head's, weights to their input
-        (nemotron_h.py:_with_its_input, ROADMAP D23)."""
+        (:func:`with_its_input`, ROADMAP D23)."""
         B, T = tokens.shape
-        x = self._embed(params, tokens)
+        x = self.streams_in(self._embed(params, tokens))
         threaded, side = side is not None, side or ()
         tie = tie or (lambda p, x: (p, x))
         for i in range(len(self.layer_ids)):
@@ -232,7 +259,8 @@ class SequencePolicy:
             elif took is not None:
                 took(second)
         top, x = tie(
-            {k: params[k] for k in ("final", self.head_table, "value")}, x)
+            {k: params[k] for k in ("final", self.head_table, "value")},
+            self.streams_out(x))
         out = self._head(top, x.reshape(B * T, -1))
         aux = aux() if aux is not None else {}
         return PolicyValue(

@@ -151,10 +151,36 @@ OP_MAMBA2_SSD = "op_mamba2/ssd"
 SSD_CHUNKS = "ssd_chunks"
 OP_MAMBA2_SSD_KERNEL = f"{OP_MAMBA2_SSD}/{SSD_CHUNKS}"
 OP_MAMBA2_OUT = "op_mamba2/out"
+#: latent attention (models/xing4.py): ``q`` (the query's down-projection,
+#: its norm, its up-projection, RoPE), ``kv_latent`` (the keys' and values'
+#: shared down-projection, the latent's norm, the shared key's RoPE and, in
+#: the decode, the cache row's write), ``expand`` (the unroll alone: keys and
+#: values of every head from the latent, ``W_kvb``), ``absorb`` (the decode
+#: alone: ``W_kvb`` taken into the query and out of the attended latent, so
+#: that no key or value is ever formed), ``attend`` (scores, softmax, the
+#: weighted sum; in the decode over the cache's latent rows, with
+#: ``decode_attend`` inside it round the kernel), ``out`` (``W_o``)
+OP_MLA = "op_mla"
+OP_MLA_Q = "op_mla/q"
+OP_MLA_KV_LATENT = "op_mla/kv_latent"
+OP_MLA_EXPAND = "op_mla/expand"
+OP_MLA_ABSORB = "op_mla/absorb"
+OP_MLA_ATTEND = "op_mla/attend"
+OP_MLA_ATTEND_DECODE = f"{OP_MLA_ATTEND}/{DECODE_ATTEND}"
+OP_MLA_OUT = "op_mla/out"
+#: a residual path of several streams (ops/hyper_connection.py), round
+#: every sub-block of models/xing4.py: ``mappings`` (the streams' norm, the
+#: projection, the sigmoids, the Sinkhorn iterations), ``mix`` (the
+#: sub-block's input read from the streams; its output written into them and
+#: the streams mixed)
+HYPER_CONN = "hyper_conn"
+HYPER_CONN_MAPPINGS = "hyper_conn/mappings"
+HYPER_CONN_MIX = "hyper_conn/mix"
 #: the layers each sequence policy opens (models/lfm2_moe.py with ops/moe.py;
 #: models/phi4_flash.py with ops/ssm.py; models/keye_vl2.py with ops/moe.py;
 #: models/olmo_hybrid.py with ops/delta_rule.py; models/nemotron_h.py with
-#: ops/ssd.py and ops/moe.py): a step holds its own policy's
+#: ops/ssd.py and ops/moe.py; models/xing4.py with ops/hyper_connection.py
+#: and ops/moe.py): a step holds its own policy's
 LFM2_LAYERS = (
     EMBED, OP_CONV, OP_ATTN, FFN_DENSE, MOE, MOE_ROUTER, MOE_DISPATCH,
     MOE_EXPERTS, MOE_EXPERTS_GMM, MOE_COMBINE, HEAD,
@@ -182,10 +208,16 @@ NEMOTRON_H_LAYERS = (
     OP_MAMBA2_OUT, OP_ATTN_FULL, MOE, MOE_ROUTER, MOE_DISPATCH, MOE_EXPERTS,
     MOE_EXPERTS_GMM, MOE_COMBINE, MOE_SHARED, HEAD, OP_MAMBA2_SSD_KERNEL,
 )
+XING4_LAYERS = (
+    EMBED, OP_MLA, OP_MLA_Q, OP_MLA_KV_LATENT, OP_MLA_EXPAND, OP_MLA_ABSORB,
+    OP_MLA_ATTEND, OP_MLA_ATTEND_DECODE, OP_MLA_OUT, HYPER_CONN,
+    HYPER_CONN_MAPPINGS, HYPER_CONN_MIX, FFN_DENSE, MOE, MOE_ROUTER,
+    MOE_DISPATCH, MOE_EXPERTS, MOE_EXPERTS_GMM, MOE_COMBINE, MOE_SHARED, HEAD,
+)
 #: every policy's layers, each once, in the order they are first named
 POLICY_LAYERS = tuple(dict.fromkeys(
     LFM2_LAYERS + PHI4_FLASH_LAYERS + KEYE_VL2_LAYERS + OLMO_HYBRID_LAYERS
-    + NEMOTRON_H_LAYERS))
+    + NEMOTRON_H_LAYERS + XING4_LAYERS))
 #: the rollout's once-an-update bfloat16 snapshot of the matrix weights
 ROLLOUT_WEIGHTS_BF16 = "rollout/weights_bf16"
 

@@ -632,13 +632,14 @@ def test_this_policys_layers_are_among_the_policies_layers():
     assert profiling.scope_of(
         "jit(multi_step)/rollout/while/body/policy/moe/shared/dot_general"
     ) == "rollout/policy/moe/shared"
-    # what was there keeps its place: the new layers come after
-    before = [l for l in profiling.POLICY_LAYERS
-              if l not in (profiling.OP_MAMBA2, profiling.OP_MAMBA2_IN_PROJ,
-                           profiling.OP_MAMBA2_CONV, profiling.OP_MAMBA2_SSD,
-                           profiling.OP_MAMBA2_OUT, profiling.MOE_SHARED,
-                           profiling.OP_MAMBA2_SSD_KERNEL)]
+    # what was there keeps its place: the new layers come after (and a later
+    # policy's after them)
+    earlier = (profiling.LFM2_LAYERS + profiling.PHI4_FLASH_LAYERS
+               + profiling.KEYE_VL2_LAYERS + profiling.OLMO_HYBRID_LAYERS)
+    before = [l for l in profiling.POLICY_LAYERS if l in earlier]
     assert list(profiling.POLICY_LAYERS[:len(before)]) == before
+    own = [l for l in profiling.NEMOTRON_H_LAYERS if l not in earlier]
+    assert list(profiling.POLICY_LAYERS[len(before):len(before) + len(own)]) == own
     # the decode's kernel takes 8 query heads a K/V head and never runs here
     assert f"{profiling.OP_ATTN_FULL}/{profiling.DECODE_ATTEND}" not in (
         profiling.NEMOTRON_H_LAYERS)
